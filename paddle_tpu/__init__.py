@@ -11,12 +11,6 @@ from __future__ import annotations
 
 __version__ = "0.1.0"
 
-# forward-compat: newer-jax names (jax.shard_map, sharding.AxisType, ...)
-# installed on older jax runtimes before anything dereferences them
-from .core import jax_compat as _jax_compat
-
-_jax_compat.install()
-
 from .core import dtype as _dtype_mod
 from .core.dtype import (  # noqa: F401
     bfloat16, bool, complex64, complex128, float16, float32, float64,

@@ -27,10 +27,6 @@ def main():
     if nprocs > 1 and not os.environ.get("PADDLE_SKIP_DIST_INIT"):
         import jax
 
-        # sitecustomize-style PJRT plugins can override JAX_PLATFORMS;
-        # re-assert the env var through the config API
-        if os.environ.get("JAX_PLATFORMS"):
-            jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
         coord = (os.environ.get("PADDLE_MASTER")
                  or os.environ.get("MASTER_ADDR", "127.0.0.1"))
         port = os.environ.get("MASTER_PORT", "8471")
@@ -39,6 +35,9 @@ def main():
             num_processes=nprocs,
             process_id=int(os.environ.get("PADDLE_TRAINER_ID", "0")),
         )
+    from ...jit.cache import place_compile_cache
+
+    place_compile_cache()
     script = sys.argv[1]
     sys.argv = sys.argv[1:]
     runpy.run_path(script, run_name="__main__")

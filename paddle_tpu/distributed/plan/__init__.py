@@ -14,11 +14,11 @@ README.md's multichip recipe for the CPU-virtual-device workflow.
 
 from .compile import compile_step_with_plan  # noqa: F401
 from .mesh import AXES, make_mesh, mesh_axes  # noqa: F401
-from .plan import Plan, PlanError  # noqa: F401
+from .plan import Plan, PlanError, active_plan  # noqa: F401
 from .strategies import STRATEGIES, apply, register_strategy  # noqa: F401
 
 __all__ = [
-    "AXES", "Plan", "PlanError", "STRATEGIES", "apply",
+    "AXES", "Plan", "PlanError", "STRATEGIES", "active_plan", "apply",
     "compile_step_with_plan", "make_mesh", "mesh_axes",
     "register_strategy",
 ]

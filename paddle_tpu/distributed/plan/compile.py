@@ -19,6 +19,8 @@ mesh) or a ready ``Sharding``.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -45,6 +47,16 @@ def _resolve_tree(plan, tree):
     return jax.tree.map(conv, tree, is_leaf=is_leaf)
 
 
+def _traced_under(plan, fn):
+    """``fn`` with ``plan`` marked active while jax traces it, so the
+    Pallas kernel dispatchers inside can run per shard (``active_plan``)."""
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        with plan.tracing():
+            return fn(*args, **kwargs)
+    return traced
+
+
 def compile_step_with_plan(fn, plan=None, *, in_specs=None, out_specs=None,
                            donate_argnums=(), static_argnums=(), name=None):
     """Compile ``fn`` under a :class:`~.plan.Plan`.
@@ -63,6 +75,7 @@ def compile_step_with_plan(fn, plan=None, *, in_specs=None, out_specs=None,
     kwargs = dict(donate_argnums=tuple(donate_argnums),
                   static_argnums=tuple(static_argnums))
     if plan is not None and plan.mesh.devices.size > 1:
+        fn = _traced_under(plan, fn)
         ins = _resolve_tree(plan, in_specs)
         outs = _resolve_tree(plan, out_specs)
         if ins is not None:

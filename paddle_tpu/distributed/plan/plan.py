@@ -23,6 +23,8 @@ mesh fails with a typed error instead of mis-sharding silently.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import fnmatch
 import hashlib
 
@@ -31,7 +33,18 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .mesh import make_mesh, mesh_axes
 
-__all__ = ["Plan", "PlanError"]
+__all__ = ["Plan", "PlanError", "active_plan"]
+
+_ACTIVE = contextvars.ContextVar("paddle_tpu_active_plan", default=None)
+
+
+def active_plan():
+    """The multi-device Plan whose step is being traced right now, else
+    ``None``. GSPMD partitions everything in a planned step except Mosaic
+    custom calls ("Mosaic kernels cannot be automatically partitioned"),
+    so the Pallas kernel dispatchers read this to run themselves per
+    shard (:meth:`Plan.per_shard`)."""
+    return _ACTIVE.get()
 
 
 class PlanError(ValueError):
@@ -78,6 +91,8 @@ class Plan:
         # parameter fallback sharding axis (zero3): applied after the rule
         # table for params no rule matched
         self.param_fallback_axis: str | None = None
+        # mesh axis the attention heads are split over (set by ``tp``)
+        self.head_axis: str | None = None
         self.sep_impl: str | None = None       # "ring" | "ulysses"
         self.sep_axis: str = "sep"
         self.pp_stages: int | None = None
@@ -207,6 +222,41 @@ class Plan:
         if not getattr(arr, "ndim", 0):
             return arr
         return jax.device_put(arr, self.data_sharding(arr.ndim, arr.shape))
+
+    # -- per-shard kernels ----------------------------------------------
+    @contextlib.contextmanager
+    def tracing(self):
+        """Mark this plan active while one of its steps is traced
+        (``compile_step_with_plan`` wraps every planned step in it)."""
+        token = _ACTIVE.set(self)
+        try:
+            yield self
+        finally:
+            _ACTIVE.reset(token)
+
+    def batch_axis_for(self, n):
+        """The data-parallel axis if it splits a batch of ``n``, else
+        ``None`` (the kernel then sees the whole batch on every shard)."""
+        ax = self.data_dims.get(0)
+        if ax is None or self._axis_size(ax) == 1 or n % self._axis_size(ax):
+            return None
+        return ax
+
+    def head_axis_for(self, *head_counts):
+        """The head axis if it splits every one of ``head_counts``."""
+        ax = self.head_axis
+        if ax is None or self._axis_size(ax) == 1 or any(
+                h % self._axis_size(ax) for h in head_counts):
+            return None
+        return ax
+
+    def per_shard(self, fn, in_specs, out_specs):
+        """``fn`` as a manual region over the whole mesh: each device runs
+        it on its block of the operands. Operands that arrive laid out
+        otherwise are re-laid-out by GSPMD at the region's edge, so the
+        specs decide cost, never correctness."""
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def place_params(self, named_arrays, moments=False):
         """device_put a ``{name: array}`` tree onto the plan's layout."""

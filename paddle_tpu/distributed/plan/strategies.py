@@ -120,6 +120,10 @@ def _zero3(plan, axis="dp"):
 def _tp(plan, rules=None):
     for pattern, spec in (rules or _LLAMA_TP_RULES):
         plan.add_param_rule(pattern, spec)
+    if rules is None:
+        # column-parallel q/k/v leave the heads split over tp: the axis
+        # the attention kernels run per shard on
+        plan.head_axis = "tp"
 
 
 @register_strategy("sep")

@@ -55,17 +55,9 @@ def get_config():
 
 def _apply():
     if _CONFIG["kernel"]["enable"]:
-        import jax
+        from ..jit.cache import place_compile_cache
 
-        cache_dir = os.environ.get(
-            "PT_COMPILE_CACHE", os.path.expanduser("~/.paddle_tpu_xla_cache"))
-        os.makedirs(cache_dir, exist_ok=True)
-        try:
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.5)
-        except Exception:
-            pass  # older jax without the persistent cache config
+        place_compile_cache()
 
 
 def tuned_num_workers():

@@ -380,8 +380,7 @@ class LLMEngine:
         # (tier revive, page import, prefix-store entries) verify and
         # degrade to re-prefill on mismatch
         self.cache.page_checksums = bool(kv_page_checksums)
-        if self._mp:
-            self._globalize_cache(self.cache)
+        self._place_cache(self.cache, self.config)
         self._kv_bytes_saved = self.cache.bytes_saved_vs_unquantized(
             self.config)
         # prefix sharing (ISSUE 11): content-hashed block identity over the
@@ -492,6 +491,7 @@ class LLMEngine:
             self.draft_cache = PagedKVCache(
                 draft_model.config, num_blocks, block_size, dtype=ddtype,
                 allocator=self.cache.allocator, kv_dtype=kv_dtype)
+            self._place_cache(self.draft_cache, draft_model.config)
             self._draft_params = draft_model._unique_params()
             self._draft_prefill_name = f"llm_engine_draft_prefill#{n}"
             self._draft_decode_name = f"llm_engine_draft_decode#{n}"
@@ -684,14 +684,44 @@ class LLMEngine:
             return np.asarray(arr)
         return np.asarray(arr.addressable_data(0))
 
-    def _globalize_cache(self, cache):
-        """Re-commit freshly zeroed pool arrays (created on the local
-        default device) replicated over the global mesh so the compiled
-        steps can donate and rebind them."""
-        cache.k = [self._g(x) for x in cache.k]
-        cache.v = [self._g(x) for x in cache.v]
-        cache.k_scale = [self._g(x) for x in cache.k_scale]
-        cache.v_scale = [self._g(x) for x in cache.v_scale]
+    def _pool_specs(self, config):
+        """``(pool, scale-pool)`` PartitionSpecs of ``config``'s KV pools
+        under this engine's plan; ``None`` without a multi-device plan.
+        Replicated when the mesh spans processes (ISSUE 19: the
+        rebind/donate contract stays rank-agnostic), else kv heads over
+        the plan's head axis — the split the paged kernels run on."""
+        plan = self._plan
+        if plan is None or plan.mesh.devices.size == 1:
+            return None
+        if self._mp:
+            from jax.sharding import PartitionSpec
+            return PartitionSpec(), PartitionSpec()
+        from .paged_attention import kv_pool_specs
+        return kv_pool_specs(plan, config.num_attention_heads,
+                             config.num_key_value_heads)
+
+    def _place_cache(self, cache, config):
+        """Commit freshly zeroed pool arrays (created on the default
+        device) to their plan layout so the compiled steps can donate and
+        rebind them without a re-layout."""
+        specs = self._pool_specs(config)
+        if specs is None:
+            return
+        import jax
+        from jax.sharding import NamedSharding
+
+        pool, scale = (NamedSharding(self._plan.mesh, s) for s in specs)
+
+        def put(x, sharding):
+            # a process-spanning mesh takes host values only (every rank
+            # passes the same zeros: collective-free)
+            return jax.device_put(np.asarray(x) if self._mp else x,
+                                  sharding)
+
+        cache.k = [put(x, pool) for x in cache.k]
+        cache.v = [put(x, pool) for x in cache.v]
+        cache.k_scale = [put(x, scale) for x in cache.k_scale]
+        cache.v_scale = [put(x, scale) for x in cache.v_scale]
 
     # ------------------------------------------------------------------
     # request lifecycle
@@ -1053,7 +1083,8 @@ class LLMEngine:
                     pages = sb // block_size
                     start = jnp.asarray(start, jnp.int32)
                     upto = jnp.asarray(true_upto, jnp.int32)
-                    page0 = start // block_size
+                    blks = jax.lax.dynamic_slice(
+                        tables_row, (start // block_size,), (pages,))
                     tables2 = tables_row[None]  # [1, P]
                     x = model.llama.embed_tokens(Tensor._wrap(ids))
                     cos_t = _arr(model.llama.rope_cos)
@@ -1078,27 +1109,25 @@ class LLMEngine:
                         ka = _rope_apply_at.raw_fn(_arr(k), cos_t, sin_t,
                                                    start)
                         va = _arr(v)
-                        for j in range(pages):
-                            sl = slice(j * block_size, (j + 1) * block_size)
-                            blk = tables_row[page0 + j]
-                            if quantized:
-                                qk, sk = quantize_kv_rows(ka[0:1, sl])
-                                qv, sv = quantize_kv_rows(va[0:1, sl])
-                                kp = jax.lax.dynamic_update_slice(
-                                    kp, qk, (blk, 0, 0, 0))
-                                vp = jax.lax.dynamic_update_slice(
-                                    vp, qv, (blk, 0, 0, 0))
-                                ksc = jax.lax.dynamic_update_slice(
-                                    ksc, sk, (blk, 0, 0))
-                                vsc = jax.lax.dynamic_update_slice(
-                                    vsc, sv, (blk, 0, 0))
-                            else:
-                                kp = jax.lax.dynamic_update_slice(
-                                    kp, ka[0:1, sl].astype(kp.dtype),
-                                    (blk, 0, 0, 0))
-                                vp = jax.lax.dynamic_update_slice(
-                                    vp, va[0:1, sl].astype(vp.dtype),
-                                    (blk, 0, 0, 0))
+                        # one scatter per pool: the chunk's pages land
+                        # in its blocks at once (a page-by-page
+                        # dynamic_update_slice loop made the 128-page top
+                        # bucket a minutes-long compile). Bucket pages
+                        # past the request's blocks all hit null block 0.
+                        def paged(a):
+                            return a.reshape((pages, block_size)
+                                             + a.shape[2:])
+
+                        if quantized:
+                            qk, sk = quantize_kv_rows(ka)
+                            qv, sv = quantize_kv_rows(va)
+                            kp = kp.at[blks].set(paged(qk))
+                            vp = vp.at[blks].set(paged(qv))
+                            ksc = ksc.at[blks].set(paged(sk))
+                            vsc = vsc.at[blks].set(paged(sv))
+                        else:
+                            kp = kp.at[blks].set(paged(ka).astype(kp.dtype))
+                            vp = vp.at[blks].set(paged(va).astype(vp.dtype))
                         out = paged_multiquery_attention(
                             qa, kp, vp, tables2, upto[None], start[None],
                             scale=1.0 / math.sqrt(attn.head_dim),
@@ -1150,6 +1179,7 @@ class LLMEngine:
             import jax
             import jax.numpy as jnp
 
+            from ...models.llama import rope_rotate
             from ...ops import manipulation as M
             from .kv_cache import quantize_kv_rows
             from .paged_attention import paged_decode_attention
@@ -1177,16 +1207,8 @@ class LLMEngine:
                               [bsz, 1, attn.num_kv_heads,
                                attn.head_dim])
 
-                def rope(t):
-                    a = _arr(t)
-                    d2 = a.shape[-1] // 2
-                    a1, a2 = a[..., :d2], a[..., d2:]
-                    cc = c.astype(a.dtype)
-                    ss = sn.astype(a.dtype)
-                    return jnp.concatenate(
-                        [a1 * cc - a2 * ss, a2 * cc + a1 * ss], -1)
-
-                qa, ka, va = rope(q), rope(k), _arr(v)
+                qa = rope_rotate(_arr(q), c, sn)
+                ka, va = rope_rotate(_arr(k), c, sn), _arr(v)
                 blk = tables[jnp.arange(bsz),
                              positions // block_size]
                 off = positions % block_size
@@ -1438,6 +1460,7 @@ class LLMEngine:
             import jax
             import jax.numpy as jnp
 
+            from ...models.llama import rope_rotate
             from ...ops import manipulation as M
             from .kv_cache import quantize_kv_rows
             from .paged_attention import paged_multiquery_attention
@@ -1474,16 +1497,8 @@ class LLMEngine:
                                       [bsz, t_q, attn.num_kv_heads,
                                        attn.head_dim])
 
-                        def rope(t):
-                            a = _arr(t)
-                            d2 = a.shape[-1] // 2
-                            a1, a2 = a[..., :d2], a[..., d2:]
-                            cc = c.astype(a.dtype)
-                            ss = sn.astype(a.dtype)
-                            return jnp.concatenate(
-                                [a1 * cc - a2 * ss, a2 * cc + a1 * ss], -1)
-
-                        qa, ka, va = rope(q), rope(k), _arr(v)
+                        qa = rope_rotate(_arr(q), c, sn)
+                        ka, va = rope_rotate(_arr(k), c, sn), _arr(v)
                         blk = tables[jnp.arange(bsz)[:, None],
                                      pos_grid // block_size]
                         off = pos_grid % block_size
@@ -1551,33 +1566,35 @@ class LLMEngine:
     def _build_jits(self):
         from ...distributed.plan import compile_step_with_plan
 
-        # process-spanning mesh: pin EVERY output replicated (a single
-        # PartitionSpec leaf is a prefix pytree covering all outputs).
-        # Logits/tokens must be replicated so every rank's host fetch
-        # reads the same value from its addressable shard; pools ride
-        # along replicated, which costs an allgather on the sharded
-        # attention writes but keeps the engine's rebind/donate contract
-        # rank-agnostic.
-        mp_out = None
-        if self._mp:
-            from jax.sharding import PartitionSpec
-            mp_out = PartitionSpec()
+        # Under a multi-device plan every executable's pool outputs are
+        # pinned to the layout the pools were committed to, so a donated
+        # round-trip hands back the same layout instead of whatever GSPMD
+        # propagated (a drift costs a recompile and a re-layout per
+        # step). On a process-spanning mesh that layout is replicated and
+        # the leading outputs (logits/tokens) are pinned replicated too:
+        # every rank's host fetch must read the same value from its
+        # addressable shard.
+        pool_out = None
+        specs = self._pool_specs(self.config)
+        if specs is not None:
+            pool, scale = specs
+            pool_out = (pool if self._mp else None, pool, pool, scale, scale)
         # scale pools donate beside the payload pools (empty pytrees on
         # the fp path — a zero-leaf donation is a no-op)
         self._prefill_jit = compile_step_with_plan(
             self._make_chunk_fn(self.model, self._params), self._plan,
             name=self._prefill_name, donate_argnums=(5, 6, 7, 8),
-            out_specs=mp_out)
+            out_specs=pool_out)
         self._decode_jit = compile_step_with_plan(
             self._make_decode_fn(self.model, self._params), self._plan,
             name=self._decode_name, donate_argnums=(4, 5, 6, 7),
-            out_specs=mp_out)
+            out_specs=pool_out)
         if self._in_graph:
             self._window_jit = compile_step_with_plan(
                 self._make_window_fn(self.model, self._params,
                                      self._decode_window),
                 self._plan, name=self._window_name,
-                donate_argnums=(7, 8, 9, 10), out_specs=mp_out)
+                donate_argnums=(7, 8, 9, 10), out_specs=pool_out)
         if self.draft_model is not None:
             self._draft_prefill_jit = compile_step_with_plan(
                 self._make_chunk_fn(self.draft_model, self._draft_params),

@@ -243,6 +243,7 @@ def replica_worker_main():
     import numpy as np
 
     from ....distributed.launch import heartbeat as hb
+    from ....jit.cache import place_compile_cache
     from ....utils import fault_injection as fi
     from .. import integrity as _integrity
     from ..engine import LLMEngine, load_llama_artifact
@@ -250,6 +251,7 @@ def replica_worker_main():
     from ..kv_cache import pack_kv_pages, unpack_kv_pages
     from ..scheduler import SamplingParams
 
+    place_compile_cache()
     model = load_llama_artifact(cfg["artifact"])
     role = cfg.get("role") or "both"
     engine_kw = dict(cfg.get("engine") or {})

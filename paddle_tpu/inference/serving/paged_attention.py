@@ -21,7 +21,37 @@ import jax.numpy as jnp
 
 from ...nn.functional.flash_attention import _sdpa_ref
 
-__all__ = ["paged_decode_attention", "paged_multiquery_attention"]
+__all__ = ["paged_decode_attention", "paged_multiquery_attention",
+           "kv_pool_specs"]
+
+
+def kv_pool_specs(plan, num_heads, num_kv_heads):
+    """``(pool spec, scale-pool spec)`` of the paged KV pools
+    ``[N, block, Hkv, D]`` / ``[N, block, Hkv]`` under a plan: kv heads
+    over the plan's head axis when it divides both head counts, else
+    replicated. The engine commits its pools to these and the kernels
+    below run per shard on the same split."""
+    from jax.sharding import PartitionSpec as P
+
+    ax = plan.head_axis_for(num_heads, num_kv_heads)
+    return P(None, None, ax, None), P(None, None, ax)
+
+
+def _per_shard_paged(kernel, q, k_pool, quantized, n_index):
+    """The Pallas paged kernel, run per shard when a multi-device plan is
+    tracing: q heads and pool kv heads split over the plan's head axis,
+    the ``n_index`` block-table/length operands replicated."""
+    from ...distributed.plan import active_plan
+    from jax.sharding import PartitionSpec as P
+
+    plan = active_plan()
+    if plan is None:
+        return kernel
+    pool, scale = kv_pool_specs(plan, q.shape[-2], k_pool.shape[2])
+    q_spec = P(*([None] * (q.ndim - 2)), pool[2], None)
+    in_specs = (q_spec, pool, pool) + (P(),) * n_index \
+        + ((scale, scale) if quantized else ())
+    return plan.per_shard(kernel, in_specs, q_spec)
 
 
 def _gather_kv(pool, scale_pool, block_tables):
@@ -69,9 +99,14 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, context_lens,
         paged_decode_attention_pallas, use_pallas_paged)
 
     if use_pallas_paged(d, block_size):
-        out = paged_decode_attention_pallas(
-            q[:, 0], k_pool, v_pool, block_tables, context_lens, scale,
-            k_scale=k_scale, v_scale=v_scale)
+        def kernel(q, k_pool, v_pool, tables, lens, *scales):
+            return paged_decode_attention_pallas(
+                q, k_pool, v_pool, tables, lens, scale,
+                **dict(zip(("k_scale", "v_scale"), scales)))
+
+        scales = () if k_scale is None else (k_scale, v_scale)
+        out = _per_shard_paged(kernel, q[:, 0], k_pool, bool(scales), 2)(
+            q[:, 0], k_pool, v_pool, block_tables, context_lens, *scales)
         return out[:, None]
     return _lax_fallback(q, k_pool, v_pool, block_tables, context_lens,
                          float(scale), k_scale=k_scale, v_scale=v_scale)
@@ -118,9 +153,14 @@ def paged_multiquery_attention(q, k_pool, v_pool, block_tables, context_lens,
         paged_multiquery_attention_pallas, use_pallas_paged)
 
     if use_pallas_paged(d, block_size):
-        return paged_multiquery_attention_pallas(
-            q, k_pool, v_pool, block_tables, context_lens, q_start,
-            float(scale), k_scale=k_scale, v_scale=v_scale)
+        def kernel(q, k_pool, v_pool, tables, lens, starts, *scales):
+            return paged_multiquery_attention_pallas(
+                q, k_pool, v_pool, tables, lens, starts, float(scale),
+                **dict(zip(("k_scale", "v_scale"), scales)))
+
+        scales = () if k_scale is None else (k_scale, v_scale)
+        return _per_shard_paged(kernel, q, k_pool, bool(scales), 3)(
+            q, k_pool, v_pool, block_tables, context_lens, q_start, *scales)
     return _lax_multiquery_fallback(q, k_pool, v_pool, block_tables,
                                     context_lens, q_start, float(scale),
                                     k_scale=k_scale, v_scale=v_scale)

@@ -1,8 +1,11 @@
 """ctypes bindings to the C++ data-pipeline core (csrc/prefetch.cpp).
 
-Builds the shared library on demand with g++ (cached next to the source).
-Every entry point degrades gracefully: ``available()`` is False when no
-toolchain exists and callers fall back to the numpy path.
+Builds the shared library on demand with g++, cached next to the source
+under a name keyed by the source's hash — a binary built from any other
+``prefetch.cpp`` has another name and is never loaded (after a copy or a
+checkout, mtimes say nothing). Every entry point degrades gracefully:
+``available()`` is False when no toolchain exists and callers fall back to
+the numpy path.
 
 Why native: ctypes foreign calls release the GIL, so batch collation and
 image normalization run concurrently with Python-side sample loading and
@@ -14,6 +17,8 @@ with the training loop — the role the reference fills with C++ DataFeed
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -25,33 +30,48 @@ __all__ = ["available", "lib", "collate_samples", "normalize_image_batch",
 
 _CSRC = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "csrc")
-_LIB_PATH = os.path.join(_CSRC, "libpaddle_tpu_native.so")
+_SRC = os.path.join(_CSRC, "prefetch.cpp")
 
 _lib = None
 _tried = False
 _build_lock = threading.Lock()
 
 
-def _build():
-    src = os.path.join(_CSRC, "prefetch.cpp")
-    if not os.path.exists(src):
-        return False
+def _lib_path():
+    """Where the binary built from the CURRENT source lives (None when the
+    source is absent)."""
+    try:
+        with open(_SRC, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    except OSError:
+        return None
+    return os.path.join(_CSRC, f"libpaddle_tpu_native.{digest}.so")
+
+
+def _build(lib_path):
     # compile to a private temp file and atomically rename into place, so a
     # sibling launcher rank never dlopens a half-written .so
-    tmp = _LIB_PATH + f".tmp.{os.getpid()}"
+    tmp = lib_path + f".tmp.{os.getpid()}"
     try:
         subprocess.run(
             ["g++", "-O3", "-std=c++17", "-fPIC", "-pthread", "-shared",
-             "-o", tmp, src],
+             "-o", tmp, _SRC],
             check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _LIB_PATH)
-        return True
+        os.replace(tmp, lib_path)
     except (OSError, subprocess.SubprocessError):
         try:
             os.remove(tmp)
         except OSError:
             pass
         return False
+    # binaries of earlier sources are dead weight now
+    for old in glob.glob(os.path.join(_CSRC, "libpaddle_tpu_native.*.so")):
+        if old != lib_path:
+            try:
+                os.remove(old)
+            except OSError:
+                pass
+    return True
 
 
 def lib_ready():
@@ -80,15 +100,13 @@ def lib():
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        src = os.path.join(_CSRC, "prefetch.cpp")
-        stale = (os.path.exists(_LIB_PATH) and os.path.exists(src)
-                 and os.path.getmtime(_LIB_PATH) < os.path.getmtime(src))
-        if not os.path.exists(_LIB_PATH) or stale:
-            if not _build() and not os.path.exists(_LIB_PATH):
-                return None
-            # rebuild failure with a stale-but-loadable .so on disk: use it
+        lib_path = _lib_path()
+        if lib_path is None:
+            return None
+        if not os.path.exists(lib_path) and not _build(lib_path):
+            return None
         try:
-            L = ctypes.CDLL(_LIB_PATH)
+            L = ctypes.CDLL(lib_path)
         except OSError:
             return None
         L.pt_collate.argtypes = [

@@ -26,7 +26,6 @@ import warnings
 
 import numpy as np
 import jax
-import jax.export  # noqa: F401  (submodule not auto-imported on jax 0.4.3x)
 import jax.numpy as jnp
 
 from ..core import rng as rng_mod

@@ -79,15 +79,25 @@ def _rope_cache(seq_len, head_dim, theta, dtype=np.float32):
 from ..core.dispatch import op as _op
 
 
-@_op("rope_apply")
-def _rope_apply(x, cos, sin):
+def rope_rotate(x, c, s):
+    """Rotary embedding of ``x [..., D]`` by half-width ``c``/``s``
+    (broadcastable against ``x[..., :D/2]``): ``[x1*c - x2*s, x2*c +
+    x1*s]``, written as two full-width multiplies around one lane roll.
+    Bit-identical to the split-and-concatenate form (negation and
+    ``a + (-b)`` are exact) — which, compiled on its own at head-dim 128,
+    aborts libtpu 0.0.34's fusion emitter (``IsFusibleUnalignedDUS``: the
+    concatenate lands at lane offset 64), so an eager forward killed the
+    process. Every rope in the repo goes through here."""
     import jax.numpy as jnp
 
-    d2 = x.shape[-1] // 2
-    x1, x2 = x[..., :d2], x[..., d2:]
-    c = cos[None, :, None, :].astype(x.dtype)
-    s = sin[None, :, None, :].astype(x.dtype)
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+    c = jnp.concatenate([c, c], axis=-1).astype(x.dtype)
+    s = jnp.concatenate([-s, s], axis=-1).astype(x.dtype)
+    return x * c + jnp.roll(x, x.shape[-1] // 2, axis=-1) * s
+
+
+@_op("rope_apply")
+def _rope_apply(x, cos, sin):
+    return rope_rotate(x, cos[None, :, None, :], sin[None, :, None, :])
 
 
 def apply_rope(x, cos, sin):
@@ -108,10 +118,7 @@ def _rope_apply_at(x, cos_t, sin_t, pos):
     pos = jnp.asarray(pos, jnp.int32)
     cos = jax.lax.dynamic_slice(cos_t, (pos, jnp.int32(0)), (s, d2))
     sin = jax.lax.dynamic_slice(sin_t, (pos, jnp.int32(0)), (s, d2))
-    x1, x2 = x[..., :d2], x[..., d2:]
-    c = cos[None, :, None, :].astype(x.dtype)
-    sn = sin[None, :, None, :].astype(x.dtype)
-    return jnp.concatenate([x1 * c - x2 * sn, x2 * c + x1 * sn], axis=-1)
+    return rope_rotate(x, cos[None, :, None, :], sin[None, :, None, :])
 
 
 @_op("llama_cached_attn_step")

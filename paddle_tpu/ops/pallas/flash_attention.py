@@ -371,22 +371,45 @@ _flash_mha_rope.defvjp(_flash_mha_rope_fwd, _flash_mha_rope_bwd)
 from ...core.dispatch import op as _op
 
 
+def _run_bshd(mha, q, k, v, *tables):
+    """Run a ``[B*H, S, D]`` flash kernel ``mha(qt, kt, vt, *tables, bq,
+    bk)`` on paddle-layout ``[B, S, H, D]`` operands (GQA: kv heads
+    broadcast). When a multi-device plan is tracing, the call runs per
+    shard — GSPMD cannot partition a Mosaic call: batch over the plan's
+    data axis, heads over its head axis, each only where it divides;
+    ``tables`` (rope) are replicated."""
+    from ...distributed.plan import active_plan
+    from jax.sharding import PartitionSpec as P
+
+    def local(q, k, v, *tables):
+        b, sq, hq, d = q.shape
+        hk = k.shape[2]
+        if hk != hq:
+            rep = hq // hk
+            k = jnp.repeat(k, rep, axis=2)
+            v = jnp.repeat(v, rep, axis=2)
+        qt = jnp.swapaxes(q, 1, 2).reshape(b * hq, sq, d)
+        kt = jnp.swapaxes(k, 1, 2).reshape(b * hq, k.shape[1], d)
+        vt = jnp.swapaxes(v, 1, 2).reshape(b * hq, v.shape[1], d)
+        out = mha(qt, kt, vt, *tables, *_block_sizes(sq, kt.shape[1]))
+        return jnp.swapaxes(out.reshape(b, hq, sq, d), 1, 2)
+
+    plan = active_plan()
+    if plan is not None:
+        spec = P(plan.batch_axis_for(q.shape[0]), None,
+                 plan.head_axis_for(q.shape[2], k.shape[2]), None)
+        local = plan.per_shard(
+            local, (spec,) * 3 + (P(),) * len(tables), spec)
+    return local(q, k, v, *tables)
+
+
 @_op("flash_attention_pallas")
 def _flash_attention_arrays(q, k, v, causal=True, scale=None):
     """q/k/v: [B, S, H, D] (paddle layout). GQA: kv heads broadcast."""
-    b, sq, hq, d = q.shape
-    hk = k.shape[2]
-    if hk != hq:
-        rep = hq // hk
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
-    s = scale if scale is not None else 1.0 / math.sqrt(d)
-    qt = jnp.swapaxes(q, 1, 2).reshape(b * hq, sq, d)
-    kt = jnp.swapaxes(k, 1, 2).reshape(b * hq, k.shape[1], d)
-    vt = jnp.swapaxes(v, 1, 2).reshape(b * hq, v.shape[1], d)
-    bq, bk = _block_sizes(sq, kt.shape[1])
-    out = _flash_mha(qt, kt, vt, float(s), bool(causal), bq, bk)
-    return jnp.swapaxes(out.reshape(b, hq, sq, d), 1, 2)
+    s = float(scale if scale is not None else 1.0 / math.sqrt(q.shape[3]))
+    return _run_bshd(
+        lambda qt, kt, vt, bq, bk: _flash_mha(qt, kt, vt, s, bool(causal),
+                                              bq, bk), q, k, v)
 
 
 def flash_attention_fwd(q, k, v, causal=True, scale=None):
@@ -414,20 +437,11 @@ def _rope_widened(x, c2, s2):
 def _flash_attention_rope_arrays(q, k, v, cos, sin, causal=True, scale=None):
     """Rope-fused flash attention. q/k/v: [B, S, H, D] PRE-rotary;
     cos/sin: [S, D/2] rope tables (models/llama.py:_rope_cache layout)."""
-    b, sq, hq, d = q.shape
-    hk = k.shape[2]
-    if hk != hq:
-        rep = hq // hk
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
-    s = scale if scale is not None else 1.0 / math.sqrt(d)
-    c2, s2 = _widen_tables(cos, sin)
-    qt = jnp.swapaxes(q, 1, 2).reshape(b * hq, sq, d)
-    kt = jnp.swapaxes(k, 1, 2).reshape(b * hq, k.shape[1], d)
-    vt = jnp.swapaxes(v, 1, 2).reshape(b * hq, v.shape[1], d)
-    bq, bk = _block_sizes(sq, kt.shape[1])
-    out = _flash_mha_rope(qt, kt, vt, c2, s2, float(s), bool(causal), bq, bk)
-    return jnp.swapaxes(out.reshape(b, hq, sq, d), 1, 2)
+    s = float(scale if scale is not None else 1.0 / math.sqrt(q.shape[3]))
+    return _run_bshd(
+        lambda qt, kt, vt, c2, s2, bq, bk: _flash_mha_rope(
+            qt, kt, vt, c2, s2, s, bool(causal), bq, bk),
+        q, k, v, *_widen_tables(cos, sin))
 
 
 def flash_attention_rope_fwd(q, k, v, cos, sin, causal=True, scale=None):

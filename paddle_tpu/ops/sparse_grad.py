@@ -1,7 +1,7 @@
 """Row-sparse gradient representation for embedding lookups.
 
-The problem (PERF.md round 6, BENCH_r05): DeepFM's 1M-row embedding tables
-train at 0.4% MFU because every step materializes a dense ``[vocab, dim]``
+The problem (PERF.md; round-5 chip run on an earlier installation, not
+reproduced): DeepFM's 1M-row embedding tables train at 0.4% MFU because every step materializes a dense ``[vocab, dim]``
 gradient (the transpose of the gather is a vocab-sized scatter-add) and the
 optimizer then streams the full table plus BOTH Adam moments through HBM to
 update the ~0.04% of rows a batch actually touches. The reference's answer

@@ -1700,6 +1700,9 @@ def main():
                     help="CPU smoke sizing (llama_tiny)")
     args = ap.parse_args()
 
+    from paddle_tpu.jit.cache import place_compile_cache
+
+    place_compile_cache()
     tiny = args.tiny
     if not tiny:
         try:

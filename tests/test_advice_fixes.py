@@ -300,23 +300,6 @@ class TestRound4AdviceFixes:
         outs = eng.predict(DS(), batch_size=2)
         assert len(outs) == 2 and outs[0].shape == (2, 1)
 
-    def test_fft_numpy_fallback_refuses_live_grad(self, monkeypatch):
-        """ADVICE r3: the host fft fallback must raise instead of silently
-        detaching a grad-requiring input."""
-        import paddle_tpu.fft as pfft
-
-        monkeypatch.setattr(pfft, "_COMPLEX_OK", False)
-        x = paddle.to_tensor(np.random.randn(8).astype("float32"))
-        x.stop_gradient = False
-        with pytest.raises(RuntimeError, match="fallback"):
-            pfft.fft(x)
-        # detached input still works
-        y = paddle.to_tensor(np.random.randn(8).astype("float32"))
-        out = pfft.fft(y)
-        np.testing.assert_allclose(np.asarray(out._data),
-                                   np.fft.fft(np.asarray(y._data)),
-                                   rtol=1e-5)
-
     def test_vjp_none_grad_slot_matches_primal_shape(self):
         """ADVICE r3: float0/None grad slots must carry primal-shaped zeros,
         not 0-d scalars."""

@@ -459,3 +459,39 @@ class TestDominantLengthRule:
             out = fwd(ids, mask=mask)
             assert out.shape == [2, 2]
         assert jit.cache_stats(fwd._stats_name)["compiles"] == 2
+
+
+class TestCompileCachePlacement:
+    """jit.cache.place_compile_cache (ISSUE 21): the persistent compile
+    cache is placed from outside when JAX_COMPILATION_CACHE_DIR is set,
+    else at a fixed path inside the checkout."""
+
+    def test_env_set_leaves_the_config_alone(self, monkeypatch, tmp_path):
+        import jax
+
+        from paddle_tpu.jit.cache import place_compile_cache
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        calls = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda *a, **k: calls.append(a))
+        assert place_compile_cache() == str(tmp_path)
+        assert calls == []
+
+    def test_unset_places_it_in_the_checkout(self, monkeypatch):
+        import os
+
+        import jax
+
+        from paddle_tpu.jit.cache import place_compile_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            path = place_compile_cache()
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert path == os.path.join(repo, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+

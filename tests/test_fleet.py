@@ -108,6 +108,30 @@ PROMPT = np.arange(1, 7, dtype=np.int32)
 # typed errors
 # ---------------------------------------------------------------------------
 
+class TestOneProcessPerChip:
+    def test_imports_initialise_no_backend(self):
+        """A chip belongs to one process: the parent that only routes or
+        launches must be able to import the package, the fleet router and
+        the launcher without touching a JAX backend (ISSUE 21)."""
+        import os
+        import subprocess
+        import sys
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        code = (
+            f"import sys; sys.path.insert(0, {repo!r})\n"
+            "import paddle_tpu\n"
+            "import paddle_tpu.inference.serving.fleet.router\n"
+            "import paddle_tpu.inference.serving.fleet.supervisor\n"
+            "import paddle_tpu.distributed.launch\n"
+            "import paddle_tpu.distributed.launch.bootstrap\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n")
+        r = subprocess.run([sys.executable, "-c", code],
+                           capture_output=True, text=True, timeout=300)
+        assert r.returncode == 0, r.stderr[-2000:]
+
+
 class TestTypedErrors:
     def test_hierarchy_and_exports(self):
         from paddle_tpu.distributed.launch import (CrashLoopError,

@@ -697,7 +697,7 @@ class TestHapiPlan:
 # ---------------------------------------------------------------------------
 
 class TestEnginePlan:
-    def _tokens(self, plan):
+    def _tokens(self, plan, kv_dtype=None):
         from paddle_tpu.inference.serving import LLMEngine
         from paddle_tpu.models import LlamaForCausalLM, llama_tiny
 
@@ -706,17 +706,69 @@ class TestEnginePlan:
         model.eval()
         eng = LLMEngine(model, num_blocks=16, block_size=8,
                         max_batch_size=2, max_model_len=64,
-                        ingest_async=False, plan=plan)
+                        ingest_async=False, plan=plan, kv_dtype=kv_dtype)
         try:
-            return eng.generate([list(range(1, 9))])[0]
+            toks = eng.generate([list(range(1, 9))])[0]
+            return toks, eng.cache.k[0].sharding
         finally:
             eng.close()
 
     def test_tp_planned_decode_bitexact_vs_unplanned(self):
-        base = self._tokens(None)
+        base, _ = self._tokens(None)
         plan = Plan.build({"tp": 2}, ["tp"])
-        got = self._tokens(plan)
+        got, _ = self._tokens(plan)
         assert list(got) == list(base)
+
+    def test_paged_kernels_run_per_shard_under_a_plan(self, monkeypatch):
+        """On a TPU a Mosaic call inside a GSPMD step must sit in a manual
+        region (ISSUE 21). Interpret mode puts the Pallas paged kernels on
+        the planned path here (int8: scale pools ride along): pools stay
+        head-sharded through donated round-trips and the tokens match the
+        unplanned engine's."""
+        monkeypatch.setenv("PT_PALLAS_INTERPRET", "1")
+        base, _ = self._tokens(None, "int8")
+        plan = Plan.build({"tp": 2}, ["tp"])
+        got, pool = self._tokens(plan, "int8")
+        assert list(got) == list(base)
+        assert pool.spec == P(None, None, "tp", None)
+        assert len(pool.device_set) == 2
+
+
+class TestFlashPerShard:
+    def test_flash_kernel_under_plan_matches_unsharded(self, monkeypatch):
+        """The flash kernels run per shard (batch over dp, heads over tp)
+        when a multi-device plan traces the step; forward and gradients
+        equal the single-device call."""
+        import jax.numpy as jnp
+
+        from paddle_tpu.ops.pallas.flash_attention import (
+            _flash_attention_arrays)
+
+        monkeypatch.setenv("PT_PALLAS_INTERPRET", "1")
+        attn = _flash_attention_arrays.raw_fn
+        rng = np.random.RandomState(0)
+        q, k, v = (jnp.asarray(rng.randn(4, 128, 4, 64), jnp.float32)
+                   for _ in range(3))
+
+        def loss(q, k, v):
+            return (attn(q, k, v, causal=True) ** 2).sum()
+
+        plan = Plan.build({"dp": 2, "tp": 2}, ["dp", "tp"])
+        want = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, k, v)
+        got = compile_step_with_plan(
+            jax.value_and_grad(loss, argnums=(0, 1, 2)), plan)(
+                *(plan.place_data(x) for x in (q, k, v)))
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-5, atol=2e-5)
+
+    def test_axes_that_do_not_divide_stay_whole(self):
+        plan = Plan.build({"dp": 2, "tp": 2}, ["dp", "tp"])
+        assert plan.batch_axis_for(4) == "dp"
+        assert plan.batch_axis_for(3) is None
+        assert plan.head_axis_for(4, 2) == "tp"
+        assert plan.head_axis_for(4, 1) is None
+        assert Plan.build({"dp": 4}, ["dp"]).head_axis_for(4) is None
 
 
 # ---------------------------------------------------------------------------
