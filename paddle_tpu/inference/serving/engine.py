@@ -90,6 +90,11 @@ _H_TTFT = _obs_metrics.histogram(
 _H_ITL = _obs_metrics.histogram(
     "serving_itl_ms", "inter-token latency per decoded token",
     buckets=_obs_metrics.DEFAULT_MS_BUCKETS)
+_H_QUEUE_WAIT = _obs_metrics.histogram(
+    "serving_queue_wait_ms", "time a request waited for admission: from "
+    "when it was due (add_request's arrival_t) or else accepted, or from "
+    "its re-queue after an eviction, to the step that admitted it",
+    buckets=_obs_metrics.DEFAULT_MS_BUCKETS)
 _M_TOKENS = _obs_metrics.counter(
     "serving_tokens_out_total", "tokens sampled across all requests")
 _M_PREFILLS = _obs_metrics.counter(
@@ -151,6 +156,7 @@ _SERVING_METRICS = (_M_ADMITTED, _M_EVICTIONS, _M_FINISHED, _M_QUEUED_EXH,
                     _M_PREFIX_REUSED, _M_COW, _M_PREFILLS,
                     _M_PREFILL_CHUNKS, _M_SPEC_PROPOSED, _M_SPEC_ACCEPTED,
                     _M_TOKENS, _M_DEADLINE, _M_KV_SAVED, _H_TTFT, _H_ITL,
+                    _H_QUEUE_WAIT,
                     _G_SPEC_RATIO, _G_KV_UTIL, _G_OCCUPANCY,
                     _G_QUANT_BLOCKS,
                     # KV tiering + prefix store (ISSUE 16);
@@ -169,6 +175,40 @@ _SERVING_METRICS = (_M_ADMITTED, _M_EVICTIONS, _M_FINISHED, _M_QUEUED_EXH,
                     # serving integrity (ISSUE 20)
                     _M_PAGES_VERIFIED, _M_PAGES_REJECTED,
                     _M_WEIGHT_AUDIT_FAIL)
+
+
+class _StepPhases:
+    """The phases of one engine step as spans (``observability.trace``):
+    ``begin(name)`` ends the phase that was open and opens the next, so the
+    phases of a step follow each other and never overlap, whichever decode
+    path the step takes. Same names on every path:
+
+    ``engine.admit`` (ingest drain, deadline scan, admission, tier
+    revivals), ``engine.prefill`` (one chunk: dispatch, and on the last
+    chunk the fetch and the first token), ``engine.decode.prepare`` (decode
+    room, copy-on-write, the ready list, the step's inputs and their
+    puts), ``engine.decode.dispatch`` (the call of the decode / window /
+    verify executable until it returns), ``engine.decode.fetch`` (the wait
+    for the device, then the transfer), ``engine.decode.emit`` (sampling,
+    commit, latency observations, finishes), ``engine.bookkeeping`` (store
+    autosave, gauges); the speculative path adds ``engine.decode.draft``
+    (the draft model's catch-up and proposals, with their own fetches).
+    All lie inside the step's ``engine.step`` and carry its ``args``."""
+
+    __slots__ = ("args", "_open")
+
+    def __init__(self):
+        self.args = None
+        self._open = None
+
+    def begin(self, name):
+        self.end()
+        self._open = _obs_trace.span(name, cat="engine", args=self.args)
+
+    def end(self):
+        if self._open is not None:
+            self._open.end()
+            self._open = None
 
 
 @dataclasses.dataclass
@@ -581,6 +621,7 @@ class LLMEngine:
         self._ingest = (_IngestThread(self._stage_request, self._name)
                         if ingest_async else None)
         self.stats_extra = {"steps": 0, "prefills": 0, "tokens_out": 0}
+        self._phases = _StepPhases()
 
     # ------------------------------------------------------------------
     # persistent prefix store (ISSUE 16)
@@ -762,6 +803,13 @@ class LLMEngine:
         (blocks freed, slot recycled, stream finished with reason
         ``"timeout"``).
 
+        ``arrival_t`` is the time the request was DUE, in seconds on the
+        ``time.perf_counter()`` clock: an open-loop caller that runs late
+        passes the scheduled send time, and TTFT, the ``request.queued``
+        span and ``serving_queue_wait_ms`` then count from it, so the wait
+        a stall imposes on later requests is not hidden. Without it they
+        count from the moment the engine accepted the request.
+
         ``tenant``/``tier`` (ISSUE 17) attach a QoS identity — defaults
         (``"default"``/latency) keep the exact pre-QoS FIFO behavior."""
         self._ensure_open()
@@ -770,12 +818,15 @@ class LLMEngine:
                 f"deadline {deadline} already expired at admission "
                 f"(now={time.time():.3f}); request rejected before any "
                 "block allocation", deadline=deadline)
-        req = Request(prompt_ids, sampling, arrival_t=arrival_t,
-                      deadline=deadline, tenant=tenant, tier=tier)
+        req = Request(prompt_ids, sampling, deadline=deadline,
+                      tenant=tenant, tier=tier)
         self._check_admissible(req)
-        # observability clock zero: TTFT and the queued span both measure
-        # from the moment the engine accepted the request
-        req.t_submit = req.t_queue_start = time.perf_counter_ns()
+        # observability clock zero: TTFT, the queued span and the queue
+        # wait measure from the due time if the caller gave one, else from
+        # the moment the engine accepted the request
+        req.t_submit = req.t_queue_start = (
+            time.perf_counter_ns() if arrival_t is None
+            else int(float(arrival_t) * 1e9))
         self._requests[req.rid] = req
         if self._ingest is not None:
             self._ingest.submit(req)
@@ -956,11 +1007,13 @@ class LLMEngine:
             self.prefix_cache.register(req.tokens, req.blocks,
                                        req.num_cached, tenant=req.tenant)
         req.t_decode_start = time.perf_counter_ns()
-        _obs_trace.add_complete(
-            "request.import", getattr(req, "_t_admit", req.t_queue_start),
-            req.t_decode_start, cat="request", tid=req.rid,
-            args={"rid": req.rid, "engine": self._name,
-                  "covered": req.num_cached})
+        if _obs_trace.enabled():
+            _obs_trace.add_complete(
+                "request.import",
+                getattr(req, "_t_admit", req.t_queue_start),
+                req.t_decode_start, cat="request", tid=req.rid,
+                args={"rid": req.rid, "engine": self._name,
+                      "covered": req.num_cached})
 
     def request(self, rid):
         return self._requests[rid]
@@ -1662,8 +1715,7 @@ class LLMEngine:
         ``req`` starting at ``start`` in the pool(s); on the final chunk,
         sample the first output token from the chunk's last-position
         logits."""
-        import jax.numpy as jnp
-
+        self._phases.begin("engine.prefill")
         staged = getattr(req, "_staged", None)
         if staged is None or staged[2] != req.prefill_upto:
             self._stage_request(req)  # re-prefill after eviction
@@ -1731,23 +1783,37 @@ class LLMEngine:
             # the prefill span closes right after it
             outputs.extend(self._emit(req, self._fetch(logits)[0]))
             req.t_decode_start = time.perf_counter_ns()
-            _obs_trace.add_complete(
-                "request.prefill",
-                getattr(req, "_t_admit", req.t_queue_start),
-                req.t_decode_start, cat="request", tid=req.rid,
-                args={"rid": req.rid, "engine": self._name,
-                      "bucket": bucket, "true_len": req.prefill_upto})
+            if _obs_trace.enabled():
+                _obs_trace.add_complete(
+                    "request.prefill",
+                    getattr(req, "_t_admit", req.t_queue_start),
+                    req.t_decode_start, cat="request", tid=req.rid,
+                    args={"rid": req.rid, "engine": self._name,
+                          "bucket": bucket, "true_len": req.prefill_upto})
 
     def step(self):
         """One engine tick: drain ingest, admit, advance chunked prefills
         under the token budget, one decode (or speculative verify) for all
-        decode-ready slots. Returns the ``StepOutput`` tokens produced."""
-        import jax.numpy as jnp
+        decode-ready slots. Returns the ``StepOutput`` tokens produced.
 
+        The call is one ``engine.step`` span cut into the phases of
+        ``_StepPhases``; each carries the instance's name and, once the
+        tick is known to have work, the step's number."""
         self._ensure_open()
         if self._decode_jit is None:
             self._build_jits()
+        phases = self._phases
+        phases.args = ({"engine": self._name} if _obs_trace.enabled()
+                       else None)
+        with _obs_trace.span("engine.step", cat="engine", args=phases.args):
+            try:
+                return self._step(phases)
+            finally:
+                phases.end()
+
+    def _step(self, phases):
         sched = self.scheduler
+        phases.begin("engine.admit")
         if self._ingest is not None:
             # block (briefly) only when the scheduler would otherwise spin
             # empty while requests are in flight on the ingest thread
@@ -1768,17 +1834,22 @@ class LLMEngine:
         if not sched.has_work():
             return outputs
         self.stats_extra["steps"] += 1
+        if phases.args is not None:
+            phases.args["step"] = self.stats_extra["steps"]
 
         # -- admission ---------------------------------------------------
         for slot, req in sched.pick_prefills():
             # queued->running transition: the span closes here, at a point
             # where the host is already doing admission bookkeeping
             req._t_admit = time.perf_counter_ns()
-            _obs_trace.add_complete(
-                "request.queued", req.t_queue_start, req._t_admit,
-                cat="request", tid=req.rid,
-                args={"rid": req.rid, "engine": self._name,
-                      "evictions": req.evictions})
+            _H_QUEUE_WAIT.observe((req._t_admit - req.t_queue_start) / 1e6,
+                                  instance=self._name)
+            if _obs_trace.enabled():
+                _obs_trace.add_complete(
+                    "request.queued", req.t_queue_start, req._t_admit,
+                    cat="request", tid=req.rid,
+                    args={"rid": req.rid, "engine": self._name,
+                          "evictions": req.evictions})
             if req.preloaded is not None:
                 # disaggregated handoff OR tier revival: imported pages
                 # land in the freshly allocated blocks before this step
@@ -1794,10 +1865,12 @@ class LLMEngine:
         if self.prefill_only:
             # disaggregated prefill worker: decode-ready requests wait
             # for export_kv_pages + cancel; nothing decodes here
+            phases.begin("engine.bookkeeping")
             self._update_gauges()
             return outputs
 
         # -- decode ------------------------------------------------------
+        phases.begin("engine.decode.prepare")
         sched.ensure_decode_room(
             extra=self._spec_k,
             extra_for=(self._window_extra if self._decode_window > 1
@@ -1827,17 +1900,23 @@ class LLMEngine:
                     ids[i, 0] = req.last_token
                     positions[i] = req.num_cached
                 c = self.cache
+                params = [p._data for p in self._params]
+                ids, positions = self._g(ids), self._g(positions)
+                tables = self._tables()
+                phases.begin("engine.decode.dispatch")
                 (logits, c.k, c.v, c.k_scale, c.v_scale) = \
                     self._decode_jit(
-                        [p._data for p in self._params], self._g(ids),
-                        self._g(positions), self._tables(),
+                        params, ids, positions, tables,
                         c.k, c.v, c.k_scale, c.v_scale)
+                phases.begin("engine.decode.fetch")
                 logits = self._fetch(logits)
+                phases.begin("engine.decode.emit")
                 _M_HOST_SYNCS.inc(instance=self._name)
                 _M_FETCH_BYTES.inc(logits.nbytes, instance=self._name)
                 for i, req in ready:
                     req.num_cached += 1
                     outputs.extend(self._emit(req, logits[i]))
+        phases.begin("engine.bookkeeping")
         self._maybe_autosave_store()
         self._update_gauges()
         return outputs
@@ -1874,12 +1953,16 @@ class LLMEngine:
             if req.sampling.eos_token_id is not None:
                 eos_ids[i] = req.sampling.eos_token_id
         c = self.cache
+        inputs = ([p._data for p in self._params], self._g(ids),
+                  self._g(positions), self._g(active), self._g(budget),
+                  self._g(eos_ids), self._tables())
+        phases = self._phases
+        phases.begin("engine.decode.dispatch")
         (toks, c.k, c.v, c.k_scale, c.v_scale) = self._window_jit(
-            [p._data for p in self._params], self._g(ids),
-            self._g(positions), self._g(active),
-            self._g(budget), self._g(eos_ids), self._tables(),
-            c.k, c.v, c.k_scale, c.v_scale)
+            *inputs, c.k, c.v, c.k_scale, c.v_scale)
+        phases.begin("engine.decode.fetch")
         toks = self._fetch(toks)
+        phases.begin("engine.decode.emit")
         _M_HOST_SYNCS.inc(instance=self._name)
         _M_FETCH_BYTES.inc(toks.nbytes, instance=self._name)
         for i, req in ready:
@@ -2053,8 +2136,11 @@ class LLMEngine:
         import jax.numpy as jnp
 
         B, K = self.max_batch_size, self._spec_k
+        phases = self._phases
         tables = self._tables()
+        phases.begin("engine.decode.draft")
         drafts = self._draft_propose(ready, tables)
+        phases.begin("engine.decode.prepare")
         _M_SPEC_PROPOSED.inc(K * len(ready), instance=self._name)
         ids_v = np.zeros((B, K + 1), np.int32)
         pos_v = np.zeros(B, np.int32)
@@ -2065,12 +2151,15 @@ class LLMEngine:
             pos_v[i] = r.num_cached
             n_old[r.rid] = r.num_tokens
         c = self.cache
+        inputs = ([p._data for p in self._params], jnp.asarray(ids_v),
+                  jnp.asarray(pos_v), tables, jnp.asarray(drafts[:, :K]))
+        phases.begin("engine.decode.dispatch")
         (counts, nxt, c.k, c.v, c.k_scale, c.v_scale) = self._verify_jit(
-            [p._data for p in self._params], jnp.asarray(ids_v),
-            jnp.asarray(pos_v), tables, jnp.asarray(drafts[:, :K]),
-            c.k, c.v, c.k_scale, c.v_scale)
+            *inputs, c.k, c.v, c.k_scale, c.v_scale)
+        phases.begin("engine.decode.fetch")
         counts = np.asarray(counts)
         nxt = np.asarray(nxt)
+        phases.begin("engine.decode.emit")
         _M_HOST_SYNCS.inc(instance=self._name)
         _M_FETCH_BYTES.inc(counts.nbytes + nxt.nbytes,
                            instance=self._name)
@@ -2165,17 +2254,24 @@ class LLMEngine:
         done = req.should_finish()
         if done:
             self.scheduler.finish(req)
-            start = req.t_decode_start or req.t_first_token or now
-            _obs_trace.add_complete(
-                "request.decode", start, now, cat="request", tid=req.rid,
-                args={"rid": req.rid, "engine": self._name,
-                      "tokens": len(req.output_tokens),
-                      "finish_reason": req.finish_reason()})
+            if _obs_trace.enabled():
+                self._trace_decode(req, now)
         for j, tok in enumerate(accepted):
             last = j == m - 1
             outputs.append(StepOutput(
                 req.rid, int(tok), done and last,
                 req.finish_reason() if done and last else None))
+
+    def _trace_decode(self, req, now):
+        """The finished request's ``request.decode`` span, first decode
+        step (or first token) to ``now``."""
+        _obs_trace.add_complete(
+            "request.decode",
+            req.t_decode_start or req.t_first_token or now, now,
+            cat="request", tid=req.rid,
+            args={"rid": req.rid, "engine": self._name,
+                  "tokens": len(req.output_tokens),
+                  "finish_reason": req.finish_reason()})
 
     def _emit_token(self, req, tok):
         """Commit one already-chosen token (sampled host-side, or accepted
@@ -2202,12 +2298,8 @@ class LLMEngine:
         done = req.should_finish()
         if done:
             self.scheduler.finish(req)
-            start = req.t_decode_start or req.t_first_token or now
-            _obs_trace.add_complete(
-                "request.decode", start, now, cat="request", tid=req.rid,
-                args={"rid": req.rid, "engine": self._name,
-                      "tokens": len(req.output_tokens),
-                      "finish_reason": req.finish_reason()})
+            if _obs_trace.enabled():
+                self._trace_decode(req, now)
         return [StepOutput(req.rid, int(tok), done,
                            req.finish_reason() if done else None)]
 
@@ -2393,6 +2485,7 @@ class LLMEngine:
             "tokens_out": int(_M_TOKENS.value(instance=inst)),
             "ttft_ms": _H_TTFT.summary(instance=inst),
             "itl_ms": _H_ITL.summary(instance=inst),
+            "queue_wait_ms": _H_QUEUE_WAIT.summary(instance=inst),
             "kv_block_utilization": _G_KV_UTIL.value(instance=inst),
             "decode_batch_occupancy": _G_OCCUPANCY.value(instance=inst),
             "kv_dtype": self.kv_dtype,

@@ -214,14 +214,12 @@ class Request:
     _ids = itertools.count(1)
 
     def __init__(self, prompt_ids, sampling: SamplingParams | None = None,
-                 rid=None, arrival_t=None, deadline=None, tenant=None,
-                 tier=None):
+                 rid=None, deadline=None, tenant=None, tier=None):
         self.rid = rid if rid is not None else next(Request._ids)
         self.prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
         if self.prompt.size == 0:
             raise ValueError("empty prompt")
         self.sampling = sampling or SamplingParams()
-        self.arrival_t = arrival_t
         # multi-tenant QoS (ISSUE 17): who this request bills to and how
         # urgent it is. ``latency`` requests hold their decode slots;
         # ``batch`` requests admit behind latency work and yield their

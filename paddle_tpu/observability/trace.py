@@ -13,9 +13,23 @@ close ONLY at points where the host already blocks or already holds the
 value — window boundaries, metric-fetch points, ingest staging, sampling
 (post-fetch), checkpoint IO. A span never forces a device sync, never
 wraps an async dispatch mid-flight, and costs one ``perf_counter_ns``
-pair plus a dict append when enabled. Disabled (the default), ``span()``
-returns a shared no-op context manager and ``add_complete`` returns
-before taking the lock — the instrumented code paths stay allocation-free.
+pair plus a dict append when enabled.
+
+``span()`` is the one way the program opens a span, and it is live in two
+cases. With the tracer enabled the event goes to the in-memory buffer that
+``export`` writes out. While a ``jax.profiler`` session runs
+(``TraceAnnotation.is_enabled()``) the span also enters a
+``jax.profiler.TraceAnnotation`` of exactly its name: a ``perf_counter_ns``
+stamp cannot be laid over the profile afterwards (the ``.xplane.pb`` counts
+from the session's start), so a span shares the device line's clock only by
+being an event *in* the profile, nested in whatever span the caller holds.
+With both off (the default) ``span()`` returns a shared no-op context
+manager and ``add_complete`` returns before taking the lock: one attribute
+read and one ``is_enabled()`` call, nothing allocated. A caller that passes
+``args`` builds them under ``enabled()`` so that this stays true of the
+call site too. ``args`` go to the buffer only; the profile's event carries
+the name alone. ``jax`` is imported by the first ``span()`` call, not by
+importing this module.
 
 Timestamps are ``time.perf_counter_ns`` (monotonic), emitted in the
 chrome-trace microsecond unit. Complete events use ``ph="X"``; per-request
@@ -52,24 +66,46 @@ class _NoopSpan:
 
 _NOOP = _NoopSpan()
 
+_annotation = None   # jax.profiler.TraceAnnotation, once a span has asked
+
+
+def _profiling():
+    """True while a ``jax.profiler`` session is running in this process."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation.is_enabled()
+
 
 class _Span:
-    __slots__ = ("_tracer", "name", "cat", "tid", "args", "_start")
+    """A live span: in the profile when ``profiled``, and in the tracer's
+    buffer if the tracer is enabled when it ends."""
 
-    def __init__(self, tracer, name, cat, tid, args):
+    __slots__ = ("_tracer", "name", "cat", "tid", "args", "_start", "_ann")
+
+    def __init__(self, tracer, name, cat, tid, args, profiled):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.tid = tid
         self.args = args
+        self._ann = None
+        if profiled:
+            self._ann = _annotation(name)
+            self._ann.__enter__()
         self._start = time.perf_counter_ns()
 
     def end(self):
         if self._start is None:
             return
-        self._tracer.add_complete(self.name, self._start,
-                                  time.perf_counter_ns(), cat=self.cat,
-                                  tid=self.tid, args=self.args)
+        end_ns = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        self._tracer.add_complete(self.name, self._start, end_ns,
+                                  cat=self.cat, tid=self.tid, args=self.args)
         self._start = None
 
     def __enter__(self):
@@ -135,12 +171,13 @@ class Tracer:
 
     # -- recording -------------------------------------------------------
     def span(self, name, cat="host", tid=None, args=None):
-        """Context manager measuring a host-side region. When the tracer
-        is disabled this returns a shared no-op — callers never pay more
-        than one attribute read."""
-        if not self.enabled:
+        """Context manager measuring a host-side region: buffered when
+        the tracer is enabled, a ``TraceAnnotation`` of the same name while
+        a ``jax.profiler`` session runs, the shared no-op when neither."""
+        profiled = _profiling()
+        if not (self.enabled or profiled):
             return _NOOP
-        return _Span(self, name, cat, tid, args)
+        return _Span(self, name, cat, tid, args, profiled)
 
     def add_complete(self, name, start_ns, end_ns, cat="host", tid=None,
                      args=None):

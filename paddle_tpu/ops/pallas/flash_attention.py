@@ -147,6 +147,7 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, rope_cs=None):
             jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_attention_fwd",
     )(*args)
     return out, lse
 
@@ -292,6 +293,7 @@ def _bwd(scale, causal, block_q, block_k, res, dout, rope_cs=None):
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         interpret=_interpret(),
+        name="flash_attention_bwd_dq",
     )(q, k, v, dout, out, lse, *rope_args)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
@@ -314,6 +316,7 @@ def _bwd(scale, causal, block_q, block_k, res, dout, rope_cs=None):
             jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
         ],
         interpret=_interpret(),
+        name="flash_attention_bwd_dkv",
     )(q, k, v, dout, out, lse, *rope_args)
     return dq, dk, dv
 

@@ -81,6 +81,7 @@ def _ffn_fwd_arrays(x, gate_w, up_w, down_w):
         out_specs=pl.BlockSpec((1, bc, h), lambda ei, ci, ii: (ei, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((e, c, h), jnp.float32),
         interpret=_interpret(),
+        name="moe_ffn",
     )(x, gate_w, up_w, down_w)
     return out.astype(x.dtype)
 

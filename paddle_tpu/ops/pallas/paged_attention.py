@@ -169,6 +169,7 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, groups, hkv, d), q.dtype),
         interpret=_interpret(),
+        name="paged_decode_attention",
     )(tables_flat, lens, *operands)
     return jnp.swapaxes(out, 1, 2).reshape(b, h, d)
 
@@ -312,4 +313,5 @@ def paged_multiquery_attention_pallas(q, k_pool, v_pool, block_tables,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, t, h, d), q.dtype),
         interpret=_interpret(),
+        name="paged_prefill_attention",
     )(tables_flat, lens, starts, *operands)

@@ -74,6 +74,7 @@ def _fwd(x, y, w, eps):
         out_shape=[jax.ShapeDtypeStruct((rows, h), x.dtype),
                    jax.ShapeDtypeStruct((rows, h), x.dtype)],
         interpret=_interpret(),
+        name="rms_norm_fwd",
     )
     return kern(x, y, w)
 
@@ -164,6 +165,7 @@ def _ln_fwd(x, y, w, b, eps):
         out_shape=[jax.ShapeDtypeStruct((rows, h), x.dtype),
                    jax.ShapeDtypeStruct((rows, h), x.dtype)],
         interpret=_interpret(),
+        name="layer_norm_fwd",
     )
     return kern(x, y, w, b)
 

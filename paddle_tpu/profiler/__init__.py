@@ -55,10 +55,8 @@ def export_protobuf(dir_name=None, worker_name=None):
         os.makedirs(d, exist_ok=True)
         name = worker_name or f"worker_{os.getpid()}"
         path = os.path.join(d, f"{name}_{int(time.time())}.pb")
-        events = getattr(prof, "_events_snapshot", [])
         with open(path, "wb") as f:
-            pickle.dump([e.__dict__ if hasattr(e, "__dict__") else e
-                         for e in events], f)
+            pickle.dump(prof.record_events(), f)
         return path
 
     return handler

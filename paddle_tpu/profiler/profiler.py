@@ -4,11 +4,13 @@ Reference: python/paddle/profiler/profiler.py — Profiler (:346),
 make_scheduler (:117), export_chrome_tracing (:215), ProfilerState /
 ProfilerTarget enums.
 
-TPU-native: host spans come from RecordEvent (utils.py); device traces
-are jax.profiler sessions (libtpu/XLA trace, viewable in TensorBoard/
-Perfetto) started and stopped around RECORD windows. export_chrome_
-tracing writes the host spans as a chrome://tracing JSON next to the
-device trace directory.
+TPU-native: host spans come from the one span recorder,
+``paddle.observability.trace`` (RecordEvent is a wrapper over it, utils.py);
+device traces are jax.profiler sessions (libtpu/XLA trace, viewable in
+TensorBoard/Perfetto) started and stopped around RECORD windows, and every
+span open meanwhile is an event in them too. export_chrome_tracing
+writes the host spans as a chrome://tracing JSON next to the device trace
+directory.
 """
 
 from __future__ import annotations
@@ -19,7 +21,8 @@ import os
 import socket
 import time
 
-from .utils import RECORDER
+from ..observability import trace as obs_trace
+from .utils import RECORD_EVENT_CAT
 
 __all__ = ["Profiler", "ProfilerState", "ProfilerTarget", "make_scheduler",
            "export_chrome_tracing", "load_profiler_result"]
@@ -129,11 +132,8 @@ class Profiler:
         self.current_state = ProfilerState.CLOSED
         self._device_tracing = False
         self._trace_dir = None
-        self._events_snapshot = []
-        # observability-tracer spans captured during the RECORD window
-        # (ISSUE 10: the export path is rebased onto paddle.observability
-        # .trace, so drive/serving/checkpoint spans land in the same
-        # chrome trace as RecordEvent host spans)
+        # the tracer's events of the RECORD window: RecordEvent spans and
+        # the runtime's own (drive/serving/checkpoint) in one chrome trace
         self._obs_spans = []
         self._owns_tracer = False
         self._obs_window_start_ts = 0.0  # chrome-trace us clock
@@ -184,17 +184,12 @@ class Profiler:
         recording_new = new_state in (ProfilerState.RECORD,
                                       ProfilerState.RECORD_AND_RETURN)
         if not recording_old and recording_new:
-            RECORDER.enabled = True
-            from ..observability import trace as obs_trace
-
             # arm the span tracer for the window; if the user already has
             # it on (collecting their own trace), leave it theirs and
             # remember where this window starts so export() takes only
             # in-window spans, not the user's whole history
-            import time as _time
-
             self._owns_tracer = not obs_trace.TRACER.enabled
-            self._obs_window_start_ts = _time.perf_counter_ns() / 1e3
+            self._obs_window_start_ts = time.perf_counter_ns() / 1e3
             if self._owns_tracer:
                 obs_trace.TRACER.enable()
             self._start_device_trace()
@@ -206,11 +201,6 @@ class Profiler:
         self.current_state = new_state
 
     def _finish_window(self):
-        from ..observability import trace as obs_trace
-
-        self._events_snapshot = list(RECORDER.events)
-        RECORDER.enabled = False
-        RECORDER.clear()
         # capture ONLY the observability spans recorded during this
         # window (ts cutoff at RECORD start — a user's pre-window
         # history, enabled or disabled-but-buffered, never leaks into
@@ -264,21 +254,13 @@ class Profiler:
 
     # -- output ----------------------------------------------------------
     def export(self, path, format="json"):
-        """Write the captured host spans as a chrome trace: RecordEvent
-        spans plus every ``paddle.observability.trace`` span recorded in
-        the window (drive windows, serving request lifecycles, checkpoint
-        IO). The device trace (if any) lives in self._trace_dir for
-        TensorBoard."""
-        events = []
-        for name, start, end, tid in self._events_snapshot:
-            events.append({
-                "name": name, "ph": "X", "cat": "host",
-                "ts": start / 1e3, "dur": (end - start) / 1e3,
-                "pid": os.getpid(), "tid": tid,
-            })
-        events.extend(self._obs_spans)
+        """Write the captured host spans as a chrome trace: every
+        ``paddle.observability.trace`` span recorded in the window
+        (RecordEvent spans, drive windows, serving request lifecycles,
+        checkpoint IO). The device trace (if any) lives in
+        self._trace_dir for TensorBoard."""
         doc = {
-            "traceEvents": events,
+            "traceEvents": list(self._obs_spans),
             "metadata": {"device_trace_dir": self._trace_dir},
         }
         with open(path, "w") as f:
@@ -289,4 +271,12 @@ class Profiler:
                 time_unit="ms"):
         from .profiler_statistic import build_summary
 
-        return build_summary(self._events_snapshot, time_unit=time_unit)
+        return build_summary(self.record_events(), time_unit=time_unit)
+
+    def record_events(self):
+        """``(name, start_ns, end_ns, tid)`` of the window's RecordEvent
+        spans: the rows of ``summary()``."""
+        return [(e["name"], e["ts"] * 1e3, (e["ts"] + e["dur"]) * 1e3,
+                 e["tid"])
+                for e in self._obs_spans
+                if e.get("cat") == RECORD_EVENT_CAT and e.get("ph") == "X"]

@@ -2,44 +2,29 @@
 
 Reference: python/paddle/profiler/utils.py (RecordEvent) backed by the
 C++ HostTracer/HostEventRecorder (paddle/fluid/platform/profiler/
-host_tracer.cc, host_event_recorder.h). TPU-native: a process-local
-recorder list; device-side tracing is delegated to jax.profiler
-(libtpu/XLA) by profiler.py, and RecordEvent doubles as a
-jax.profiler.TraceAnnotation so host spans show up inside the device
-trace timeline too.
+host_tracer.cc, host_event_recorder.h). TPU-native: ``RecordEvent`` is a
+thin wrapper over the one span recorder, ``paddle.observability.trace``:
+its span goes to the tracer's buffer while a ``Profiler`` RECORD window
+(or the user) has the tracer enabled, and into the ``jax.profiler`` trace
+while a device trace runs, so host spans show up inside the device trace
+timeline too.
 """
 
 from __future__ import annotations
 
-import threading
-import time
+from ..observability import trace as _obs_trace
 
 __all__ = ["RecordEvent", "in_profiler_mode", "wrap_optimizers"]
 
-
-class _Recorder:
-    def __init__(self):
-        self.events = []  # (name, start_ns, end_ns, tid)
-        self.enabled = False
-        self._lock = threading.Lock()
-
-    def clear(self):
-        with self._lock:
-            self.events = []
-
-    def add(self, name, start_ns, end_ns):
-        if not self.enabled:
-            return
-        with self._lock:
-            self.events.append(
-                (name, start_ns, end_ns, threading.get_ident()))
-
-
-RECORDER = _Recorder()
+#: chrome-trace category of ``RecordEvent`` spans: what tells them from the
+#: runtime's own spans in ``Profiler.summary()``
+RECORD_EVENT_CAT = "record_event"
 
 
 def in_profiler_mode():
-    return RECORDER.enabled
+    """True while host spans are being recorded (a ``Profiler`` RECORD
+    window arms the tracer)."""
+    return _obs_trace.enabled()
 
 
 class RecordEvent:
@@ -54,29 +39,16 @@ class RecordEvent:
     def __init__(self, name, event_type=None):
         self.name = name
         self.event_type = event_type
-        self._start = None
-        self._jax_ctx = None
+        self._span = None
 
     def begin(self):
-        self._start = time.perf_counter_ns()
-        if RECORDER.enabled:
-            try:
-                import jax
-
-                self._jax_ctx = jax.profiler.TraceAnnotation(self.name)
-                self._jax_ctx.__enter__()
-            except Exception:
-                self._jax_ctx = None
+        self._span = _obs_trace.span(self.name, cat=RECORD_EVENT_CAT)
         return self
 
     def end(self):
-        if self._start is None:
-            return
-        if self._jax_ctx is not None:
-            self._jax_ctx.__exit__(None, None, None)
-            self._jax_ctx = None
-        RECORDER.add(self.name, self._start, time.perf_counter_ns())
-        self._start = None
+        if self._span is not None:
+            self._span.end()
+            self._span = None
 
     __enter__ = begin
 

@@ -201,7 +201,8 @@ class TestRound3AdviceFixes:
         """A scheduler that drops RECORD -> READY without RECORD_AND_RETURN
         must still finish the window (recorder off, callback fired)."""
         from paddle_tpu import profiler as prof
-        from paddle_tpu.profiler.profiler import RECORDER, ProfilerState
+        from paddle_tpu.observability.trace import TRACER
+        from paddle_tpu.profiler.profiler import ProfilerState
 
         fired = []
 
@@ -212,9 +213,10 @@ class TestRound3AdviceFixes:
         p = prof.Profiler(scheduler=sched,
                           on_trace_ready=lambda pr: fired.append(1))
         p.start()
+        assert TRACER.enabled is True
         p.step()
         p.step()  # transition RECORD -> READY
-        assert RECORDER.enabled is False
+        assert TRACER.enabled is False
         assert fired == [1]
         p.stop()
 

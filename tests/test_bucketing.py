@@ -258,7 +258,7 @@ class TestCompileCacheAcceptance:
 
 class TestCacheTelemetry:
     def test_eager_fallback_counted_and_marked(self):
-        from paddle_tpu.profiler.utils import RECORDER
+        from paddle_tpu.observability.trace import TRACER
 
         @jit.to_static
         def f(x):
@@ -266,20 +266,20 @@ class TestCacheTelemetry:
                 return x * 2
             return x * 3
 
-        RECORDER.clear()
-        RECORDER.enabled = True
+        TRACER.clear()
+        TRACER.enable()
         try:
             with pytest.warns(UserWarning, match="Falling back to EAGER"):
                 f(paddle.to_tensor(np.ones(4, np.float32)))
             for _ in range(3):
                 f(paddle.to_tensor(np.ones(4, np.float32)))
         finally:
-            RECORDER.enabled = False
+            TRACER.disable()
         stats = jit.cache_stats(f._stats_name)
         assert stats["eager_fallbacks"] == 4
         assert stats["compiles"] == 0
-        marks = [e[0] for e in RECORDER.events
-                 if e[0].startswith("jit::eager_fallback::")]
+        marks = [e["name"] for e in TRACER.drain()
+                 if e["name"].startswith("jit::eager_fallback::")]
         assert len(marks) == 4
 
     def test_compile_cliff_warning_is_flag_gated(self):

@@ -1,0 +1,366 @@
+"""The engine's step phases as spans (ISSUE 25): the same names on every
+decode path, nothing recorded and nothing built with tracing off, the spans
+in a real ``jax.profiler`` session on the CPU, the Pallas kernels' names in
+the lowered text, and ``add_request(arrival_t=)`` as the due time."""
+
+import glob
+import re
+import time
+import tracemalloc
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu.inference.serving import LLMEngine, SamplingParams
+from paddle_tpu.observability import metrics, trace
+from paddle_tpu.observability.trace import TRACER
+
+DECODE_PHASES = ["engine.decode.prepare", "engine.decode.dispatch",
+                 "engine.decode.fetch", "engine.decode.emit"]
+PATHS = {
+    "per-step": {},
+    "window": {"decode_steps_per_sync": 4},
+    "speculative": {"spec_tokens": 3},   # draft_model: the model itself
+}
+
+
+@pytest.fixture(scope="module")
+def model():
+    from paddle_tpu.models import LlamaForCausalLM, llama_tiny
+
+    paddle.seed(7)
+    m = LlamaForCausalLM(llama_tiny())
+    m.eval()
+    return m
+
+
+@pytest.fixture
+def tracer():
+    TRACER.clear()
+    TRACER.enable()
+    yield TRACER
+    TRACER.disable()
+    TRACER.clear()
+
+
+def _engine(model, **kw):
+    if "spec_tokens" in kw:
+        kw["draft_model"] = model
+    return LLMEngine(model, num_blocks=64, block_size=8, max_batch_size=4,
+                     **kw)
+
+
+def _submit(eng, lengths=(5, 11, 17), new=6, **kw):
+    rng = np.random.RandomState(0)
+    vocab = eng.model.config.vocab_size
+    return [eng.add_request(rng.randint(0, vocab, n).astype(np.int32),
+                            SamplingParams(max_new_tokens=new), **kw)
+            for n in lengths]
+
+
+def _steps(events):
+    """[(engine.step event, [the engine.* events inside it])], by time."""
+    evs = sorted((e for e in events if e["name"].startswith("engine.")),
+                 key=lambda e: e["ts"])
+    steps = [(e, []) for e in evs if e["name"] == "engine.step"]
+    for e in evs:
+        if e["name"] == "engine.step":
+            continue
+        holders = [s for s in steps
+                   if s[0]["ts"] <= e["ts"]
+                   and e["ts"] + e["dur"] <= s[0]["ts"] + s[0]["dur"] + 1e-3]
+        assert len(holders) == 1, f"{e['name']} lies in no engine.step"
+        holders[0][1].append(e)
+    return steps
+
+
+@pytest.mark.parametrize("path", list(PATHS))
+def test_every_step_is_cut_into_the_same_phases(model, tracer, path):
+    with _engine(model, **PATHS[path]) as eng:
+        name = eng._name
+        _submit(eng)
+        n_steps = 0
+        while eng.has_work():
+            eng.step()
+            n_steps += 1
+    steps = _steps(tracer.events())
+    assert len(steps) == n_steps > 3
+    prefills = 0
+    for k, (step, inside) in enumerate(steps, start=1):
+        names = [e["name"] for e in inside]
+        assert step["args"] == {"engine": name, "step": k}
+        assert all(e["args"] == step["args"] and e["cat"] == "engine"
+                   for e in inside)
+        # the phases follow each other: none starts before the last ended
+        for a, b in zip(inside, inside[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"] + 1e-3, (a["name"], b["name"])
+        assert names[0] == "engine.admit" and names[-1] == "engine.bookkeeping"
+        decode = [n for n in names if n.startswith("engine.decode.")]
+        if path == "speculative":
+            assert decode == ["engine.decode.prepare", "engine.decode.draft",
+                              *DECODE_PHASES]
+        else:
+            assert decode == DECODE_PHASES
+        prefills += names.count("engine.prefill")
+        assert set(names) <= {"engine.admit", "engine.prefill",
+                              "engine.bookkeeping", "engine.decode.draft",
+                              *DECODE_PHASES}
+    assert prefills == 3   # one chunk a prompt, in the steps that admit them
+    assert "engine.prefill" in [e["name"] for e in steps[0][1]]
+    assert "engine.prefill" not in [e["name"] for e in steps[-1][1]]
+    # request spans keep the request id as their shared identifier
+    queued = [e for e in tracer.events() if e["name"] == "request.queued"]
+    assert sorted(e["args"]["rid"] for e in queued) == sorted(
+        e["tid"] for e in queued) and len(queued) == 3
+
+
+def test_trace_report_tables_the_phases_of_an_export(model, tracer, tmp_path):
+    """``scripts/trace_report.py`` is the reader of the tracer's export:
+    its span table has a row for each phase, one event a step."""
+    import importlib.util
+    import json
+    import os
+
+    with _engine(model) as eng:
+        _submit(eng, new=3)
+        n_steps = 0
+        while eng.has_work():
+            eng.step()
+            n_steps += 1
+    path = tracer.export(str(tmp_path / "t.json"))
+    script = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts", "trace_report.py")
+    spec = importlib.util.spec_from_file_location("trace_report", script)
+    report = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(report)
+    doc = json.load(open(path))
+    agg = report.aggregate_spans(doc["traceEvents"])
+    for name in ("engine.step", "engine.admit", "engine.bookkeeping",
+                 *DECODE_PHASES):
+        assert agg[name]["count"] == n_steps, name
+    assert agg["engine.prefill"]["count"] == 3
+    assert "engine.decode.fetch" in report.build_report(trace_doc=doc)
+
+
+def test_a_tick_without_work_is_no_step(model, tracer):
+    with _engine(model) as eng:
+        assert eng.step() == []
+    (step, inside), = _steps(tracer.events())
+    assert step["args"] == {"engine": step["args"]["engine"]}   # no number
+    assert [e["name"] for e in inside] == ["engine.admit"]
+
+
+def test_with_tracing_off_nothing_is_recorded_or_built(model, monkeypatch):
+    """The disabled path: ``span()`` hands back the one shared no-op,
+    ``engine.step()`` appends no event, no ``add_complete`` call is even
+    made (so no ``args`` dict is built for one), and ``trace.py`` itself
+    allocates nothing."""
+    TRACER.disable()
+    TRACER.clear()
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    assert trace.span("x") is trace.span("y", cat="engine") is trace._NOOP
+
+    def refuse(*a, **kw):
+        raise AssertionError("add_complete called with the tracer off")
+
+    monkeypatch.setattr(trace, "add_complete", refuse)
+    monkeypatch.setattr(TRACER, "add_complete", refuse)
+    with _engine(model) as eng:
+        _submit(eng, new=3)
+        eng.step()                       # compiles, imports
+        tracemalloc.start()
+        try:
+            before = tracemalloc.take_snapshot()
+            while eng.has_work():
+                eng.step()
+            for _ in range(100):
+                trace.span("engine.step", cat="engine", args=None)
+                trace.instant("x")
+            after = tracemalloc.take_snapshot()
+        finally:
+            tracemalloc.stop()
+    only = [tracemalloc.Filter(True, trace.__file__)]
+    grown = after.filter_traces(only).compare_to(
+        before.filter_traces(only), "lineno")
+    assert [str(s) for s in grown if s.size_diff > 0] == []
+    assert TRACER.events() == []
+
+
+def test_spans_land_in_a_profile_inside_the_callers_span(model, tmp_path):
+    """Under a real ``jax.profiler`` session (CPU) the phases are events
+    of exactly their names on the ``/host:CPU`` line that holds the
+    caller's outer span, inside it, and the tracer's buffer stays empty."""
+    TRACER.disable()
+    TRACER.clear()
+    with _engine(model) as eng:
+        _submit(eng, lengths=(5,), new=8)
+        for _ in range(3):
+            eng.step()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            assert trace.span("x") is not trace._NOOP
+            for _ in range(2):
+                with jax.profiler.TraceAnnotation("outer"):
+                    eng.step()
+        finally:
+            jax.profiler.stop_trace()
+    assert trace.span("x") is trace._NOOP
+    assert TRACER.events() == []
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    lines = [[(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for e in line.events]
+             for plane in data.planes if plane.name == "/host:CPU"
+             for line in plane.lines]
+    line, = [ln for ln in lines if any(n == "outer" for n, _, _ in ln)]
+    outer = [(a, b) for n, a, b in line if n == "outer"]
+    assert len(outer) == 2
+    for want in ("engine.step", "engine.admit", "engine.decode.prepare",
+                 "engine.decode.dispatch", "engine.decode.fetch",
+                 "engine.decode.emit", "engine.bookkeeping"):
+        got = [(a, b) for n, a, b in line if n == want]
+        assert len(got) == 2, want
+        for (a, b), (oa, ob) in zip(got, outer):
+            assert oa <= a and b <= ob, want
+    step = [(a, b) for n, a, b in line if n == "engine.step"]
+    emit = [(a, b) for n, a, b in line if n == "engine.decode.emit"]
+    assert all(sa <= a and b <= sb for (a, b), (sa, sb) in zip(emit, step))
+
+
+def test_arrival_t_is_the_due_time(model, tracer):
+    """A request due 250 ms before it was handed in: TTFT, the queued
+    span and the queue wait all count from the due time."""
+    with _engine(model) as eng:
+        _submit(eng, lengths=(5, 7), new=2)   # compile before the clock matters
+        while eng.has_work():
+            eng.step()
+        eng.reset_metrics()
+        tracer.clear()
+        due = time.perf_counter() - 0.25
+        late, = _submit(eng, lengths=(5,), new=2, arrival_t=due)
+        now, = _submit(eng, lengths=(7,), new=2)
+        while eng.has_work():
+            eng.step()
+        m = eng.metrics()
+        assert eng.request(late).t_submit == int(due * 1e9)
+        assert "serving_queue_wait_ms" in metrics.to_prometheus_text()
+    assert m["queue_wait_ms"]["count"] == 2
+    assert m["queue_wait_ms"]["max"] >= 250.0 > m["queue_wait_ms"]["min"]
+    assert m["ttft_ms"]["max"] >= 250.0
+    queued = {e["tid"]: e for e in tracer.events()
+              if e["name"] == "request.queued"}
+    assert queued[late]["dur"] >= 250e3 > queued[now]["dur"]
+
+
+# --- kernels under names of their own -------------------------------------------
+
+def _paged_decode():
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    pool = jnp.zeros((8, 16, 2, 128), jnp.float32)
+    return (lambda q, k, v, t, n: pa.paged_decode_attention_pallas(
+        q, k, v, t, n, 0.088)), (
+        jnp.zeros((2, 4, 128), jnp.float32), pool, pool,
+        jnp.zeros((2, 4), jnp.int32), jnp.ones((2,), jnp.int32))
+
+
+def _paged_prefill():
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    pool = jnp.zeros((8, 16, 2, 128), jnp.float32)
+    return (lambda q, k, v, t, n, s: pa.paged_multiquery_attention_pallas(
+        q, k, v, t, n, s, 0.088)), (
+        jnp.zeros((1, 16, 4, 128), jnp.float32), pool, pool,
+        jnp.zeros((1, 4), jnp.int32), jnp.full((1,), 16, jnp.int32),
+        jnp.zeros((1,), jnp.int32))
+
+
+def _flash(grad):
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    q = jnp.zeros((2, 256, 128), jnp.float32)
+    bq, bk = fa._block_sizes(256, 256)
+
+    def fwd(q, k, v):
+        return fa._flash_mha(q, k, v, 0.125, True, bq, bk)
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    return (bwd if grad else fwd), (q, q, q)
+
+
+def _rms_norm():
+    from paddle_tpu.ops.pallas import rms_norm as rn
+
+    x = jnp.zeros((16, 128), jnp.float32)
+    return (lambda x, y, w: rn._fwd(x, y, w, 1e-6)), (
+        x, x, jnp.ones((1, 128), jnp.float32))
+
+
+def _layer_norm():
+    from paddle_tpu.ops.pallas import rms_norm as rn
+
+    x = jnp.zeros((16, 128), jnp.float32)
+    w = jnp.ones((1, 128), jnp.float32)
+    return (lambda x, y, w, b: rn._ln_fwd(x, y, w, b, 1e-6)), (x, x, w, w)
+
+
+def _moe_ffn():
+    from paddle_tpu.ops.pallas import moe_ffn as mf
+
+    return mf._ffn_fwd_arrays, (
+        jnp.zeros((2, 128, 128), jnp.float32),
+        jnp.zeros((2, 128, 256), jnp.float32),
+        jnp.zeros((2, 128, 256), jnp.float32),
+        jnp.zeros((2, 256, 128), jnp.float32))
+
+
+KERNELS = [
+    ("paged_decode_attention", _paged_decode),
+    ("paged_prefill_attention", _paged_prefill),
+    ("flash_attention_fwd", lambda: _flash(False)),
+    ("flash_attention_bwd_dq", lambda: _flash(True)),
+    ("flash_attention_bwd_dkv", lambda: _flash(True)),
+    ("rms_norm_fwd", _rms_norm),
+    ("layer_norm_fwd", _layer_norm),
+    ("moe_ffn", _moe_ffn),
+]
+
+
+@pytest.mark.parametrize("name,make", KERNELS, ids=[k[0] for k in KERNELS])
+def test_the_lowered_text_carries_the_kernels_name(name, make, monkeypatch):
+    """The name a ``pl.pallas_call`` is given enters the name stack of
+    everything it lowers to (interpret mode here; on the TPU it becomes the
+    HLO instruction's own name, ``%paged_decode_attention.16``)."""
+    monkeypatch.setenv("PT_PALLAS_INTERPRET", "1")
+    fn, args = make()
+    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    assert re.search(r'loc\("[^"]*[/(]' + name + r'[/)]', text), name
+
+
+def test_every_pallas_call_has_a_name():
+    import os
+
+    import paddle_tpu.ops.pallas as pkg
+
+    root = os.path.dirname(pkg.__file__)
+    calls = 0
+    for f in sorted(os.listdir(root)):
+        if not f.endswith(".py"):
+            continue
+        src = open(os.path.join(root, f)).read()
+        for m in re.finditer(r"pl\.pallas_call\(", src):
+            calls += 1
+            # the call's own argument list runs to the first line that
+            # closes it at the call's indentation
+            body = src[m.end():].split("\n    )", 1)[0]
+            assert re.search(r'\bname="[a-z_]+"', body), (f, body[:80])
+    assert calls == len(KERNELS)

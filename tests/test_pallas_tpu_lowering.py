@@ -128,8 +128,12 @@ _COMPILE = textwrap.dedent("""
                 for a in args]
 
     for name, fn, args in T.COMPILED_CASES:
-        jax.jit(fn).trace(*on(one, args)).lower().compile()
+        text = jax.jit(fn).trace(*on(one, args)).lower().compile().as_text()
         print("COMPILED", name, flush=True)
+        # the HLO instructions that are kernels, by their own names
+        print("KERNELS", name, *sorted(set(
+            ln.split(" = ")[0].split("%")[-1].rsplit(".", 1)[0]
+            for ln in text.splitlines() if " custom-call(" in ln)), flush=True)
 
     # the rope op on its own, at head-dim 128 (the split-and-concatenate
     # form aborted the compiler here)
@@ -178,3 +182,18 @@ def test_compiles_for_v5e_without_a_chip():
     assert done == {c[0] for c in COMPILED_CASES} | {
         "rope", "per-shard under plan"}
     assert "REFUSED bare mosaic under gspmd" in r.stdout
+    # a pallas_call's ``name`` becomes its HLO instruction's own name (under
+    # jax.grad wrapped: ``transpose_jvp_flash_attention_bwd_dq__``), which is
+    # what a profile's device line and the benchmark's reduction show
+    kernels = {ln.split(" ")[1]: ln.split(" ")[2:] for ln in
+               r.stdout.splitlines() if ln.startswith("KERNELS ")}
+    for case, want in (("decode", ["paged_decode_attention"]),
+                       ("mq2048", ["paged_prefill_attention"]),
+                       ("flash-bwd", ["flash_attention_fwd",
+                                      "flash_attention_bwd_dq",
+                                      "flash_attention_bwd_dkv"])):
+        for name, got in kernels.items():
+            if name.startswith(case):
+                assert len(got) == len(want) and all(
+                    any(w in g for g in got) for w in want), (name, got)
+    assert set(kernels) == {c[0] for c in COMPILED_CASES}

@@ -38,21 +38,24 @@ class TestScheduler:
 
 class TestRecordEvent:
     def test_spans_recorded_only_when_enabled(self):
-        from paddle_tpu.profiler.utils import RECORDER
+        from paddle_tpu.observability.trace import TRACER
 
-        RECORDER.clear()
-        RECORDER.enabled = False
+        TRACER.clear()
+        TRACER.disable()
+        assert not profiler.in_profiler_mode()
         with profiler.RecordEvent("not_recorded"):
             pass
-        assert len(RECORDER.events) == 0
-        RECORDER.enabled = True
+        assert len(TRACER.events()) == 0
+        TRACER.enable()
         try:
+            assert profiler.in_profiler_mode()
             with profiler.RecordEvent("recorded"):
                 pass
         finally:
-            RECORDER.enabled = False
-        assert [e[0] for e in RECORDER.events] == ["recorded"]
-        RECORDER.clear()
+            TRACER.disable()
+        assert [(e["name"], e["cat"]) for e in TRACER.events()] == [
+            ("recorded", "record_event")]
+        TRACER.clear()
 
 
 class TestProfiler:
