@@ -11,6 +11,9 @@ from a seed):
   ``stream()``, fp then int8 KV, logits checked against the model's plain
   full forward on the same chip.
 * host/device latencies, the compile-cache placement, a device FFT.
+* the paged decode kernel alone against the lax fallback at the benchmark's
+  serving geometry (Mistral-7B's 32 heads over 8 kv heads), ragged contexts
+  around every chunk boundary, NaN in every pool slot no live token holds.
 * with four or more devices: the same two models under a ``Plan``
   (dp2 x tp2 + zero1 training, tp4 serving) in place of the one-chip serve
   phases, asserting that arrays really leave device 0.
@@ -56,6 +59,9 @@ INT8_LOGIT_TOL = 0.08 + FP_LOGIT_TOL
 # Plan vs one chip, per-step training loss: same data, same seed, bf16
 # weights; only the reduction order of the dp/tp collectives differs.
 PLAN_LOSS_TOL = 0.02
+# The decode kernel alone against the float32 fallback, max|kernel - ref| /
+# max|ref| over one request's [H, D] output (phase_decode_kernel).
+DECODE_KERNEL_TOL = 0.02
 
 
 class SmokeFailure(Exception):
@@ -464,6 +470,66 @@ def phase_fft():
     check(err < 1e-2 and err2 < 1e-2, "fft: device result off numpy's")
 
 
+def phase_decode_kernel():
+    """The paged decode kernel against the lax fallback at the benchmark's
+    serving geometry (Mistral-7B: 32 heads over 8 kv heads x 128, pages of
+    16, 256 pages a request), ragged contexts around every chunk boundary.
+    The kernel reads pools that hold NaN wherever no live token sits (a
+    page's unwritten slots, the null page, free pages); the reference
+    reads the same pools clean, in float32."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.inference.serving.paged_attention import _lax_fallback
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    b, h, hkv, d, blk, p, n = 32, 32, 8, 128, 16, 256, 6145
+    chunk, vmem = pa._decode_chunk(blk, hkv, h, d, 2, p)
+    t = chunk * blk
+    rng = np.random.RandomState(SEED + 3)
+    lens = np.concatenate([
+        [1, blk - 1, blk, t - 1, t, t + 1, 2 * t + blk + 3, p * blk],
+        rng.randint(1, 1500, b - 8)]).astype(np.int32)
+    pages = -(-lens // blk)
+    check(pages.sum() < n, "decode kernel: the pool is too small")
+    order = rng.permutation(np.arange(1, n))
+    tables = np.zeros((b, p), np.int32)          # unused slots: the null page
+    live = np.zeros((n, blk), bool)
+    at = 0
+    for i in range(b):
+        tables[i, :pages[i]] = order[at:at + pages[i]]
+        at += pages[i]
+        live[tables[i, :pages[i]]] = True
+        live[tables[i, pages[i] - 1], lens[i] - (pages[i] - 1) * blk:] = False
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(SEED + 3), 3)
+    q = jax.random.normal(kq, (b, h, d), jnp.bfloat16)
+    k_pool = jax.random.normal(kk, (n, blk, hkv, d), jnp.bfloat16)
+    v_pool = jax.random.normal(kv, (n, blk, hkv, d), jnp.bfloat16)
+    scale = 1.0 / math.sqrt(d)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(jax.jit(_lax_fallback, static_argnums=5)(
+            q[:, None].astype(jnp.float32), k_pool.astype(jnp.float32),
+            v_pool.astype(jnp.float32), jnp.asarray(tables),
+            jnp.asarray(lens), scale)[:, 0])
+    dead = jnp.asarray(~live)[:, :, None, None]
+    got = np.asarray(jax.jit(pa.paged_decode_attention_pallas,
+                             static_argnums=5)(
+        q, jnp.where(dead, jnp.nan, k_pool), jnp.where(dead, jnp.nan, v_pool),
+        jnp.asarray(tables), jnp.asarray(lens), scale).astype(jnp.float32))
+    check(np.isfinite(got).all(), "decode kernel: non-finite output "
+          f"in rows {sorted(set(np.argwhere(~np.isfinite(got))[:, 0]))}")
+    err = np.abs(got - want)
+    rel = err.max(axis=(1, 2)) / np.abs(want).max(axis=(1, 2))
+    log(f"[decode kernel] chunk {chunk} pages ({vmem / 2**20:.2f} MiB of "
+        f"VMEM planned), contexts {lens.min()}..{lens.max()}: max abs err "
+        f"{err.max():.2e}, max relative err {rel.max():.2e} "
+        f"(row {int(rel.argmax())}, context {int(lens[rel.argmax()])})")
+    # a bf16 output keeps 8 significand bits (2^-9 = 0.2% a rounding) and p
+    # enters the PV dot in bf16; a wrong page, mask or head reads 0.3-1.0
+    check(rel.max() < DECODE_KERNEL_TOL,
+          f"decode kernel: relative error {rel.max():.3e} over "
+          f"{DECODE_KERNEL_TOL}")
+
+
 def build_server_model():
     import paddle_tpu as paddle
     from paddle_tpu.models import LlamaForCausalLM, llama_1b
@@ -560,6 +626,7 @@ def main():
     run("latency", phase_latencies)
     train = run("train", phase_train)
     run("fft", phase_fft)
+    run("decode kernel", phase_decode_kernel)
     if len(devices) >= 4:
         log("[four chips] four or more devices: running the planned "
             "phases; the one-chip serve phases are left to a one-chip run")

@@ -1,15 +1,11 @@
-"""Paged-attention decode kernel — Pallas TPU (ISSUE 7 tentpole, part b).
+"""Paged-attention kernels — Pallas TPU (ISSUE 7 tentpole, part b; the decode
+kernel rewritten by ISSUE 26).
 
-Single-token decode over a block-paged KV cache (PAPERS.md: "Ragged Paged
-Attention: A High-Performance and Flexible LLM Inference Kernel for TPU").
-Each grid step (request b, page p) DMAs ONE pool block — chosen by the
-scalar-prefetched block table, so the gather never materializes the
-per-request KV in HBM — and folds it into an online-softmax accumulator
-held in VMEM scratch across the page loop. Ragged per-request lengths come
-from the scalar-prefetched ``context_lens``: pages past a request's length
-are skipped (``pl.when``), and the tail page masks positions beyond the
-length, so ONE compiled kernel serves any mix of request lengths — the
-whole point of the paged layout.
+Attention over a block-paged KV cache (PAPERS.md: "Ragged Paged Attention:
+A High-Performance and Flexible LLM Inference Kernel for TPU"). The block
+table and the context lengths are scalar-prefetched, so the gather never
+materializes a request's KV in HBM, and ONE compiled kernel serves any mix
+of request lengths — the whole point of the paged layout.
 
 Layouts:
   q            [B, H, D]         (one decode token per request)
@@ -17,30 +13,50 @@ Layouts:
   block_tables [B * P] int32     (flattened; P = max pages per request)
   context_lens [B]     int32     (tokens INCLUDING the one just written)
 
-GQA: q arrives grouped ``[B, G, Hkv, D]`` (head ``h = kvh * G + g``), so
-each group's heads line up one-to-one with the pool block's kv heads and
-the pool stays at Hkv.
+**Decode: a chunk of pages a step, copied by hand, folded on the MXU.** The
+pools stay in HBM (``pl.ANY``). One grid step is one request; inside, a
+``fori_loop`` runs over the request's live chunks of ``C`` pages and never
+visits a dead table slot. For each chunk the kernel starts one async copy a
+live page for K and for V into one slot of a double buffer in VMEM, and it
+starts the next chunk's copies — the first chunk of the NEXT request, at a
+request's last chunk — before it waits for and folds this one, so the copy
+pipeline does not drain between requests. ``C`` follows from the operands'
+shapes (``_decode_chunk``): 16 pages, 256 tokens, at 8 kv heads x 128 bf16.
 
-The decode kernel is a VPU kernel: one query row per head has no matmul
-``M`` dimension for the MXU (Mosaic rejects the ``[H,D]·[H,blk,D]`` batched
-mat-vec outright), so scores are an elementwise multiply + lane reduce
-over the block exactly as it sits in VMEM (``[blk, Hkv, D]``, no
-transpose) and the PV fold is a multiply + sum over the block's leading
-dim. The multi-query kernel (prefill chunks, speculative verify) has real
-``M = T`` rows and uses batched MXU dots; its query rows are tiled over a
-grid axis so VMEM holds one tile (``_MQ_ROWS``), not the whole chunk.
+A page arrives as ``block * Hkv`` rows of D, token-major, exactly as the
+pool holds it (the wrapper's ``[N, block * Hkv, D]`` view is a bitcast), so
+a chunk is ``[C * block * Hkv, D]`` and the fold is two plain 2-D dots for
+all heads at once: scores ``q [H, D] . chunk^T -> [H, C * block * Hkv]``,
+the columns of another kv head than the row's masked with the positions
+past the context, then ``p . V -> [H, D]``. That spends Hkv times the
+flops a per-head dot would, on an MXU that is otherwise idle, and needs no
+strided load; max, exp and sum run on lane-dense rows. Scale is applied to
+the f32 scores; max, sum and accumulator are f32; ``p`` enters the second
+dot in V's dtype. A dead column has ``p = 0``, and ``0 x NaN`` is NaN on
+the MXU, so a tail chunk's V rows past the context (never copied, or a
+page's unwritten slots) are zeroed before the dot. GQA, one kv head a
+shard, one query head a kv head are all shapes of this one kernel.
 
 **Quantized pools (ISSUE 14, dequant-in-kernel):** with
 ``kv_dtype="int8"`` the pools hold int8 codes and two sidecar scale
 pools ``[N, block, Hkv]`` f32 ride along. The kernels take two extra
 scalar-prefetch-indexed operands — the scale rows of exactly the block
 being DMA'd — and dequantize IN VMEM (``codes.astype(f32) *
-scale[..., None]``) right before the existing online-softmax fold, so
-HBM traffic per page drops ~4x while the attention math past the
-dequant is bit-identical to the fp kernel fed the dequantized values.
-The lax path in ``inference/serving/paged_attention.py`` (CPU backends)
-mirrors the same gather + multiply. The scale pools' lane dim is Hkv:
-Mosaic takes it as is, but HBM tiles pad it to 128 lanes.
+scale[..., None]``) right before the online-softmax fold, so HBM traffic
+per page drops ~4x while the attention math past the dequant is that of
+the fp kernel fed the dequantized values. The lax path in
+``inference/serving/paged_attention.py`` (CPU backends) mirrors the same
+gather + multiply. The scale pools' lane dim is Hkv: HBM tiles pad it to
+128 lanes, and Mosaic refuses a hand-made copy of a ``[block, Hkv]`` slab
+of them (a slice must be aligned to the 128-lane tile). So **int8 decode
+keeps the per-page kernel** (``_int8_page_kernel``: grid ``(B, P)``, one
+pool block a step through the ``BlockSpec`` pipeline, a VPU fold), chosen
+by the scale operands being there.
+
+The multi-query kernel (prefill chunks, speculative verify) has real
+``M = T`` rows, one pool block a grid step, and batched MXU dots; its
+query rows are tiled over a grid axis so VMEM holds one tile
+(``_MQ_ROWS``), not the whole chunk.
 """
 
 from __future__ import annotations
@@ -68,14 +84,126 @@ def use_pallas_paged(head_dim, block_size):
     return head_dim % 128 == 0 and block_size % 8 == 0
 
 
-def _kernel(tables_ref, lens_ref, *refs, block_size, groups, scale,
-            quantized=False):
-    if quantized:
-        (q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-         acc_ref, m_ref, l_ref) = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
-        ks_ref = vs_ref = None
+#: VMEM the decode kernel plans for: both double buffers of a chunk's K and
+#: V pages plus the f32 working set of its fold. A quarter of Mosaic's 16 MiB
+#: scoped default, so the pipeline's q/out blocks and spills have room.
+_DECODE_VMEM_BUDGET = 4 * 1024 * 1024
+
+
+def _decode_chunk(block_size, hkv, h, d, itemsize, p):
+    """``(C, bytes)``: the pages of a decode chunk — the largest power of
+    two whose VMEM plan fits ``_DECODE_VMEM_BUDGET``, and no more than a
+    request's table holds — and that plan's bytes: two slots of C pages for
+    K and for V, and four live ``[H, C * block * Hkv]`` f32 arrays of the
+    fold (scores, probabilities, the two masks)."""
+    def plan(c):
+        cols = c * block_size * hkv
+        return 2 * 2 * cols * d * itemsize + 4 * h * cols * 4
+
+    c = 1
+    while 2 * c <= pl.next_power_of_2(p) \
+            and plan(2 * c) <= _DECODE_VMEM_BUDGET:
+        c *= 2
+    return c, plan(c)
+
+
+def _kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
+            k_buf, v_buf, sems, slot_ref, *, block_size, chunk, groups,
+            scale):
+    """Decode over fp pools: one request a grid step, a loop over its live
+    chunks of ``chunk`` pages inside (see the module docstring)."""
+    b = pl.program_id(0)
+    h, d = q_ref.shape[1:]
+    hkv = h // groups
+    rows = block_size * hkv                 # pool rows a page
+    cols = chunk * rows
+    p_max = tables_ref.shape[0] // lens_ref.shape[0]
+
+    def n_pages(r):
+        return jnp.minimum(pl.cdiv(lens_ref[r], block_size), p_max)
+
+    def copies(r, c, slot, act):
+        """Start, or wait for, the live page copies of request r's chunk c."""
+        first = c * chunk
+
+        def page(j, carry):
+            idx = tables_ref[r * p_max + first + j]
+            for i, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                act(pltpu.make_async_copy(
+                    hbm.at[idx], buf.at[slot, pl.ds(j * rows, rows)],
+                    sems.at[i, slot]))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(n_pages(r) - first, 0, chunk), page, 0)
+
+    @pl.when(b == 0)
+    def _first():
+        slot_ref[0] = 0
+        copies(0, 0, 0, lambda cp: cp.start())
+
+    ctx = lens_ref[b]
+    # an empty request still takes one (all-masked) chunk, so the next
+    # request's first copies are started
+    n_chunks = jnp.maximum(pl.cdiv(n_pages(b), chunk), 1)
+    slot0 = slot_ref[0]
+    cdt = jnp.promote_types(q_ref.dtype, k_buf.dtype)
+    q = q_ref[0].astype(cdt)                              # [H, D]
+    # column c of a chunk is token c // Hkv of it, kv head c % Hkv; a query
+    # head sees the columns of its own kv head (h = kvh * groups + g)
+    col = jax.lax.broadcasted_iota(jnp.int32, (h, cols), 1)
+    own = col % hkv == jax.lax.broadcasted_iota(
+        jnp.int32, (h, cols), 0) // groups
+    tok = col // hkv
+
+    def fold(c, carry):
+        m_prev, l_prev, acc = carry
+        slot = (slot0 + c) % 2
+        last = c + 1 == n_chunks
+        nr = jnp.where(last, b + 1, b)
+
+        @pl.when(nr < pl.num_programs(0))
+        def _prefetch():
+            copies(nr, jnp.where(last, 0, c + 1), 1 - slot,
+                   lambda cp: cp.start())
+
+        copies(b, c, slot, lambda cp: cp.wait())
+        seen = ctx - c * (chunk * block_size)     # live tokens from here on
+
+        @pl.when(seen < chunk * block_size)
+        def _tail():
+            # rows past the context were not copied, or are a page's
+            # unwritten slots: 0 x NaN is NaN in the PV dot
+            live = jax.lax.broadcasted_iota(
+                jnp.int32, (cols, d), 0) < seen * hkv
+            v_buf[slot] = jnp.where(live, v_buf[slot],
+                                    jnp.zeros((), v_buf.dtype))
+
+        s = jax.lax.dot_general(
+            q, k_buf[slot].astype(cdt), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # [H, cols]
+        s = jnp.where(own & (tok < seen), s, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        pexp = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + jnp.sum(pexp, axis=1, keepdims=True)
+        acc = acc * corr + jax.lax.dot_general(
+            pexp.astype(cdt), v_buf[slot].astype(cdt),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        return m_new, l_new, acc
+
+    m, l, acc = jax.lax.fori_loop(
+        0, n_chunks, fold,
+        (jnp.full((h, 1), NEG_INF, jnp.float32),
+         jnp.zeros((h, 1), jnp.float32), jnp.zeros((h, d), jnp.float32)))
+    slot_ref[0] = (slot0 + n_chunks) % 2
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def _int8_page_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref,
+                      vs_ref, o_ref, acc_ref, m_ref, l_ref, *, block_size,
+                      groups, scale):
+    """Decode over int8 pools: one pool block a grid step (see the module
+    docstring for why it is not the chunked kernel)."""
     p = pl.program_id(1)
 
     @pl.when(p == 0)
@@ -89,18 +217,16 @@ def _kernel(tables_ref, lens_ref, *refs, block_size, groups, scale,
 
     @pl.when(p < n_pages)
     def _page():
-        k = k_ref[0].astype(jnp.float32)                  # [block, Hkv, D]
-        v = v_ref[0].astype(jnp.float32)
-        if quantized:
-            # dequant-in-kernel: the DMA'd block is int8 codes; its scale
-            # rows [block, Hkv] ride in as scalar-prefetch-indexed
-            # operands and the multiply happens here in VMEM — HBM never
-            # sees a dequantized page
-            k = k * ks_ref[0][..., None]
-            v = v * vs_ref[0][..., None]
+        # dequant-in-kernel: the DMA'd block is int8 codes; its scale rows
+        # [block, Hkv] ride in as scalar-prefetch-indexed operands and the
+        # multiply happens here in VMEM — HBM never sees a dequantized page
+        k = k_ref[0].astype(jnp.float32) * ks_ref[0][..., None]
         tok = p * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (block_size, k.shape[1], 1), 0)
         visible = tok < ctx
+        # a page's unwritten slots may hold anything: 0 x NaN is NaN
+        v = jnp.where(visible,
+                      v_ref[0].astype(jnp.float32) * vs_ref[0][..., None], 0.0)
         for g in range(groups):
             q = q_ref[0, g].astype(jnp.float32) * scale   # [Hkv, D]
             s = jnp.sum(q[None] * k, axis=-1, keepdims=True)  # [blk, Hkv, 1]
@@ -117,45 +243,25 @@ def _kernel(tables_ref, lens_ref, *refs, block_size, groups, scale,
                     / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
-def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
-                                  context_lens, scale,
-                                  k_scale=None, v_scale=None):
-    """q [B, H, D]; pools [N, block, Hkv, D]; block_tables [B, P] int32;
-    context_lens [B] int32. Returns [B, H, D]. With int8 pools,
-    ``k_scale``/``v_scale`` [N, block, Hkv] f32 arm dequant-in-kernel."""
+def _int8_page_call(q, k_pool, v_pool, tables_flat, lens, scale, k_scale,
+                    v_scale):
     b, h, d = q.shape
-    n, block_size, hkv, _ = k_pool.shape
-    p = block_tables.shape[1]
+    _, block_size, hkv, _ = k_pool.shape
+    p = tables_flat.shape[0] // b
     groups = h // hkv
-    quantized = k_scale is not None
-    tables_flat = block_tables.reshape(-1).astype(jnp.int32)
-    lens = context_lens.astype(jnp.int32)
-    # head h = kvh * groups + g  ->  [B, G, Hkv, D]
+    # head h = kvh * groups + g  ->  [B, G, Hkv, D]: each group's heads line
+    # up one-to-one with the pool block's kv heads
     qg = jnp.swapaxes(q.reshape(b, hkv, groups, d), 1, 2)
-
     q_spec = pl.BlockSpec((1, groups, hkv, d),
                           lambda i, j, T, L: (i, 0, 0, 0))
-    in_specs = [
-        q_spec,
-        pl.BlockSpec((1, block_size, hkv, d),
-                     lambda i, j, T, L: (T[i * p + j], 0, 0, 0)),
-        pl.BlockSpec((1, block_size, hkv, d),
-                     lambda i, j, T, L: (T[i * p + j], 0, 0, 0)),
-    ]
-    operands = [qg, k_pool, v_pool]
-    if quantized:
-        in_specs += [
-            pl.BlockSpec((1, block_size, hkv),
-                         lambda i, j, T, L: (T[i * p + j], 0, 0)),
-            pl.BlockSpec((1, block_size, hkv),
-                         lambda i, j, T, L: (T[i * p + j], 0, 0)),
-        ]
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+    page = pl.BlockSpec((1, block_size, hkv, d),
+                        lambda i, j, T, L: (T[i * p + j], 0, 0, 0))
+    rows = pl.BlockSpec((1, block_size, hkv),
+                        lambda i, j, T, L: (T[i * p + j], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, p),
-        in_specs=in_specs,
+        in_specs=[q_spec, page, page, rows, rows],
         out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((groups, hkv, d), jnp.float32),
@@ -164,14 +270,59 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, block_size=block_size, groups=groups,
-                          scale=float(scale), quantized=quantized),
+        functools.partial(_int8_page_kernel, block_size=block_size,
+                          groups=groups, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, groups, hkv, d), q.dtype),
         interpret=_interpret(),
         name="paged_decode_attention",
-    )(tables_flat, lens, *operands)
+    )(tables_flat, lens, qg, k_pool, v_pool, k_scale.astype(jnp.float32),
+      v_scale.astype(jnp.float32))
     return jnp.swapaxes(out, 1, 2).reshape(b, h, d)
+
+
+def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
+                                  context_lens, scale,
+                                  k_scale=None, v_scale=None):
+    """q [B, H, D]; pools [N, block, Hkv, D]; block_tables [B, P] int32;
+    context_lens [B] int32. Returns [B, H, D]. With int8 pools,
+    ``k_scale``/``v_scale`` [N, block, Hkv] f32 arm dequant-in-kernel."""
+    b, h, d = q.shape
+    n, block_size, hkv, _ = k_pool.shape
+    tables_flat = block_tables.reshape(-1).astype(jnp.int32)
+    lens = context_lens.astype(jnp.int32)
+    if k_scale is not None:
+        return _int8_page_call(q, k_pool, v_pool, tables_flat, lens,
+                               float(scale), k_scale, v_scale)
+    chunk, _ = _decode_chunk(block_size, hkv, h, d, k_pool.dtype.itemsize,
+                             block_tables.shape[1])
+    rows = block_size * hkv
+    q_spec = pl.BlockSpec((1, h, d), lambda i, T, L: (i, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[q_spec, hbm, hbm],
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((2, chunk * rows, d), k_pool.dtype),
+            pltpu.VMEM((2, chunk * rows, d), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel, block_size=block_size, chunk=chunk,
+                          groups=h // hkv, scale=float(scale)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        # the copy pipeline runs from one request into the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(),
+        name="paged_decode_attention",
+    )(tables_flat, lens, q, k_pool.reshape(n, rows, d),
+      v_pool.reshape(n, rows, d))
 
 
 #: multi-query grid tile: at most ``_MQ_ROWS`` query rows and at most

@@ -261,14 +261,16 @@ def test_arrival_t_is_the_due_time(model, tracer):
 
 # --- kernels under names of their own -------------------------------------------
 
-def _paged_decode():
+def _paged_decode(int8=False):
     from paddle_tpu.ops.pallas import paged_attention as pa
 
-    pool = jnp.zeros((8, 16, 2, 128), jnp.float32)
-    return (lambda q, k, v, t, n: pa.paged_decode_attention_pallas(
-        q, k, v, t, n, 0.088)), (
+    pool = jnp.zeros((8, 16, 2, 128), jnp.int8 if int8 else jnp.float32)
+    # int8 pools go to a call of their own, under the same name
+    scales = (jnp.ones((8, 16, 2), jnp.float32),) * 2 if int8 else ()
+    return (lambda q, k, v, t, n, *s: pa.paged_decode_attention_pallas(
+        q, k, v, t, n, 0.088, *s)), (
         jnp.zeros((2, 4, 128), jnp.float32), pool, pool,
-        jnp.zeros((2, 4), jnp.int32), jnp.ones((2,), jnp.int32))
+        jnp.zeros((2, 4), jnp.int32), jnp.ones((2,), jnp.int32)) + scales
 
 
 def _paged_prefill():
@@ -325,6 +327,7 @@ def _moe_ffn():
 
 KERNELS = [
     ("paged_decode_attention", _paged_decode),
+    ("paged_decode_attention", lambda: _paged_decode(int8=True)),
     ("paged_prefill_attention", _paged_prefill),
     ("flash_attention_fwd", lambda: _flash(False)),
     ("flash_attention_bwd_dq", lambda: _flash(True)),

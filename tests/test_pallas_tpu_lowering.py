@@ -39,31 +39,40 @@ H, D, BLK, N, P = 16, 128, 16, 512, 128
 
 def _paged_cases():
     """(name, fn, abstract args) for decode and every multi-query rung the
-    smoke touches, fp and int8 pools."""
+    smoke touches, fp and int8 pools; decode also at the benchmark's
+    serving geometry (Mistral-7B: 32 requests, 32 heads over 8 kv heads,
+    256 pages a request, 6145 pages) and at one tp4 shard of it."""
     sds = jax.ShapeDtypeStruct
     cases = []
+
+    def decode(q, k, v, t, l, *s):
+        return pa.paged_decode_attention_pallas(
+            q, k, v, t, l, 0.088, **dict(zip(("k_scale", "v_scale"), s)))
+
+    def mq(q, k, v, t, l, st, *s):
+        return pa.paged_multiquery_attention_pallas(
+            q, k, v, t, l, st, 0.088,
+            **dict(zip(("k_scale", "v_scale"), s)))
+
+    def pools(n, hkv, kv):
+        pool = sds((n, BLK, hkv, D), kv)
+        return (pool, pool), ((sds((n, BLK, hkv), jnp.float32),) * 2
+                              if kv == jnp.int8 else ())
+
     for kv in (jnp.bfloat16, jnp.int8):
-        pool = sds((N, BLK, H, D), kv)
-        scales = ((sds((N, BLK, H), jnp.float32),) * 2
-                  if kv == jnp.int8 else ())
-
-        def decode(q, k, v, t, l, *s):
-            return pa.paged_decode_attention_pallas(
-                q, k, v, t, l, 0.088, **dict(zip(("k_scale", "v_scale"), s)))
-
-        def mq(q, k, v, t, l, st, *s):
-            return pa.paged_multiquery_attention_pallas(
-                q, k, v, t, l, st, 0.088,
-                **dict(zip(("k_scale", "v_scale"), s)))
-
         name = jnp.dtype(kv).name
-        cases.append((f"decode-{name}", decode,
-                      (sds((8, H, D), jnp.bfloat16), pool, pool,
-                       sds((8, P), jnp.int32), sds((8,), jnp.int32))
-                      + scales))
+        for geo, b, h, hkv, p, n in (("", 8, H, H, P, N),
+                                     ("-mistral", 32, 32, 8, 256, 6145),
+                                     ("-tp4shard", 32, 8, 2, 256, 6145)):
+            (k, v), scales = pools(n, hkv, kv)
+            cases.append((f"decode{geo}-{name}", decode,
+                          (sds((b, h, D), jnp.bfloat16), k, v,
+                           sds((b, p), jnp.int32), sds((b,), jnp.int32))
+                          + scales))
+        (k, v), scales = pools(N, H, kv)
         for t in (64, 128, 2048):
             cases.append((f"mq{t}-{name}", mq,
-                          (sds((1, t, H, D), jnp.bfloat16), pool, pool,
+                          (sds((1, t, H, D), jnp.bfloat16), k, v,
                            sds((1, P), jnp.int32), sds((1,), jnp.int32),
                            sds((1,), jnp.int32)) + scales))
     return cases
@@ -130,10 +139,12 @@ _COMPILE = textwrap.dedent("""
     for name, fn, args in T.COMPILED_CASES:
         text = jax.jit(fn).trace(*on(one, args)).lower().compile().as_text()
         print("COMPILED", name, flush=True)
-        # the HLO instructions that are kernels, by their own names
+        # the HLO instructions that are Mosaic kernels, by their own names
+        # (XLA has custom calls of its own, e.g. ``ConcatBitcast``)
         print("KERNELS", name, *sorted(set(
             ln.split(" = ")[0].split("%")[-1].rsplit(".", 1)[0]
-            for ln in text.splitlines() if " custom-call(" in ln)), flush=True)
+            for ln in text.splitlines() if " custom-call(" in ln
+            and 'custom_call_target="tpu_custom_call"' in ln)), flush=True)
 
     # the rope op on its own, at head-dim 128 (the split-and-concatenate
     # form aborted the compiler here)
@@ -197,3 +208,83 @@ def test_compiles_for_v5e_without_a_chip():
                 assert len(got) == len(want) and all(
                     any(w in g for g in got) for w in want), (name, got)
     assert set(kernels) == {c[0] for c in COMPILED_CASES}
+
+
+# --- the decode kernel's chunk, and its parity in interpret mode ------------
+
+def test_decode_chunk_fits_its_vmem_budget():
+    """The pages a decode chunk holds follow from the operands' shapes; at
+    the benchmark's geometry that is 16 pages (256 tokens), and the plan
+    stays inside the stated budget, itself well inside Mosaic's 16 MiB."""
+    assert pa._DECODE_VMEM_BUDGET <= 16 * 2 ** 20 // 4
+    for shape, want in (((16, 8, 32, 128, 2, 256), 16),    # Mistral-7B
+                        ((16, 2, 8, 128, 2, 256), 64),     # a tp4 shard
+                        ((16, 16, 16, 128, 2, 128), 8),    # llama_1b
+                        ((16, 8, 32, 128, 2, 4), 4)):      # a 4-page table
+        chunk, nbytes = pa._decode_chunk(*shape)
+        assert chunk == want, (shape, chunk)
+        assert nbytes <= pa._DECODE_VMEM_BUDGET, (shape, nbytes)
+
+
+@pytest.mark.parametrize("groups", [1, 4])
+@pytest.mark.parametrize("pool", ["float32-2pages", "float32",
+                                  "bfloat16-2pages", "int8"])
+def test_decode_interpret_matches_lax_fallback(groups, pool, monkeypatch):
+    """One batch with a context at every edge of a page and of a chunk
+    (1, block - 1, block, C*block - 1, C*block, C*block + 1, the cap),
+    unused table slots on the null page, and NaN in every pool slot that
+    holds no live token: a dead column has p = 0, and 0 x NaN is NaN."""
+    import numpy as np
+
+    from paddle_tpu.inference.serving.kv_cache import quantize_kv_rows
+    from paddle_tpu.inference.serving.paged_attention import _lax_fallback
+
+    monkeypatch.setenv("PT_PALLAS_INTERPRET", "1")
+    hkv, d, blk, p = 2, 16, 4, 10
+    h = hkv * groups
+    dtype = jnp.bfloat16 if pool.startswith("bfloat16") else jnp.float32
+    if pool.endswith("2pages"):
+        # the budget that fits a 2-page chunk and not a 4-page one
+        for budget in range(256, 1 << 20, 64):
+            monkeypatch.setattr(pa, "_DECODE_VMEM_BUDGET", budget)
+            if pa._decode_chunk(blk, hkv, h, d, 4, p)[0] == 2:
+                break
+    chunk = pa._decode_chunk(blk, hkv, h, d, 4, p)[0]
+    assert chunk == (2 if pool.endswith("2pages") else 16)
+    c = 2 * blk   # the chunk edge of the 2-page cases; a page edge otherwise
+    lens = np.array([1, blk - 1, blk, c - 1, c, c + 1, 2 * c + 2, p * blk],
+                    np.int32)
+    b, n = len(lens), len(lens) * p + 1
+    rng = np.random.default_rng(groups)
+    order = rng.permutation(np.arange(1, n))
+    tables = np.zeros((b, p), np.int32)
+    live = np.zeros((n, blk), bool)
+    at = 0
+    for i, ctx in enumerate(lens):
+        pages = -(-ctx // blk)
+        tables[i, :pages] = order[at:at + pages]
+        at += pages
+        live[tables[i, :pages]] = True
+        live[tables[i, pages - 1], ctx - (pages - 1) * blk:] = False
+    q = jnp.asarray(rng.standard_normal((b, h, d)), dtype)
+    k_pool = jnp.asarray(rng.standard_normal((n, blk, hkv, d)), dtype)
+    v_pool = jnp.asarray(rng.standard_normal((n, blk, hkv, d)), dtype)
+    scales, dirty_scales = {}, {}
+    if pool == "int8":
+        k_pool, ks = quantize_kv_rows(k_pool)
+        v_pool, vs = quantize_kv_rows(v_pool)
+        scales = {"k_scale": ks, "v_scale": vs}
+        dirty_scales = {key: jnp.where(jnp.asarray(live)[..., None], s,
+                                       jnp.nan) for key, s in scales.items()}
+        dirty = (k_pool, v_pool)     # codes cannot hold NaN; scales do
+    else:
+        dirty = tuple(jnp.where(jnp.asarray(live)[..., None, None], x,
+                                jnp.nan) for x in (k_pool, v_pool))
+    want = _lax_fallback(q[:, None], k_pool, v_pool, jnp.asarray(tables),
+                         jnp.asarray(lens), 0.3, **scales)[:, 0]
+    got = pa.paged_decode_attention_pallas(
+        q, *dirty, jnp.asarray(tables), jnp.asarray(lens), 0.3,
+        **dirty_scales)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=2e-2 if dtype == jnp.bfloat16 else 1e-5)
