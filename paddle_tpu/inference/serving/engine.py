@@ -135,17 +135,18 @@ _G_QUANT_BLOCKS = _obs_metrics.gauge(
     "step (0 series absent on unquantized engines) — the occupancy the "
     "halved block memory buys")
 # device-resident decode (ISSUE 18): how often the decode loop blocks on
-# a device->host fetch and how many bytes it pulls. Host-side sampling
-# fetches [B, V] f32 logits per emitted token; in-graph sampling fetches
-# [B] int32 tokens; a fused k-step window fetches [B, k] int32 once.
+# a device->host fetch and how many bytes it pulls. A step whose rows the
+# host samples from or keeps (do_sample, capture_logits) fetches [B, V]
+# f32 logits; a greedy step fetches [B] int32 tokens, the decode graph's
+# own argmax (ISSUE 27); a fused k-step window fetches [B, k] int32 once.
 _M_HOST_SYNCS = _obs_metrics.counter(
     "serving_host_syncs_total",
     "blocking device->host fetches made by the decode loop (logits or "
     "sampled tokens); one per decode round-trip, prefill fetches excluded")
 _M_FETCH_BYTES = _obs_metrics.counter(
     "serving_decode_fetch_bytes_total",
-    "bytes fetched device->host by the decode loop: B*V*4 per step under "
-    "host-side sampling, B*4 per step with in-graph sampling, B*k*4 per "
+    "bytes fetched device->host by the decode loop: B*V*4 per step whose "
+    "rows the host samples from or keeps, B*4 per greedy step, B*k*4 per "
     "fused k-step decode window")
 
 # the ONE list of every serving metric handle an engine instance owns —
@@ -175,6 +176,30 @@ _SERVING_METRICS = (_M_ADMITTED, _M_EVICTIONS, _M_FINISHED, _M_QUEUED_EXH,
                     # serving integrity (ISSUE 20)
                     _M_PAGES_VERIFIED, _M_PAGES_REJECTED,
                     _M_WEIGHT_AUDIT_FAIL)
+
+
+#: what a model exposes to be served (``models/llama.py`` has the
+#: reference implementation of each)
+_SERVING_CALLS = ("kv_layout", "serve_dtype", "serve_embed", "serve_layer",
+                  "serve_norm", "serve_head")
+
+
+def _counter_names(model):
+    """The device-side counters a model's ``serve_layer`` adds to."""
+    return tuple(getattr(model, "serve_counters", ()))
+
+
+def _add_counts(counters, counts, names, decode):
+    """The step's counter array plus what the layers counted: the decode
+    graph's counts in the first ``len(names)`` places, the prefill chunk's
+    in the second."""
+    import jax.numpy as jnp
+
+    if not names:
+        return counters
+    got = jnp.stack([jnp.asarray(counts.get(n, 0), jnp.int32) for n in names])
+    zero = jnp.zeros_like(got)
+    return counters + jnp.concatenate([got, zero] if decode else [zero, got])
 
 
 class _StepPhases:
@@ -335,10 +360,21 @@ class LLMEngine:
                  kv_page_checksums=False, weight_audit=False):
         from ...models.llama import LlamaForCausalLM, sample_next_tokens
 
-        if not isinstance(model, LlamaForCausalLM):
-            raise TypeError("LLMEngine serves LlamaForCausalLM models; got "
-                            f"{type(model).__name__}")
+        # the serving calls a model exposes (ISSUE 27): the prefill-chunk
+        # and decode graphs are built from these and nothing else of it
+        missing = [a for a in _SERVING_CALLS if not hasattr(model, a)]
+        if missing:
+            raise TypeError(
+                "LLMEngine serves models that expose the serving calls "
+                f"{', '.join(_SERVING_CALLS)}; {type(model).__name__} "
+                f"lacks {', '.join(missing)}")
+        # decode windows, draft verify and catch-up keep Llama's layer
+        # body written out (or were only ever run with it): another model
+        # is refused below, by name
+        self._llama = isinstance(model, LlamaForCausalLM)
         self.model = model
+        if not self._llama and plan is not None:
+            raise ValueError(self._llama_only("plan (a sharded engine)"))
         # sharding plan (distributed.plan.Plan): weights are committed to
         # the plan's layouts (e.g. Megatron tp for pod-scale serving) and
         # both engine executables lower through compile_step_with_plan —
@@ -407,14 +443,33 @@ class LLMEngine:
                 f"{self.max_model_len} so prefill stays page-aligned",
                 RuntimeWarning)
         self.max_pages = self.max_model_len // self.block_size
-        dtype = model.llama.layers[0].self_attn.k_proj.weight.dtype
+        dtype = model.serve_dtype()
         # int8 paged-KV quantization (ISSUE 14): pools store codes +
         # per-row scale sidecars, dequantized inside the attention
         # kernels; everything identity-shaped (allocator, prefix cache,
         # COW, tables) is payload-dtype-blind and composes unchanged
         self.kv_dtype = kv_dtype
+        # per-layer pool geometry and kinds of pages (ISSUE 27): the model
+        # says what each layer caches; a window kind gets a pool, an
+        # allocator and a block table of its own, which the cache sizes
+        # from the batch (every slot a full ring: it never preempts)
         self.cache = PagedKVCache(self.config, num_blocks, block_size,
-                                  dtype=dtype, kv_dtype=kv_dtype)
+                                  dtype=dtype, kv_dtype=kv_dtype,
+                                  layout=model.kv_layout(),
+                                  max_batch_size=max_batch_size)
+        if not self.cache.uniform:
+            # what assumes one layout refuses here rather than corrupt
+            for flag, what in (
+                    (enable_prefix_cache, "enable_prefix_cache (block "
+                     "identity is one hash a block of ONE table)"),
+                    (int(kv_host_blocks) > 0, "kv_host_blocks > 0 (the "
+                     "host tier exports pages)"),
+                    (prefix_store_path is not None, "prefix_store_path"),
+                    (prefill_only, "prefill_only (the handoff exports "
+                     "pages)"),
+                    (kv_page_checksums, "kv_page_checksums")):
+                if flag:
+                    self.cache._require_uniform(what)
         # serving integrity (ISSUE 20): arm per-block CRC sealing of
         # every host-materialized page payload; read-back boundaries
         # (tier revive, page import, prefix-store entries) verify and
@@ -479,7 +534,8 @@ class LLMEngine:
                                    max_batch_size, max_prefills_per_step,
                                    instance=self._name,
                                    prefix_cache=self.prefix_cache,
-                                   kv_tier=self.kv_tier)
+                                   kv_tier=self.kv_tier,
+                                   window_pages=self.cache.window)
         if self.cache.quantized:
             _M_KV_SAVED.inc(self._kv_bytes_saved, instance=self._name)
             _G_QUANT_BLOCKS.set(0, instance=self._name)
@@ -511,6 +567,9 @@ class LLMEngine:
         self.draft_model = draft_model
         self._spec_k = 0
         if draft_model is not None:
+            if not self._llama:
+                raise ValueError(self._llama_only(
+                    "draft_model (speculative verify and draft catch-up)"))
             if not isinstance(draft_model, LlamaForCausalLM):
                 raise TypeError("draft_model must be a LlamaForCausalLM; "
                                 f"got {type(draft_model).__name__}")
@@ -543,8 +602,9 @@ class LLMEngine:
         # shrinks the per-step fetch from [B, V] f32 logits to [B] int32
         # tokens; fused windows (decode_steps_per_sync=k) run k decode
         # iterations inside one fori_loop graph and fetch [B, k] tokens
-        # per host round-trip. k=1 with in_graph_sampling unset keeps the
-        # pre-ISSUE-18 host-sampling path byte-identical.
+        # per host round-trip. k=1 with in_graph_sampling unset decodes a
+        # step at a time; the host samples wherever a request asks for it
+        # and otherwise fetches the decode graph's own argmax (ISSUE 27).
         k = int(decode_steps_per_sync)
         if k < 1:
             raise ValueError(
@@ -557,6 +617,10 @@ class LLMEngine:
         if in_graph_sampling is None:
             in_graph_sampling = k > 1
         in_graph_sampling = bool(in_graph_sampling)
+        if in_graph_sampling and not self._llama:
+            raise ValueError(self._llama_only(
+                "decode_steps_per_sync > 1 / in_graph_sampling (fused "
+                "decode windows)"))
         if k > 1 and not in_graph_sampling:
             raise ValueError(
                 "decode_steps_per_sync > 1 requires in_graph_sampling: a "
@@ -573,6 +637,8 @@ class LLMEngine:
                 "device-resident decode never fetches the logits rows")
         self._decode_window = k
         self._in_graph = in_graph_sampling
+        #: read at every step, so a caller may switch it off once it has
+        #: the rows it wanted: greedy steps then fetch tokens only
         self.capture_logits = bool(capture_logits)
         self._window_name = f"llm_engine_decode_window#{n}"
         self._window_jit = None
@@ -585,6 +651,21 @@ class LLMEngine:
         # ZERO table H2D
         self._tables_version = None
         self._tables_dev = None
+        #: with a window kind: the host copy of ``_decode_tables``' array
+        #: and how much of each row's block list it holds
+        self._tables_host = None
+        self._tables_known = None
+        self._tables_mask = None
+        # device-side counters of the model's layer steps (ISSUE 27): a
+        # small int32 array carried through the chunk and decode graphs,
+        # fetched (and folded into these host totals) only by metrics()
+        self._counter_names = _counter_names(model)
+        self._counters_dev = None
+        self._counter_totals = dict.fromkeys(
+            [n + tail for tail in ("_decode", "_prefill")
+             for n in self._counter_names], 0)
+        # page-steps of either kind, for the live-bytes-vs-one-table ratio
+        self._page_steps = [0, 0]
         self._requests: dict[int, Request] = {}
         self._closed = False
         # fused ragged draft catch-up (ISSUE 16 perf satellite): one
@@ -688,6 +769,21 @@ class LLMEngine:
                 warnings.warn(f"{self._name}: prefix store autosave "
                               f"failed: {e}", RuntimeWarning)
                 self._store_saved_chains = len(self.prefix_cache)
+
+    def _llama_only(self, what):
+        return (f"{what} is built for LlamaForCausalLM only; this engine "
+                f"serves {type(self.model).__name__}")
+
+    def _graph_extras(self, window_operand):
+        """The operands a cache with a window kind, or a model with
+        counters, adds behind a graph's pools (none for Llama, whose
+        graphs keep their operands)."""
+        if self.cache.window is None and not self._counter_names:
+            return ()
+        if self._counters_dev is None:
+            self._counters_dev = self._g(
+                np.zeros(max(2 * len(self._counter_names), 1), np.int32))
+        return (window_operand, self._counters_dev)
 
     def _ensure_open(self):
         if self._closed:
@@ -1106,104 +1202,68 @@ class LLMEngine:
         [0, true_upto) via paged multi-query attention, so one graph per
         chunk-length bucket serves every offset. Quantized caches
         (non-empty scale lists) quantize each page's rows on write and
-        store the per-row scales beside the codes (ISSUE 14)."""
+        store the per-row scales beside the codes (ISSUE 14).
+
+        The layer body is the model's (``serve_layer``); what a layer
+        writes and attends over is its ``ChunkAttnState``. A cache with a
+        window kind, or a model with counters, adds two operands behind
+        the pools — the request's window row and the counter array — and
+        one result, the counters."""
         from ...core import state as _state
         from ...core.tensor import Tensor
 
         block_size = self.block_size
-        _head = self._head_fn(model)
         _arr = self._arr
+        layout = model.kv_layout()
+        counter_names = _counter_names(model)
+        n_tail = self.cache.window.n_tail if self.cache.window else 0
 
         def chunk_pure(param_arrays, ids, start, true_upto, tables_row,
-                       k_pools, v_pools, k_scales, v_scales):
+                       k_pools, v_pools, k_scales, v_scales, *extra):
             import jax
             import jax.numpy as jnp
 
-            from ...models.llama import _rope_apply_at
-            from ...ops import manipulation as M
-            from .kv_cache import quantize_kv_rows
-            from .paged_attention import paged_multiquery_attention
+            from .paged_attention import ChunkAttnState
 
             quantized = len(k_scales) > 0
             ks_in = k_scales if quantized else [None] * len(k_pools)
             vs_in = v_scales if quantized else [None] * len(v_pools)
+            window_row, counters = extra if extra else (None, None)
+            counts = {} if extra else None
             old = [p._data for p in params]
             try:
                 for p, a in zip(params, param_arrays):
                     p._data = a
                 with _state.trace_guard():
-                    sb = ids.shape[1]
-                    pages = sb // block_size
                     start = jnp.asarray(start, jnp.int32)
                     upto = jnp.asarray(true_upto, jnp.int32)
-                    blks = jax.lax.dynamic_slice(
-                        tables_row, (start // block_size,), (pages,))
-                    tables2 = tables_row[None]  # [1, P]
-                    x = model.llama.embed_tokens(Tensor._wrap(ids))
-                    cos_t = _arr(model.llama.rope_cos)
-                    sin_t = _arr(model.llama.rope_sin)
+                    x = model.serve_embed(ids)
                     new_k, new_v, new_ks, new_vs = [], [], [], []
-                    for layer, kp, vp, ksc, vsc in zip(model.llama.layers,
-                                                       k_pools, v_pools,
-                                                       ks_in, vs_in):
-                        attn = layer.self_attn
-                        h = layer.input_layernorm(x)
-                        b, s = 1, sb
-                        q = M.reshape(attn.q_proj(h),
-                                      [b, s, attn.num_heads, attn.head_dim])
-                        k = M.reshape(attn.k_proj(h),
-                                      [b, s, attn.num_kv_heads,
-                                       attn.head_dim])
-                        v = M.reshape(attn.v_proj(h),
-                                      [b, s, attn.num_kv_heads,
-                                       attn.head_dim])
-                        qa = _rope_apply_at.raw_fn(_arr(q), cos_t, sin_t,
-                                                   start)
-                        ka = _rope_apply_at.raw_fn(_arr(k), cos_t, sin_t,
-                                                   start)
-                        va = _arr(v)
-                        # one scatter per pool: the chunk's pages land
-                        # in its blocks at once (a page-by-page
-                        # dynamic_update_slice loop made the 128-page top
-                        # bucket a minutes-long compile). Bucket pages
-                        # past the request's blocks all hit null block 0.
-                        def paged(a):
-                            return a.reshape((pages, block_size)
-                                             + a.shape[2:])
-
+                    for i, (spec, kp, vp, ksc, vsc) in enumerate(zip(
+                            layout, k_pools, v_pools, ks_in, vs_in)):
+                        st = ChunkAttnState(
+                            spec, block_size, start, upto, tables_row,
+                            kp, vp, ksc, vsc, window_row=window_row,
+                            n_tail=n_tail, counters=counts)
+                        x = model.serve_layer(i, x, st)
+                        new_k.append(st.k_pool)
+                        new_v.append(st.v_pool)
                         if quantized:
-                            qk, sk = quantize_kv_rows(ka)
-                            qv, sv = quantize_kv_rows(va)
-                            kp = kp.at[blks].set(paged(qk))
-                            vp = vp.at[blks].set(paged(qv))
-                            ksc = ksc.at[blks].set(paged(sk))
-                            vsc = vsc.at[blks].set(paged(sv))
-                        else:
-                            kp = kp.at[blks].set(paged(ka).astype(kp.dtype))
-                            vp = vp.at[blks].set(paged(va).astype(vp.dtype))
-                        out = paged_multiquery_attention(
-                            qa, kp, vp, tables2, upto[None], start[None],
-                            scale=1.0 / math.sqrt(attn.head_dim),
-                            k_scale=ksc, v_scale=vsc)
-                        attn_out = attn.o_proj(
-                            M.reshape(Tensor._wrap(out), [b, s, -1]))
-                        x = x + attn_out
-                        x = x + layer.mlp(layer.post_attention_layernorm(x))
-                        new_k.append(kp)
-                        new_v.append(vp)
-                        if quantized:
-                            new_ks.append(ksc)
-                            new_vs.append(vsc)
-                    h = model.llama.norm(x)
+                            new_ks.append(st.k_scale)
+                            new_vs.append(st.v_scale)
+                    h = model.serve_norm(x)
                     h_arr = _arr(h)
                     last = jax.lax.dynamic_slice(
                         h_arr, (0, upto - 1 - start, 0),
                         (1, 1, h_arr.shape[-1]))
-                    logits = _head(Tensor._wrap(last))
+                    logits = model.serve_head(Tensor._wrap(last))
             finally:
                 for p, a in zip(params, old):
                     p._data = a
-            return _arr(logits)[:, 0], new_k, new_v, new_ks, new_vs
+            out = (_arr(logits)[:, 0], new_k, new_v, new_ks, new_vs)
+            if extra:
+                out += (_add_counts(counters, counts, counter_names, False),)
+            return out
 
         return chunk_pure
 
@@ -1213,98 +1273,42 @@ class LLMEngine:
         the fused path must run the IDENTICAL op sequence per step or
         draft proposals — and therefore acceptance counts — would drift
         between modes. Assumes params are already swapped in and the
-        caller is inside ``trace_guard``.
+        caller is inside ``trace_guard``. The layer body is the model's
+        (``serve_layer``) over a ``DecodeAttnState`` a layer.
 
         ``active`` (jnp [B] bool, optional) is the fused decode window's
         EOS-freeze mask (ISSUE 18): rows marked inactive have their K/V
         write redirected to the reserved null block 0 at offset 0 — the
         same scratch target empty slots already write through their
         all-zero table rows — so a finished row can ride out the rest of
-        the window without corrupting live pages."""
-        from ...core.tensor import Tensor
-
+        the window without corrupting live pages. ``wtables`` is the
+        window kind's ring table, ``counts`` the dict the layers' counters
+        land in."""
         block_size = self.block_size
-        _head = self._head_fn(model)
         _arr = self._arr
+        layout = model.kv_layout()
 
         def core(ids, positions, tables, k_pools, v_pools, ks_in, vs_in,
-                 active=None):
-            import jax
-            import jax.numpy as jnp
-
-            from ...models.llama import rope_rotate
-            from ...ops import manipulation as M
-            from .kv_cache import quantize_kv_rows
-            from .paged_attention import paged_decode_attention
+                 active=None, wtables=None, counts=None):
+            from .paged_attention import DecodeAttnState
 
             quantized = ks_in[0] is not None if ks_in else False
-            bsz = ids.shape[0]
-            x = model.llama.embed_tokens(Tensor._wrap(ids))
-            cos_t = _arr(model.llama.rope_cos)
-            sin_t = _arr(model.llama.rope_sin)
-            # batched rope at per-request positions
-            c = cos_t[positions][:, None, None, :]
-            sn = sin_t[positions][:, None, None, :]
+            x = model.serve_embed(ids)
             new_k, new_v, new_ks, new_vs = [], [], [], []
-            for layer, kp, vp, ksc, vsc in zip(model.llama.layers,
-                                               k_pools, v_pools,
-                                               ks_in, vs_in):
-                attn = layer.self_attn
-                h = layer.input_layernorm(x)
-                q = M.reshape(attn.q_proj(h),
-                              [bsz, 1, attn.num_heads, attn.head_dim])
-                k = M.reshape(attn.k_proj(h),
-                              [bsz, 1, attn.num_kv_heads,
-                               attn.head_dim])
-                v = M.reshape(attn.v_proj(h),
-                              [bsz, 1, attn.num_kv_heads,
-                               attn.head_dim])
-
-                qa = rope_rotate(_arr(q), c, sn)
-                ka, va = rope_rotate(_arr(k), c, sn), _arr(v)
-                blk = tables[jnp.arange(bsz),
-                             positions // block_size]
-                off = positions % block_size
-                if active is not None:
-                    # EOS-freeze: park frozen rows' writes on the null
-                    # block (reserved, never allocated to a request)
-                    blk = jnp.where(active, blk, 0)
-                    off = jnp.where(active, off, 0)
+            for i, (spec, kp, vp, ksc, vsc) in enumerate(zip(
+                    layout, k_pools, v_pools, ks_in, vs_in)):
+                st = DecodeAttnState(
+                    spec, block_size, positions,
+                    wtables if spec.kind == "window" else tables,
+                    kp, vp, ksc, vsc, active=active, counters=counts)
+                x = model.serve_layer(i, x, st)
+                new_k.append(st.k_pool)
+                new_v.append(st.v_pool)
                 if quantized:
-                    qk, sk = quantize_kv_rows(ka)   # [B,1,Hkv,D]
-                    qv, sv = quantize_kv_rows(va)
-                for i in range(bsz):
-                    if quantized:
-                        kp = jax.lax.dynamic_update_slice(
-                            kp, qk[i:i + 1], (blk[i], off[i], 0, 0))
-                        vp = jax.lax.dynamic_update_slice(
-                            vp, qv[i:i + 1], (blk[i], off[i], 0, 0))
-                        ksc = jax.lax.dynamic_update_slice(
-                            ksc, sk[i:i + 1], (blk[i], off[i], 0))
-                        vsc = jax.lax.dynamic_update_slice(
-                            vsc, sv[i:i + 1], (blk[i], off[i], 0))
-                    else:
-                        kp = jax.lax.dynamic_update_slice(
-                            kp, ka[i:i + 1].astype(kp.dtype),
-                            (blk[i], off[i], 0, 0))
-                        vp = jax.lax.dynamic_update_slice(
-                            vp, va[i:i + 1].astype(vp.dtype),
-                            (blk[i], off[i], 0, 0))
-                out = paged_decode_attention(
-                    qa, kp, vp, tables, positions + 1,
-                    scale=1.0 / math.sqrt(attn.head_dim),
-                    k_scale=ksc, v_scale=vsc)
-                attn_out = attn.o_proj(
-                    M.reshape(Tensor._wrap(out), [bsz, 1, -1]))
-                x = x + attn_out
-                x = x + layer.mlp(layer.post_attention_layernorm(x))
-                new_k.append(kp)
-                new_v.append(vp)
-                if quantized:
-                    new_ks.append(ksc)
-                    new_vs.append(vsc)
-            h = model.llama.norm(x)
-            logits = _head(h[:, -1:])
+                    new_ks.append(st.k_scale)
+                    new_vs.append(st.v_scale)
+            h = model.serve_norm(x)
+            logits = model.serve_head(h[:, -1:])
             return _arr(logits)[:, 0], new_k, new_v, new_ks, new_vs
 
         return core
@@ -1312,19 +1316,35 @@ class LLMEngine:
     def _make_decode_fn(self, model, params):
         """Pure one-token decode over ``model``: ``(param_arrays,
         ids [B, 1], positions [B], tables [B, P], k_pools, v_pools,
-        k_scales, v_scales) -> (logits [B, V], pools, scale pools)``.
-        Writes each token at ``positions``, attends over ``positions+1``
-        ragged lengths. Quantized caches quantize the written row and
-        store its per-head scale beside the codes (ISSUE 14)."""
+        k_scales, v_scales) -> (logits [B, V], greedy tokens [B] int32,
+        pools, scale pools)``. Writes each token at ``positions``, attends
+        over ``positions+1`` ragged lengths. The greedy tokens are the
+        logits' argmax taken in the graph (``greedy_tokens_in_graph``: the
+        host sampler's choice, bit for bit), so that a step whose requests
+        all decode greedily fetches ``[B]`` int32 and not ``[B, V]``
+        float32. Quantized caches quantize the written row and
+        store its per-head scale beside the codes (ISSUE 14). The two
+        extra operands and the extra result are ``_make_chunk_fn``'s, but
+        the first of them is empty here: with a window kind ``tables`` is
+        ``_decode_tables``' ``[B, P + R]``, the rings behind the global
+        table, so that a step puts one table and not two."""
         from ...core import state as _state
+        from ...models.llama import greedy_tokens_in_graph
 
         core = self._make_decode_core(model)
+        counter_names = _counter_names(model)
+        windowed, P = self.cache.window is not None, self.max_pages
 
         def decode_pure(param_arrays, ids, positions, tables,
-                        k_pools, v_pools, k_scales, v_scales):
+                        k_pools, v_pools, k_scales, v_scales, *extra):
             quantized = len(k_scales) > 0
             ks_in = k_scales if quantized else [None] * len(k_pools)
             vs_in = v_scales if quantized else [None] * len(v_pools)
+            _, counters = extra if extra else (None, None)
+            counts = {} if extra else None
+            wtables = None
+            if windowed:        # ``_decode_tables``: global | rings
+                tables, wtables = tables[:, :P], tables[:, P:]
             old = [p._data for p in params]
             try:
                 for p, a in zip(params, param_arrays):
@@ -1332,11 +1352,15 @@ class LLMEngine:
                 with _state.trace_guard():
                     logits, new_k, new_v, new_ks, new_vs = core(
                         ids, positions, tables, k_pools, v_pools,
-                        ks_in, vs_in)
+                        ks_in, vs_in, wtables=wtables, counts=counts)
             finally:
                 for p, a in zip(params, old):
                     p._data = a
-            return logits, new_k, new_v, new_ks, new_vs
+            out = (logits, greedy_tokens_in_graph(logits),
+                   new_k, new_v, new_ks, new_vs)
+            if extra:
+                out += (_add_counts(counters, counts, counter_names, True),)
+            return out
 
         return decode_pure
 
@@ -1641,7 +1665,7 @@ class LLMEngine:
         self._decode_jit = compile_step_with_plan(
             self._make_decode_fn(self.model, self._params), self._plan,
             name=self._decode_name, donate_argnums=(4, 5, 6, 7),
-            out_specs=pool_out)
+            out_specs=pool_out and pool_out[:1] + pool_out)
         if self._in_graph:
             self._window_jit = compile_step_with_plan(
                 self._make_window_fn(self.model, self._params,
@@ -1701,6 +1725,48 @@ class LLMEngine:
             self._tables_version = key
         return self._tables_dev
 
+    def _decode_tables(self, ready):
+        """The decode graph's table operand. Without a window kind that is
+        ``_tables()``. With one it is the global table and the rings side by
+        side, ``[B, P + R]`` in ONE put (the graph cuts it in two), after
+        turning every ready request's ring to the page its next token lands
+        in: the page that fell out of the window goes back to the allocator
+        here. The host copy is kept and only what moved is rewritten: a row
+        of the global table grows at its end (nothing with a window kind
+        shares or copies a block), a ring turns once a page; a change of the
+        ready slots rewrites it all."""
+        window = self.cache.window
+        if window is None:
+            return self._tables()
+        P, bs = self.max_pages, self.block_size
+        host, known = self._tables_host, self._tables_known
+        if host is None:
+            host = self._tables_host = np.zeros(
+                (self.max_batch_size, P + window.ring), np.int32)
+            known = self._tables_known = [0] * self.max_batch_size
+        mask = tuple((i, req.rid) for i, req in ready)
+        fresh = mask != self._tables_mask
+        if fresh:
+            host[:] = 0
+            known[:] = [0] * len(known)
+            self._tables_mask = mask
+        moved = fresh
+        for i, req in ready:
+            n = min(len(req.blocks), P)
+            if n != known[i]:
+                host[i, known[i]:n] = req.blocks[known[i]:n]
+                known[i] = n
+                moved = True
+            if fresh or req.num_cached % bs == 0:
+                page = req.num_cached // bs
+                window.ensure(req.rid, page, page)
+                host[i, P:] = window.table_row(req.rid)
+                moved = True
+        if moved:
+            # a copy: the CPU backend may keep the host array it is given
+            self._tables_dev = self._g(host.copy())
+        return self._tables_dev
+
     def _drain_cow(self):
         """Execute queued copy-on-write page copies (device-side) before
         the next pool write can touch the replaced blocks."""
@@ -1748,11 +1814,20 @@ class LLMEngine:
             # of the staged ids, already replicated on the global mesh)
             start_a, upto_a = self._g(start_a), self._g(upto_a)
         cache = self.cache
-        (logits, cache.k, cache.v, cache.k_scale, cache.v_scale) = \
-            self._prefill_jit(
+        window_row = None
+        if cache.window is not None:
+            # the ring turns here: the pages behind the chunk's newest go
+            # back to the allocator before the chunk is dispatched
+            window_row = self._g(cache.window.chunk_row(
+                req.rid, start, start + take, C // self.block_size))
+        (logits, cache.k, cache.v, cache.k_scale, cache.v_scale,
+         *counters) = self._prefill_jit(
                 [p._data for p in self._params], ids_chunk,
                 start_a, upto_a, tables_dev,
-                cache.k, cache.v, cache.k_scale, cache.v_scale)
+                cache.k, cache.v, cache.k_scale, cache.v_scale,
+                *self._graph_extras(window_row))
+        if counters:
+            self._counters_dev = counters[0]
         if self.draft_model is not None:
             # mirror every target chunk into the draft pools: the draft
             # proposes continuations over the same block tables, so its
@@ -1859,7 +1934,8 @@ class LLMEngine:
 
         # -- chunked prefill (budgeted; interleaves with decode below) ---
         for req, start, take in sched.prefill_work(
-                self.max_prefill_tokens_per_step):
+                self.max_prefill_tokens_per_step,
+                align=self.prefill_buckets[0]):
             self._run_chunk(req, start, take, outputs)
 
         if self.prefill_only:
@@ -1902,20 +1978,28 @@ class LLMEngine:
                 c = self.cache
                 params = [p._data for p in self._params]
                 ids, positions = self._g(ids), self._g(positions)
-                tables = self._tables()
+                tables = self._decode_tables(ready)
+                extras = self._graph_extras(None)
                 phases.begin("engine.decode.dispatch")
-                (logits, c.k, c.v, c.k_scale, c.v_scale) = \
-                    self._decode_jit(
+                (logits, greedy, c.k, c.v, c.k_scale, c.v_scale,
+                 *counters) = self._decode_jit(
                         params, ids, positions, tables,
-                        c.k, c.v, c.k_scale, c.v_scale)
+                        c.k, c.v, c.k_scale, c.v_scale, *extras)
+                if counters:
+                    self._counters_dev = counters[0]
                 phases.begin("engine.decode.fetch")
-                logits = self._fetch(logits)
+                # the rows themselves only where the host samples from
+                # them or keeps them; a greedy batch fetches its tokens
+                rows = sampled or self.capture_logits
+                fetched = self._fetch(logits if rows else greedy)
                 phases.begin("engine.decode.emit")
                 _M_HOST_SYNCS.inc(instance=self._name)
-                _M_FETCH_BYTES.inc(logits.nbytes, instance=self._name)
+                _M_FETCH_BYTES.inc(fetched.nbytes, instance=self._name)
                 for i, req in ready:
                     req.num_cached += 1
-                    outputs.extend(self._emit(req, logits[i]))
+                    outputs.extend(
+                        self._emit(req, fetched[i]) if rows
+                        else self._emit_token(req, fetched[i]))
         phases.begin("engine.bookkeeping")
         self._maybe_autosave_store()
         self._update_gauges()
@@ -2032,6 +2116,9 @@ class LLMEngine:
     def _update_gauges(self):
         # utilization gauges: free-list arithmetic the host already holds
         usable = max(self.cache.num_blocks - 1, 1)
+        self._page_steps[0] += usable - self.cache.allocator.num_free
+        if self.cache.window is not None:
+            self._page_steps[1] += self.cache.window.blocks_in_use
         _G_KV_UTIL.set(1.0 - self.cache.allocator.num_free / usable,
                        instance=self._name)
         _G_OCCUPANCY.set(len(self.scheduler.running) / self.max_batch_size,
@@ -2095,7 +2182,7 @@ class LLMEngine:
                     ids[i, 0] = toks[r.rid][j]
                     pos[i] = j
                 dc = self.draft_cache
-                (logits, dc.k, dc.v, dc.k_scale, dc.v_scale) = \
+                (logits, _, dc.k, dc.v, dc.k_scale, dc.v_scale) = \
                     self._draft_decode_jit(
                         [p._data for p in self._draft_params],
                         jnp.asarray(ids), jnp.asarray(pos), tables,
@@ -2114,7 +2201,7 @@ class LLMEngine:
                     ids[i, 0] = drafts[i, kstep]
                     pos[i] = r.num_tokens + kstep
                 dc = self.draft_cache
-                (prev, dc.k, dc.v, dc.k_scale, dc.v_scale) = \
+                (prev, _, dc.k, dc.v, dc.k_scale, dc.v_scale) = \
                     self._draft_decode_jit(
                         [p._data for p in self._draft_params],
                         jnp.asarray(ids), jnp.asarray(pos), tables,
@@ -2466,6 +2553,7 @@ class LLMEngine:
         prop = _M_SPEC_PROPOSED.value(instance=inst)
         return {
             "instance": inst,
+            **self._kind_metrics(),
             "admitted": int(_M_ADMITTED.value(instance=inst)),
             "evictions": int(_M_EVICTIONS.value(instance=inst)),
             "finished": int(_M_FINISHED.value(instance=inst)),
@@ -2529,6 +2617,37 @@ class LLMEngine:
             "weight_audits": int(self._weight_audits),
             "weight_audit_failures": int(
                 _M_WEIGHT_AUDIT_FAIL.value(instance=inst)),
+        }
+
+    def _kind_metrics(self):
+        """Pages of either kind and the model's own counters (ISSUE 27).
+        ``kv_live_byte_steps`` / ``kv_one_table_byte_steps``: K and V bytes
+        the live pages hold at the published widths, summed over the
+        steps so far, and what ONE table paging every layer alike would
+        hold for the same requests. The model's device-side counters are
+        fetched here and nowhere else; each comes whole and split by the
+        graph that counted it (``_decode``, ``_prefill``)."""
+        cache, bs = self.cache, self.block_size
+        window = cache.window
+        if self._counters_dev is not None and self._counter_names:
+            got = self._fetch(self._counters_dev)
+            self._counters_dev = None
+            for name, v in zip(self._counter_totals, got):
+                self._counter_totals[name] += int(v)
+        g_steps, w_steps = self._page_steps
+        g_bytes = cache.published_bytes_per_token("global")
+        w_bytes = cache.published_bytes_per_token("window")
+        return {
+            "global_blocks_in_use":
+                cache.num_blocks - 1 - cache.allocator.num_free,
+            "window_blocks_in_use": window.blocks_in_use if window else 0,
+            "window_blocks_released": window.released if window else 0,
+            "kv_live_byte_steps": bs * (g_steps * g_bytes + w_steps * w_bytes),
+            "kv_one_table_byte_steps": bs * g_steps * (g_bytes + w_bytes),
+            **self._counter_totals,
+            **{n: self._counter_totals[n + "_decode"]
+               + self._counter_totals[n + "_prefill"]
+               for n in self._counter_names},
         }
 
     def _remove_tenant_series(self):

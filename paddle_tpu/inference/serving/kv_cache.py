@@ -84,10 +84,26 @@ of re-prefill — bit-exact by construction (PR 15) — and spilled prefix
 blocks keep their chain hashes as tier keys, so
 :meth:`PrefixCache.match_with_tier` extends a device chain walk into the
 host tier and the scheduler revives host-resident prefixes on admission.
+
+ISSUE 27 adds **per-layer pool geometry and a second kind of pages**. A
+model hands the cache one :class:`KVLayerSpec` a layer (``kv_layout()``):
+kv heads, the width of a K row and of a V row (they may differ, and a K
+row may be stored wider than published so that its lane dim is a multiple
+of 128), and a ``kind``. *Global* layers page the whole context through
+the allocator and block table above. *Window* layers only ever need the
+newest ``window`` tokens, so their pools are carved by a second allocator
+and a request holds a **ring** of ``ceil(window / block) + 1`` pages in
+them (:class:`WindowPages`): logical page ``p`` sits in ring slot ``p % R``,
+and the page that falls out of the window goes back to the allocator as
+the request advances, in prefill chunks and in decode. Everything that
+assumes ONE layout (prefix sharing, the host tier, page export/import,
+int8 pools, checksums, copy-on-write) refuses a cache that is not uniform,
+at construction and by name, rather than corrupt.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import hashlib
 import io
 import queue
@@ -106,7 +122,7 @@ from . import integrity as _integrity
 from .errors import KVIntegrityError
 
 __all__ = ["BlockAllocator", "PagedKVCache", "PrefixCache", "HostKVTier",
-           "PageSnapshot", "KV_QMAX",
+           "PageSnapshot", "KV_QMAX", "KVLayerSpec", "WindowPages",
            "quantize_kv_rows", "kv_pool_bytes_per_block",
            "pack_kv_pages", "unpack_kv_pages"]
 
@@ -540,6 +556,147 @@ class PrefixCache:
                 lru.pop(block_id, None)
 
 
+@dataclasses.dataclass(frozen=True)
+class KVLayerSpec:
+    """What one layer keeps in the cache. ``k_store`` is the width a K row
+    is stored at (``k_dim`` unless padded up to the lanes); ``prefill`` is
+    how a prefill chunk reads the layer's keys: ``"paged"`` page by page
+    through the multi-query kernel (the verify step's too), ``"linear"``
+    with the request's pages laid out in a row for the chunk kernel."""
+    kind: str = "global"            # "global" | "window"
+    num_kv_heads: int = 1
+    k_dim: int = 128
+    v_dim: int = 128
+    k_store: int = 0                # 0: as published
+    window: int | None = None
+    prefill: str = "paged"
+
+    def __post_init__(self):
+        if self.kind not in ("global", "window"):
+            raise ValueError(f"unknown KV layer kind {self.kind!r}")
+        if (self.kind == "window") != (self.window is not None):
+            raise ValueError("a window kind, and only it, states a window")
+        if self.kind == "window" and self.prefill != "linear":
+            raise ValueError("a window layer's chunk reads keys in a row")
+        if not self.k_store:
+            object.__setattr__(self, "k_store", self.k_dim)
+
+    def pool_shape(self, n, block_size, width):
+        """The shape a pool of ``n`` pages is held in. A ``"paged"`` layer
+        keeps ``[N, block, Hkv, D]``. A ``"linear"`` one keeps a page as
+        the ``block * Hkv`` rows of ``D`` the decode kernel copies,
+        ``[N, block * Hkv, D]`` (row = token * Hkv + head): with fewer kv
+        heads than sublanes XLA tiles the 4-D form ``(Hkv, 128)``, and
+        every scatter into it and every view of it as rows then copied
+        the whole pool (ISSUE 27, the deviceless compile's HLO)."""
+        if self.prefill == "paged":
+            return (n, block_size, self.num_kv_heads, width)
+        return (n, block_size * self.num_kv_heads, width)
+
+    def bytes_per_token(self, itemsize=2):
+        """K and V of one token in this layer, at the published widths."""
+        return self.num_kv_heads * (self.k_dim + self.v_dim) * itemsize
+
+
+def uniform_layout(config):
+    """The layout of a model whose layers all cache alike (Llama)."""
+    spec = KVLayerSpec("global", config.num_key_value_heads,
+                       config.head_dim, config.head_dim)
+    return [spec] * config.num_hidden_layers
+
+
+def ring_pages(window, block_size):
+    """Pages a window layer holds a request: the window can straddle
+    ``ceil(window / block)`` page boundaries, plus the page being written."""
+    return -(-int(window) // int(block_size)) + 1
+
+
+class WindowPages:
+    """The window kind's pages: a ring of ``R = ring_pages(window, block)``
+    slots a request, over an allocator of its own. Slot ``p % R`` holds
+    logical page ``p``; asking for a newer page in a slot sends the older
+    one back to the allocator. The pool is sized so that every slot of the
+    batch can hold a full ring (checked where the engine is built), so an
+    allocation here never fails and never preempts."""
+
+    def __init__(self, allocator, window, block_size):
+        self.allocator = allocator
+        self.window = int(window)
+        self.block_size = int(block_size)
+        self.ring = ring_pages(window, block_size)
+        self.n_tail = self.ring - 1
+        self._rings = {}            # rid -> (blocks [R], pages [R])
+        self.released = 0           # pages sent back behind a request
+
+    def _ring(self, rid):
+        ring = self._rings.get(rid)
+        if ring is None:
+            ring = self._rings[rid] = ([0] * self.ring, [-1] * self.ring)
+        return ring
+
+    def held(self, rid):
+        ring = self._rings.get(rid)
+        return sum(1 for b in ring[0] if b) if ring else 0
+
+    @property
+    def blocks_in_use(self):
+        return self.allocator.num_blocks - 1 - self.allocator.num_free
+
+    def ensure(self, rid, lo_page, hi_page):
+        """Hold pages ``lo_page..hi_page`` (at most a ring of them), each in
+        its slot; whatever older page sat there is released."""
+        blocks, pages = self._ring(rid)
+        for p in range(max(lo_page, hi_page - self.ring + 1), hi_page + 1):
+            s = p % self.ring
+            if pages[s] == p:
+                continue
+            if blocks[s]:
+                self.allocator.free([blocks[s]])
+                self.released += 1
+            got = self.allocator.allocate(1)
+            if got is None:
+                raise RuntimeError(
+                    "window page pool exhausted: it is sized at "
+                    "max_batch_size rings, so this is a leak")
+            blocks[s], pages[s] = got[0], p
+
+    def release(self, rid):
+        """Everything the request holds goes back (finish, abort, evict)."""
+        ring = self._rings.pop(rid, None)
+        if ring is None:
+            return
+        held = [b for b in ring[0] if b]
+        if held:
+            self.allocator.free(held)
+
+    def table_row(self, rid):
+        """The request's ring as a table row ``[R]`` (0 = no page)."""
+        ring = self._rings.get(rid)
+        return list(ring[0]) if ring else [0] * self.ring
+
+    def chunk_row(self, rid, start, upto, chunk_pages):
+        """What a prefill chunk ``[start, upto)`` of ``chunk_pages`` pages
+        needs (``ChunkAttnState``): the blocks of the ``n_tail`` pages
+        before ``start`` as they are NOW, then — after the ring has turned —
+        the blocks of the chunk's newest pages and the chunk page they start
+        at. int32 ``[n_tail + n_w + 1]``."""
+        bs = self.block_size
+        blocks, pages = self._ring(rid)
+        p0 = start // bs
+        tail = []
+        for p in range(p0 - self.n_tail, p0):
+            s = p % self.ring
+            tail.append(blocks[s] if p >= 0 and pages[s] == p else 0)
+        n_w = min(self.ring, chunk_pages)
+        last = (upto - 1 - start) // bs           # chunk page of the last token
+        first = max(last - (n_w - 1), 0)
+        self.ensure(rid, p0 + first, p0 + last)
+        blocks, pages = self._ring(rid)
+        write = [blocks[(p0 + j) % self.ring] if j <= last else 0
+                 for j in range(first, first + n_w)]
+        return np.asarray(tail + write + [first], np.int32)
+
+
 class PagedKVCache:
     """Static per-layer K/V block pools + the allocator that carves them.
 
@@ -567,7 +724,8 @@ class PagedKVCache:
     page_checksums = False
 
     def __init__(self, config, num_blocks, block_size, dtype=None,
-                 allocator=None, kv_dtype=None):
+                 allocator=None, kv_dtype=None, layout=None,
+                 max_batch_size=None):
         if dtype is None:
             dtype = jnp.float32
         if kv_dtype not in (None, "int8"):
@@ -579,18 +737,46 @@ class PagedKVCache:
         self.kv_dtype = kv_dtype
         self.quantized = kv_dtype == "int8"
         self.base_dtype = dtype
-        shape = (self.num_blocks, self.block_size,
-                 config.num_key_value_heads, config.head_dim)
-        L = config.num_hidden_layers
-        pool_dtype = jnp.int8 if self.quantized else dtype
-        self.k = [jnp.zeros(shape, pool_dtype) for _ in range(L)]
-        self.v = [jnp.zeros(shape, pool_dtype) for _ in range(L)]
+        self.layout = list(layout) if layout is not None \
+            else uniform_layout(config)
+        #: one geometry and one kind: what sharing, spill, export, int8 and
+        #: copy-on-write assume
+        self.uniform = (len(set(self.layout)) == 1
+                        and self.layout[0].kind == "global"
+                        and self.layout[0].k_store == self.layout[0].v_dim)
         if self.quantized:
-            sshape = shape[:-1]
-            self.k_scale = [jnp.zeros(sshape, jnp.float32)
-                            for _ in range(L)]
-            self.v_scale = [jnp.zeros(sshape, jnp.float32)
-                            for _ in range(L)]
+            self._require_uniform("int8 KV pools (kv_dtype='int8')")
+        windows = {sp.window for sp in self.layout if sp.kind == "window"}
+        if len(windows) > 1:
+            raise ValueError(f"one window size a cache; got {sorted(windows)}")
+        self.window = None
+        if windows:
+            if not max_batch_size:
+                raise ValueError("a window kind needs max_batch_size: its "
+                                 "pool holds a ring for every slot")
+            window = windows.pop()
+            # every slot of the batch a full ring and a page of slack, and
+            # the null page: an allocation there never fails, so the window
+            # kind never preempts
+            self.window_num_blocks = int(max_batch_size) * (
+                ring_pages(window, self.block_size) + 1) + 1
+            self.window = WindowPages(BlockAllocator(self.window_num_blocks),
+                                      window, self.block_size)
+        pool_dtype = jnp.int8 if self.quantized else dtype
+
+        def pool(sp, width):
+            n = self.window_num_blocks if sp.kind == "window" \
+                else self.num_blocks
+            return jnp.zeros(sp.pool_shape(n, self.block_size, width),
+                             pool_dtype)
+
+        self.k = [pool(sp, sp.k_store) for sp in self.layout]
+        self.v = [pool(sp, sp.v_dim) for sp in self.layout]
+        if self.quantized:
+            self.k_scale = [jnp.zeros(kp.shape[:-1], jnp.float32)
+                            for kp in self.k]
+            self.v_scale = [jnp.zeros(kp.shape[:-1], jnp.float32)
+                            for kp in self.k]
         else:
             self.k_scale = []
             self.v_scale = []
@@ -598,6 +784,21 @@ class PagedKVCache:
         # pool's allocator: one block table indexes both pools
         self.allocator = (allocator if allocator is not None
                           else BlockAllocator(num_blocks))
+
+    def _require_uniform(self, what):
+        if not self.uniform:
+            kinds = sorted({f"{sp.kind}: {sp.num_kv_heads} kv heads, K "
+                            f"{sp.k_store} / V {sp.v_dim}"
+                            for sp in self.layout})
+            raise ValueError(
+                f"{what} assumes one pool geometry and one kind of pages; "
+                f"this cache has {kinds}")
+
+    def published_bytes_per_token(self, kind, itemsize=2):
+        """K and V bytes of one token over all layers of ``kind``, at the
+        published widths."""
+        return sum(sp.bytes_per_token(itemsize) for sp in self.layout
+                   if sp.kind == kind)
 
     def bytes_saved_vs_unquantized(self, config):
         """Total pool bytes an int8 cache saves versus the SAME pool in
@@ -631,6 +832,7 @@ class PagedKVCache:
         original is never mutated). Host-triggered and rare — this is NOT
         inside the compiled step. Quantized pools copy the scale rows
         too: codes without their scales are not a copy."""
+        self._require_uniform("copy-on-write of a shared block")
         self.k = [kp.at[dst].set(kp[src]) for kp in self.k]
         self.v = [vp.at[dst].set(vp[src]) for vp in self.v]
         if self.quantized:
@@ -659,6 +861,7 @@ class PagedKVCache:
         :meth:`PageSnapshot.materialize` (normally run on the tier's
         transfer thread). The materialized payload is exactly an
         :meth:`export_request_pages` dict."""
+        self._require_uniform("page export (handoff, host tier, prefix store)")
         return PageSnapshot(self, blocks, covered)
 
     def validate_request_pages(self, pages):
@@ -669,6 +872,7 @@ class PagedKVCache:
         blocks are allocated); :meth:`import_request_pages` calls it
         again before writing, so a bad payload can never leave the pool
         half-imported. Returns the number of payload blocks."""
+        self._require_uniform("page import")
         if pages.get("kv_dtype") != self.kv_dtype:
             raise ValueError(
                 f"imported pages carry kv_dtype={pages.get('kv_dtype')!r} "

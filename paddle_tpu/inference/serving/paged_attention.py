@@ -22,7 +22,8 @@ import jax.numpy as jnp
 from ...nn.functional.flash_attention import _sdpa_ref
 
 __all__ = ["paged_decode_attention", "paged_multiquery_attention",
-           "kv_pool_specs"]
+           "chunk_attention", "kv_pool_specs", "ChunkAttnState",
+           "DecodeAttnState"]
 
 
 def kv_pool_specs(plan, num_heads, num_kv_heads):
@@ -69,47 +70,115 @@ def _gather_kv(pool, scale_pool, block_tables):
     return g
 
 
+def _softmax_with_sink(scores, allowed, sink):
+    """Softmax over the last axis of ``scores [B, H, T, S]`` with
+    ``allowed`` (broadcastable) masking keys out and, if given, ``sink [H]``
+    joining the denominator as one more logit that carries no value."""
+    scores = jnp.where(allowed, scores, -jnp.inf)
+    if sink is not None:
+        col = jnp.broadcast_to(
+            sink.astype(scores.dtype)[None, :, None, None],
+            scores.shape[:-1] + (1,))
+        scores = jnp.concatenate([scores, col], -1)
+    m = jnp.max(scores, -1, keepdims=True)
+    m = jnp.where(jnp.isfinite(m), m, 0.0)   # a row that sees nothing
+    e = jnp.exp(scores - m)
+    p = e / jnp.maximum(jnp.sum(e, -1, keepdims=True), 1e-30)
+    return p[..., :-1] if sink is not None else p
+
+
+def _masked_attention(q, k, v, allowed, scale, sink=None):
+    """q [B, T, H, Dk], k [B, S, Hkv, Dk], v [B, S, Hkv, Dv], ``allowed``
+    [B, 1, T, S] -> [B, T, H, Dv]; float32 inside."""
+    groups = q.shape[2] // k.shape[2]
+    kf = jnp.repeat(k.astype(jnp.float32), groups, axis=2)
+    vf = jnp.repeat(v.astype(jnp.float32), groups, axis=2)
+    s = jnp.einsum("bthd,bshd->bhts", q.astype(jnp.float32), kf) * scale
+    p = _softmax_with_sink(s, allowed, sink)
+    return jnp.einsum("bhts,bshd->bthd", p, vf).astype(q.dtype)
+
+
 def _lax_fallback(q, k_pool, v_pool, block_tables, context_lens, scale,
-                  k_scale=None, v_scale=None):
+                  k_scale=None, v_scale=None, window=None, ring=False,
+                  sink=None):
     """q [B, 1, H, D] -> [B, 1, H, D] via gather + masked dense sdpa."""
     b, p = block_tables.shape
     block_size = k_pool.shape[1]
     k = _gather_kv(k_pool, k_scale, block_tables)
     v = _gather_kv(v_pool, v_scale, block_tables)
-    pos = jnp.arange(p * block_size, dtype=jnp.int32)[None, :]
-    mask = (pos < context_lens[:, None])[:, None, None, :]  # [B,1,1,S]
-    return _sdpa_ref.raw_fn(q, k, v, attn_mask=mask, scale=scale)
+    slot_pos = jnp.arange(p * block_size, dtype=jnp.int32)[None, :]
+    if ring:
+        # slot s of a ring row holds the newest logical page congruent to
+        # s: count back from the page of the token just written
+        last = (context_lens[:, None] - 1) // block_size
+        page = last - (last - slot_pos // block_size) % p
+        pos = page * block_size + slot_pos % block_size
+    else:
+        pos = slot_pos
+    allowed = (pos >= 0) & (pos < context_lens[:, None])
+    if window is not None:
+        allowed = allowed & (pos >= context_lens[:, None] - window)
+    mask = allowed[:, None, None, :]  # [B,1,1,S]
+    if window is None and sink is None and k.shape[-1] == v.shape[-1]:
+        return _sdpa_ref.raw_fn(q, k, v, attn_mask=mask, scale=scale)
+    return _masked_attention(q, k, v, mask, scale, sink)
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, context_lens,
-                           scale=None, k_scale=None, v_scale=None):
+                           scale=None, k_scale=None, v_scale=None, *,
+                           window=None, ring=False, sink=None,
+                           num_kv_heads=None,
+                           name="paged_decode_attention"):
     """One decode token per request against the paged pool.
 
     q: [B, 1, H, D] (the just-written token's query); pools
-    [N, block, Hkv, D]; block_tables [B, P] int32; context_lens [B] int32
-    counting tokens INCLUDING the one just written. Returns [B, 1, H, D].
+    [N, block, Hkv, D] (V's width may differ from K's); block_tables [B, P]
+    int32; context_lens [B] int32 counting tokens INCLUDING the one just
+    written. Returns [B, 1, H, Dv].
     ``k_scale``/``v_scale`` ([N, block, Hkv] f32) arm the int8
     dequant-in-kernel path (ISSUE 14) when the pools hold codes.
+    ``window`` keeps the newest ``window`` keys only, ``ring`` reads the
+    table row as a ring of pages (slot = page % P), ``sink`` [H] joins the
+    softmax's denominator; ``name`` is the kernel's name in a trace. Pools
+    held as rows ``[N, block * Hkv, D]`` come with ``num_kv_heads``.
     """
     d = q.shape[-1]
-    block_size = k_pool.shape[1]
+    block_size = k_pool.shape[1] // (num_kv_heads if k_pool.ndim == 3 else 1)
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     from ...ops.pallas.paged_attention import (
         paged_decode_attention_pallas, use_pallas_paged)
 
     if use_pallas_paged(d, block_size):
-        def kernel(q, k_pool, v_pool, tables, lens, *scales):
+        extra = () if sink is None else (sink,)
+
+        def kernel(q, k_pool, v_pool, tables, lens, *rest):
+            rest = list(rest)
+            sk = rest.pop(0) if sink is not None else None
             return paged_decode_attention_pallas(
                 q, k_pool, v_pool, tables, lens, scale,
-                **dict(zip(("k_scale", "v_scale"), scales)))
+                **dict(zip(("k_scale", "v_scale"), rest)),
+                window=window, ring=ring, sink=sk,
+                num_kv_heads=num_kv_heads, name=name)
 
         scales = () if k_scale is None else (k_scale, v_scale)
-        out = _per_shard_paged(kernel, q[:, 0], k_pool, bool(scales), 2)(
-            q[:, 0], k_pool, v_pool, block_tables, context_lens, *scales)
+        if sink is not None:
+            from ...distributed.plan import active_plan
+            if active_plan() is not None:
+                raise NotImplementedError(
+                    "paged attention with a sink runs on one device")
+            out = kernel(q[:, 0], k_pool, v_pool, block_tables,
+                         context_lens, *extra, *scales)
+        else:
+            out = _per_shard_paged(kernel, q[:, 0], k_pool, bool(scales), 2)(
+                q[:, 0], k_pool, v_pool, block_tables, context_lens, *scales)
         return out[:, None]
+    if k_pool.ndim == 3:
+        k_pool, v_pool = (a.reshape(a.shape[0], block_size, num_kv_heads,
+                                    a.shape[-1]) for a in (k_pool, v_pool))
     return _lax_fallback(q, k_pool, v_pool, block_tables, context_lens,
-                         float(scale), k_scale=k_scale, v_scale=v_scale)
+                         float(scale), k_scale=k_scale, v_scale=v_scale,
+                         window=window, ring=ring, sink=sink)
 
 
 def _lax_multiquery_fallback(q, k_pool, v_pool, block_tables, context_lens,
@@ -164,3 +233,249 @@ def paged_multiquery_attention(q, k_pool, v_pool, block_tables, context_lens,
     return _lax_multiquery_fallback(q, k_pool, v_pool, block_tables,
                                     context_lens, q_start, float(scale),
                                     k_scale=k_scale, v_scale=v_scale)
+
+
+def chunk_attention(q, k, v, q_start, k_start, upto, scale, *, window=None,
+                    sink=None, block_size=8, name="chunk_attention"):
+    """One request's prefill chunk over keys laid out in a row: q
+    [T, H, Dk] at positions ``q_start + t``, k/v [L, Hkv, D] at ``k_start +
+    l``; causal, banded by ``window`` if given, ``sink`` [H] in the
+    denominator; positions ``>= upto`` (and negative ones) are no tokens.
+    Pallas on the TPU (``chunk_attention_pallas``), ``jax.numpy`` here."""
+    from ...ops.pallas.paged_attention import (chunk_attention_pallas,
+                                               use_pallas_paged)
+
+    if use_pallas_paged(q.shape[-1], block_size):
+        return chunk_attention_pallas(q, k, v, q_start, k_start, upto,
+                                      float(scale), window=window,
+                                      sink=sink, name=name)
+    qp = q_start + jnp.arange(q.shape[0], dtype=jnp.int32)[:, None]
+    kp = k_start + jnp.arange(k.shape[0], dtype=jnp.int32)[None, :]
+    ok = (kp <= qp) & (kp >= 0) & (kp < upto)
+    if window is not None:
+        ok = ok & (kp > qp - window)
+    return _masked_attention(q[None], k[None], v[None], ok[None, None],
+                             float(scale), sink)[0]
+
+
+# ---------------------------------------------------------------------------
+# the attention-state handles a model's layer step is given (ISSUE 27)
+# ---------------------------------------------------------------------------
+#
+# ``LLMEngine`` builds its prefill-chunk and decode graphs around ONE call a
+# layer, ``layer.serve_step(x, state)``. The layer computes its own
+# projections, asks the state to rotate them (``state.rope``: the state
+# knows the positions), and hands q, k and v to ``state.attend``, which
+# writes k and v where this request's tokens live and returns the attention
+# of q over the request's state. What a "state" is (pages of one pool, a
+# ring of pages, int8 codes) is the engine's and the cache's business, not
+# the model's. ``state.count`` adds to the step's device-side counters.
+
+
+def _pad_last(x, width):
+    extra = width - x.shape[-1]
+    if extra == 0:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, extra)])
+
+
+class _AttnState:
+    def __init__(self, spec, block_size, k_pool, v_pool, k_scale, v_scale,
+                 counters):
+        self.spec, self.block_size = spec, block_size
+        self.k_pool, self.v_pool = k_pool, v_pool
+        self.k_scale, self.v_scale = k_scale, v_scale
+        self._counters = counters
+
+    @property
+    def quantized(self):
+        return self.k_scale is not None
+
+    def count(self, name, value):
+        """Add ``value`` to the step's counter ``name`` (a device scalar the
+        engine carries through the graph and fetches in ``metrics()``)."""
+        if self._counters is not None:
+            self._counters[name] = self._counters.get(name, 0) + value
+
+    def _kernel_name(self, base):
+        return base if self.spec.prefill == "paged" \
+            else f"{base}_{self.spec.kind}"
+
+
+class DecodeAttnState(_AttnState):
+    """One layer's state inside the decode graph: every row of the batch
+    writes ONE token at ``positions`` and attends over ``positions + 1``
+    tokens. ``table`` is the kind's block table ([B, P] pages, or [B, R]
+    ring slots for a window kind). ``active`` (optional [B] bool) parks the
+    frozen rows' writes on the null block (fused decode windows)."""
+
+    def __init__(self, spec, block_size, positions, table, k_pool, v_pool,
+                 k_scale=None, v_scale=None, active=None, counters=None):
+        super().__init__(spec, block_size, k_pool, v_pool, k_scale, v_scale,
+                         counters)
+        self.positions, self.table, self.active = positions, table, active
+
+    def rope(self, x, cos_t, sin_t):
+        """Rotate ``x [B, 1, H, D]`` at each row's own position; ``cos_t``/
+        ``sin_t`` are the full ``[max_pos, D/2]`` tables."""
+        from ...models.llama import rope_rotate
+
+        c = cos_t[self.positions][:, None, None, :]
+        sn = sin_t[self.positions][:, None, None, :]
+        return rope_rotate(x, c, sn)
+
+    def attend(self, q, k, v, scale, sink=None):
+        import jax
+
+        from .kv_cache import quantize_kv_rows
+
+        spec, bs = self.spec, self.block_size
+        positions, tables = self.positions, self.table
+        kp, vp, ksc, vsc = self.k_pool, self.v_pool, self.k_scale, self.v_scale
+        bsz = q.shape[0]
+        qa = _pad_last(q, spec.k_store)
+        ka, va = _pad_last(k, spec.k_store), v
+        page = positions // bs
+        if spec.kind == "window":
+            page = page % tables.shape[1]
+        blk = tables[jnp.arange(bsz), page]
+        off = positions % bs
+        if self.active is not None:
+            # EOS-freeze: park frozen rows' writes on the null
+            # block (reserved, never allocated to a request)
+            blk = jnp.where(self.active, blk, 0)
+            off = jnp.where(self.active, off, 0)
+        if self.quantized:
+            qk, sk = quantize_kv_rows(ka)   # [B,1,Hkv,D]
+            qv, sv = quantize_kv_rows(va)
+        if spec.prefill != "paged":
+            # one scatter a pool, of a token's Hkv rows a request; the
+            # rows of empty slots all land on the null block's first
+            hkv = spec.num_kv_heads
+            rows = off[:, None] * hkv + jnp.arange(hkv)[None, :]
+            kp = kp.at[blk[:, None], rows].set(ka[:, 0].astype(kp.dtype))
+            vp = vp.at[blk[:, None], rows].set(va[:, 0].astype(vp.dtype))
+        else:
+            for i in range(bsz):
+                if self.quantized:
+                    kp = jax.lax.dynamic_update_slice(
+                        kp, qk[i:i + 1], (blk[i], off[i], 0, 0))
+                    vp = jax.lax.dynamic_update_slice(
+                        vp, qv[i:i + 1], (blk[i], off[i], 0, 0))
+                    ksc = jax.lax.dynamic_update_slice(
+                        ksc, sk[i:i + 1], (blk[i], off[i], 0))
+                    vsc = jax.lax.dynamic_update_slice(
+                        vsc, sv[i:i + 1], (blk[i], off[i], 0))
+                else:
+                    kp = jax.lax.dynamic_update_slice(
+                        kp, ka[i:i + 1].astype(kp.dtype),
+                        (blk[i], off[i], 0, 0))
+                    vp = jax.lax.dynamic_update_slice(
+                        vp, va[i:i + 1].astype(vp.dtype),
+                        (blk[i], off[i], 0, 0))
+        self.k_pool, self.v_pool, self.k_scale, self.v_scale = kp, vp, ksc, vsc
+        return paged_decode_attention(
+            qa, kp, vp, tables, positions + 1, scale=scale,
+            k_scale=ksc, v_scale=vsc, window=spec.window,
+            ring=spec.kind == "window", sink=sink,
+            num_kv_heads=spec.num_kv_heads,
+            name=self._kernel_name("paged_decode_attention"))
+
+
+class ChunkAttnState(_AttnState):
+    """One layer's state inside the prefill-chunk graph: ONE request's
+    block-aligned chunk of ``C`` tokens at ``start``, of which those before
+    ``upto`` are real. A global kind writes the chunk's pages into the
+    blocks ``tables_row`` names and attends over the request's pages. A
+    window kind attends over the chunk's own keys and the ``n_tail`` pages
+    before it (``window_row[:n_tail]``, read BEFORE anything is written),
+    and keeps only the chunk's newest pages: ``window_row[n_tail:-1]`` are
+    the blocks of chunk pages ``first ..`` (0, the null block, for a page
+    past the last real one), ``window_row[-1]`` is ``first``."""
+
+    def __init__(self, spec, block_size, start, upto, tables_row, k_pool,
+                 v_pool, k_scale=None, v_scale=None, window_row=None,
+                 n_tail=0, counters=None):
+        super().__init__(spec, block_size, k_pool, v_pool, k_scale, v_scale,
+                         counters)
+        self.start, self.upto, self.tables_row = start, upto, tables_row
+        self.window_row, self.n_tail = window_row, n_tail
+
+    def rope(self, x, cos_t, sin_t):
+        """Rotate ``x [1, C, H, D]``, whose rows sit at ``start + i``."""
+        from ...models.llama import _rope_apply_at
+
+        return _rope_apply_at.raw_fn(x, cos_t, sin_t, self.start)
+
+    def attend(self, q, k, v, scale, sink=None):
+        import jax
+
+        from .kv_cache import quantize_kv_rows
+
+        spec, bs = self.spec, self.block_size
+        kp, vp, ksc, vsc = self.k_pool, self.v_pool, self.k_scale, self.v_scale
+        start, upto = self.start, self.upto
+        qa = _pad_last(q, spec.k_store)
+        ka, va = _pad_last(k, spec.k_store), v
+        pages = q.shape[1] // bs
+
+        def paged(a):
+            return a.reshape((pages, bs) + a.shape[2:])
+
+        def in_a_row(a):
+            """Pages ``[n, block * Hkv, D]`` as tokens ``[n * block, Hkv, D]``."""
+            return a.reshape(-1, spec.num_kv_heads, a.shape[-1])
+
+        def as_pages(a):
+            """The chunk's rows ``[1, C, Hkv, D]`` as pages of the pool's
+            own form (``KVLayerSpec.pool_shape``)."""
+            return a.reshape(spec.pool_shape(pages, bs, a.shape[-1]))
+
+        if spec.kind == "window":
+            n_tail = self.n_tail
+            row = self.window_row
+            n_w = row.shape[0] - n_tail - 1
+            tail_k = in_a_row(kp[row[:n_tail]])
+            tail_v = in_a_row(vp[row[:n_tail]])
+            out = chunk_attention(
+                qa[0], jnp.concatenate([tail_k, ka[0].astype(kp.dtype)]),
+                jnp.concatenate([tail_v, va[0].astype(vp.dtype)]),
+                start, start - n_tail * bs, upto, scale,
+                window=spec.window, sink=sink, block_size=bs,
+                name=self._kernel_name("chunk_attention"))[None]
+            first = row[-1]
+            blks = row[n_tail:-1]
+            kp = kp.at[blks].set(jax.lax.dynamic_slice_in_dim(
+                as_pages(ka), first, n_w).astype(kp.dtype))
+            vp = vp.at[blks].set(jax.lax.dynamic_slice_in_dim(
+                as_pages(va), first, n_w).astype(vp.dtype))
+            self.k_pool, self.v_pool = kp, vp
+            return out
+
+        blks = jax.lax.dynamic_slice(self.tables_row, (start // bs,), (pages,))
+        # one scatter per pool: the chunk's pages land
+        # in its blocks at once (a page-by-page
+        # dynamic_update_slice loop made the 128-page top
+        # bucket a minutes-long compile). Bucket pages
+        # past the request's blocks all hit null block 0.
+        if self.quantized:
+            qk, sk = quantize_kv_rows(ka)
+            qv, sv = quantize_kv_rows(va)
+            kp = kp.at[blks].set(paged(qk))
+            vp = vp.at[blks].set(paged(qv))
+            ksc = ksc.at[blks].set(paged(sk))
+            vsc = vsc.at[blks].set(paged(sv))
+        else:
+            kp = kp.at[blks].set(as_pages(ka).astype(kp.dtype))
+            vp = vp.at[blks].set(as_pages(va).astype(vp.dtype))
+        self.k_pool, self.v_pool, self.k_scale, self.v_scale = kp, vp, ksc, vsc
+        if spec.prefill == "paged":
+            return paged_multiquery_attention(
+                qa, kp, vp, self.tables_row[None], upto[None], start[None],
+                scale=scale, k_scale=ksc, v_scale=vsc)
+        # one request's pages in a row: a few tens of MB at the longest
+        # context, against the chunk's own matmuls
+        return chunk_attention(
+            qa[0], in_a_row(kp[self.tables_row]),
+            in_a_row(vp[self.tables_row]), start, 0, upto, scale, sink=sink,
+            block_size=bs, name=self._kernel_name("chunk_attention"))[None]

@@ -330,8 +330,11 @@ class Scheduler:
 
     def __init__(self, allocator, block_size, max_batch_size,
                  max_prefills_per_step=1, instance=None, prefix_cache=None,
-                 kv_tier=None):
+                 kv_tier=None, window_pages=None):
         self.allocator = allocator
+        # the window kind's pages (ISSUE 27, a kv_cache.WindowPages): a
+        # request's ring goes back with its blocks on finish, abort, evict
+        self.window_pages = window_pages
         self.block_size = int(block_size)
         self.slots: list[Request | None] = [None] * int(max_batch_size)
         self.waiting: deque[Request] = deque()
@@ -615,15 +618,20 @@ class Scheduler:
         return picked
 
     # -- chunked prefill work -------------------------------------------
-    def prefill_work(self, budget=None):
+    def prefill_work(self, budget=None, align=None):
         """Chunk assignments ``[(req, start, n_new_tokens)]`` for this
         engine step: oldest-admitted prefilling requests first, total NEW
         tokens bounded by ``budget`` (``None`` = unlimited — whole prompts
         in one chunk, the PR-7 behavior). Non-final chunks are
         block-aligned (chunk starts must sit on page boundaries for
         whole-page pool writes); the head assignment always gets at least
-        one block so prefill can never stall under a tiny budget."""
+        one block so prefill can never stall under a tiny budget.
+        ``align`` (a multiple of the block size, the block size if not
+        given) is what a non-final chunk's length is cut down to: the
+        engine passes its smallest chunk rung, so that what is left of a
+        request's staged bucket behind any chunk still holds a rung."""
         out = []
+        align = int(align or self.block_size)
         remaining = float("inf") if budget is None else int(budget)
         for req in sorted((r for r in self.slots
                            if r is not None and r.prefilling),
@@ -635,11 +643,11 @@ class Scheduler:
                 break
             allowed = remaining
             if allowed < todo:
-                allowed = int(allowed) // self.block_size * self.block_size
+                allowed = int(allowed) // align * align
                 if allowed == 0:
                     if out:
                         break
-                    allowed = self.block_size  # guaranteed progress
+                    allowed = align  # guaranteed progress
             take = int(min(todo, allowed))
             out.append((req, req.num_cached, take))
             remaining -= take
@@ -761,6 +769,7 @@ class Scheduler:
                                           tenant=req.tenant):
                 req.spill_key = req.rid
         self.allocator.free(req.blocks)
+        self._release_window(req)
         req.blocks = []
         req.num_cached = 0
         req.draft_cached = 0
@@ -810,6 +819,7 @@ class Scheduler:
                                     if d not in dying]
             if req.blocks:
                 self.allocator.free(req.blocks)
+            self._release_window(req)
             req.blocks = []
             self.slots[slot] = None
             self.version += 1
@@ -827,9 +837,14 @@ class Scheduler:
         req.state = FINISHED
 
     # -- completion ------------------------------------------------------
+    def _release_window(self, req):
+        if self.window_pages is not None:
+            self.window_pages.release(req.rid)
+
     def finish(self, req):
         slot = self.slots.index(req)
         self.allocator.free(req.blocks)
+        self._release_window(req)
         req.blocks = []
         req.state = FINISHED
         self.slots[slot] = None

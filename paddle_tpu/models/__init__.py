@@ -12,3 +12,6 @@ from .llama import (  # noqa: F401
     sample_next_tokens, llama_1b, llama_7b, llama_13b, llama_125m,
     llama_small, llama_tiny,
 )
+from .mimo_v2 import (  # noqa: F401
+    MiMoV2Config, MiMoV2ForCausalLM, mimo_v2_tiny,
+)

@@ -192,7 +192,12 @@ def sample_next_tokens(last, *, do_sample=False, temperature=1.0, top_k=None,
     greedy argmax, or seeded temperature/top-k/top-p sampling via ``rng``
     (a ``np.random.RandomState``). Shared by ``LlamaForCausalLM.generate``
     and the serving engine so both paths sample identically."""
-    last = np.asarray(last).astype(np.float64)
+    last = np.asarray(last)
+    if not do_sample and last.dtype in (np.float32, np.float64):
+        # float32 -> float64 is exact and keeps order and ties: the argmax
+        # of the row as it is fetched is the same index, without the copy
+        return last.argmax(-1)
+    last = last.astype(np.float64)
     if not do_sample:
         return last.argmax(-1)
     if rng is None:
@@ -566,6 +571,52 @@ class LlamaForCausalLM(Layer):
                         loss = loss + coef * aux
             return loss, logits
         return logits
+
+    # ---- the serving path (LLMEngine; ISSUE 27) -----------------------
+    # ``LLMEngine`` builds its prefill-chunk and decode graphs from these
+    # calls and nothing else of the model: embed, one ``serve_layer`` a
+    # layer with an attention-state handle (``serving.paged_attention``:
+    # ``state.rope`` rotates at the state's positions, ``state.attend``
+    # writes k and v and attends over the request's state), norm, head.
+    #: device-side counters ``serve_layer`` adds to (none here)
+    serve_counters = ()
+
+    def kv_layout(self):
+        from ..inference.serving.kv_cache import uniform_layout
+
+        return uniform_layout(self.config)
+
+    def serve_dtype(self):
+        return self.llama.layers[0].self_attn.k_proj.weight.dtype
+
+    def serve_embed(self, ids):
+        return self.llama.embed_tokens(Tensor._wrap(ids))
+
+    def serve_layer(self, i, x, state):
+        layer = self.llama.layers[i]
+        attn = layer.self_attn
+        h = layer.input_layernorm(x)
+        b, s = x.shape[0], x.shape[1]
+        q = M.reshape(attn.q_proj(h), [b, s, attn.num_heads, attn.head_dim])
+        k = M.reshape(attn.k_proj(h),
+                      [b, s, attn.num_kv_heads, attn.head_dim])
+        v = M.reshape(attn.v_proj(h),
+                      [b, s, attn.num_kv_heads, attn.head_dim])
+        cos_t, sin_t = self.llama.rope_cos._data, self.llama.rope_sin._data
+        qa = state.rope(q._data, cos_t, sin_t)
+        ka = state.rope(k._data, cos_t, sin_t)
+        out = state.attend(qa, ka, v._data,
+                           scale=1.0 / math.sqrt(attn.head_dim))
+        x = x + attn.o_proj(M.reshape(Tensor._wrap(out), [b, s, -1]))
+        return x + layer.mlp(layer.post_attention_layernorm(x))
+
+    def serve_norm(self, x):
+        return self.llama.norm(x)
+
+    def serve_head(self, h):
+        if self.lm_head is not None:
+            return self.lm_head(h)
+        return F.linear(h, self.llama.embed_tokens.weight.t())
 
     # ---- generation (static-capacity KV-cache decode) ----------------
     #: decode caches round their capacity up to this multiple so compile

@@ -91,8 +91,22 @@ class _Metric:
         self.help = help
         self._series = {}          # label_key -> value
         self._label_names = None   # fixed by the first series
+        self._keys = {}            # labels as a call gave them -> label_key
 
     def _check_labels(self, labels):
+        # a call site gives the same few label sets again and again (an
+        # engine counts every token under its instance's name), so the
+        # checked, sorted key of one is kept. Of strings only: 1, True and
+        # "1" are equal or not as dict keys, and three labels.
+        given = tuple(labels.items())
+        if not all(type(v) is str for v in labels.values()):
+            return self._checked(labels)
+        key = self._keys.get(given)
+        if key is None:
+            key = self._keys[given] = self._checked(labels)
+        return key
+
+    def _checked(self, labels):
         names = tuple(sorted(str(k) for k in labels))
         if self._label_names is None:
             self._label_names = names
@@ -114,12 +128,14 @@ class _Metric:
         window-local numbers). Missing series is a no-op."""
         with self._registry._lock:
             self._series.pop(_label_key(labels), None)
+            self._keys.clear()
 
     def clear(self):
         """Drop every series of this metric."""
         with self._registry._lock:
             self._series.clear()
             self._label_names = None
+            self._keys.clear()
 
 
 class Counter(_Metric):

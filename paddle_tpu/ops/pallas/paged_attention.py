@@ -71,7 +71,8 @@ from jax.experimental.pallas import tpu as pltpu
 from .flash_attention import NEG_INF, _interpret
 
 __all__ = ["paged_decode_attention_pallas",
-           "paged_multiquery_attention_pallas", "use_pallas_paged"]
+           "paged_multiquery_attention_pallas", "chunk_attention_pallas",
+           "use_pallas_paged"]
 
 
 def use_pallas_paged(head_dim, block_size):
@@ -81,7 +82,15 @@ def use_pallas_paged(head_dim, block_size):
         return True
     if jax.default_backend() != "tpu":
         return False
-    return head_dim % 128 == 0 and block_size % 8 == 0
+    if head_dim % 128 or block_size % 8:
+        # PR 21: no hidden fallback. On the chip a geometry the kernels
+        # cannot take is an error; the lax gather is the CPU's path.
+        raise ValueError(
+            f"the paged Pallas kernels take head_dim % 128 == 0 and "
+            f"block_size % 8 == 0; got head_dim={head_dim}, "
+            f"block_size={block_size} (pad the stored width, as "
+            "KVLayerSpec.k_store does)")
+    return True
 
 
 #: VMEM the decode kernel plans for: both double buffers of a chunk's K and
@@ -90,15 +99,18 @@ def use_pallas_paged(head_dim, block_size):
 _DECODE_VMEM_BUDGET = 4 * 1024 * 1024
 
 
-def _decode_chunk(block_size, hkv, h, d, itemsize, p):
+def _decode_chunk(block_size, hkv, h, d, itemsize, p, dv=None):
     """``(C, bytes)``: the pages of a decode chunk — the largest power of
     two whose VMEM plan fits ``_DECODE_VMEM_BUDGET``, and no more than a
     request's table holds — and that plan's bytes: two slots of C pages for
-    K and for V, and four live ``[H, C * block * Hkv]`` f32 arrays of the
-    fold (scores, probabilities, the two masks)."""
+    K (``d`` wide) and for V (``dv`` wide, ``d`` if not given), and four
+    live ``[H, C * block * Hkv]`` f32 arrays of the fold (scores,
+    probabilities, the two masks)."""
+    dv = d if dv is None else dv
+
     def plan(c):
         cols = c * block_size * hkv
-        return 2 * 2 * cols * d * itemsize + 4 * h * cols * 4
+        return 2 * cols * (d + dv) * itemsize + 4 * h * cols * 4
 
     c = 1
     while 2 * c <= pl.next_power_of_2(p) \
@@ -107,27 +119,51 @@ def _decode_chunk(block_size, hkv, h, d, itemsize, p):
     return c, plan(c)
 
 
-def _kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
-            k_buf, v_buf, sems, slot_ref, *, block_size, chunk, groups,
-            scale):
+def _kernel(tables_ref, lens_ref, *refs, block_size, chunk, groups, scale,
+            window=None, ring=False, sink=False):
     """Decode over fp pools: one request a grid step, a loop over its live
-    chunks of ``chunk`` pages inside (see the module docstring)."""
+    chunks of ``chunk`` pages inside (see the module docstring). K rows are
+    ``q``'s width and V rows the output's; the two may differ.
+
+    ``window`` (tokens) puts a lower bound on the keys walked: the loop
+    starts at the page that holds position ``ctx - window`` and never
+    touches an older one. ``ring`` says the table row is a ring: logical
+    page ``p`` sits in slot ``p % P``, so a row of ``ceil(window / block) +
+    1`` slots serves any context. ``sink`` adds one operand ``[H, 1]`` f32,
+    a per-head logit that joins the softmax's denominator and carries no
+    value: the fold simply starts from ``m = sink, l = 1``."""
+    if sink:
+        q_ref, sink_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref = refs
+    else:
+        q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref = refs
     b = pl.program_id(0)
-    h, d = q_ref.shape[1:]
+    h = q_ref.shape[1]
+    d = v_buf.shape[-1]
     hkv = h // groups
     rows = block_size * hkv                 # pool rows a page
     cols = chunk * rows
     p_max = tables_ref.shape[0] // lens_ref.shape[0]
 
+    def first_page(r):
+        if window is None:
+            return 0
+        return jnp.maximum(lens_ref[r] - window, 0) // block_size
+
     def n_pages(r):
-        return jnp.minimum(pl.cdiv(lens_ref[r], block_size), p_max)
+        """Live pages of request r, counted from ``first_page(r)``."""
+        top = pl.cdiv(lens_ref[r], block_size)
+        if not ring:
+            top = jnp.minimum(top, p_max)
+        return top - first_page(r)
 
     def copies(r, c, slot, act):
         """Start, or wait for, the live page copies of request r's chunk c."""
         first = c * chunk
+        base = first_page(r)
 
         def page(j, carry):
-            idx = tables_ref[r * p_max + first + j]
+            at = base + first + j
+            idx = tables_ref[r * p_max + (at % p_max if ring else at)]
             for i, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
                 act(pltpu.make_async_copy(
                     hbm.at[idx], buf.at[slot, pl.ds(j * rows, rows)],
@@ -142,6 +178,7 @@ def _kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
         copies(0, 0, 0, lambda cp: cp.start())
 
     ctx = lens_ref[b]
+    base = first_page(b)
     # an empty request still takes one (all-masked) chunk, so the next
     # request's first copies are started
     n_chunks = jnp.maximum(pl.cdiv(n_pages(b), chunk), 1)
@@ -167,7 +204,8 @@ def _kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
                    lambda cp: cp.start())
 
         copies(b, c, slot, lambda cp: cp.wait())
-        seen = ctx - c * (chunk * block_size)     # live tokens from here on
+        # live tokens from this chunk's first on
+        seen = ctx - (base + c * chunk) * block_size
 
         @pl.when(seen < chunk * block_size)
         def _tail():
@@ -181,7 +219,10 @@ def _kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
         s = jax.lax.dot_general(
             q, k_buf[slot].astype(cdt), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # [H, cols]
-        s = jnp.where(own & (tok < seen), s, NEG_INF)
+        ok = own & (tok < seen)
+        if window is not None:
+            ok = ok & (tok >= seen - window)
+        s = jnp.where(ok, s, NEG_INF)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         pexp = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
@@ -191,10 +232,13 @@ def _kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         return m_new, l_new, acc
 
+    if sink:
+        m0, l0 = sink_ref[...], jnp.ones((h, 1), jnp.float32)
+    else:
+        m0 = jnp.full((h, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((h, 1), jnp.float32)
     m, l, acc = jax.lax.fori_loop(
-        0, n_chunks, fold,
-        (jnp.full((h, 1), NEG_INF, jnp.float32),
-         jnp.zeros((h, 1), jnp.float32), jnp.zeros((h, d), jnp.float32)))
+        0, n_chunks, fold, (m0, l0, jnp.zeros((h, d), jnp.float32)))
     slot_ref[0] = (slot0 + n_chunks) % 2
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
@@ -283,46 +327,67 @@ def _int8_page_call(q, k_pool, v_pool, tables_flat, lens, scale, k_scale,
 
 def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
                                   context_lens, scale,
-                                  k_scale=None, v_scale=None):
-    """q [B, H, D]; pools [N, block, Hkv, D]; block_tables [B, P] int32;
-    context_lens [B] int32. Returns [B, H, D]. With int8 pools,
-    ``k_scale``/``v_scale`` [N, block, Hkv] f32 arm dequant-in-kernel."""
+                                  k_scale=None, v_scale=None, *,
+                                  window=None, ring=False, sink=None,
+                                  num_kv_heads=None,
+                                  name="paged_decode_attention"):
+    """q [B, H, Dk]; pools [N, block, Hkv, Dk] and [N, block, Hkv, Dv], or
+    already as rows ``[N, block * Hkv, D]`` with ``num_kv_heads`` given;
+    block_tables [B, P] int32; context_lens [B] int32. Returns [B, H, Dv].
+    With int8 pools, ``k_scale``/``v_scale`` [N, block, Hkv] f32 arm
+    dequant-in-kernel. ``window``/``ring``/``sink`` ([H] f32) as
+    ``_kernel`` has them; ``name`` is the call's name in a device trace."""
     b, h, d = q.shape
-    n, block_size, hkv, _ = k_pool.shape
+    if k_pool.ndim == 3:
+        n, hkv = k_pool.shape[0], int(num_kv_heads)
+        block_size = k_pool.shape[1] // hkv
+    else:
+        n, block_size, hkv, _ = k_pool.shape
+    dv = v_pool.shape[-1]
     tables_flat = block_tables.reshape(-1).astype(jnp.int32)
     lens = context_lens.astype(jnp.int32)
     if k_scale is not None:
+        if window is not None or sink is not None or dv != d:
+            raise NotImplementedError(
+                "the int8 decode kernel takes one K/V width and neither a "
+                "window nor a sink")
         return _int8_page_call(q, k_pool, v_pool, tables_flat, lens,
                                float(scale), k_scale, v_scale)
     chunk, _ = _decode_chunk(block_size, hkv, h, d, k_pool.dtype.itemsize,
-                             block_tables.shape[1])
+                             block_tables.shape[1], dv)
     rows = block_size * hkv
     q_spec = pl.BlockSpec((1, h, d), lambda i, T, L: (i, 0, 0))
+    o_spec = pl.BlockSpec((1, h, dv), lambda i, T, L: (i, 0, 0))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs, operands = [q_spec], [q]
+    if sink is not None:
+        in_specs.append(pl.BlockSpec((h, 1), lambda i, T, L: (0, 0)))
+        operands.append(sink.astype(jnp.float32).reshape(h, 1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
-        in_specs=[q_spec, hbm, hbm],
-        out_specs=q_spec,
+        in_specs=in_specs + [hbm, hbm],
+        out_specs=o_spec,
         scratch_shapes=[
             pltpu.VMEM((2, chunk * rows, d), k_pool.dtype),
-            pltpu.VMEM((2, chunk * rows, d), v_pool.dtype),
+            pltpu.VMEM((2, chunk * rows, dv), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),
         ],
     )
     return pl.pallas_call(
         functools.partial(_kernel, block_size=block_size, chunk=chunk,
-                          groups=h // hkv, scale=float(scale)),
+                          groups=h // hkv, scale=float(scale),
+                          window=window, ring=ring, sink=sink is not None),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
         # the copy pipeline runs from one request into the next
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
-        name="paged_decode_attention",
-    )(tables_flat, lens, q, k_pool.reshape(n, rows, d),
-      v_pool.reshape(n, rows, d))
+        name=name,
+    )(tables_flat, lens, *operands, k_pool.reshape(n, rows, d),
+      v_pool.reshape(n, rows, dv))
 
 
 #: multi-query grid tile: at most ``_MQ_ROWS`` query rows and at most
@@ -466,3 +531,155 @@ def paged_multiquery_attention_pallas(q, k_pool, v_pool, block_tables,
         interpret=_interpret(),
         name="paged_prefill_attention",
     )(tables_flat, lens, starts, *operands)
+
+
+# ---------------------------------------------------------------------------
+# one request's prefill chunk against keys laid out in a row (ISSUE 27)
+# ---------------------------------------------------------------------------
+
+#: rows of one chunk-attention tile: query rows x the heads of one kv group
+_CHUNK_TILE_ROWS = 1024
+
+
+def _chunk_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, tq, tk, groups, scale,
+                  window, sink):
+    """One ``[groups * tq]``-row query tile of one kv head against one
+    ``tk``-key tile: a flash fold with the causal (and, with ``window``,
+    banded) mask worked out from absolute positions. ``pos_ref`` holds
+    (position of query row 0, position of key row 0, visible tokens)."""
+    if sink:
+        sink_ref, o_ref, acc_ref, m_ref, l_ref = rest
+    else:
+        o_ref, acc_ref, m_ref, l_ref = rest
+    i, j = pl.program_id(1), pl.program_id(2)
+    q0 = pos_ref[0] + i * tq
+    k0 = pos_ref[1] + _key_tile(pos_ref, i, j, tq, tk, window) * tk
+    upto = pos_ref[2]
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        if sink:
+            m_ref[...] = sink_ref[0]
+            l_ref[...] = jnp.ones_like(l_ref)
+        else:
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+
+    lo = q0 - window + 1 if window is not None else 0
+    live = (k0 <= jnp.minimum(q0 + tq, upto) - 1) & (k0 + tk > lo) \
+        & (j < _live_tiles(pos_ref, i, tq, tk, window))
+
+    @pl.when(live)
+    def _fold():
+        rows = groups * tq
+        q = q_ref[0].reshape(rows, q_ref.shape[-1])
+        s = jax.lax.dot_general(
+            q, k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale       # [rows, tk]
+        qp = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) % tq
+        kp = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        ok = (kp <= qp) & (kp >= 0) & (kp < upto)
+        if window is not None:
+            ok = ok & (kp > qp - window)
+        s = jnp.where(ok, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        pexp = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(pexp, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            pexp.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _out():
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).reshape(
+            o_ref.shape[1:]).astype(o_ref.dtype)
+
+
+def _live_tiles(pos, i, tq, tk, window):
+    """Key tiles query tile ``i`` folds, counted from its first."""
+    last = jnp.minimum(pos[0] + (i + 1) * tq, pos[2]) - 1 - pos[1]
+    n = last // tk + 1
+    if window is not None:
+        n = n - _first_tile(pos, i, tq, tk, window)
+    return jnp.maximum(n, 1)
+
+
+def _first_tile(pos, i, tq, tk, window):
+    return jnp.maximum(pos[0] + i * tq - window + 1 - pos[1], 0) // tk
+
+
+def _key_tile(pos, i, j, tq, tk, window):
+    """The key tile grid step ``(i, j)`` reads: the walk starts at the first
+    tile the window reaches, and a step past the last live tile repeats it,
+    so the pipeline copies nothing for a dead step."""
+    j = jnp.minimum(j, _live_tiles(pos, i, tq, tk, window) - 1)
+    return j + _first_tile(pos, i, tq, tk, window) if window is not None else j
+
+
+def chunk_attention_pallas(q, k, v, q_start, k_start, upto, scale, *,
+                           window=None, sink=None, name="chunk_attention"):
+    """Causal attention of ONE request's chunk of queries over keys laid
+    out in a row. q [T, H, Dk] at absolute positions ``q_start + t``; k
+    [L, Hkv, Dk] and v [L, Hkv, Dv] at ``k_start + l`` (negative positions
+    are no tokens); positions ``>= upto`` are padding. Query t sees keys
+    ``max(0, t - window + 1) .. t`` (all of ``0 .. t`` without a window);
+    ``sink`` [H] f32 is a per-head logit in the softmax's denominator.
+    Returns [T, H, Dv]; rows past ``upto`` are undefined.
+
+    The grid is (kv head, query tile, key tile); with a window a query tile
+    walks only the ``window / tk + 1`` key tiles its band reaches, so the
+    work follows T x window, not T x L."""
+    t, h, dk = q.shape
+    ln, hkv, dv = v.shape
+    groups = h // hkv
+    tq = min(t, max(8, _CHUNK_TILE_ROWS // groups))
+    while t % tq:
+        tq //= 2
+    tk = min(ln, 256 if window is None else tq)
+    while ln % tk:
+        tk //= 2
+    n_k = ln // tk if window is None else min(ln // tk, -(-(window + tq - 1) // tk) + 1)
+    pos = jnp.stack([jnp.asarray(x, jnp.int32).reshape(())
+                     for x in (q_start, k_start, upto)])
+    # [Hkv, G, T, Dk]: a tile's rows are (head of the group, query row)
+    qg = jnp.transpose(q.reshape(t, hkv, groups, dk), (1, 2, 0, 3))
+    kg = jnp.swapaxes(k, 0, 1)
+    vg = jnp.swapaxes(v, 0, 1)
+    q_spec = pl.BlockSpec((1, groups, tq, dk), lambda a, i, j, P: (a, 0, i, 0))
+    o_spec = pl.BlockSpec((1, groups, tq, dv), lambda a, i, j, P: (a, 0, i, 0))
+
+    def key_spec(width):
+        return pl.BlockSpec(
+            (1, tk, width),
+            lambda a, i, j, P: (a, _key_tile(P, i, j, tq, tk, window), 0))
+
+    in_specs, operands = [q_spec, key_spec(dk), key_spec(dv)], [qg, kg, vg]
+    if sink is not None:
+        in_specs.append(pl.BlockSpec((1, groups * tq, 1),
+                                     lambda a, i, j, P: (a, 0, 0)))
+        operands.append(jnp.repeat(
+            sink.astype(jnp.float32).reshape(hkv, groups), tq,
+            axis=1)[..., None])
+    out = pl.pallas_call(
+        functools.partial(_chunk_kernel, tq=tq, tk=tk, groups=groups,
+                          scale=float(scale), window=window,
+                          sink=sink is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(hkv, t // tq, n_k),
+            in_specs=in_specs,
+            out_specs=o_spec,
+            scratch_shapes=[
+                pltpu.VMEM((groups * tq, dv), jnp.float32),
+                pltpu.VMEM((groups * tq, 1), jnp.float32),
+                pltpu.VMEM((groups * tq, 1), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((hkv, groups, t, dv), q.dtype),
+        interpret=_interpret(),
+        name=name,
+    )(pos, *operands)
+    return jnp.transpose(out, (2, 0, 1, 3)).reshape(t, h, dv)

@@ -489,7 +489,7 @@ def run_decode_sync_ab(tiny=True, seed=0, repeat=1, k=None):
     model.eval()
     stream = request_stream(cfg, seed=seed, **stream_kwargs)
     warm = request_stream(cfg, seed=seed + 1, **stream_kwargs)
-    arms = (("host_sampling", {}),
+    arms = (("host_sampling", dict(capture_logits=True)),
             ("in_graph", dict(in_graph_sampling=True)),
             ("window", dict(decode_steps_per_sync=k)))
     engines = {}

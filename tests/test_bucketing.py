@@ -296,6 +296,35 @@ class TestCacheTelemetry:
         finally:
             paddle.set_flags(old)
 
+    def test_counting_jit_takes_jaxs_word_for_a_call_it_knew(self, monkeypatch):
+        # a call jax's own cache of the function knew is a hit, counted
+        # without the arguments' signature being built; a new shape, a new
+        # dtype or a new static value is a compile under its signature
+        import jax.numpy as jnp
+
+        from paddle_tpu.jit.cache import CountingJit
+
+        f = CountingJit(lambda xs, n, y: [x * n for x in xs] + [y], "cj_t",
+                        static_argnums=(1,))
+        built = []
+        sig = CountingJit._signature
+        monkeypatch.setattr(CountingJit, "_signature",
+                            lambda self, a: built.append(1) or sig(self, a))
+        xs = [jnp.ones((2, 3)), jnp.ones(4)]
+        for _ in range(4):
+            f(xs, 2, jnp.zeros(1, jnp.int32))
+        assert len(built) == 1
+        f(xs, 3, jnp.zeros(1, jnp.int32))                # static value
+        f(xs, 2, jnp.zeros(1, jnp.float32))              # dtype
+        f([jnp.ones((2, 5)), jnp.ones(4)], 2, jnp.zeros(1, jnp.int32))
+        for _ in range(3):
+            f(xs, 3, jnp.zeros(1, jnp.int32))
+        stats = jit.cache_stats("cj_t")
+        assert stats["compiles"] == 4 and stats["hits"] == 6
+        assert len(built) == 4
+        assert sorted(stats["per_shape_misses"].values()) == [1, 1, 1, 1]
+        assert any(k.endswith("||3") for k in stats["per_shape_misses"])
+
     def test_reset_cache_stats(self):
         @jit.to_static
         def h(x):

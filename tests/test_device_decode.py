@@ -73,17 +73,61 @@ class TestInGraphSampling:
         cfg = model.config
         prompts = prompts_fixed(cfg, [5, 12, 9, 17], seed=3)
         sp = SamplingParams(max_new_tokens=9)
-        ref, mref = _generate(model, prompts, sp)
+        # host-sampled from the fetched rows (capture_logits keeps them),
+        # the default (ISSUE 27: the decode graph's own argmax, fetched
+        # when the whole batch is greedy), and the window path's switch
+        ref, mref = _generate(model, prompts, sp, capture_logits=True)
+        dflt, mdflt = _generate(model, prompts, sp)
         ing, ming = _generate(model, prompts, sp, in_graph_sampling=True)
-        for a, b in zip(ref, ing):
+        for a, b, c in zip(ref, dflt, ing):
             np.testing.assert_array_equal(a, b)
+            np.testing.assert_array_equal(a, c)
         # ISSUE 18 satellite: per-sync decode fetch drops from B*V*4
-        # logits bytes to B*4 token bytes with in-graph sampling on
+        # logits bytes to B*4 token bytes
         B, V = 4, cfg.vocab_size
         assert mref["host_syncs"] > 0
         assert mref["decode_fetch_bytes"] == mref["host_syncs"] * B * V * 4
-        assert ming["host_syncs"] == mref["host_syncs"]
-        assert ming["decode_fetch_bytes"] == ming["host_syncs"] * B * 4
+        for m in (mdflt, ming):
+            assert m["host_syncs"] == mref["host_syncs"]
+            assert m["decode_fetch_bytes"] == m["host_syncs"] * B * 4
+
+    def test_rows_are_fetched_only_while_something_reads_them(self, model):
+        # one engine, three stretches: greedy (tokens), a sampled request
+        # in the batch (rows for all), capture_logits switched on and off
+        # between steps (rows, then tokens again); the greedy request's
+        # tokens are what it gets alone whatever was fetched beside it
+        cfg = model.config
+        B, V = 2, cfg.vocab_size
+        p, q = prompts_fixed(cfg, [7, 11], seed=9)
+        alone, _ = _generate(model, [p], SamplingParams(max_new_tokens=12))
+        with LLMEngine(model, num_blocks=64, block_size=8, max_batch_size=B,
+                       ingest_async=False) as eng:
+            rid = eng.add_request(p, SamplingParams(max_new_tokens=12))
+
+            def stretch(steps):
+                m0 = eng.metrics()
+                for _ in range(steps):
+                    eng.step()
+                m1 = eng.metrics()
+                return ((m1["decode_fetch_bytes"] - m0["decode_fetch_bytes"])
+                        // (m1["host_syncs"] - m0["host_syncs"]))
+
+            eng.step()                                   # prefill + 1 decode
+            assert stretch(2) == B * 4
+            other = eng.add_request(q, SamplingParams(
+                max_new_tokens=3, do_sample=True, temperature=1.1, seed=5))
+            while not eng.request(other).finished:
+                assert stretch(1) == B * V * 4
+            eng.release(other)
+            eng.capture_logits = True
+            assert stretch(1) == B * V * 4
+            assert eng.request(rid).last_logits.shape == (V,)
+            eng.capture_logits = False
+            assert stretch(1) == B * 4
+            while not eng.request(rid).finished:
+                eng.step()
+            np.testing.assert_array_equal(
+                alone[0][len(p):], eng.request(rid).output_tokens)
 
     def test_do_sample_keeps_host_path_with_one_shot_warning(self, model):
         cfg = model.config
@@ -212,9 +256,10 @@ class TestDecodeWindows:
             alloc = eng.cache.allocator
             assert alloc.num_free == eng.cache.num_blocks - 1
 
-    def test_window_one_defaults_keep_host_path(self, model):
-        # decode_steps_per_sync=1 (the default) is byte-identical to the
-        # pre-ISSUE-18 engine: host-sampled, window graph never built
+    def test_window_one_defaults_keep_the_per_step_path(self, model):
+        # decode_steps_per_sync=1 (the default) decodes a step at a time
+        # and never builds the window graph; a greedy batch fetches its
+        # tokens, as the decode graph's own argmax gives them (ISSUE 27)
         cfg = model.config
         with LLMEngine(model, num_blocks=64, block_size=8,
                        max_batch_size=2, ingest_async=False) as eng:
@@ -225,7 +270,7 @@ class TestDecodeWindows:
             assert eng._window_jit is None
             assert eng._window_name not in paddle.jit.cache_stats()
             assert eng.metrics()["decode_fetch_bytes"] == (
-                eng.metrics()["host_syncs"] * 2 * cfg.vocab_size * 4)
+                eng.metrics()["host_syncs"] * 2 * 4)
 
 
 class TestTypedRejections:

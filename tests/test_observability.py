@@ -85,6 +85,32 @@ class TestRegistry:
         with pytest.raises(ValueError):
             c.inc(function="f")
 
+    def test_a_label_sets_checked_key_is_kept_and_kept_right(self):
+        # the key of a label set is worked out once (an engine counts
+        # every token under its instance's name); what must survive that:
+        # the order the call names its labels in, values that are not
+        # strings, the check of the names, and clear()
+        c = _fresh().counter("c_total")
+        for _ in range(3):
+            c.inc(instance="a", tenant="t")
+            c.inc(tenant="t", instance="a")
+        assert c.value(instance="a", tenant="t") == 6
+        assert c.labels() == [(("instance", "a"), ("tenant", "t"))]
+        g = _fresh().counter("g_total")
+        for v in (1, "1", True, 1.0):
+            g.inc(rank=v)
+            g.inc(rank=v)
+        assert g.value(rank=1) == g.value(rank="1") == 4
+        assert g.value(rank=True) == g.value(rank=1.0) == 2
+        with pytest.raises(ValueError):
+            c.inc(instance="a")
+        c.clear()
+        c.inc(function="f")
+        c.inc(function="f")
+        with pytest.raises(ValueError):
+            c.inc(instance="a", tenant="t")
+        assert c.value(function="f") == 2
+
     def test_disabled_registry_freezes_values(self):
         r = _fresh()
         c = r.counter("c_total")
