@@ -311,11 +311,17 @@ def reference_logits(model, prompts, toks_by_req):
     return out
 
 
-def executable_text(eng, jit, prompt_bucket=None):
+def executable_text(eng, prompt_bucket=None):
     """Compiled HLO of one engine executable at the shapes the burst used
-    (abstract inputs: the live pools were donated)."""
+    (abstract inputs: the live pools were donated): the prefill at
+    ``prompt_bucket``, or the decode step, whose operands the engine names
+    itself (``decode_abstract_args``)."""
     import jax
     import jax.numpy as jnp
+
+    if prompt_bucket is None:
+        return eng._decode_jit.lower(
+            *eng.decode_abstract_args()).compile().as_text()
 
     def abstract(tree):
         # single-device arrays stay unplaced, as jit treats them at a call
@@ -329,13 +335,9 @@ def executable_text(eng, jit, prompt_bucket=None):
     params = abstract([p._data for p in eng._params])
     pools = abstract([c.k, c.v, c.k_scale, c.v_scale])
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
-    if prompt_bucket is not None:
-        args = (params, i32(1, prompt_bucket), i32(), i32(),
-                i32(eng.max_pages), *pools)
-    else:
-        b = eng.max_batch_size
-        args = (params, i32(b, 1), i32(b), i32(b, eng.max_pages), *pools)
-    return jit.lower(*args).compile().as_text()
+    return eng._prefill_jit.lower(
+        params, i32(1, prompt_bucket), i32(), i32(), i32(eng.max_pages),
+        *pools).compile().as_text()
 
 
 def phase_serve(model, kv_dtype, tol, counter, plan=None):
@@ -380,8 +382,8 @@ def phase_serve(model, kv_dtype, tol, counter, plan=None):
 
         # the executables hold the Mosaic kernels, one per layer
         for name, text in (
-                ("prefill@2048", executable_text(eng, eng._prefill_jit, 2048)),
-                ("decode", executable_text(eng, eng._decode_jit))):
+                ("prefill@2048", executable_text(eng, 2048)),
+                ("decode", executable_text(eng))):
             n = sum(mosaic_calls(text))
             log(f"[{tag}] {name}: {n} Mosaic calls ({layers} layers)")
             check(n == layers, f"{tag}: {name} holds {n} Mosaic calls, "

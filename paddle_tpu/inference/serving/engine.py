@@ -15,7 +15,12 @@ server:
   fixed-shape decode step for every decode-ready slot against the paged
   KV pool — the decode graph compiles once and is reused for the life of
   the engine (``paddle.jit.cache_stats()`` row ``llm_engine_decode#n``
-  proves it);
+  proves it). The per-step decode path runs a step AHEAD of the host
+  (ISSUE 28): once this call's decode step is on the device the next one
+  is enqueued behind it, fed by its greedy tokens on the device, and only
+  then are this step's tokens fetched and emitted — call k still returns
+  step k's tokens, and the host's work lies beside the device's, not
+  between two of its steps;
 * with ``enable_prefix_cache=True``, full prompt blocks are registered
   under hash-chain identities after prefill: N requests sharing a prompt
   prefix prefill its full blocks ONCE, later admissions ``acquire`` the
@@ -149,6 +154,24 @@ _M_FETCH_BYTES = _obs_metrics.counter(
     "rows the host samples from or keeps, B*4 per greedy step, B*k*4 per "
     "fused k-step decode window")
 
+# decode dispatch-ahead (ISSUE 28): how each emitted decode step of the
+# plain path reached the device, and what the overlap cost in dropped rows
+_M_DECODE_AHEAD = _obs_metrics.counter(
+    "serving_decode_steps_ahead_total",
+    "decode steps emitted that were dispatched ahead: enqueued behind the "
+    "step before them, before that step's tokens were fetched")
+_M_DECODE_SYNC = _obs_metrics.counter(
+    "serving_decode_steps_sync_total",
+    "decode steps emitted that were dispatched with nothing in flight, by "
+    "reason: idle (first after a break), sampled (a do_sample row), evict "
+    "(room only by evicting or copying), drain (import, export, reload, "
+    "store save), path (speculative, fused window, process-spanning mesh)")
+_M_ROWS_DISCARDED = _obs_metrics.counter(
+    "serving_decode_rows_discarded_total",
+    "rows of a decode step in flight whose token was dropped: the request "
+    "finished by EOS, was cancelled, expired or preempted meanwhile, or "
+    "the step was drained")
+
 # the ONE list of every serving metric handle an engine instance owns —
 # metrics() and reset_metrics() both iterate it, so a new metric cannot
 # be added to one and silently missed by the other (a reset that skips a
@@ -173,6 +196,9 @@ _SERVING_METRICS = (_M_ADMITTED, _M_EVICTIONS, _M_FINISHED, _M_QUEUED_EXH,
                     _M_THROTTLED, _M_BATCH_YIELD,
                     # device-resident decode (ISSUE 18)
                     _M_HOST_SYNCS, _M_FETCH_BYTES,
+                    # decode dispatch-ahead (ISSUE 28); _M_DECODE_SYNC is
+                    # reason-labeled, handled like _M_STORE_REJECTED
+                    _M_DECODE_AHEAD, _M_ROWS_DISCARDED,
                     # serving integrity (ISSUE 20)
                     _M_PAGES_VERIFIED, _M_PAGES_REJECTED,
                     _M_WEIGHT_AUDIT_FAIL)
@@ -218,7 +244,13 @@ class _StepPhases:
     commit, latency observations, finishes), ``engine.bookkeeping`` (store
     autosave, gauges); the speculative path adds ``engine.decode.draft``
     (the draft model's catch-up and proposals, with their own fetches).
-    All lie inside the step's ``engine.step`` and carry its ``args``."""
+    All lie inside the step's ``engine.step`` and carry its ``args``.
+
+    On the per-step path a steady call's prepare and dispatch are the
+    NEXT step's and its fetch and emit this step's (ISSUE 28); the first
+    call after a break holds prepare and dispatch twice, this step's and
+    the next one's, and a call that dispatches nothing ahead holds an
+    empty prepare."""
 
     __slots__ = ("args", "_open")
 
@@ -234,6 +266,31 @@ class _StepPhases:
         if self._open is not None:
             self._open.end()
             self._open = None
+
+
+@dataclasses.dataclass(slots=True)
+class _DecodeInFlight:
+    """A decode step the device has been given and the host has not yet
+    fetched (ISSUE 28). ``rows`` is what it was made for, ``[(slot,
+    request, position written, the request's admit_seq then)]``; ``logits``
+    and ``greedy`` are its result arrays, still on the device; ``sampled``
+    says that a row's token is the host sampler's to choose; ``how`` is
+    how it was dispatched: ``"ahead"``, or the reason it was not."""
+
+    rows: list
+    logits: object
+    greedy: object
+    sampled: bool
+    how: str
+
+    def standing(self, slots):
+        """The rows whose request still sits where the step was made for
+        it, as it was: not finished, cancelled, expired or preempted (a
+        re-admission takes a new ``admit_seq``), its cache where this step
+        wrote."""
+        return [row for row in self.rows
+                if slots[row[0]] is row[1] and row[1].admit_seq == row[3]
+                and not row[1].prefilling and row[1].num_cached == row[2]]
 
 
 @dataclasses.dataclass
@@ -703,6 +760,14 @@ class LLMEngine:
                         if ingest_async else None)
         self.stats_extra = {"steps": 0, "prefills": 0, "tokens_out": 0}
         self._phases = _StepPhases()
+        # decode dispatch-ahead (ISSUE 28): the decode step in flight, made
+        # in the last call for this one (a ``_DecodeInFlight``); why the
+        # next step will be dispatched with nothing in flight, if something
+        # said so; and the operand a step takes in place of the step
+        # before's tokens when every id comes from the host
+        self._ahead = None
+        self._sync_reason = None
+        self._no_prev = None
 
     # ------------------------------------------------------------------
     # persistent prefix store (ISSUE 16)
@@ -726,6 +791,8 @@ class LLMEngine:
         Returns the number of entries written."""
         if self._store_path is None:
             raise ValueError(f"{self._name} has no prefix_store_path")
+        # beside a step in flight: the chains it exports are full blocks
+        # the index names, and no step made ahead writes into one of those
         entries = self._prefix_store_entries()
         save_prefix_store(self._store_path, entries,
                           fingerprint=self._store_fingerprint,
@@ -1013,6 +1080,9 @@ class LLMEngine:
                 f"request {rid} is not decode-ready "
                 f"(state={req.state}, prefilling={req.prefilling}); only "
                 "a completed prefill exports pages")
+        # a decode step in flight stays in flight: the gather is enqueued
+        # behind it, and what that step wrote for this request lies at
+        # ``num_cached``, past what the pages cover
         n_pages = -(-req.num_cached // self.block_size)
         return self.cache.export_request_pages(req.blocks[:n_pages],
                                                req.num_cached)
@@ -1081,7 +1151,9 @@ class LLMEngine:
         and publish their identities to the prefix cache so later
         admissions can share them. One-shot: after this, the request is
         indistinguishable from one prefilled locally — an eviction
-        re-prefills through the normal staged path."""
+        re-prefills through the normal staged path. A decode step in
+        flight stays in flight: the import is a program enqueued behind
+        it, into blocks none of its standing rows holds."""
         pages = req.preloaded
         req.preloaded = None
         revived = req.revived_from_tier
@@ -1313,7 +1385,7 @@ class LLMEngine:
 
         return core
 
-    def _make_decode_fn(self, model, params):
+    def _make_decode_fn(self, model, params, feed_back=False):
         """Pure one-token decode over ``model``: ``(param_arrays,
         ids [B, 1], positions [B], tables [B, P], k_pools, v_pools,
         k_scales, v_scales) -> (logits [B, V], greedy tokens [B] int32,
@@ -1327,7 +1399,15 @@ class LLMEngine:
         extra operands and the extra result are ``_make_chunk_fn``'s, but
         the first of them is empty here: with a window kind ``tables`` is
         ``_decode_tables``' ``[B, P + R]``, the rings behind the global
-        table, so that a step puts one table and not two."""
+        table, so that a step puts one table and not two.
+
+        With ``feed_back`` (the engine's own decode, ISSUE 28) ``ids`` is
+        ``[B, 2]`` and one operand comes before the others behind the
+        pools: the step before's greedy tokens ``[B]``, still on the
+        device. A row whose second column is set takes its id from there
+        and not from the first column, so a step can be enqueued before
+        the host has seen the tokens it continues from. One executable
+        serves both: a step whose ids all come from the host passes zeros."""
         from ...core import state as _state
         from ...models.llama import greedy_tokens_in_graph
 
@@ -1337,6 +1417,11 @@ class LLMEngine:
 
         def decode_pure(param_arrays, ids, positions, tables,
                         k_pools, v_pools, k_scales, v_scales, *extra):
+            if feed_back:
+                import jax.numpy as jnp
+
+                prev, *extra = extra
+                ids = jnp.where(ids[:, 1:] != 0, prev[:, None], ids[:, :1])
             quantized = len(k_scales) > 0
             ks_in = k_scales if quantized else [None] * len(k_pools)
             vs_in = v_scales if quantized else [None] * len(v_pools)
@@ -1662,10 +1747,18 @@ class LLMEngine:
             self._make_chunk_fn(self.model, self._params), self._plan,
             name=self._prefill_name, donate_argnums=(5, 6, 7, 8),
             out_specs=pool_out)
+        # the greedy tokens go back in as the next step's operand (ISSUE
+        # 28): pinned replicated under a plan, where ``_prev_operand``
+        # puts the zeros that stand in for them, so that either is the
+        # same input layout to the one executable
+        greedy_out = None
+        if pool_out is not None:
+            from jax.sharding import PartitionSpec
+            greedy_out = (pool_out[0], PartitionSpec()) + pool_out[1:]
         self._decode_jit = compile_step_with_plan(
-            self._make_decode_fn(self.model, self._params), self._plan,
-            name=self._decode_name, donate_argnums=(4, 5, 6, 7),
-            out_specs=pool_out and pool_out[:1] + pool_out)
+            self._make_decode_fn(self.model, self._params, feed_back=True),
+            self._plan, name=self._decode_name, donate_argnums=(4, 5, 6, 7),
+            out_specs=greedy_out)
         if self._in_graph:
             self._window_jit = compile_step_with_plan(
                 self._make_window_fn(self.model, self._params,
@@ -1703,17 +1796,25 @@ class LLMEngine:
     # ------------------------------------------------------------------
     # the scheduler tick
     # ------------------------------------------------------------------
-    def _tables(self):
+    def _tables(self, rows=None):
         """Device block-table array for the decode-ready slots, cached
         against the scheduler's table version + slot readiness (ISSUE 11
         satellite: steady-state decode re-uploads nothing). Slots that are
         empty OR still mid-prefill map to the null block: the decode graph
         writes a K/V row for EVERY batch row, and an inactive row's write
         must land in the null block — pointing it at a prefilling
-        request's real blocks would corrupt its just-written pages."""
+        request's real blocks would corrupt its just-written pages. So
+        does a slot the step leaves out: ``rows`` (``_dispatch_decode``'s
+        form) names the slots of a step that does not run every ready
+        one, a step dispatched ahead without the rows that finish on the
+        step before it."""
         sched = self.scheduler
-        mask = tuple(r is not None and not r.prefilling
-                     for r in sched.slots)
+        if rows is None:
+            mask = tuple(r is not None and not r.prefilling
+                         for r in sched.slots)
+        else:
+            live = {row[0] for row in rows}
+            mask = tuple(i in live for i in range(len(sched.slots)))
         key = (sched.version, mask)
         if key != self._tables_version:
             lists = [(r.blocks if ok else [])
@@ -1725,40 +1826,42 @@ class LLMEngine:
             self._tables_version = key
         return self._tables_dev
 
-    def _decode_tables(self, ready):
-        """The decode graph's table operand. Without a window kind that is
-        ``_tables()``. With one it is the global table and the rings side by
-        side, ``[B, P + R]`` in ONE put (the graph cuts it in two), after
-        turning every ready request's ring to the page its next token lands
-        in: the page that fell out of the window goes back to the allocator
-        here. The host copy is kept and only what moved is rewritten: a row
-        of the global table grows at its end (nothing with a window kind
-        shares or copies a block), a ring turns once a page; a change of the
-        ready slots rewrites it all."""
+    def _decode_tables(self, rows):
+        """The decode graph's table operand for ``rows`` (``[(slot, request,
+        position the step writes, ...)]``). Without a window kind that is
+        ``_tables(rows)``. With one it is the global table and the rings
+        side by side, ``[B, P + R]`` in ONE put (the graph cuts it in two),
+        after turning every row's ring to the page its token lands in: the
+        page that fell out of the window goes back to the allocator here
+        (a step still in flight may be reading it: whatever writes it next
+        is enqueued behind that step). The host copy is kept and only what
+        moved is rewritten: a row of the global table grows at its end
+        (nothing with a window kind shares or copies a block), a ring turns
+        once a page; a change of the rows' slots rewrites it all."""
         window = self.cache.window
         if window is None:
-            return self._tables()
+            return self._tables(rows)
         P, bs = self.max_pages, self.block_size
         host, known = self._tables_host, self._tables_known
         if host is None:
             host = self._tables_host = np.zeros(
                 (self.max_batch_size, P + window.ring), np.int32)
             known = self._tables_known = [0] * self.max_batch_size
-        mask = tuple((i, req.rid) for i, req in ready)
+        mask = tuple((row[0], row[1].rid, row[3]) for row in rows)
         fresh = mask != self._tables_mask
         if fresh:
             host[:] = 0
             known[:] = [0] * len(known)
             self._tables_mask = mask
         moved = fresh
-        for i, req in ready:
+        for i, req, pos, _ in rows:
             n = min(len(req.blocks), P)
             if n != known[i]:
                 host[i, known[i]:n] = req.blocks[known[i]:n]
                 known[i] = n
                 moved = True
-            if fresh or req.num_cached % bs == 0:
-                page = req.num_cached // bs
+            if fresh or pos % bs == 0:
+                page = pos // bs
                 window.ensure(req.rid, page, page)
                 host[i, P:] = window.table_row(req.rid)
                 moved = True
@@ -1769,7 +1872,10 @@ class LLMEngine:
 
     def _drain_cow(self):
         """Execute queued copy-on-write page copies (device-side) before
-        the next pool write can touch the replaced blocks."""
+        the next pool write can touch the replaced blocks. No decode step
+        is in flight here: ``ensure_decode_room``, which queues them, runs
+        only in a call that found none, and a step is dispatched ahead
+        only where none of its rows needs a copy (``reserve_ahead``)."""
         for src, dst in self.scheduler.pending_cow:
             self.cache.copy_block(src, dst)
             if self.draft_model is not None:
@@ -1869,7 +1975,9 @@ class LLMEngine:
     def step(self):
         """One engine tick: drain ingest, admit, advance chunked prefills
         under the token budget, one decode (or speculative verify) for all
-        decode-ready slots. Returns the ``StepOutput`` tokens produced.
+        decode-ready slots. Returns the ``StepOutput`` tokens produced:
+        call k returns step k's tokens, also where step k was enqueued by
+        call k-1 and step k+1 is enqueued by this one (``_dispatch_ahead``).
 
         The call is one ``engine.step`` span cut into the phases of
         ``_StepPhases``; each carries the instance's name and, once the
@@ -1907,6 +2015,8 @@ class LLMEngine:
         # blocks/slot are available to this very step's admissions
         self._expire_deadlines(outputs)
         if not sched.has_work():
+            # a step in flight for requests that are all gone by now
+            self._drain("idle")
             return outputs
         self.stats_extra["steps"] += 1
         if phases.args is not None:
@@ -1946,64 +2056,233 @@ class LLMEngine:
             return outputs
 
         # -- decode ------------------------------------------------------
+        # this call's decode step is the one the last call dispatched ahead,
+        # where it did and a row of it still stands; else it is made now,
+        # as ever. Either way the NEXT one is enqueued behind it before its
+        # tokens are fetched, where ``_dispatch_ahead`` finds it can be, so
+        # that fetch, emit and the next call's admission run beside the
+        # device and not between two of its steps (ISSUE 28)
         phases.begin("engine.decode.prepare")
-        sched.ensure_decode_room(
-            extra=self._spec_k,
-            extra_for=(self._window_extra if self._decode_window > 1
-                       else None))
-        self._drain_cow()
-        ready = [(i, r) for i, r in enumerate(sched.slots)
-                 if r is not None and not r.prefilling]
-        if ready:
-            sampled = any(r.sampling.do_sample for _, r in ready)
-            if self._spec_k:
-                self._spec_step(ready, outputs)
-            elif self._in_graph and not sampled:
-                self._window_step(ready, outputs)
-            else:
-                if self._in_graph and not self._warned_do_sample:
-                    self._warned_do_sample = True
-                    warnings.warn(
-                        f"{self._name}: do_sample=True requests keep the "
-                        "host sampling path (per-request numpy RNG); "
-                        "device-resident decode degrades to per-step "
-                        "host sampling while any is in the batch",
-                        RuntimeWarning)
-                B = self.max_batch_size
-                ids = np.zeros((B, 1), np.int32)
-                positions = np.zeros(B, np.int32)
-                for i, req in ready:
-                    ids[i, 0] = req.last_token
-                    positions[i] = req.num_cached
-                c = self.cache
-                params = [p._data for p in self._params]
-                ids, positions = self._g(ids), self._g(positions)
-                tables = self._decode_tables(ready)
-                extras = self._graph_extras(None)
-                phases.begin("engine.decode.dispatch")
-                (logits, greedy, c.k, c.v, c.k_scale, c.v_scale,
-                 *counters) = self._decode_jit(
-                        params, ids, positions, tables,
-                        c.k, c.v, c.k_scale, c.v_scale, *extras)
-                if counters:
-                    self._counters_dev = counters[0]
-                phases.begin("engine.decode.fetch")
-                # the rows themselves only where the host samples from
-                # them or keeps them; a greedy batch fetches its tokens
-                rows = sampled or self.capture_logits
-                fetched = self._fetch(logits if rows else greedy)
-                phases.begin("engine.decode.emit")
-                _M_HOST_SYNCS.inc(instance=self._name)
-                _M_FETCH_BYTES.inc(fetched.nbytes, instance=self._name)
-                for i, req in ready:
-                    req.num_cached += 1
-                    outputs.extend(
-                        self._emit(req, fetched[i]) if rows
-                        else self._emit_token(req, fetched[i]))
+        cur = self._take_ahead()
+        if cur is None:
+            sched.ensure_decode_room(
+                extra=self._spec_k,
+                extra_for=(self._window_extra if self._decode_window > 1
+                           else None))
+            self._drain_cow()
+            ready = [(i, r) for i, r in enumerate(sched.slots)
+                     if r is not None and not r.prefilling]
+            if ready:
+                sampled = any(r.sampling.do_sample for _, r in ready)
+                if self._spec_k:
+                    _M_DECODE_SYNC.inc(instance=self._name, reason="path")
+                    self._spec_step(ready, outputs)
+                elif self._in_graph and not sampled:
+                    _M_DECODE_SYNC.inc(instance=self._name, reason="path")
+                    self._window_step(ready, outputs)
+                else:
+                    if self._in_graph and not self._warned_do_sample:
+                        self._warned_do_sample = True
+                        warnings.warn(
+                            f"{self._name}: do_sample=True requests keep the "
+                            "host sampling path (per-request numpy RNG); "
+                            "device-resident decode degrades to per-step "
+                            "host sampling while any is in the batch",
+                            RuntimeWarning)
+                    cur = self._dispatch_decode(
+                        [(i, r, r.num_cached, r.admit_seq)
+                         for i, r in ready], (), sampled,
+                        self._sync_reason or "idle")
+                    self._sync_reason = None
+                    phases.begin("engine.decode.prepare")
+        if cur is not None:
+            self._ahead = self._dispatch_ahead(cur)
+            self._emit_decode(cur, outputs)
+            if self._ahead is not None and not any(sched.slots):
+                # every request ended on this step (EOS: not seen ahead)
+                self._drain("idle")
         phases.begin("engine.bookkeeping")
         self._maybe_autosave_store()
         self._update_gauges()
         return outputs
+
+    # -- decode dispatch-ahead (ISSUE 28) -------------------------------
+    def _drain(self, reason="drain"):
+        """Forget the decode step in flight, if there is one: its tokens
+        are dropped and no ``num_cached`` moves, so the next call makes the
+        same step again with nothing in flight. What the forgotten step
+        wrote, each row at its request's own next position, that step
+        writes again, the same. For a reload of the weights (the step ran
+        on the old ones), ``close``, and a call that finds nothing left to
+        run. Page import and export, tier revival and a store save do NOT
+        drain: the pools are arrays handed from program to program, so
+        their gathers and scatters are enqueued behind the step in flight,
+        and its writes lie past what they cover (``DESIGN_DECISIONS.md``)."""
+        a, self._ahead = self._ahead, None
+        if a is not None:
+            _M_ROWS_DISCARDED.inc(len(a.rows), instance=self._name)
+            self._sync_reason = reason
+
+    def _take_ahead(self):
+        """The step in flight as THIS call's decode, cut down to the rows
+        that still stand (``_DecodeInFlight.standing``); the others are
+        discarded here: token dropped, ``num_cached`` as it was. None, and
+        the call decodes as ever, if nothing is in flight or nothing of it
+        stands."""
+        a, self._ahead = self._ahead, None
+        if a is None:
+            return None
+        rows = a.standing(self.scheduler.slots)
+        if len(rows) != len(a.rows):
+            _M_ROWS_DISCARDED.inc(len(a.rows) - len(rows),
+                                  instance=self._name)
+            a.rows = rows
+        return a if rows else None
+
+    def _prev_operand(self):
+        """Zeros in the place of the step before's tokens, for a step
+        whose ids all come from the host; laid out as those tokens are."""
+        if self._no_prev is None:
+            z = np.zeros(self.max_batch_size, np.int32)
+            if self._plan is not None and self._plan.mesh.devices.size > 1:
+                import jax
+                from jax.sharding import NamedSharding, PartitionSpec
+                self._no_prev = jax.device_put(
+                    z, NamedSharding(self._plan.mesh, PartitionSpec()))
+            else:
+                self._no_prev = self._g(z)
+        return self._no_prev
+
+    def _decode_args(self, ids, positions, tables, prev=None):
+        """The decode executable's operands, in its order: ``ids [B, 2]``,
+        ``positions [B]``, the tables, the pools, the step before's greedy
+        tokens (zeros where there is none), then ``_graph_extras``."""
+        c = self.cache
+        return ([p._data for p in self._params], ids, positions, tables,
+                c.k, c.v, c.k_scale, c.v_scale,
+                self._prev_operand() if prev is None else prev,
+                *self._graph_extras(None))
+
+    def decode_abstract_args(self):
+        """``_decode_args`` as ``ShapeDtypeStruct``s, for lowering the decode
+        executable without running a step (``chip_smoke.py`` reads its
+        compiled text; the live pools are donated). An array on one device
+        stays unplaced, as jit treats it at a call."""
+        import jax
+
+        if self._decode_jit is None:
+            self._build_jits()
+        B, window = self.max_batch_size, self.cache.window
+        width = self.max_pages + (window.ring if window is not None else 0)
+        args = self._decode_args(
+            self._g(np.zeros((B, 2), np.int32)),
+            self._g(np.zeros(B, np.int32)),
+            self._g(np.zeros((B, width), np.int32)))
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype,
+                sharding=(x.sharding if len(x.sharding.device_set) > 1
+                          else None)), tuple(args))
+
+    def _dispatch_decode(self, rows, fed, sampled, how, prev=None):
+        """Enqueue one decode step for ``rows`` (``[(slot, request,
+        position, admit_seq)]``) and return it as a ``_DecodeInFlight``.
+        The slots in ``fed`` take their input id from ``prev``, the greedy
+        tokens of the step before, on the device; the others from the
+        request's last token on the host. Called inside
+        ``engine.decode.prepare``; leaves ``engine.decode.dispatch`` open."""
+        B = self.max_batch_size
+        ids = np.zeros((B, 2), np.int32)
+        positions = np.zeros(B, np.int32)
+        for i, req, pos, _ in rows:
+            if i in fed:
+                ids[i, 1] = 1
+            else:
+                ids[i, 0] = req.last_token
+            positions[i] = pos
+        c = self.cache
+        args = self._decode_args(self._g(ids), self._g(positions),
+                                 self._decode_tables(rows), prev)
+        self._phases.begin("engine.decode.dispatch")
+        (logits, greedy, c.k, c.v, c.k_scale, c.v_scale,
+         *counters) = self._decode_jit(*args)
+        if counters:
+            self._counters_dev = counters[0]
+        return _DecodeInFlight(rows, logits, greedy, sampled, how)
+
+    def _dispatch_ahead(self, cur):
+        """Enqueue the decode step AFTER ``cur`` while ``cur`` is still the
+        device's, if what the engine can see allows it; returns it, or None
+        with the reason kept for the counter. It runs the rows of ``cur``
+        that ``cur``'s token does not finish by length, each a position on
+        and fed by ``cur``'s greedy tokens without their visiting the
+        host, and the rows that became ready since (a prefill's last chunk
+        ended in this call: their first token is on the host). Not
+        dispatched: beside a row the host samples for; where room for it
+        takes an eviction or a copy (``Scheduler.reserve_ahead``: the next
+        call's ``ensure_decode_room`` does those, with nothing in flight);
+        on a mesh that spans processes, whose ranks are held in step call
+        by call. Stops and EOS cannot be seen ahead: such a row is run,
+        and discarded when the next call finds its request gone."""
+        if self._mp:
+            self._sync_reason = "path"
+            return None
+        if cur.sampled:
+            self._sync_reason = "sampled"
+            return None
+        wrote = {row[0]: row[2] for row in cur.rows}
+        rows, fed = [], set()
+        for i, req in enumerate(self.scheduler.slots):
+            if req is None or req.prefilling:
+                continue
+            s = req.sampling
+            if s.do_sample:
+                self._sync_reason = "sampled"
+                return None
+            pos = wrote.get(i)
+            if pos is None:
+                rows.append((i, req, req.num_cached, req.admit_seq))
+            elif len(req.output_tokens) + 1 < s.max_new_tokens:
+                rows.append((i, req, pos + 1, req.admit_seq))
+                fed.add(i)
+        if not rows:
+            return None
+        if not self.scheduler.reserve_ahead([(r[1], r[2]) for r in rows]):
+            self._sync_reason = "evict"
+            return None
+        return self._dispatch_decode(rows, fed, False, "ahead",
+                                     prev=cur.greedy)
+
+    def _emit_decode(self, cur, outputs):
+        """Fetch ``cur``'s result and commit a token a row. A step whose
+        rows all decode greedily fetches its ``[B]`` int32 tokens, and with
+        ``capture_logits`` on (read here, so it may be flipped between
+        calls) the ``[B, V]`` rows beside them for ``last_logits``: the
+        token is the graph's argmax either way. A step with a sampled row
+        fetches the rows and the host chooses every token from them."""
+        phases = self._phases
+        phases.begin("engine.decode.fetch")
+        greedy = None if cur.sampled else self._fetch(cur.greedy)
+        logits = (self._fetch(cur.logits)
+                  if cur.sampled or self.capture_logits else None)
+        phases.begin("engine.decode.emit")
+        inst = self._name
+        _M_HOST_SYNCS.inc(instance=inst)
+        _M_FETCH_BYTES.inc(
+            (0 if greedy is None else greedy.nbytes)
+            + (0 if logits is None else logits.nbytes), instance=inst)
+        if cur.how == "ahead":
+            _M_DECODE_AHEAD.inc(instance=inst)
+        else:
+            _M_DECODE_SYNC.inc(instance=inst, reason=cur.how)
+        for i, req, _, _ in cur.rows:
+            req.num_cached += 1
+            if greedy is None:
+                outputs.extend(self._emit(req, logits[i]))
+                continue
+            if logits is not None:
+                req.last_logits = logits[i]
+            outputs.extend(self._emit_token(req, greedy[i]))
 
     def _window_extra(self, req):
         """Lookahead positions ``ensure_decode_room`` must reserve for
@@ -2466,6 +2745,9 @@ class LLMEngine:
         ``CheckpointManager`` (prefers ``latest_healthy_step()``, falls
         back to ``latest_valid_step()``), a checkpoint step directory, or
         a state-dict file path. Returns the restored step (or None)."""
+        # a step in flight ran on the weights that are about to go: drop
+        # it, and the next call makes it again on the new ones
+        self._drain()
         try:
             step = self._reload_weights_impl(source)
         finally:
@@ -2551,6 +2833,8 @@ class LLMEngine:
         percentiles from — engine-measured, not bench-side timing."""
         inst = self._name
         prop = _M_SPEC_PROPOSED.value(instance=inst)
+        store_rejected = self._by_reason(_M_STORE_REJECTED, "corrupt")
+        decode_sync = self._by_reason(_M_DECODE_SYNC)
         return {
             "instance": inst,
             **self._kind_metrics(),
@@ -2596,10 +2880,8 @@ class LLMEngine:
                 _M_STORE_LOADED.value(instance=inst)),
             # reason-labeled since ISSUE 20: the plain key stays the
             # all-reasons sum so existing consumers keep working
-            "prefix_store_rejected": sum(
-                self._store_rejected_by_reason().values()),
-            "prefix_store_rejected_by_reason":
-                self._store_rejected_by_reason(),
+            "prefix_store_rejected": sum(store_rejected.values()),
+            "prefix_store_rejected_by_reason": store_rejected,
             # multi-tenant QoS (ISSUE 17) — zeros when QoS is unused
             "quota_throttled": int(_M_THROTTLED.value(instance=inst)),
             "batch_yields": int(_M_BATCH_YIELD.value(instance=inst)),
@@ -2608,6 +2890,14 @@ class LLMEngine:
             # and the bytes they pulled (prefill fetches excluded)
             "host_syncs": int(_M_HOST_SYNCS.value(instance=inst)),
             "decode_fetch_bytes": int(_M_FETCH_BYTES.value(instance=inst)),
+            # decode dispatch-ahead (ISSUE 28): of the decode steps
+            # emitted, how many were enqueued behind the step before them
+            # and how many with nothing in flight, by why; rows dropped
+            "decode_steps_ahead": int(_M_DECODE_AHEAD.value(instance=inst)),
+            "decode_steps_sync": sum(decode_sync.values()),
+            "decode_steps_sync_by_reason": decode_sync,
+            "decode_rows_discarded": int(
+                _M_ROWS_DISCARDED.value(instance=inst)),
             # serving integrity (ISSUE 20) — zeros when checksums / the
             # weight audit are off
             "kv_pages_verified": int(
@@ -2654,23 +2944,24 @@ class LLMEngine:
         """Remove THIS instance's tenant-labeled series. The extra
         ``tenant`` label means the plain ``remove(instance=)`` sweep in
         ``reset_metrics``/``close`` cannot reach them — iterate the live
-        label sets instead. The reason-labeled store-rejected counter
-        (ISSUE 20) needs the same treatment."""
-        for m in (_M_TENANT_TOKENS, _M_STORE_REJECTED):
+        label sets instead. The reason-labeled counters (store rejections,
+        ISSUE 20; synchronous decode steps, ISSUE 28) need the same
+        treatment."""
+        for m in (_M_TENANT_TOKENS, _M_STORE_REJECTED, _M_DECODE_SYNC):
             for labels in list(m.labels()):
                 d = dict(labels)
                 if d.get("instance") == self._name:
                     m.remove(**d)
 
-    def _store_rejected_by_reason(self):
-        """Per-reason store-rejection counts for THIS instance (ISSUE
-        20) — iterated from live label sets, like the tenant tokens."""
+    def _by_reason(self, metric, unlabeled="none"):
+        """Per-reason counts of a reason-labeled counter for THIS
+        instance — iterated from live label sets, like the tenant
+        tokens."""
         out = {}
-        for labels in _M_STORE_REJECTED.labels():
+        for labels in metric.labels():
             d = dict(labels)
             if d.get("instance") == self._name:
-                out[d.get("reason", "corrupt")] = int(
-                    _M_STORE_REJECTED.value(**d))
+                out[d.get("reason", unlabeled)] = int(metric.value(**d))
         return out
 
     def _tenant_token_counts(self):
@@ -2723,6 +3014,7 @@ class LLMEngine:
         ingest thread."""
         if self._closed:
             return
+        self._drain()
         if self._store_path is not None:
             # persist the warm prefix chains BEFORE teardown frees their
             # blocks; a failed save keeps the previous store intact and

@@ -692,48 +692,81 @@ class Scheduler:
         copy queued on ``pending_cow`` (COW). Returns the evicted
         requests."""
         evicted = []
-        for i, req in enumerate(self.slots):
+        for req in self.slots:
             if req is None:
                 continue
-            # mid-prefill requests already own blocks for prompt+1 tokens
-            # (charged at admission) and take no speculative lookahead
-            lookahead = 0 if req.prefilling else int(
-                extra_for(req) if extra_for is not None else extra)
+            if req.prefilling:
+                # mid-prefill requests already own blocks for prompt+1
+                # tokens (charged at admission), take no speculative
+                # lookahead and write nothing here
+                self._room(req, None, req.num_tokens - 1, evicted)
+                continue
+            lookahead = int(extra_for(req) if extra_for is not None
+                            else extra)
             # the decode step writes ONE token at position len(tokens)-1
             # (plus ``lookahead`` speculative positions), so capacity
             # len(tokens)+lookahead is exactly enough — demanding more
             # would evict needlessly when the pool is full at a boundary
-            while (req.state == RUNNING and req.num_tokens + lookahead
-                    > len(req.blocks) * self.block_size):
+            self._room(req, req.num_cached,
+                       req.num_tokens - 1 + lookahead, evicted)
+        return evicted
+
+    def reserve_ahead(self, rows):
+        """Room for a decode step that is enqueued while the one before it
+        is still in flight (ISSUE 28): ``rows`` is ``[(request, the
+        position it will write)]``, in slot order. ``ensure_decode_room``'s
+        own arithmetic (``_room``), but only out of what the allocator has
+        free: it never evicts, copies or retracts. Returns False at the
+        first row for which that does not do: a block short, or a write
+        into a block another request shares or the prefix index still
+        names. The step is then not dispatched ahead, and the next call's
+        ``ensure_decode_room`` evicts, copies or retracts as ever, with
+        nothing in flight; the blocks the rows before that one took are
+        those it would have given them first."""
+        return all(self._room(req, pos, pos, None) for req, pos in rows)
+
+    def _room(self, req, first, last, evicted):
+        """Blocks for ``req`` to hold position ``last``, and the blocks it
+        writes from ``first`` on (None: it writes none) its own alone.
+        With a list to evict into it takes what that needs: peers evicted
+        on exhaustion (then ``req`` itself), a shared block swapped for a
+        private copy, a published one retracted. With ``evicted=None`` it
+        takes free blocks only and returns False where more is needed."""
+        bs = self.block_size
+        while req.state == RUNNING and last >= len(req.blocks) * bs:
+            if evicted is None and not self.allocator.num_free:
+                return False
+            got = self._grow_one(req, evicted)
+            if got is None:
+                return False
+            req.blocks.append(got)
+            self.version += 1
+        if req.state != RUNNING or first is None:
+            return True
+        # COW guard over the write window: a shared block must never be
+        # mutated in place
+        for bi in range(first // bs, min(last // bs, len(req.blocks) - 1) + 1):
+            b = req.blocks[bi]
+            shared = self.allocator.is_shared(b)
+            if not shared and not (self.prefix_cache is not None
+                                   and self.prefix_cache.registered(b)):
+                continue
+            if evicted is None:
+                return False
+            if shared:
                 got = self._grow_one(req, evicted)
                 if got is None:
-                    break
-                req.blocks.append(got)
+                    return False
+                self.pending_cow.append((b, got))
+                self.allocator.free([b])
+                req.blocks[bi] = got
                 self.version += 1
-            if req.state != RUNNING or req.prefilling:
-                continue
-            # COW guard over the write window [num_cached, num_cached+
-            # lookahead]: a shared block must never be mutated in place
-            first = req.num_cached // self.block_size
-            last = min((req.num_cached + lookahead) // self.block_size,
-                       len(req.blocks) - 1)
-            for bi in range(first, last + 1):
-                b = req.blocks[bi]
-                if self.allocator.is_shared(b):
-                    got = self._grow_one(req, evicted)
-                    if got is None:
-                        break
-                    self.pending_cow.append((b, got))
-                    self.allocator.free([b])
-                    req.blocks[bi] = got
-                    self.version += 1
-                    _M_COW.inc(instance=self.instance)
-                elif (self.prefix_cache is not None
-                        and self.prefix_cache.registered(b)):
-                    # sole holder, but the content is published: the write
-                    # diverges it from its hash — retract the identity
-                    self.prefix_cache.forget(b)
-        return evicted
+                _M_COW.inc(instance=self.instance)
+            else:
+                # sole holder, but the content is published: the write
+                # diverges it from its hash — retract the identity
+                self.prefix_cache.forget(b)
+        return True
 
     def trim_to_capacity(self, req, extra=0):
         """Free tail blocks beyond what ``req.num_tokens + extra`` needs

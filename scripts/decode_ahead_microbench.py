@@ -1,0 +1,115 @@
+"""How often the serving engine runs a decode step ahead (ISSUE 28), and what
+a decode-only step then costs beside the device's own time for it.
+
+Runs one serving cell of ``BENCHMARK.json`` in this process through its own
+runner, as ``benchmarks/run.py`` does (same engine, same closed loop, same
+warm-up, a full batch), and keeps every ``LLMEngine.metrics()`` the runner
+takes: the last two are the measured window's ends. Prints one JSON object:
+the window's tokens/s and decode-only step p50 on the host's clock, the
+engage share ``ahead / (ahead + synchronous)`` of the window's decode steps
+with the synchronous ones by reason and the rows discarded, the window's
+seconds by kind of call, and with
+``--trace 1`` the device's busy time in ``decode_pure`` a traced decode step
+and the device's idle share. Run on the chip:
+
+    python3 scripts/decode_ahead_microbench.py \
+        --workload mimo-v2-flash-serve.mixed-len-decode --seed 7 --trace 1
+"""
+import argparse
+import importlib
+import json
+import os
+import sys
+import time
+
+T_START = time.perf_counter()
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+KEYS = ("decode_steps_ahead", "decode_steps_sync", "decode_rows_discarded",
+        "host_syncs", "tokens_out")
+
+
+def window_counts(snaps):
+    """The counters' change between the last two snapshots, and the
+    synchronous steps of that stretch by reason."""
+    m0, m1 = snaps[-2], snaps[-1]
+    out = {k: m1[k] - m0[k] for k in KEYS}
+    r0, r1 = (m["decode_steps_sync_by_reason"] for m in (m0, m1))
+    out["sync_by_reason"] = {k: v - r0.get(k, 0) for k, v in r1.items()
+                             if v - r0.get(k, 0)}
+    steps = out["decode_steps_ahead"] + out["decode_steps_sync"]
+    out["engage_share"] = out["decode_steps_ahead"] / steps if steps else None
+    return out
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, default=7)
+    ap.add_argument("--seconds", type=float, default=30.0)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    args = ap.parse_args(argv)
+
+    from benchmarks import run as bench
+    from benchmarks.harness import stats, trace_reduce
+    from benchmarks.runners import common
+    from paddle_tpu.inference.serving import LLMEngine
+
+    manifest = bench.load_json("BENCHMARK.json")
+    cell = bench.find_cell(manifest, args.workload)
+    config = bench.load_json("benchmarks", "configs", cell["config"] + ".json")
+    traffic = bench.load_json("benchmarks", "traffic", cell["traffic"] + ".json")
+    common.require_tpu(cell["chips"])
+    common.place_cache()
+
+    snaps = []
+    plain = LLMEngine.metrics
+
+    def kept(self):
+        m = plain(self)
+        snaps.append(m)
+        return m
+
+    LLMEngine.metrics = kept
+    runner = importlib.import_module("benchmarks.runners." + config["kind"])
+    run = runner.run(config, traffic, seed=args.seed, seconds=args.seconds,
+                     trace=bool(args.trace),
+                     out_dir=os.path.join(ROOT, "benchmarks_out",
+                                          args.workload, "trace"),
+                     t_start=T_START, chips=cell["chips"])
+    out = {"workload": args.workload, "seed": args.seed,
+           "correct": bool(run["correct"]),
+           "compiles_in_window": run["compiles_in_window"],
+           "serve_tokens_per_s": run["values"]["serve_tokens_per_s"],
+           "decode_step_ms_p50": stats.percentile(
+               run["series"]["decode_step_ms"], 50),
+           "window": window_counts(snaps),
+           # where the window's seconds went: calls that prefilled
+           # nothing, and calls that ended a prefill (chunk + decode)
+           "account": {k: {"calls": len(v), "seconds": sum(v) / 1e3,
+                           "p50_ms": stats.percentile(v, 50) if v else None}
+                       for k, v in run["series"].items()
+                       if k in ("decode_step_ms", "prefill_step_ms")}}
+    tr = run.get("trace")
+    if tr:
+        decode = trace_reduce.select(tr["events"], None, "decode_pure")
+        steps = sum(1 for s in run["traced_steps"] if s[4])
+        out["traced"] = {
+            "decode_steps": steps,
+            "decode_pure_device_ms_a_step":
+                1e3 * trace_reduce.busy_seconds(decode) / steps,
+            "decode_step_ms_p50": stats.percentile(
+                [(s[1] - s[0]) * 1e3 for s in run["traced_steps"]
+                 if s[4] and not s[3]], 50),
+            "idle_share": 100.0 * (1.0 - tr["busy_s"] / tr["window_s"]),
+            "idle_gaps": tr["breakdown"]["idle_gaps"],
+        }
+        # the benchmark's own per-layer readings of this run
+        out["per_layer"] = {k: v["value"] for k, v in bench.result_line(
+            manifest, args.workload, run, True)["metrics"].items()}
+    print(json.dumps(out), flush=True)
+
+
+if __name__ == "__main__":
+    main()

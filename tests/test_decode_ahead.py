@@ -1,0 +1,837 @@
+"""Decode dispatch-ahead (ISSUE 28): ``LLMEngine`` enqueues decode step
+k+1 behind step k before it fetches step k's tokens, feeding k's greedy
+tokens to k+1 on the device.
+
+The synchronous engine these cases compare with is the SAME class with the
+one decision overridden (``Synchronous._dispatch_ahead``): what is left
+then is the path the engine takes by itself beside a sampled row, under
+pool pressure, on a draft model or a fused window. Every case plays one
+script of submissions and events, call by call, on both, and wants the
+same tokens a request, the same reasons, the same rows of logits."""
+
+import itertools
+import os
+import sys
+import time
+
+import jax
+import numpy as np
+import pytest
+
+import paddle_tpu
+from paddle_tpu.inference.serving import (EngineClosedError, LLMEngine,
+                                          SamplingParams, load_prefix_store)
+from paddle_tpu.models import LlamaForCausalLM, llama_tiny
+from paddle_tpu.models.mimo_v2 import MiMoV2ForCausalLM, mimo_v2_tiny
+from paddle_tpu.observability import metrics
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+from benchmarks.harness import reference_mimo_v2 as ref  # noqa: E402
+
+#: every waiting request is admitted in the call it is found in, so that a
+#: script's events meet the same state on both engines: one prefill a call
+#: (the engine's default) makes requests JOIN beside a step in flight, a
+#: call later than on the synchronous engine, which the cases that are
+#: about joining ask for
+LLAMA = dict(num_blocks=64, block_size=8, max_batch_size=4,
+             max_prefills_per_step=4, ingest_async=False)
+MIMO = dict(num_blocks=96, block_size=4, max_batch_size=4, max_model_len=96,
+            prefill_buckets=[8, 16, 32, 64, 96], max_prefills_per_step=4,
+            ingest_async=False)
+
+
+class Synchronous(LLMEngine):
+    """Never a step ahead: every decode step is dispatched, fetched and
+    emitted inside its own call."""
+
+    def _dispatch_ahead(self, cur):
+        return None
+
+
+@pytest.fixture(autouse=True)
+def _highest():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def llama(seed=7):
+    paddle_tpu.seed(seed)
+    net = LlamaForCausalLM(llama_tiny())
+    net.eval()
+    return net
+
+
+def mimo(seed=3):
+    paddle_tpu.seed(seed)
+    net = MiMoV2ForCausalLM(mimo_v2_tiny())
+    net.eval()
+    return net
+
+
+def prompts_of(lengths, seed=0, vocab=160):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, vocab, size=n).astype(np.int32) for n in lengths]
+
+
+def compiles_counter():
+    """Executables JAX builds from here on, eager operations included."""
+    box = [0]
+
+    def on(event, _secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            box[0] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(on)
+    return box
+
+
+COMPILES = compiles_counter()
+
+
+class Played:
+    """What one engine made of a script."""
+
+    def __init__(self):
+        self.calls = []        # [[(request index, token, finished, reason)]]
+        self.tokens = {}       # request index -> its output tokens
+        self.reasons = {}      # request index -> finish reason
+        self.cached = {}       # request index -> num_cached when it ended
+        self.logits = {}       # (call, request index) -> last_logits then
+        self.metrics = None
+        self.kept = {}         # what the script's events put aside
+
+
+def play(cls, net, engine, requests, events=None):
+    """Run ``requests`` (``[(prompt, SamplingParams fields, call it is
+    handed in before)]``) through ``cls(net, **engine)``. ``events`` maps a
+    call's number to ``fn(eng, rids, played)``, run before that call. The
+    engine is stepped until it has no work and nothing is left to hand in."""
+    events = events or {}
+    out = Played()
+    with cls(net, **engine) as eng:
+        rids, index = {}, {}
+        for call in itertools.count():
+            for k, (prompt, fields, at) in enumerate(requests):
+                if at == call:
+                    rids[k] = eng.add_request(prompt, SamplingParams(**fields))
+                    index[rids[k]] = k
+            if call in events:
+                events[call](eng, rids, out)
+            pending = any(at > call for _, _, at in requests)
+            if not eng.has_work() and not pending and max(events, default=-1) <= call:
+                break
+            got = []
+            for o in eng.step():
+                k = index[o.rid]
+                got.append((k, o.token, o.finished, o.finish_reason))
+                req = eng.request(o.rid)
+                if req.last_logits is not None:
+                    out.logits[(call, k)] = np.array(req.last_logits)
+            out.calls.append(got)
+        out.kept["in_flight_at_end"] = eng._ahead is not None
+        for k, rid in rids.items():
+            req = eng._requests.get(rid)
+            if req is not None:
+                out.tokens[k] = list(req.output_tokens)
+                out.reasons[k] = req.finish_reason()
+                out.cached[k] = req.num_cached
+        out.metrics = eng.metrics()
+        out.kept["free"] = eng.cache.allocator.num_free
+    return out
+
+
+def both(net, engine, requests, events=None):
+    return (play(LLMEngine, net, engine, requests, events),
+            play(Synchronous, net, engine, requests, events))
+
+
+def adds_up(m):
+    """Every emitted decode step of the plain path is counted once, ahead
+    or under the reason it was not."""
+    by = m["decode_steps_sync_by_reason"]
+    assert m["decode_steps_sync"] == sum(by.values())
+    assert m["decode_steps_ahead"] + m["decode_steps_sync"] == m["host_syncs"]
+    assert set(by) <= {"idle", "sampled", "evict", "drain", "path"}
+
+
+def same_requests(a, s):
+    assert a.tokens == s.tokens
+    assert a.reasons == s.reasons
+    assert a.metrics["tokens_out"] == s.metrics["tokens_out"]
+    adds_up(a.metrics)
+    adds_up(s.metrics)
+    assert s.metrics["decode_steps_ahead"] == 0
+    assert s.metrics["decode_rows_discarded"] == 0
+    assert a.kept["free"] == s.kept["free"]            # nothing leaked
+
+
+# --------------------------------------------------------------------------
+# one script, two engines
+# --------------------------------------------------------------------------
+
+def _mixed(net):
+    """Six requests of mixed finish lengths through four slots, one prefill
+    a call: rows finish by length while a step is in flight and requests
+    join beside one."""
+    ps = prompts_of((5, 11, 17, 9, 23, 6))
+    reqs = [(p, dict(max_new_tokens=n), 0)
+            for p, n in zip(ps, (6, 1, 3, 12, 14, 2))]
+    return dict(LLAMA, max_prefills_per_step=1), reqs, None
+
+
+def _at_once(net):
+    """Everything admitted in the first call: no request joins beside a
+    step in flight, so the calls themselves are the same."""
+    ps = prompts_of((5, 11, 7, 9), seed=1)
+    reqs = [(p, dict(max_new_tokens=n), 0)
+            for p, n in zip(ps, (7, 3, 10, 5))]
+    return LLAMA, reqs, None
+
+
+def _eos(net):
+    """EOS comes while the next step is in flight: the row is discarded."""
+    ps = prompts_of((5, 11, 7), seed=2)
+    plain = play(Synchronous, net, LLAMA,
+                 [(p, dict(max_new_tokens=12), 0) for p in ps])
+    reqs = [(p, dict(max_new_tokens=12, eos_token_id=plain.tokens[k][3 + k]), 0)
+            for k, p in enumerate(ps)]
+    return LLAMA, reqs, None
+
+
+def _cancel(net):
+    ps = prompts_of((5, 11, 7), seed=3)
+    reqs = [(p, dict(max_new_tokens=12), 0) for p in ps]
+
+    def cancel(eng, rids, out):
+        out.kept["cancelled_with"] = list(eng.request(rids[1]).output_tokens)
+        assert eng.cancel(rids[1])
+
+    return LLAMA, reqs, {5: cancel}
+
+
+def _deadline(net):
+    ps = prompts_of((5, 11, 7), seed=4)
+    reqs = [(p, dict(max_new_tokens=12), 0) for p in ps]
+
+    def expire(eng, rids, out):
+        eng.request(rids[0]).deadline = time.time() - 1.0
+
+    return LLAMA, reqs, {5: expire}
+
+
+def _evict(net):
+    """A pool too small for three requests to run to their ends: room for
+    the next step takes an eviction, so it is not dispatched ahead."""
+    ps = prompts_of((6, 7, 5), seed=5)
+    reqs = [(p, dict(max_new_tokens=14), 0) for p in ps]
+    return dict(LLAMA, num_blocks=9, block_size=4, max_batch_size=3,
+                max_prefills_per_step=1), reqs, None
+
+
+def _prefix_cow(net):
+    """Shared prefixes, two prompts equal to the last token of a full
+    block: admission shares blocks, a write diverges from one."""
+    rng = np.random.default_rng(6)
+    shared = rng.integers(0, 160, size=16).astype(np.int32)
+    tails = [rng.integers(0, 160, size=n).astype(np.int32) for n in (3, 5, 0, 0)]
+    reqs = [(np.concatenate([shared, t]), dict(max_new_tokens=9), k)
+            for k, t in enumerate(tails)]
+    return dict(LLAMA, enable_prefix_cache=True,
+                max_prefills_per_step=1), reqs, None
+
+
+def _chunked_join(net):
+    """Long prompts prefilled eight tokens a call beside a decoding batch:
+    chunks run between a step in flight and the next, and the request joins
+    with its first token from the host."""
+    ps = prompts_of((5, 7, 30, 21), seed=7)
+    reqs = [(ps[0], dict(max_new_tokens=16), 0),
+            (ps[1], dict(max_new_tokens=14), 0),
+            (ps[2], dict(max_new_tokens=6), 3),
+            (ps[3], dict(max_new_tokens=5), 4)]
+    return dict(LLAMA, max_prefill_tokens_per_step=8,
+                max_prefills_per_step=1), reqs, None
+
+
+def _int8(net):
+    ps = prompts_of((5, 11, 7, 13), seed=8)
+    reqs = [(p, dict(max_new_tokens=n), 0)
+            for p, n in zip(ps, (9, 4, 12, 6))]
+    return dict(LLAMA, kv_dtype="int8"), reqs, None
+
+
+def _tier(net):
+    """Decode pressure preempts a request into the host tier and revives
+    it by page import, both beside steps in flight."""
+    ps = prompts_of((8, 8, 8), seed=9)
+    reqs = [(p, dict(max_new_tokens=20), 0) for p in ps]
+    return dict(LLAMA, num_blocks=5, block_size=8, max_batch_size=2,
+                kv_host_blocks=32, max_prefills_per_step=1), reqs, None
+
+
+def _rings(net):
+    """The second model's window rings turn a page every four tokens and
+    send the page behind them back while the step that read it is in
+    flight; prompts cross the window and the chunk."""
+    ps = prompts_of((5, 21, 38, 12), seed=10)
+    reqs = [(p, dict(max_new_tokens=n), 0)
+            for p, n in zip(ps, (14, 9, 6, 11))]
+    return dict(MIMO, max_prefill_tokens_per_step=16,
+                max_prefills_per_step=1), reqs, None
+
+
+def _rings_cancel(net):
+    ps = prompts_of((14, 21, 9), seed=11)
+    reqs = [(p, dict(max_new_tokens=16), 0) for p in ps]
+    return MIMO, reqs, {7: lambda eng, rids, out: eng.cancel(rids[0])}
+
+
+CASES = {"mixed-finish-lengths": (llama, _mixed),
+         "all-at-once": (llama, _at_once),
+         "eos-in-flight": (llama, _eos),
+         "cancel-in-flight": (llama, _cancel),
+         "deadline-in-flight": (llama, _deadline),
+         "eviction-pressure": (llama, _evict),
+         "prefix-copy-on-write": (llama, _prefix_cow),
+         "chunked-prefill-joins": (llama, _chunked_join),
+         "int8-pools": (llama, _int8),
+         "tier-revival": (llama, _tier),
+         "rings-turn": (mimo, _rings),
+         "rings-turn-cancel": (mimo, _rings_cancel)}
+
+
+#: the cases in which no request joins beside a step in flight (all are
+#: admitted by the first call): there the CALLS are the same, token for token
+ALIGNED = {"all-at-once", "eos-in-flight", "cancel-in-flight",
+           "deadline-in-flight", "int8-pools", "rings-turn-cancel"}
+
+
+@pytest.fixture(scope="module")
+def played():
+    """Each case played once on both engines, whatever asks for it."""
+    done = {}
+
+    def get(case):
+        if case not in done:
+            build, script = CASES[case]
+            net = build()
+            engine, reqs, events = script(net)
+            done[case] = both(net, engine, reqs, events)
+        return done[case]
+
+    return get
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_the_same_tokens_as_the_synchronous_engine(played, case):
+    a, s = played(case)
+    same_requests(a, s)
+    assert a.metrics["decode_steps_ahead"] > 0
+    assert not a.kept["in_flight_at_end"]
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_a_call_returns_its_own_steps_tokens(played, case):
+    """``step()`` call k returns step k's tokens: a token a decoding
+    request a call, never two and never none while it decodes, and a
+    request's first decode step is the one after its prefill's call or,
+    beside a step in flight, the one after that."""
+    a, s = played(case)
+    for run in (a, s):
+        seen = {}
+        for call, outs in enumerate(run.calls):
+            for k, tok, _, _ in outs:
+                if tok >= 0:
+                    seen.setdefault(k, []).append(call)
+        for k, calls in seen.items():
+            gaps = np.diff(calls)
+            # the first token is the prefill's; its call may hold the first
+            # decode too (0), or the decode joins one call on (1): from
+            # then on one a call, but for a preemption's wait
+            late = {"eviction-pressure", "tier-revival"}
+            assert (gaps[1:] == 1).all() or case in late, (case, k, calls)
+            assert len(gaps) == 0 or gaps[0] in (0, 1, 2) or case in late
+    if case in ALIGNED:
+        assert a.calls == s.calls
+        assert a.metrics["host_syncs"] == s.metrics["host_syncs"]
+    # a step ahead can add a call to a request's life, never take tokens
+    assert len(a.calls) >= len(s.calls)
+    assert len(a.calls) - len(s.calls) <= len(a.tokens)
+
+
+def test_what_each_case_is_there_for(played):
+    a, s = played("mixed-finish-lengths")
+    m = a.metrics
+    assert m["decode_steps_sync_by_reason"] == {"idle": 1}
+    assert m["decode_rows_discarded"] == 0      # a finish by length is seen ahead
+    assert m["decode_steps_ahead"] >= m["host_syncs"] - 1
+
+    a, s = played("eos-in-flight")
+    assert set(a.reasons.values()) == {"eos"}
+    assert 1 <= a.metrics["decode_rows_discarded"] <= 3
+    assert a.cached == s.cached                 # num_cached as if it never ran
+
+    a, s = played("cancel-in-flight")
+    assert a.reasons[1] == "cancelled" and a.metrics["decode_rows_discarded"] == 1
+    assert a.tokens[1] == a.kept["cancelled_with"] == s.kept["cancelled_with"]
+
+    a, s = played("deadline-in-flight")
+    assert a.reasons[0] == "timeout" and a.metrics["decode_rows_discarded"] == 1
+    assert a.metrics["deadline_expired"] == 1
+    assert [o for outs in a.calls for o in outs if o[3] == "timeout"] == \
+        [(0, -1, True, "timeout")]
+
+    a, s = played("eviction-pressure")
+    assert a.metrics["evictions"] >= 1 and s.metrics["evictions"] >= 1
+    assert a.metrics["decode_steps_sync_by_reason"].get("evict", 0) >= 1
+
+    a, s = played("prefix-copy-on-write")
+    assert a.metrics["prefix_blocks_reused"] >= 4
+    assert a.metrics["prefix_blocks_reused"] == s.metrics["prefix_blocks_reused"]
+
+    a, s = played("chunked-prefill-joins")
+    assert a.metrics["prefill_chunks"] == s.metrics["prefill_chunks"] > 6
+
+    a, s = played("tier-revival")
+    assert a.metrics["kv_spills"] >= 1 and a.metrics["kv_revives"] >= 1
+    assert a.metrics["decode_steps_sync_by_reason"].get("evict", 0) >= 1
+
+    a, s = played("rings-turn")
+    assert a.metrics["window_blocks_released"] > 0
+    assert a.metrics["window_blocks_released"] == s.metrics["window_blocks_released"]
+    assert a.metrics["global_blocks_in_use"] == a.metrics["window_blocks_in_use"] == 0
+
+    a, s = played("rings-turn-cancel")
+    assert a.reasons[0] == "cancelled" and a.metrics["decode_rows_discarded"] == 1
+    assert a.metrics["window_blocks_in_use"] == 0
+
+
+def test_the_second_models_tokens_are_the_float32_references():
+    """Against code that shares nothing with the engine: each token the
+    engine chose with steps in flight is the argmax of the reference's row
+    for the tokens before it."""
+    net = mimo()
+    _, reqs, _ = _rings(net)
+    a = play(LLMEngine, net, MIMO, reqs)
+    w = {n: p._data for n, p in net.named_parameters()}
+    import dataclasses
+    model = dataclasses.asdict(net.config)
+    for k, (p, _, _) in enumerate(reqs):
+        t = a.tokens[k]
+        want = np.asarray(ref.logits(
+            w, np.concatenate([p, t])[None].astype(np.int32), model,
+            experts_held=net.config.experts_held))[0]
+        assert [int(want[len(p) - 1 + j].argmax()) for j in range(len(t))] == t
+
+
+def test_greedy_rows_beside_a_sampled_row_take_the_synchronous_path():
+    """The condition itself: with a ``do_sample`` request in the batch
+    nothing is dispatched ahead, and the greedy requests' tokens are what
+    they are without it."""
+    net = llama()
+    ps = prompts_of((5, 11, 7), seed=12)
+    reqs = [(p, dict(max_new_tokens=10), 0) for p in ps]
+    alone = play(LLMEngine, net, LLAMA, reqs)
+    beside = play(LLMEngine, net, LLAMA, reqs + [
+        (ps[0], dict(max_new_tokens=30, do_sample=True, temperature=1.2,
+                     seed=5), 0)])
+    assert {k: beside.tokens[k] for k in alone.tokens} == alone.tokens
+    m = beside.metrics
+    adds_up(m)
+    assert m["decode_steps_sync_by_reason"]["sampled"] >= 25
+    assert m["decode_steps_ahead"] <= 3     # before the sampled row was ready
+    assert alone.metrics["decode_steps_sync_by_reason"] == {"idle": 1}
+
+
+@pytest.mark.parametrize("path", ["speculative", "window", "prefill-only"])
+def test_the_other_decode_paths_are_not_touched(path):
+    net = llama()
+    kw = {"speculative": dict(draft_model=net, spec_tokens=2),
+          "window": dict(decode_steps_per_sync=4),
+          "prefill-only": dict(prefill_only=True)}[path]
+    ps = prompts_of((5, 11), seed=13)
+    with LLMEngine(net, **LLAMA, **kw) as eng:
+        rids = [eng.add_request(p, SamplingParams(max_new_tokens=9)) for p in ps]
+        for _ in range(4):
+            eng.step()
+            assert eng._ahead is None
+        m = eng.metrics()
+        assert m["decode_steps_ahead"] == 0
+        if path == "prefill-only":
+            assert m["decode_steps_sync"] == 0
+            assert all(len(eng.request(r).output_tokens) == 1 for r in rids)
+        else:
+            assert m["decode_steps_sync_by_reason"] == {
+                "path": m["decode_steps_sync"]}
+            assert m["decode_steps_sync"] >= 2
+
+
+# --------------------------------------------------------------------------
+# rows of logits
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("capture", ["on", "off", "flipped"])
+@pytest.mark.parametrize("build", [llama, mimo], ids=["llama", "mimo"])
+def test_last_logits_are_the_synchronous_engines_bit_for_bit(build, capture):
+    """``capture_logits`` rides along: the rows are fetched with the step's
+    tokens after the next dispatch. Whatever the setting, and flipped
+    between calls, every token and every kept row is the synchronous
+    engine's; a call's row belongs to the token that call returned."""
+    net = build()
+    engine = MIMO if build is mimo else LLAMA
+    ps = prompts_of((5, 13, 22), seed=14)
+    reqs = [(p, dict(max_new_tokens=n), 0) for p, n in zip(ps, (9, 12, 7))]
+    events = None
+    if capture == "flipped":
+        def flip(eng, rids, out):
+            eng.capture_logits = not eng.capture_logits
+        events = {c: flip for c in (2, 3, 6, 9)}
+    engine = dict(engine, capture_logits=capture != "off")
+    a, s = both(net, engine, reqs, events)
+    same_requests(a, s)
+    assert a.calls == s.calls
+    assert a.logits.keys() == s.logits.keys()
+    for key in a.logits:
+        assert np.array_equal(a.logits[key], s.logits[key]), key
+    assert (len(a.logits) == 0) == (capture == "off")
+    if capture == "on":
+        for (call, k), row in a.logits.items():
+            tok = [t for kk, t, _, _ in a.calls[call] if kk == k][-1]
+            assert int(row.argmax()) == tok
+        v = row.shape[0]
+        per_step = engine["max_batch_size"] * (v + 1) * 4
+        assert a.metrics["decode_fetch_bytes"] == a.metrics["host_syncs"] * per_step
+    if capture == "off":
+        assert a.metrics["decode_fetch_bytes"] == \
+            a.metrics["host_syncs"] * engine["max_batch_size"] * 4
+
+
+# --------------------------------------------------------------------------
+# readers and writers of the pools beside a step in flight: enqueued behind
+# it, so it stays in flight; only a reload of the weights drops it
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
+def test_export_right_after_an_ahead_dispatch(kv_dtype):
+    """``export_kv_pages`` with a step in flight: the pages and their
+    ``covered`` are the synchronous engine's at the same call (what the
+    step in flight wrote lies past them), the step stays in flight and
+    becomes the next call's decode, the tokens go on the same."""
+    net = llama()
+    engine = dict(LLAMA, kv_dtype=kv_dtype)
+    ps = prompts_of((5, 11), seed=15)
+    reqs = [(p, dict(max_new_tokens=12), 0) for p in ps]
+
+    def export(eng, rids, out):
+        out.kept["was_in_flight"] = ahead = eng._ahead
+        out.kept["pages"] = eng.export_kv_pages(rids[1])
+        assert eng._ahead is ahead
+
+    a, s = both(net, engine, reqs, {5: export})
+    same_requests(a, s)
+    assert a.calls == s.calls
+    assert a.kept["was_in_flight"] is not None and s.kept["was_in_flight"] is None
+    pa, ps_ = a.kept["pages"], s.kept["pages"]
+    assert pa["covered"] == ps_["covered"]
+    n = pa["covered"]
+    for name in ("k", "v") + (("k_scale", "v_scale") if kv_dtype else ()):
+        flat_a = pa[name].reshape(pa[name].shape[0], -1, *pa[name].shape[3:])
+        flat_s = ps_[name].reshape(flat_a.shape)
+        assert np.array_equal(flat_a[:, :n], flat_s[:, :n]), name
+    assert a.metrics["decode_steps_sync_by_reason"] == {"idle": 1}
+    assert a.metrics["decode_rows_discarded"] == 0
+
+
+def test_import_beside_a_step_in_flight():
+    """The decode side of a handoff: pages come in while the engine has a
+    step in flight for the requests it already runs."""
+    net = llama()
+    ps = prompts_of((5, 11, 9), seed=16)
+    with LLMEngine(net, prefill_only=True, **LLAMA) as pre:
+        rid = pre.add_request(ps[2], SamplingParams(max_new_tokens=8))
+        first, = pre.step()
+        pages = pre.export_kv_pages(rid)
+    handed = np.concatenate([ps[2], [first.token]]).astype(np.int32)
+    reqs = [(p, dict(max_new_tokens=14), 0) for p in ps[:2]]
+
+    def admit(eng, rids, out):
+        out.kept["was_in_flight"] = eng._ahead is not None
+        rids[2] = eng.add_request_with_pages(
+            handed, pages, SamplingParams(max_new_tokens=7))
+
+    def played_with(cls):
+        out = Played()
+        with cls(net, **LLAMA) as eng:
+            rids = {k: eng.add_request(p, SamplingParams(**f))
+                    for k, (p, f, _) in enumerate(reqs)}
+            for call in itertools.count():
+                if call == 4:
+                    admit(eng, rids, out)
+                if not eng.has_work():
+                    break
+                eng.step()
+            out.tokens = {k: list(eng.request(r).output_tokens)
+                          for k, r in rids.items()}
+            out.metrics = eng.metrics()
+        return out
+
+    a, s = played_with(LLMEngine), played_with(Synchronous)
+    assert a.tokens == s.tokens
+    colocated = play(Synchronous, net, LLAMA, [(ps[2], dict(max_new_tokens=8), 0)])
+    assert [first.token] + a.tokens[2] == colocated.tokens[0]
+    # the import is enqueued behind the step in flight, which stays: no
+    # step is made twice and no call goes without its decode
+    assert a.kept["was_in_flight"]
+    assert a.metrics["decode_steps_sync_by_reason"] == {"idle": 1}
+    assert a.metrics["decode_rows_discarded"] == 0
+    assert a.metrics["host_syncs"] == s.metrics["host_syncs"]
+    adds_up(a.metrics)
+
+
+def test_a_reload_of_the_weights_drops_the_step_in_flight(tmp_path):
+    """The step in flight ran on the old weights: after ``reload_weights``
+    the next token is the new weights', as on the synchronous engine."""
+    from paddle_tpu.inference.serving import save_llama_artifact
+
+    path = str(tmp_path / "other")
+    save_llama_artifact(llama(seed=8), path)
+    ps = prompts_of((5, 11), seed=17)
+    reqs = [(p, dict(max_new_tokens=12), 0) for p in ps]
+    runs = []
+    for cls in (LLMEngine, Synchronous):
+        runs.append(play(cls, llama(), LLAMA, reqs, {
+            5: lambda eng, rids, out: eng.reload_weights(path)}))
+    a, s = runs
+    same_requests(a, s)
+    unswapped = play(Synchronous, llama(), LLAMA, reqs)
+    assert a.tokens != unswapped.tokens
+    assert a.metrics["decode_steps_sync_by_reason"] == {"idle": 1, "drain": 1}
+
+
+def test_a_store_save_beside_a_step_in_flight(tmp_path):
+    """The chains a save exports are full blocks the index names; a step
+    made ahead writes into none of those, so it stays in flight."""
+    net = llama()
+    engine = dict(LLAMA, enable_prefix_cache=True, kv_host_blocks=32)
+    ps = prompts_of((24, 17), seed=18)
+    reqs = [(p, dict(max_new_tokens=10), 0) for p in ps]
+
+    def save(eng, rids, out):
+        out.kept["was_in_flight"] = ahead = eng._ahead
+        out.kept["saved"] = eng.save_prefix_store()
+        assert eng._ahead is ahead
+        out.kept["store"] = load_prefix_store(
+            eng._store_path, fingerprint=eng._store_fingerprint,
+            geometry=eng._store_geometry, instance=eng._name)
+
+    # a store each: the second engine would boot from the first one's
+    a, s = (play(cls, net, dict(engine, prefix_store_path=str(tmp_path / name)),
+                 reqs, {4: save})
+            for cls, name in ((LLMEngine, "ahead"), (Synchronous, "sync")))
+    same_requests(a, s)
+    assert a.kept["was_in_flight"] is not None and s.kept["was_in_flight"] is None
+    assert a.kept["saved"] == s.kept["saved"] >= 3
+    sa, ss = dict(a.kept["store"]), dict(s.kept["store"])
+    assert sa.keys() == ss.keys()
+    for h in sa:
+        for name in ("k", "v"):
+            assert np.array_equal(sa[h][name], ss[h][name]), (h, name)
+    assert a.metrics["decode_steps_sync_by_reason"] == {"idle": 1}
+    assert a.metrics["decode_rows_discarded"] == 0
+
+
+# --------------------------------------------------------------------------
+# counts, compiles, teardown
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("build", [llama, mimo], ids=["llama", "mimo"])
+def test_nothing_compiles_after_the_warm_up(build):
+    """One decode executable serves a step made now and a step made
+    ahead, joined rows and fed rows: a second burst of the same shapes
+    builds nothing, JAX's eager operations included."""
+    net = build()
+    engine = MIMO if build is mimo else LLAMA
+    ps = prompts_of((5, 13, 22, 9, 30), seed=19)
+    with LLMEngine(net, **engine) as eng:
+        def burst():
+            rids = [eng.add_request(p, SamplingParams(max_new_tokens=n))
+                    for p, n in zip(ps, (6, 9, 4, 11, 3))]
+            while eng.has_work():
+                eng.step()
+            for r in rids:
+                eng.release(r)
+
+        burst()
+        before = COMPILES[0]
+        stats0 = dict(paddle_tpu.jit.cache_stats()[eng._decode_name])
+        burst()
+        assert COMPILES[0] == before
+        stats1 = paddle_tpu.jit.cache_stats()[eng._decode_name]
+        assert stats1["compiles"] == stats0["compiles"] == 1
+        m = eng.metrics()
+        assert m["decode_steps_sync_by_reason"] == {"idle": 2}
+        assert m["decode_steps_ahead"] > 12
+
+
+@pytest.mark.parametrize("kind", ["llama", "llama-int8", "llama-tp2", "mimo"])
+def test_the_decode_executable_lowers_from_the_engines_own_operands(kind):
+    """``chip_smoke.py`` reads the decode executable's compiled text; the
+    engine names that executable's operands itself
+    (``decode_abstract_args``, built by the function that builds a step's),
+    so the smoke follows a change of them. The smoke's own function builds
+    its text from them here, under a plan too."""
+    import chip_smoke
+    from paddle_tpu.distributed.plan import Plan
+
+    net = mimo() if kind == "mimo" else llama()
+    engine = dict(MIMO if kind == "mimo" else LLAMA)
+    if kind == "llama-int8":
+        engine["kv_dtype"] = "int8"
+    if kind == "llama-tp2":
+        engine["plan"] = Plan.build({"tp": 2}, ["tp"])
+    ps = prompts_of((5, 11), seed=22)
+    with LLMEngine(net, **engine) as eng:
+        for p in ps:
+            eng.add_request(p, SamplingParams(max_new_tokens=6))
+        while eng.has_work():
+            eng.step()
+        assert eng.metrics()["decode_steps_ahead"] > 0
+        args = eng.decode_abstract_args()
+        B = eng.max_batch_size
+        assert args[1].shape == (B, 2) and args[2].shape == (B,)
+        assert args[8].shape == (B,) and args[8].dtype == np.int32
+        outs = eng._decode_jit.lower(*args).out_info
+        assert outs[0].shape == (B, net.config.vocab_size)
+        assert outs[1].shape == (B,)
+        if kind != "mimo":      # the smoke serves Llama
+            text = chip_smoke.executable_text(eng)
+            assert "ENTRY" in text
+            assert "ENTRY" in chip_smoke.executable_text(
+                eng, eng.prefill_buckets[0])
+
+
+def test_a_sharded_engine_feeds_its_tokens_back_in_one_layout():
+    """Under a tp plan the greedy tokens are pinned replicated and the
+    zeros that stand in for them are put the same way: the step made now
+    and the step made ahead are one executable there too."""
+    from paddle_tpu.distributed.plan import Plan
+
+    net = llama(seed=5)
+    plan = Plan.build({"tp": 2}, ["tp"])
+    ps = prompts_of((8, 5), seed=20)
+    with LLMEngine(net, num_blocks=16, block_size=8, max_batch_size=2,
+                   max_model_len=64, ingest_async=False, plan=plan) as eng:
+        rids = [eng.add_request(p, SamplingParams(max_new_tokens=10)) for p in ps]
+        eng.step()
+        eng.step()                      # a step made now, two made ahead
+        before = COMPILES[0]
+        while eng.has_work():
+            eng.step()
+        assert COMPILES[0] == before
+        got = [list(eng.request(r).output_tokens) for r in rids]
+        assert eng.metrics()["decode_steps_ahead"] >= 8
+    plain = play(Synchronous, llama(seed=5), dict(
+        num_blocks=16, block_size=8, max_batch_size=2, max_model_len=64,
+        ingest_async=False), [(p, dict(max_new_tokens=10), 0) for p in ps])
+    assert got == [plain.tokens[0], plain.tokens[1]]
+
+
+def test_has_work_release_and_close_with_a_step_in_flight():
+    net = llama()
+    ps = prompts_of((5, 11), seed=21)
+    eng = LLMEngine(net, **LLAMA)
+    rids = [eng.add_request(p, SamplingParams(max_new_tokens=20)) for p in ps]
+    for _ in range(3):
+        eng.step()
+    assert eng._ahead is not None and eng.has_work()
+    free = eng.cache.allocator.num_free
+    # a request that ends while its row is in flight can be released
+    assert eng.cancel(rids[0])
+    eng.release(rids[0])
+    outs = eng.step()
+    assert [o.rid for o in outs] == [rids[1]]
+    assert eng.cache.allocator.num_free > free
+    # nothing left to run: the step in flight is forgotten, not waited for
+    assert eng.cancel(rids[1])
+    assert eng._ahead is not None and not eng.has_work()
+    assert eng.step() == [] and eng._ahead is None
+    m = eng.metrics()
+    assert m["decode_rows_discarded"] == 2
+    adds_up(m)
+    # and a close with one in flight leaves nothing behind
+    eng.add_request(ps[0], SamplingParams(max_new_tokens=20))
+    eng.step()
+    eng.step()
+    assert eng._ahead is not None
+    name = eng._name
+    eng.close()
+    assert eng._ahead is None and not eng.has_work()
+    assert eng.cache.allocator.num_free == eng.cache.num_blocks - 1
+    with pytest.raises(EngineClosedError):
+        eng.step()
+    for counter in ("serving_decode_steps_ahead_total",
+                    "serving_decode_steps_sync_total",
+                    "serving_decode_rows_discarded_total"):
+        assert all(dict(labels).get("instance") != name
+                   for labels in metrics.REGISTRY.get(counter).labels())
+
+
+def test_the_counters_are_registry_series_and_reset_with_the_others():
+    net = llama()
+    ps = prompts_of((5, 11), seed=22)
+    with LLMEngine(net, **LLAMA) as eng:
+        eng.generate(ps, SamplingParams(max_new_tokens=6))
+        m = eng.metrics()
+        inst = eng._name
+        assert metrics.REGISTRY.get("serving_decode_steps_ahead_total").value(
+            instance=inst) == m["decode_steps_ahead"] > 0
+        assert metrics.REGISTRY.get("serving_decode_steps_sync_total").value(
+            instance=inst, reason="idle") == 1
+        assert metrics.REGISTRY.get("serving_decode_rows_discarded_total").value(
+            instance=inst) == 0
+        assert "serving_decode_steps_sync_total" in metrics.to_prometheus_text()
+        eng.reset_metrics()
+        m = eng.metrics()
+        assert m["decode_steps_ahead"] == m["decode_steps_sync"] == 0
+        assert m["decode_steps_sync_by_reason"] == {}
+
+
+def test_reserve_ahead_never_evicts_and_never_copies():
+    """The scheduler's part: room for the step after the one in flight
+    only out of free blocks; nobody evicted, nothing copied or retracted
+    where that does not do."""
+    from paddle_tpu.inference.serving import (BlockAllocator, PrefixCache,
+                                              Request, Scheduler)
+
+    alloc = BlockAllocator(6)                       # five usable blocks
+    pc = PrefixCache(alloc, 4)
+    sched = Scheduler(alloc, 4, 2, max_prefills_per_step=2, prefix_cache=pc)
+    a = Request(np.arange(1, 8, dtype=np.int32), SamplingParams(max_new_tokens=9))
+    b = Request(np.arange(1, 8, dtype=np.int32), SamplingParams(max_new_tokens=9))
+    sched.waiting.extend([a, b])
+    assert len(sched.pick_prefills()) == 2          # two blocks each
+    for r in (a, b):
+        r.prefilling, r.num_cached = False, 7
+    version = sched.version
+    # inside what they hold: nothing to take
+    assert sched.reserve_ahead([(a, 7), (b, 7)]) and sched.version == version
+    # one free block and two rows that need one: refused at the second;
+    # the first keeps what it took, the block the next call's
+    # ``ensure_decode_room`` would give it first
+    assert not sched.reserve_ahead([(a, 8), (b, 8)])
+    assert (len(a.blocks), len(b.blocks), alloc.num_free) == (3, 2, 0)
+    assert sched.reserve_ahead([(a, 8)]) and len(a.blocks) == 3
+    # none left: refused, nothing moved, nobody evicted
+    before = (list(a.blocks), list(b.blocks), sched.version)
+    assert not sched.reserve_ahead([(a, 9), (b, 8)])
+    assert (a.blocks, b.blocks, sched.version) == before
+    assert a.state == b.state == "running" and not sched.waiting
+    # a write into a block the prefix index still names, or one that is
+    # shared, is the synchronous path's to retract or copy
+    pc.register(a.tokens, a.blocks, 4)
+    assert pc.registered(a.blocks[0])
+    assert not sched.reserve_ahead([(a, 2)])
+    assert pc.registered(a.blocks[0])
+    alloc.acquire([b.blocks[1]])
+    assert not sched.reserve_ahead([(b, 7)])

@@ -83,12 +83,18 @@ class TestInGraphSampling:
             np.testing.assert_array_equal(a, b)
             np.testing.assert_array_equal(a, c)
         # ISSUE 18 satellite: per-sync decode fetch drops from B*V*4
-        # logits bytes to B*4 token bytes
+        # logits bytes to B*4 token bytes; the kept rows come beside the
+        # tokens, which are the graph's argmax either way (ISSUE 28)
         B, V = 4, cfg.vocab_size
         assert mref["host_syncs"] > 0
-        assert mref["decode_fetch_bytes"] == mref["host_syncs"] * B * V * 4
+        assert mref["decode_fetch_bytes"] == \
+            mref["host_syncs"] * B * (V + 1) * 4
+        assert mdflt["host_syncs"] == mref["host_syncs"]
+        # the per-step path runs a step ahead, so a request whose prefill
+        # ends beside a step in flight joins the step after it (ISSUE 28):
+        # the same tokens, and here one more step than the window path's
+        assert mdflt["host_syncs"] == ming["host_syncs"] + 1
         for m in (mdflt, ming):
-            assert m["host_syncs"] == mref["host_syncs"]
             assert m["decode_fetch_bytes"] == m["host_syncs"] * B * 4
 
     def test_rows_are_fetched_only_while_something_reads_them(self, model):
@@ -116,11 +122,15 @@ class TestInGraphSampling:
             assert stretch(2) == B * 4
             other = eng.add_request(q, SamplingParams(
                 max_new_tokens=3, do_sample=True, temperature=1.1, seed=5))
+            # the step in flight was made before the sampled request came
+            # (ISSUE 28): this call prefills it beside that greedy step
+            assert stretch(1) == B * 4
+            assert len(eng.request(other).output_tokens) == 1
             while not eng.request(other).finished:
                 assert stretch(1) == B * V * 4
             eng.release(other)
             eng.capture_logits = True
-            assert stretch(1) == B * V * 4
+            assert stretch(1) == B * (V + 1) * 4
             assert eng.request(rid).last_logits.shape == (V,)
             eng.capture_logits = False
             assert stretch(1) == B * 4

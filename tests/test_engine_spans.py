@@ -1,5 +1,7 @@
 """The engine's step phases as spans (ISSUE 25): the same names on every
-decode path, nothing recorded and nothing built with tracing off, the spans
+decode path (a steady call of the per-step path holds the next step's
+prepare and dispatch and this step's fetch and emit: ISSUE 28), nothing
+recorded and nothing built with tracing off, the spans
 in a real ``jax.profiler`` session on the CPU, the Pallas kernels' names in
 the lowered text, and ``add_request(arrival_t=)`` as the due time."""
 
@@ -20,6 +22,12 @@ from paddle_tpu.observability.trace import TRACER
 
 DECODE_PHASES = ["engine.decode.prepare", "engine.decode.dispatch",
                  "engine.decode.fetch", "engine.decode.emit"]
+#: what a call of the per-step path may hold (ISSUE 28): this step made
+#: now and the next one ahead of its fetch (the first call after a break);
+#: the next one alone, this one being in flight (every steady call); no
+#: dispatch at all, this one being in flight and the last
+PER_STEP = [DECODE_PHASES[:2] + DECODE_PHASES, DECODE_PHASES,
+            DECODE_PHASES[:1] + DECODE_PHASES[2:]]
 PATHS = {
     "per-step": {},
     "window": {"decode_steps_per_sync": 4},
@@ -88,7 +96,7 @@ def test_every_step_is_cut_into_the_same_phases(model, tracer, path):
             n_steps += 1
     steps = _steps(tracer.events())
     assert len(steps) == n_steps > 3
-    prefills = 0
+    prefills, shapes = 0, []
     for k, (step, inside) in enumerate(steps, start=1):
         names = [e["name"] for e in inside]
         assert step["args"] == {"engine": name, "step": k}
@@ -102,12 +110,19 @@ def test_every_step_is_cut_into_the_same_phases(model, tracer, path):
         if path == "speculative":
             assert decode == ["engine.decode.prepare", "engine.decode.draft",
                               *DECODE_PHASES]
+        elif path == "per-step":
+            assert decode in PER_STEP
+            shapes.append(PER_STEP.index(decode))
         else:
             assert decode == DECODE_PHASES
         prefills += names.count("engine.prefill")
         assert set(names) <= {"engine.admit", "engine.prefill",
                               "engine.bookkeeping", "engine.decode.draft",
                               *DECODE_PHASES}
+    if path == "per-step":
+        # two dispatches in the first call, one in every call after it
+        # until the last, which only fetches what the one before it made
+        assert shapes == [0] + [1] * (n_steps - 2) + [2]
     assert prefills == 3   # one chunk a prompt, in the steps that admit them
     assert "engine.prefill" in [e["name"] for e in steps[0][1]]
     assert "engine.prefill" not in [e["name"] for e in steps[-1][1]]
@@ -138,11 +153,45 @@ def test_trace_report_tables_the_phases_of_an_export(model, tracer, tmp_path):
     spec.loader.exec_module(report)
     doc = json.load(open(path))
     agg = report.aggregate_spans(doc["traceEvents"])
+    # every step was dispatched once and fetched once; the first call
+    # prepared two (ISSUE 28)
     for name in ("engine.step", "engine.admit", "engine.bookkeeping",
-                 *DECODE_PHASES):
+                 *DECODE_PHASES[1:]):
         assert agg[name]["count"] == n_steps, name
+    assert agg["engine.decode.prepare"]["count"] == n_steps + 1
     assert agg["engine.prefill"]["count"] == 3
     assert "engine.decode.fetch" in report.build_report(trace_doc=doc)
+
+
+def test_the_names_the_benchmark_reads_are_the_engines(model, tracer):
+    """``benchmarks/harness/program_spans.py`` finds the phases by name:
+    its constants are the names a steady call records, each once, and the
+    phases tile the call's ``engine.step``."""
+    from benchmarks.harness import program_spans as ps
+
+    with _engine(model) as eng:
+        _submit(eng, lengths=(5,), new=8)
+        for _ in range(3):
+            eng.step()
+        tracer.clear()
+        eng.step()
+        assert eng.metrics()["decode_steps_ahead"] == 3
+    (step, inside), = _steps(tracer.events())
+    names = [e["name"] for e in inside]
+    assert step["name"] == ps.STEP
+    assert names == ["engine.admit", *DECODE_PHASES, "engine.bookkeeping"]
+    assert set(ps.PREPARE) | {ps.FETCH} <= set(names)
+    assert ps.PREFILL == "engine.prefill" and ps._PREFIX == "engine."
+    assert [n for n in names if n not in ps.PREPARE and n != ps.FETCH] == [
+        "engine.decode.dispatch", "engine.decode.emit"]
+    # the phases tile the step: what lies between two of them, and around
+    # them inside the step, is a few calls of the tracer
+    covered = sum(e["dur"] for e in inside)
+    assert 0 <= step["dur"] - covered < max(0.2 * step["dur"], 200.0)
+    parsed = ps.steps_of([(e["name"], e["ts"] * 1e-6, e["dur"] * 1e-6)
+                          for e in tracer.events()])
+    assert len(ps.decode_only(parsed)) == 1
+    assert ps.span_ms(parsed[0], ps.PREPARE) > 0
 
 
 def test_a_tick_without_work_is_no_step(model, tracer):

@@ -326,8 +326,8 @@ def test_a_requests_logits_are_the_same_alone_and_in_a_full_batch():
 
 def test_a_greedy_step_fetches_its_tokens_and_they_are_the_rows_argmax():
     """The default engine fetches ``[B]`` tokens a decode step, the decode
-    graph's own argmax; with ``capture_logits`` it fetches the rows and the
-    host chooses. Both give the same tokens, through releases and chunks."""
+    graph's own argmax; with ``capture_logits`` it fetches the rows beside
+    them. Both give the same tokens, through releases and chunks."""
     net = build()
     prompts = prompts_of((21, 9, 38, 14), seed=11)
     got = {}
@@ -335,7 +335,7 @@ def test_a_greedy_step_fetches_its_tokens_and_they_are_the_rows_argmax():
         with LLMEngine(net, capture_logits=rows, **ENGINE) as eng:
             got[rows] = eng.generate(prompts, SamplingParams(max_new_tokens=7))
             m = eng.metrics()
-        width = 160 if rows else 1
+        width = 160 + 1 if rows else 1
         assert m["decode_fetch_bytes"] == \
             m["host_syncs"] * ENGINE["max_batch_size"] * width * 4
     for a, b in zip(got[False], got[True]):

@@ -611,18 +611,39 @@ class TestEngineObservability:
             eng.reset_block_high_water()
             assert eng.cache.allocator.high_water == 3
 
-    def test_eviction_counter_engine_owned(self):
+    @pytest.mark.parametrize("prompt_len", [5, 7])
+    def test_eviction_counter_engine_owned(self, prompt_len):
         """The bench reads evictions from the registry (engine-owned),
         not from scheduler privates — force one eviction and see it in
-        both metrics() and the serving_evictions_total series."""
+        both metrics() and the serving_evictions_total series.
+
+        5-token prompts: both requests are admitted (two blocks each)
+        before either needs a third, so one is evicted. 7-token prompts:
+        the first call admits one request only (max_prefills_per_step),
+        and the step dispatched ahead takes the free block for its third
+        page a call before the second admission could have it (ISSUE 28):
+        the second request now waits, queued on exhaustion, where it used
+        to be admitted and evicted again. The tokens are the roomy
+        engine's either way."""
         from paddle_tpu.inference.serving import SamplingParams
 
+        prompts = [np.arange(1, prompt_len + 1)] * 2
+        sp = SamplingParams(max_new_tokens=8)
+        with _tiny_engine(block_size=4, max_batch_size=2) as eng:
+            want = eng.generate(prompts, sp)
         with _tiny_engine(num_blocks=5, block_size=4,
                           max_batch_size=2) as eng:
-            eng.generate([np.arange(1, 8), np.arange(1, 8)],
-                         SamplingParams(max_new_tokens=8))
+            got = eng.generate(prompts, sp)
+            for a, b in zip(got, want):
+                np.testing.assert_array_equal(a, b)
             em = eng.metrics()
-            assert em["evictions"] >= 1
+            if prompt_len == 5:
+                assert em["evictions"] >= 1
+                assert em["decode_steps_sync_by_reason"].get("evict") >= 1
+            else:
+                assert em["evictions"] == 0 and em["prefills"] == 2
+                assert em["queued_on_exhaustion"] >= 1
+                assert em["decode_steps_sync_by_reason"] == {"idle": 2}
             assert metrics.REGISTRY.get("serving_evictions_total").value(
                 instance=eng._name) == em["evictions"]
             assert em["queued_on_exhaustion"] == \
