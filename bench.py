@@ -736,46 +736,6 @@ def bench_serving(on_tpu):
                          "concurrent-capacity acceptance, held exactly "
                          "by the regression tripwire",
     })
-    # device-resident decode A/B (ISSUE 18): per-step host sampling vs
-    # in-graph greedy sampling vs fused k-step decode windows on a
-    # decode-bound mix — the tracked line is the window arm's tokens/s;
-    # bit-exactness across all three arms and zero window-graph compiles
-    # inside the timed window are asserted (a decode win that changes
-    # tokens or recompiles is a broken win)
-    ds = bsv.run_decode_sync_ab(tiny=not on_tpu, repeat=2)
-    assert ds["bit_exact"], \
-        "in-graph/window arms diverged from per-step host-sampling greedy"
-    assert ds["window"]["decode_compiles_in_window"] == 0, \
-        "window graph recompiled inside the timed window"
-    _emit({
-        "metric": "serving_decode_sync_tokens_per_sec" if on_tpu
-                  else "serving_cpu_decode_sync_tokens_per_sec",
-        "value": ds["window"]["tokens_per_sec"], "unit": "tokens/s",
-        "vs_baseline": None,
-        "tokens_per_sec_host_sampling":
-            ds["host_sampling"]["tokens_per_sec"],
-        "tokens_per_sec_in_graph": ds["in_graph"]["tokens_per_sec"],
-        "decode_sync_speedup": ds["speedup"],
-        "in_graph_speedup": ds["in_graph_speedup"],
-        "sync_reduction": ds["sync_reduction"],
-        "window_k": ds["window_k"],
-        "host_syncs_per_token_host_sampling":
-            ds["host_sampling"]["host_syncs_per_token"],
-        "host_syncs_per_token_window":
-            ds["window"]["host_syncs_per_token"],
-        "fetch_bytes_per_token_host_sampling":
-            ds["host_sampling"]["fetch_bytes_per_token"],
-        "fetch_bytes_per_token_window":
-            ds["window"]["fetch_bytes_per_token"],
-        "bit_exact": ds["bit_exact"],
-        "num_requests": ds["num_requests"],
-        "baseline_note": "one seeded decode-bound stream through "
-                         "per-step host sampling vs in-graph sampling "
-                         "vs fused k-step decode windows; greedy "
-                         "outputs bit-exact across arms; host syncs "
-                         "and fetch bytes from the engine's own "
-                         "counters",
-    })
     # KV-tiering A/B (ISSUE 16): one seeded multi-session stream whose
     # prefix working set exceeds the device pool, replayed through a
     # never-evicted reference, a recompute-eviction arm (tier off) and a

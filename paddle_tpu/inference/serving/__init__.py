@@ -4,14 +4,13 @@ A real serving path for the flagship llama models: block-allocated paged
 KV cache (``kv_cache``), a ragged paged-attention decode kernel with a
 pure-lax CPU fallback (``paged_attention`` + ``ops/pallas``), a
 continuous-batching scheduler with prefill/decode split (``scheduler``),
-and the ``LLMEngine`` front-end (``engine``). Device-resident decode
-(ISSUE 18) keeps the steady-state loop on the accelerator: greedy
-sampling runs in-graph (``in_graph_sampling=True``) and
-``decode_steps_per_sync=k`` fuses k decode iterations into one compiled
-window so the host fetches ``[B, k]`` int32 tokens per round-trip
-instead of ``[B, V]`` f32 logits per token. See DESIGN_DECISIONS.md
-"Paged KV cache & continuous batching" + "Device-resident decode" and
-the README serving recipe.
+and the ``LLMEngine`` front-end (``engine``). The decode step keeps the
+device busy: its graph returns its own argmax, so a greedy step fetches
+``[B]`` int32 tokens (the ``[B, V]`` float32 rows only where a request
+samples or ``capture_logits`` is on), and the next step is dispatched
+before this one's tokens are fetched. See DESIGN_DECISIONS.md "Paged KV
+cache & continuous batching" + "Decode dispatch-ahead" and the README
+serving recipe.
 """
 
 from .errors import (  # noqa: F401

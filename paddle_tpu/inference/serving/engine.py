@@ -40,13 +40,16 @@ server:
 
 Pool writes happen in-graph (``lax.dynamic_update_slice``); attention
 reads route through ``serving.paged_attention`` (Pallas on TPU, pure-lax
-gather on CPU). Sampling is host-side per request via
+gather on CPU). A greedy step's tokens are the decode graph's own argmax
+(``greedy_tokens_in_graph``: the host sampler's choice, bit for bit); a
+request that samples is sampled host-side via
 ``models.llama.sample_next_tokens`` — the same function the eager
 ``generate`` path uses, so engine outputs are bit-exact against it.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import math
@@ -143,7 +146,7 @@ _G_QUANT_BLOCKS = _obs_metrics.gauge(
 # a device->host fetch and how many bytes it pulls. A step whose rows the
 # host samples from or keeps (do_sample, capture_logits) fetches [B, V]
 # f32 logits; a greedy step fetches [B] int32 tokens, the decode graph's
-# own argmax (ISSUE 27); a fused k-step window fetches [B, k] int32 once.
+# own argmax (ISSUE 27).
 _M_HOST_SYNCS = _obs_metrics.counter(
     "serving_host_syncs_total",
     "blocking device->host fetches made by the decode loop (logits or "
@@ -151,8 +154,7 @@ _M_HOST_SYNCS = _obs_metrics.counter(
 _M_FETCH_BYTES = _obs_metrics.counter(
     "serving_decode_fetch_bytes_total",
     "bytes fetched device->host by the decode loop: B*V*4 per step whose "
-    "rows the host samples from or keeps, B*4 per greedy step, B*k*4 per "
-    "fused k-step decode window")
+    "rows the host samples from or keeps, B*4 per greedy step")
 
 # decode dispatch-ahead (ISSUE 28): how each emitted decode step of the
 # plain path reached the device, and what the overlap cost in dropped rows
@@ -165,7 +167,7 @@ _M_DECODE_SYNC = _obs_metrics.counter(
     "decode steps emitted that were dispatched with nothing in flight, by "
     "reason: idle (first after a break), sampled (a do_sample row), evict "
     "(room only by evicting or copying), drain (import, export, reload, "
-    "store save), path (speculative, fused window, process-spanning mesh)")
+    "store save), path (speculative, process-spanning mesh)")
 _M_ROWS_DISCARDED = _obs_metrics.counter(
     "serving_decode_rows_discarded_total",
     "rows of a decode step in flight whose token was dropped: the request "
@@ -228,6 +230,34 @@ def _add_counts(counters, counts, names, decode):
     return counters + jnp.concatenate([got, zero] if decode else [zero, got])
 
 
+@contextlib.contextmanager
+def _params_swapped(params, arrays):
+    """What every graph builder does around its trace: the operand
+    ``arrays`` stand in the model's ``params`` while the body traces under
+    ``trace_guard``, and the arrays that were there come back whatever the
+    trace does, so that one that raises leaves no tracer in the model."""
+    from ...core import state as _state
+
+    old = [p._data for p in params]
+    try:
+        for p, a in zip(params, arrays):
+            p._data = a
+        with _state.trace_guard():
+            yield
+    finally:
+        for p, a in zip(params, old):
+            p._data = a
+
+
+def _scales_in(k_pools, v_pools, k_scales, v_scales):
+    """``(quantized, ks_in, vs_in)``: the scale pools a layer loop zips
+    beside the payload pools, a None a layer where the cache holds none
+    (the float path's scale lists are empty)."""
+    if len(k_scales) > 0:
+        return True, k_scales, v_scales
+    return False, [None] * len(k_pools), [None] * len(v_pools)
+
+
 class _StepPhases:
     """The phases of one engine step as spans (``observability.trace``):
     ``begin(name)`` ends the phase that was open and opens the next, so the
@@ -238,7 +268,7 @@ class _StepPhases:
     revivals), ``engine.prefill`` (one chunk: dispatch, and on the last
     chunk the fetch and the first token), ``engine.decode.prepare`` (decode
     room, copy-on-write, the ready list, the step's inputs and their
-    puts), ``engine.decode.dispatch`` (the call of the decode / window /
+    puts), ``engine.decode.dispatch`` (the call of the decode or
     verify executable until it returns), ``engine.decode.fetch`` (the wait
     for the device, then the transfer), ``engine.decode.emit`` (sampling,
     commit, latency observations, finishes), ``engine.bookkeeping`` (store
@@ -412,9 +442,8 @@ class LLMEngine:
                  draft_model=None, spec_tokens=2, kv_dtype=None,
                  prefill_only=False, kv_host_blocks=0,
                  prefix_store_path=None, prefix_store_autosave_chains=None,
-                 fuse_draft_catchup=True, decode_steps_per_sync=1,
-                 in_graph_sampling=None, capture_logits=False,
-                 kv_page_checksums=False, weight_audit=False):
+                 capture_logits=False, kv_page_checksums=False,
+                 weight_audit=False):
         from ...models.llama import LlamaForCausalLM, sample_next_tokens
 
         # the serving calls a model exposes (ISSUE 27): the prefill-chunk
@@ -425,9 +454,9 @@ class LLMEngine:
                 "LLMEngine serves models that expose the serving calls "
                 f"{', '.join(_SERVING_CALLS)}; {type(model).__name__} "
                 f"lacks {', '.join(missing)}")
-        # decode windows, draft verify and catch-up keep Llama's layer
-        # body written out (or were only ever run with it): another model
-        # is refused below, by name
+        # draft verify and catch-up keep Llama's layer body written out
+        # (or were only ever run with it): another model is refused below,
+        # by name
         self._llama = isinstance(model, LlamaForCausalLM)
         self.model = model
         if not self._llama and plan is not None:
@@ -655,51 +684,9 @@ class LLMEngine:
             self._draft_prefill_jit = None
             self._draft_decode_jit = None
             self._verify_jit = None
-        # device-resident decode (ISSUE 18): in-graph greedy sampling
-        # shrinks the per-step fetch from [B, V] f32 logits to [B] int32
-        # tokens; fused windows (decode_steps_per_sync=k) run k decode
-        # iterations inside one fori_loop graph and fetch [B, k] tokens
-        # per host round-trip. k=1 with in_graph_sampling unset decodes a
-        # step at a time; the host samples wherever a request asks for it
-        # and otherwise fetches the decode graph's own argmax (ISSUE 27).
-        k = int(decode_steps_per_sync)
-        if k < 1:
-            raise ValueError(
-                f"decode_steps_per_sync must be >= 1, got {k}")
-        if k > 1 and draft_model is not None:
-            raise ValueError(
-                "decode_steps_per_sync > 1 and speculative decoding are "
-                "mutually exclusive: the verify window already batches "
-                "device work and samples in-graph")
-        if in_graph_sampling is None:
-            in_graph_sampling = k > 1
-        in_graph_sampling = bool(in_graph_sampling)
-        if in_graph_sampling and not self._llama:
-            raise ValueError(self._llama_only(
-                "decode_steps_per_sync > 1 / in_graph_sampling (fused "
-                "decode windows)"))
-        if k > 1 and not in_graph_sampling:
-            raise ValueError(
-                "decode_steps_per_sync > 1 requires in_graph_sampling: a "
-                "fused window cannot round-trip logits to the host "
-                "between its iterations")
-        if in_graph_sampling and draft_model is not None:
-            raise ValueError(
-                "in_graph_sampling applies to the plain decode path; the "
-                "speculative verify step already samples in-graph")
-        if capture_logits and in_graph_sampling:
-            raise ValueError(
-                "capture_logits=True requires host-side sampling "
-                "(in_graph_sampling=False, decode_steps_per_sync=1): "
-                "device-resident decode never fetches the logits rows")
-        self._decode_window = k
-        self._in_graph = in_graph_sampling
         #: read at every step, so a caller may switch it off once it has
         #: the rows it wanted: greedy steps then fetch tokens only
         self.capture_logits = bool(capture_logits)
-        self._window_name = f"llm_engine_decode_window#{n}"
-        self._window_jit = None
-        self._warned_do_sample = False
         # hoisted from _emit (ISSUE 18 satellite): one import at
         # construction instead of one per emitted token
         self._sample_next_tokens = sample_next_tokens
@@ -726,9 +713,7 @@ class LLMEngine:
         self._requests: dict[int, Request] = {}
         self._closed = False
         # fused ragged draft catch-up (ISSUE 16 perf satellite): one
-        # fori_loop graph per power-of-two feed-length bucket instead of
-        # F sequential dispatches of the single-token draft decode.
-        self._fuse_catchup = bool(fuse_draft_catchup)
+        # fori_loop graph per power-of-two feed-length bucket
         self._catchup_jits = {}
         if self.kv_tier is not None:
             # publish the tier series at zero so metrics() and dashboards
@@ -1281,7 +1266,6 @@ class LLMEngine:
         window kind, or a model with counters, adds two operands behind
         the pools — the request's window row and the counter array — and
         one result, the counters."""
-        from ...core import state as _state
         from ...core.tensor import Tensor
 
         block_size = self.block_size
@@ -1297,41 +1281,33 @@ class LLMEngine:
 
             from .paged_attention import ChunkAttnState
 
-            quantized = len(k_scales) > 0
-            ks_in = k_scales if quantized else [None] * len(k_pools)
-            vs_in = v_scales if quantized else [None] * len(v_pools)
+            quantized, ks_in, vs_in = _scales_in(k_pools, v_pools,
+                                                 k_scales, v_scales)
             window_row, counters = extra if extra else (None, None)
             counts = {} if extra else None
-            old = [p._data for p in params]
-            try:
-                for p, a in zip(params, param_arrays):
-                    p._data = a
-                with _state.trace_guard():
-                    start = jnp.asarray(start, jnp.int32)
-                    upto = jnp.asarray(true_upto, jnp.int32)
-                    x = model.serve_embed(ids)
-                    new_k, new_v, new_ks, new_vs = [], [], [], []
-                    for i, (spec, kp, vp, ksc, vsc) in enumerate(zip(
-                            layout, k_pools, v_pools, ks_in, vs_in)):
-                        st = ChunkAttnState(
-                            spec, block_size, start, upto, tables_row,
-                            kp, vp, ksc, vsc, window_row=window_row,
-                            n_tail=n_tail, counters=counts)
-                        x = model.serve_layer(i, x, st)
-                        new_k.append(st.k_pool)
-                        new_v.append(st.v_pool)
-                        if quantized:
-                            new_ks.append(st.k_scale)
-                            new_vs.append(st.v_scale)
-                    h = model.serve_norm(x)
-                    h_arr = _arr(h)
-                    last = jax.lax.dynamic_slice(
-                        h_arr, (0, upto - 1 - start, 0),
-                        (1, 1, h_arr.shape[-1]))
-                    logits = model.serve_head(Tensor._wrap(last))
-            finally:
-                for p, a in zip(params, old):
-                    p._data = a
+            with _params_swapped(params, param_arrays):
+                start = jnp.asarray(start, jnp.int32)
+                upto = jnp.asarray(true_upto, jnp.int32)
+                x = model.serve_embed(ids)
+                new_k, new_v, new_ks, new_vs = [], [], [], []
+                for i, (spec, kp, vp, ksc, vsc) in enumerate(zip(
+                        layout, k_pools, v_pools, ks_in, vs_in)):
+                    st = ChunkAttnState(
+                        spec, block_size, start, upto, tables_row,
+                        kp, vp, ksc, vsc, window_row=window_row,
+                        n_tail=n_tail, counters=counts)
+                    x = model.serve_layer(i, x, st)
+                    new_k.append(st.k_pool)
+                    new_v.append(st.v_pool)
+                    if quantized:
+                        new_ks.append(st.k_scale)
+                        new_vs.append(st.v_scale)
+                h = model.serve_norm(x)
+                h_arr = _arr(h)
+                last = jax.lax.dynamic_slice(
+                    h_arr, (0, upto - 1 - start, 0),
+                    (1, 1, h_arr.shape[-1]))
+                logits = model.serve_head(Tensor._wrap(last))
             out = (_arr(logits)[:, 0], new_k, new_v, new_ks, new_vs)
             if extra:
                 out += (_add_counts(counters, counts, counter_names, False),)
@@ -1344,24 +1320,16 @@ class LLMEngine:
         plain decode executable and the fused catch-up loop (ISSUE 16):
         the fused path must run the IDENTICAL op sequence per step or
         draft proposals — and therefore acceptance counts — would drift
-        between modes. Assumes params are already swapped in and the
-        caller is inside ``trace_guard``. The layer body is the model's
-        (``serve_layer``) over a ``DecodeAttnState`` a layer.
-
-        ``active`` (jnp [B] bool, optional) is the fused decode window's
-        EOS-freeze mask (ISSUE 18): rows marked inactive have their K/V
-        write redirected to the reserved null block 0 at offset 0 — the
-        same scratch target empty slots already write through their
-        all-zero table rows — so a finished row can ride out the rest of
-        the window without corrupting live pages. ``wtables`` is the
-        window kind's ring table, ``counts`` the dict the layers' counters
-        land in."""
+        between modes. Assumes the caller is inside ``_params_swapped``.
+        The layer body is the model's (``serve_layer``) over a
+        ``DecodeAttnState`` a layer. ``wtables`` is the window kind's ring
+        table, ``counts`` the dict the layers' counters land in."""
         block_size = self.block_size
         _arr = self._arr
         layout = model.kv_layout()
 
         def core(ids, positions, tables, k_pools, v_pools, ks_in, vs_in,
-                 active=None, wtables=None, counts=None):
+                 wtables=None, counts=None):
             from .paged_attention import DecodeAttnState
 
             quantized = ks_in[0] is not None if ks_in else False
@@ -1372,7 +1340,7 @@ class LLMEngine:
                 st = DecodeAttnState(
                     spec, block_size, positions,
                     wtables if spec.kind == "window" else tables,
-                    kp, vp, ksc, vsc, active=active, counters=counts)
+                    kp, vp, ksc, vsc, counters=counts)
                 x = model.serve_layer(i, x, st)
                 new_k.append(st.k_pool)
                 new_v.append(st.v_pool)
@@ -1408,7 +1376,6 @@ class LLMEngine:
         and not from the first column, so a step can be enqueued before
         the host has seen the tokens it continues from. One executable
         serves both: a step whose ids all come from the host passes zeros."""
-        from ...core import state as _state
         from ...models.llama import greedy_tokens_in_graph
 
         core = self._make_decode_core(model)
@@ -1422,25 +1389,17 @@ class LLMEngine:
 
                 prev, *extra = extra
                 ids = jnp.where(ids[:, 1:] != 0, prev[:, None], ids[:, :1])
-            quantized = len(k_scales) > 0
-            ks_in = k_scales if quantized else [None] * len(k_pools)
-            vs_in = v_scales if quantized else [None] * len(v_pools)
+            _, ks_in, vs_in = _scales_in(k_pools, v_pools, k_scales,
+                                         v_scales)
             _, counters = extra if extra else (None, None)
             counts = {} if extra else None
             wtables = None
             if windowed:        # ``_decode_tables``: global | rings
                 tables, wtables = tables[:, :P], tables[:, P:]
-            old = [p._data for p in params]
-            try:
-                for p, a in zip(params, param_arrays):
-                    p._data = a
-                with _state.trace_guard():
-                    logits, new_k, new_v, new_ks, new_vs = core(
-                        ids, positions, tables, k_pools, v_pools,
-                        ks_in, vs_in, wtables=wtables, counts=counts)
-            finally:
-                for p, a in zip(params, old):
-                    p._data = a
+            with _params_swapped(params, param_arrays):
+                logits, new_k, new_v, new_ks, new_vs = core(
+                    ids, positions, tables, k_pools, v_pools,
+                    ks_in, vs_in, wtables=wtables, counts=counts)
             out = (logits, greedy_tokens_in_graph(logits),
                    new_k, new_v, new_ks, new_vs)
             if extra:
@@ -1448,96 +1407,6 @@ class LLMEngine:
             return out
 
         return decode_pure
-
-    def _make_window_fn(self, model, params, window):
-        """Fused k-step decode window (ISSUE 18 tentpole): ``(param_arrays,
-        ids [B, 1], positions [B], active [B] bool, budget [B] int32,
-        eos_ids [B] int32, tables [B, P], k_pools, v_pools, k_scales,
-        v_scales) -> (tokens [B, window] int32, pools, scale pools)``.
-
-        A ``fori_loop`` body runs one full decode iteration — paged
-        attention, KV write at the advanced position, in-graph greedy
-        argmax — then advances each ACTIVE row's position/input token and
-        freezes rows that emitted their ``eos_ids`` entry or exhausted
-        their per-row ``budget`` (min(window, tokens remaining), computed
-        host-side). Frozen rows write to null block 0 via the decode
-        core's ``active`` mask and their token column repeats the frozen
-        input id, which the host-side emitter ignores. The graph compiles
-        ONCE per (B, window): every input shape is fixed, and the loop
-        body reuses the SAME traced core as the per-step path, so greedy
-        outputs are bit-identical to k sequential per-step decodes."""
-        from ...core import state as _state
-        from ...models.llama import greedy_tokens_in_graph
-
-        core = self._make_decode_core(model)
-
-        def window_pure(param_arrays, ids, positions, active, budget,
-                        eos_ids, tables, k_pools, v_pools, k_scales,
-                        v_scales):
-            import jax
-            import jax.numpy as jnp
-
-            quantized = len(k_scales) > 0
-            ks_in = k_scales if quantized else [None] * len(k_pools)
-            vs_in = v_scales if quantized else [None] * len(v_pools)
-            old = [p._data for p in params]
-            try:
-                for p, a in zip(params, param_arrays):
-                    p._data = a
-                with _state.trace_guard():
-                    def one(t, ids, positions, active, budget, toks,
-                            kps, vps, kss, vss):
-                        lg, kps, vps, kss, vss = core(
-                            ids, positions, tables, kps, vps, kss, vss,
-                            active=active)
-                        nxt = greedy_tokens_in_graph(lg)
-                        # frozen rows repeat their input id; the emitter
-                        # never reads past a row's budget anyway
-                        emitted = jnp.where(active, nxt, ids[:, 0])
-                        toks = jax.lax.dynamic_update_slice(
-                            toks, emitted[:, None], (0, t))
-                        stepped = active.astype(jnp.int32)
-                        positions = positions + stepped
-                        budget = budget - stepped
-                        done = (emitted == eos_ids) | (budget <= 0)
-                        active = active & ~done
-                        ids = emitted[:, None]
-                        return (ids, positions, active, budget, toks,
-                                kps, vps, kss, vss)
-
-                    toks0 = jnp.zeros((ids.shape[0], window), jnp.int32)
-                    # step 0 outside the loop fixes the carry avals
-                    (ids_c, pos_c, act_c, bud_c, toks, kps, vps, kss,
-                     vss) = one(0, ids, positions, active, budget, toks0,
-                                k_pools, v_pools, ks_in, vs_in)
-                    if not quantized:
-                        kss, vss = [], []
-
-                    def body(t, carry):
-                        (ids_c, pos_c, act_c, bud_c, toks, kps, vps,
-                         kss, vss) = carry
-                        (ids_c, pos_c, act_c, bud_c, toks, kps, vps,
-                         kss, vss) = one(
-                            t, ids_c, pos_c, act_c, bud_c, toks, kps,
-                            vps,
-                            kss if quantized else [None] * len(kps),
-                            vss if quantized else [None] * len(vps))
-                        if not quantized:
-                            kss, vss = [], []
-                        return (ids_c, pos_c, act_c, bud_c, toks, kps,
-                                vps, kss, vss)
-
-                    (ids_c, pos_c, act_c, bud_c, toks, kps, vps, kss,
-                     vss) = jax.lax.fori_loop(
-                        1, window, body,
-                        (ids_c, pos_c, act_c, bud_c, toks, kps, vps,
-                         kss, vss))
-            finally:
-                for p, a in zip(params, old):
-                    p._data = a
-            return toks, kps, vps, kss, vss
-
-        return window_pure
 
     def _make_catchup_fn(self, model, params):
         """Fused ragged draft catch-up (ISSUE 16 perf satellite): one
@@ -1549,53 +1418,41 @@ class LLMEngine:
         independent of ``F``, so the doubling-ladder buckets stay cheap
         to compile. Rows shorter than ``F`` left-pad by repeating their
         first (token, position) feed: rewriting the same token at the
-        same position is a deterministic no-op, so padded replays are
-        bit-identical to the unfused loop."""
-        from ...core import state as _state
-
+        same position is a deterministic no-op, so a padded replay leaves
+        the pools a token-at-a-time replay would."""
         core = self._make_decode_core(model)
 
         def catchup_pure(param_arrays, ids, positions, tables,
                          k_pools, v_pools, k_scales, v_scales):
             import jax
 
-            quantized = len(k_scales) > 0
-            ks_in = k_scales if quantized else [None] * len(k_pools)
-            vs_in = v_scales if quantized else [None] * len(v_pools)
-            old = [p._data for p in params]
-            try:
-                for p, a in zip(params, param_arrays):
-                    p._data = a
-                with _state.trace_guard():
-                    def one(t, kps, vps, kss, vss):
-                        ids_t = jax.lax.dynamic_slice_in_dim(
-                            ids, t, 1, axis=1)
-                        pos_t = jax.lax.dynamic_slice_in_dim(
-                            positions, t, 1, axis=1)[:, 0]
-                        return core(ids_t, pos_t, tables, kps, vps,
-                                    kss, vss)
+            quantized, ks_in, vs_in = _scales_in(k_pools, v_pools,
+                                                 k_scales, v_scales)
+            with _params_swapped(params, param_arrays):
+                def one(t, kps, vps, kss, vss):
+                    ids_t = jax.lax.dynamic_slice_in_dim(ids, t, 1, axis=1)
+                    pos_t = jax.lax.dynamic_slice_in_dim(
+                        positions, t, 1, axis=1)[:, 0]
+                    return core(ids_t, pos_t, tables, kps, vps, kss, vss)
 
-                    # step 0 outside the loop fixes the carry avals
-                    lg, kps, vps, kss, vss = one(0, k_pools, v_pools,
-                                                 ks_in, vs_in)
+                # step 0 outside the loop fixes the carry avals
+                lg, kps, vps, kss, vss = one(0, k_pools, v_pools,
+                                             ks_in, vs_in)
+                if not quantized:
+                    kss, vss = [], []
+
+                def body(t, carry):
+                    kps, vps, kss, vss, _ = carry
+                    lg, kps, vps, kss, vss = one(
+                        t, kps, vps,
+                        kss if quantized else [None] * len(kps),
+                        vss if quantized else [None] * len(vps))
                     if not quantized:
                         kss, vss = [], []
+                    return (kps, vps, kss, vss, lg)
 
-                    def body(t, carry):
-                        kps, vps, kss, vss, _ = carry
-                        lg, kps, vps, kss, vss = one(
-                            t, kps, vps,
-                            kss if quantized else [None] * len(kps),
-                            vss if quantized else [None] * len(vps))
-                        if not quantized:
-                            kss, vss = [], []
-                        return (kps, vps, kss, vss, lg)
-
-                    kps, vps, kss, vss, lg = jax.lax.fori_loop(
-                        1, ids.shape[1], body, (kps, vps, kss, vss, lg))
-            finally:
-                for p, a in zip(params, old):
-                    p._data = a
+                kps, vps, kss, vss, lg = jax.lax.fori_loop(
+                    1, ids.shape[1], body, (kps, vps, kss, vss, lg))
             return lg, kps, vps, kss, vss
 
         return catchup_pure
@@ -1610,7 +1467,6 @@ class LLMEngine:
         scores all K+1 positions, writes their K/V, and counts in-graph
         how many draft tokens match the target's greedy argmax (the
         accept rule that keeps outputs bit-exact)."""
-        from ...core import state as _state
         from ...core.tensor import Tensor
 
         block_size = self.block_size
@@ -1627,100 +1483,84 @@ class LLMEngine:
             from .kv_cache import quantize_kv_rows
             from .paged_attention import paged_multiquery_attention
 
-            quantized = len(k_scales) > 0
-            ks_in = k_scales if quantized else [None] * len(k_pools)
-            vs_in = v_scales if quantized else [None] * len(v_pools)
-            old = [p._data for p in params]
-            try:
-                for p, a in zip(params, param_arrays):
-                    p._data = a
-                with _state.trace_guard():
-                    bsz, t_q = ids.shape
-                    x = model.llama.embed_tokens(Tensor._wrap(ids))
-                    cos_t = _arr(model.llama.rope_cos)
-                    sin_t = _arr(model.llama.rope_sin)
-                    pos_grid = (positions[:, None]
-                                + jnp.arange(t_q, dtype=jnp.int32)[None])
-                    c = cos_t[pos_grid][:, :, None, :]
-                    sn = sin_t[pos_grid][:, :, None, :]
-                    new_k, new_v, new_ks, new_vs = [], [], [], []
-                    for layer, kp, vp, ksc, vsc in zip(model.llama.layers,
-                                                       k_pools, v_pools,
-                                                       ks_in, vs_in):
-                        attn = layer.self_attn
-                        h = layer.input_layernorm(x)
-                        q = M.reshape(attn.q_proj(h),
-                                      [bsz, t_q, attn.num_heads,
-                                       attn.head_dim])
-                        k = M.reshape(attn.k_proj(h),
-                                      [bsz, t_q, attn.num_kv_heads,
-                                       attn.head_dim])
-                        v = M.reshape(attn.v_proj(h),
-                                      [bsz, t_q, attn.num_kv_heads,
-                                       attn.head_dim])
+            quantized, ks_in, vs_in = _scales_in(k_pools, v_pools,
+                                                 k_scales, v_scales)
+            with _params_swapped(params, param_arrays):
+                bsz, t_q = ids.shape
+                x = model.llama.embed_tokens(Tensor._wrap(ids))
+                cos_t = _arr(model.llama.rope_cos)
+                sin_t = _arr(model.llama.rope_sin)
+                pos_grid = (positions[:, None]
+                            + jnp.arange(t_q, dtype=jnp.int32)[None])
+                c = cos_t[pos_grid][:, :, None, :]
+                sn = sin_t[pos_grid][:, :, None, :]
+                new_k, new_v, new_ks, new_vs = [], [], [], []
+                for layer, kp, vp, ksc, vsc in zip(
+                        model.llama.layers, k_pools, v_pools, ks_in, vs_in):
+                    attn = layer.self_attn
+                    h = layer.input_layernorm(x)
+                    q = M.reshape(attn.q_proj(h), [
+                        bsz, t_q, attn.num_heads, attn.head_dim])
+                    k = M.reshape(attn.k_proj(h), [
+                        bsz, t_q, attn.num_kv_heads, attn.head_dim])
+                    v = M.reshape(attn.v_proj(h), [
+                        bsz, t_q, attn.num_kv_heads, attn.head_dim])
 
-                        qa = rope_rotate(_arr(q), c, sn)
-                        ka, va = rope_rotate(_arr(k), c, sn), _arr(v)
-                        blk = tables[jnp.arange(bsz)[:, None],
-                                     pos_grid // block_size]
-                        off = pos_grid % block_size
-                        if quantized:
-                            qk, sk = quantize_kv_rows(ka)  # [B,T,Hkv,D]
-                            qv, sv = quantize_kv_rows(va)
-                        for i in range(bsz):
-                            for t in range(t_q):
-                                if quantized:
-                                    kp = jax.lax.dynamic_update_slice(
-                                        kp, qk[i:i + 1, t:t + 1],
-                                        (blk[i, t], off[i, t], 0, 0))
-                                    vp = jax.lax.dynamic_update_slice(
-                                        vp, qv[i:i + 1, t:t + 1],
-                                        (blk[i, t], off[i, t], 0, 0))
-                                    ksc = jax.lax.dynamic_update_slice(
-                                        ksc, sk[i:i + 1, t:t + 1],
-                                        (blk[i, t], off[i, t], 0))
-                                    vsc = jax.lax.dynamic_update_slice(
-                                        vsc, sv[i:i + 1, t:t + 1],
-                                        (blk[i, t], off[i, t], 0))
-                                else:
-                                    kp = jax.lax.dynamic_update_slice(
-                                        kp,
-                                        ka[i:i + 1, t:t + 1].astype(
-                                            kp.dtype),
-                                        (blk[i, t], off[i, t], 0, 0))
-                                    vp = jax.lax.dynamic_update_slice(
-                                        vp,
-                                        va[i:i + 1, t:t + 1].astype(
-                                            vp.dtype),
-                                        (blk[i, t], off[i, t], 0, 0))
-                        out = paged_multiquery_attention(
-                            qa, kp, vp, tables, positions + t_q, positions,
-                            scale=1.0 / math.sqrt(attn.head_dim),
-                            k_scale=ksc, v_scale=vsc)
-                        attn_out = attn.o_proj(
-                            M.reshape(Tensor._wrap(out), [bsz, t_q, -1]))
-                        x = x + attn_out
-                        x = x + layer.mlp(layer.post_attention_layernorm(x))
-                        new_k.append(kp)
-                        new_v.append(vp)
-                        if quantized:
-                            new_ks.append(ksc)
-                            new_vs.append(vsc)
-                    h = model.llama.norm(x)
-                    logits = _arr(_head(h))          # [B, K+1, V]
-                    tgt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    # in-graph accept: 1s until the first draft/target
-                    # mismatch; next token = target argmax at the first
-                    # rejected position (or the bonus position on full
-                    # accept) — exactly sequential greedy, verified at once
-                    eq = (tgt[:, :t_q - 1] == draft_toks).astype(jnp.int32)
-                    acc = jnp.cumprod(eq, axis=1)
-                    counts = jnp.sum(acc, axis=1)
-                    nxt = jnp.take_along_axis(
-                        tgt, counts[:, None], axis=1)[:, 0]
-            finally:
-                for p, a in zip(params, old):
-                    p._data = a
+                    qa = rope_rotate(_arr(q), c, sn)
+                    ka, va = rope_rotate(_arr(k), c, sn), _arr(v)
+                    blk = tables[jnp.arange(bsz)[:, None],
+                                 pos_grid // block_size]
+                    off = pos_grid % block_size
+                    if quantized:
+                        qk, sk = quantize_kv_rows(ka)  # [B,T,Hkv,D]
+                        qv, sv = quantize_kv_rows(va)
+                    for i in range(bsz):
+                        for t in range(t_q):
+                            if quantized:
+                                kp = jax.lax.dynamic_update_slice(
+                                    kp, qk[i:i + 1, t:t + 1],
+                                    (blk[i, t], off[i, t], 0, 0))
+                                vp = jax.lax.dynamic_update_slice(
+                                    vp, qv[i:i + 1, t:t + 1],
+                                    (blk[i, t], off[i, t], 0, 0))
+                                ksc = jax.lax.dynamic_update_slice(
+                                    ksc, sk[i:i + 1, t:t + 1],
+                                    (blk[i, t], off[i, t], 0))
+                                vsc = jax.lax.dynamic_update_slice(
+                                    vsc, sv[i:i + 1, t:t + 1],
+                                    (blk[i, t], off[i, t], 0))
+                            else:
+                                kp = jax.lax.dynamic_update_slice(
+                                    kp, ka[i:i + 1, t:t + 1].astype(kp.dtype),
+                                    (blk[i, t], off[i, t], 0, 0))
+                                vp = jax.lax.dynamic_update_slice(
+                                    vp, va[i:i + 1, t:t + 1].astype(vp.dtype),
+                                    (blk[i, t], off[i, t], 0, 0))
+                    out = paged_multiquery_attention(
+                        qa, kp, vp, tables, positions + t_q, positions,
+                        scale=1.0 / math.sqrt(attn.head_dim),
+                        k_scale=ksc, v_scale=vsc)
+                    attn_out = attn.o_proj(
+                        M.reshape(Tensor._wrap(out), [bsz, t_q, -1]))
+                    x = x + attn_out
+                    x = x + layer.mlp(layer.post_attention_layernorm(x))
+                    new_k.append(kp)
+                    new_v.append(vp)
+                    if quantized:
+                        new_ks.append(ksc)
+                        new_vs.append(vsc)
+                h = model.llama.norm(x)
+                logits = _arr(_head(h))          # [B, K+1, V]
+                tgt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                # in-graph accept: 1s until the first draft/target
+                # mismatch; next token = target argmax at the first
+                # rejected position (or the bonus position on full
+                # accept) — exactly sequential greedy, verified at once
+                eq = (tgt[:, :t_q - 1] == draft_toks).astype(jnp.int32)
+                acc = jnp.cumprod(eq, axis=1)
+                counts = jnp.sum(acc, axis=1)
+                nxt = jnp.take_along_axis(
+                    tgt, counts[:, None], axis=1)[:, 0]
             return counts, nxt, new_k, new_v, new_ks, new_vs
 
         return verify_pure
@@ -1759,12 +1599,6 @@ class LLMEngine:
             self._make_decode_fn(self.model, self._params, feed_back=True),
             self._plan, name=self._decode_name, donate_argnums=(4, 5, 6, 7),
             out_specs=greedy_out)
-        if self._in_graph:
-            self._window_jit = compile_step_with_plan(
-                self._make_window_fn(self.model, self._params,
-                                     self._decode_window),
-                self._plan, name=self._window_name,
-                donate_argnums=(7, 8, 9, 10), out_specs=pool_out)
         if self.draft_model is not None:
             self._draft_prefill_jit = compile_step_with_plan(
                 self._make_chunk_fn(self.draft_model, self._draft_params),
@@ -2065,36 +1899,20 @@ class LLMEngine:
         phases.begin("engine.decode.prepare")
         cur = self._take_ahead()
         if cur is None:
-            sched.ensure_decode_room(
-                extra=self._spec_k,
-                extra_for=(self._window_extra if self._decode_window > 1
-                           else None))
+            sched.ensure_decode_room(extra=self._spec_k)
             self._drain_cow()
             ready = [(i, r) for i, r in enumerate(sched.slots)
                      if r is not None and not r.prefilling]
-            if ready:
-                sampled = any(r.sampling.do_sample for _, r in ready)
-                if self._spec_k:
-                    _M_DECODE_SYNC.inc(instance=self._name, reason="path")
-                    self._spec_step(ready, outputs)
-                elif self._in_graph and not sampled:
-                    _M_DECODE_SYNC.inc(instance=self._name, reason="path")
-                    self._window_step(ready, outputs)
-                else:
-                    if self._in_graph and not self._warned_do_sample:
-                        self._warned_do_sample = True
-                        warnings.warn(
-                            f"{self._name}: do_sample=True requests keep the "
-                            "host sampling path (per-request numpy RNG); "
-                            "device-resident decode degrades to per-step "
-                            "host sampling while any is in the batch",
-                            RuntimeWarning)
-                    cur = self._dispatch_decode(
-                        [(i, r, r.num_cached, r.admit_seq)
-                         for i, r in ready], (), sampled,
-                        self._sync_reason or "idle")
-                    self._sync_reason = None
-                    phases.begin("engine.decode.prepare")
+            if ready and self._spec_k:
+                _M_DECODE_SYNC.inc(instance=self._name, reason="path")
+                self._spec_step(ready, outputs)
+            elif ready:
+                cur = self._dispatch_decode(
+                    [(i, r, r.num_cached, r.admit_seq) for i, r in ready],
+                    (), any(r.sampling.do_sample for _, r in ready),
+                    self._sync_reason or "idle")
+                self._sync_reason = None
+                phases.begin("engine.decode.prepare")
         if cur is not None:
             self._ahead = self._dispatch_ahead(cur)
             self._emit_decode(cur, outputs)
@@ -2284,53 +2102,6 @@ class LLMEngine:
                 req.last_logits = logits[i]
             outputs.extend(self._emit_token(req, greedy[i]))
 
-    def _window_extra(self, req):
-        """Lookahead positions ``ensure_decode_room`` must reserve for
-        ``req`` before a fused window: the window writes at most
-        ``min(k, tokens remaining)`` new positions, the first of which
-        the base room check already covers."""
-        remaining = req.sampling.max_new_tokens - len(req.output_tokens)
-        return max(min(self._decode_window, remaining) - 1, 0)
-
-    def _window_step(self, ready, outputs):
-        """Device-resident decode for all decode-ready slots (ISSUE 18):
-        one fused ``decode_steps_per_sync``-step dispatch, one ``[B, k]``
-        int32 token fetch, then batched host-side emission. Greedy only —
-        ``step`` routes batches containing ``do_sample`` requests to the
-        per-step host path."""
-        import jax.numpy as jnp
-
-        B, k = self.max_batch_size, self._decode_window
-        ids = np.zeros((B, 1), np.int32)
-        positions = np.zeros(B, np.int32)
-        active = np.zeros(B, np.bool_)
-        budget = np.zeros(B, np.int32)
-        eos_ids = np.full(B, -1, np.int32)
-        for i, req in ready:
-            ids[i, 0] = req.last_token
-            positions[i] = req.num_cached
-            active[i] = True
-            remaining = (req.sampling.max_new_tokens
-                         - len(req.output_tokens))
-            budget[i] = min(k, remaining)
-            if req.sampling.eos_token_id is not None:
-                eos_ids[i] = req.sampling.eos_token_id
-        c = self.cache
-        inputs = ([p._data for p in self._params], self._g(ids),
-                  self._g(positions), self._g(active), self._g(budget),
-                  self._g(eos_ids), self._tables())
-        phases = self._phases
-        phases.begin("engine.decode.dispatch")
-        (toks, c.k, c.v, c.k_scale, c.v_scale) = self._window_jit(
-            *inputs, c.k, c.v, c.k_scale, c.v_scale)
-        phases.begin("engine.decode.fetch")
-        toks = self._fetch(toks)
-        phases.begin("engine.decode.emit")
-        _M_HOST_SYNCS.inc(instance=self._name)
-        _M_FETCH_BYTES.inc(toks.nbytes, instance=self._name)
-        for i, req in ready:
-            self._emit_window(req, toks[i], outputs)
-
     def _drain_revives(self):
         """Land this step's host-tier prefix hits (queued by the
         scheduler's ``match_with_tier``) in their freshly allocated
@@ -2424,70 +2195,57 @@ class LLMEngine:
             fs = list(range(lo, r.num_tokens))
             feeds[r.rid] = fs
             F = max(F, len(fs))
-        if self._fuse_catchup and F > 1:
+        dc = self.draft_cache
+        params = [p._data for p in self._draft_params]
+
+        def decode_one(feed):
+            """One call of the draft's decode executable; ``feed(i, r)`` is
+            a ready row's ``(token, position)``."""
+            ids = np.zeros((B, 1), np.int32)
+            pos = np.zeros(B, np.int32)
+            for i, r in ready:
+                ids[i, 0], pos[i] = feed(i, r)
+            (logits, _, dc.k, dc.v, dc.k_scale, dc.v_scale) = \
+                self._draft_decode_jit(
+                    params, jnp.asarray(ids), jnp.asarray(pos), tables,
+                    dc.k, dc.v, dc.k_scale, dc.v_scale)
+            return logits
+
+        def fetched(logits):
+            rows = np.asarray(logits)
+            _M_HOST_SYNCS.inc(instance=self._name)
+            _M_FETCH_BYTES.inc(rows.nbytes, instance=self._name)
+            return rows
+
+        if F > 1:
             # fused catch-up (ISSUE 16 perf satellite): bucket F up to
             # the next power of two — the extra left-pad steps rewrite
             # the first feed in place, a deterministic no-op — and replay
-            # the whole ragged window in ONE fori_loop dispatch instead
-            # of F sequential single-token dispatches
+            # the whole ragged window in ONE fori_loop dispatch
             Fb = 1 << (F - 1).bit_length()
-            for rid, fs in feeds.items():
-                feeds[rid] = [fs[0]] * (Fb - len(fs)) + fs
             ids = np.zeros((B, Fb), np.int32)
             pos = np.zeros((B, Fb), np.int32)
             for i, r in ready:
-                for t, j in enumerate(feeds[r.rid]):
+                fs = feeds[r.rid]
+                for t, j in enumerate([fs[0]] * (Fb - len(fs)) + fs):
                     ids[i, t] = toks[r.rid][j]
                     pos[i, t] = j
-            dc = self.draft_cache
             (logits, dc.k, dc.v, dc.k_scale, dc.v_scale) = \
                 self._catchup_jit(Fb)(
-                    [p._data for p in self._draft_params],
-                    jnp.asarray(ids), jnp.asarray(pos), tables,
+                    params, jnp.asarray(ids), jnp.asarray(pos), tables,
                     dc.k, dc.v, dc.k_scale, dc.v_scale)
         else:
-            for rid, fs in feeds.items():
-                # left-pad by repeating the first feed: re-writing the
-                # same token at the same position is a deterministic
-                # no-op, so the ragged catch-up runs as F uniform batched
-                # steps
-                feeds[rid] = [fs[0]] * (F - len(fs)) + fs
-            logits = None
-            for t in range(F):
-                ids = np.zeros((B, 1), np.int32)
-                pos = np.zeros(B, np.int32)
-                for i, r in ready:
-                    j = feeds[r.rid][t]
-                    ids[i, 0] = toks[r.rid][j]
-                    pos[i] = j
-                dc = self.draft_cache
-                (logits, _, dc.k, dc.v, dc.k_scale, dc.v_scale) = \
-                    self._draft_decode_jit(
-                        [p._data for p in self._draft_params],
-                        jnp.asarray(ids), jnp.asarray(pos), tables,
-                        dc.k, dc.v, dc.k_scale, dc.v_scale)
-        prev = np.asarray(logits)
-        _M_HOST_SYNCS.inc(instance=self._name)
-        _M_FETCH_BYTES.inc(prev.nbytes, instance=self._name)
+            # every row is one token behind: the draft's decode step itself
+            logits = decode_one(lambda i, r: (toks[r.rid][feeds[r.rid][0]],
+                                              feeds[r.rid][0]))
+        prev = fetched(logits)
         drafts = np.zeros((B, K), np.int32)
         for kstep in range(K):
             for i, r in ready:
                 drafts[i, kstep] = int(prev[i].argmax())
             if kstep + 1 < K:
-                ids = np.zeros((B, 1), np.int32)
-                pos = np.zeros(B, np.int32)
-                for i, r in ready:
-                    ids[i, 0] = drafts[i, kstep]
-                    pos[i] = r.num_tokens + kstep
-                dc = self.draft_cache
-                (prev, _, dc.k, dc.v, dc.k_scale, dc.v_scale) = \
-                    self._draft_decode_jit(
-                        [p._data for p in self._draft_params],
-                        jnp.asarray(ids), jnp.asarray(pos), tables,
-                        dc.k, dc.v, dc.k_scale, dc.v_scale)
-                prev = np.asarray(prev)
-                _M_HOST_SYNCS.inc(instance=self._name)
-                _M_FETCH_BYTES.inc(prev.nbytes, instance=self._name)
+                prev = fetched(decode_one(
+                    lambda i, r: (drafts[i, kstep], r.num_tokens + kstep)))
         for _, r in ready:
             # positions 0 .. num_tokens+K-2 now hold draft K/V
             r.draft_cached = r.num_tokens + K - 1
@@ -2575,58 +2333,6 @@ class LLMEngine:
             row[None], do_sample=s.do_sample, temperature=s.temperature,
             top_k=s.top_k, top_p=s.top_p, rng=req._rng)[0])
         return self._emit_token(req, tok)
-
-    def _emit_window(self, req, toks, outputs):
-        """Commit one fused window's tokens for ``req`` (``toks`` is the
-        request's ``[k]`` int32 row from the window fetch) in a single
-        batched pass: the accept scan mirrors the in-graph EOS-freeze
-        (stop after eos or the max_new_tokens budget), QoS charges ONCE
-        for the whole window, and the single window-boundary clock read
-        is spread over the accepted tokens as m observations of Δt/m so
-        ITL percentiles stay per-token comparable (see DESIGN_DECISIONS
-        "Device-resident decode"). Appends StepOutputs to ``outputs``."""
-        s = req.sampling
-        accepted = []
-        for t in toks:
-            accepted.append(int(t))
-            if len(req.output_tokens) + len(accepted) >= s.max_new_tokens:
-                break
-            if s.eos_token_id is not None and int(t) == s.eos_token_id:
-                break
-        m = len(accepted)
-        req.output_tokens.extend(accepted)
-        req.num_cached += m
-        self.stats_extra["tokens_out"] += m
-        # QoS accounting (ISSUE 17): the tenant's quota/vtime charge moves
-        # to the window boundary — one charge of m tokens
-        self.scheduler.note_served(req, m)
-        now = time.perf_counter_ns()
-        _M_TOKENS.inc(m, instance=self._name)
-        spread = m
-        if req.t_first_token is None:
-            # first emission happens in decode only for imported requests
-            # (disagg handoff / tier revival); TTFT lands on the first
-            # token, ITL on the rest
-            req.t_first_token = now
-            if req.t_submit is not None:
-                _H_TTFT.observe((now - req.t_submit) / 1e6,
-                                instance=self._name)
-            spread = m - 1
-        if spread > 0 and req.t_last_token is not None:
-            dt_ms = (now - req.t_last_token) / 1e6 / spread
-            for _ in range(spread):
-                _H_ITL.observe(dt_ms, instance=self._name)
-        req.t_last_token = now
-        done = req.should_finish()
-        if done:
-            self.scheduler.finish(req)
-            if _obs_trace.enabled():
-                self._trace_decode(req, now)
-        for j, tok in enumerate(accepted):
-            last = j == m - 1
-            outputs.append(StepOutput(
-                req.rid, int(tok), done and last,
-                req.finish_reason() if done and last else None))
 
     def _trace_decode(self, req, now):
         """The finished request's ``request.decode`` span, first decode

@@ -295,31 +295,23 @@ def replica_worker_main():
                else replica_id)
     hb.write(step=0, dir=hb_dir, rank=hb_rank)
 
-    # In-graph/window engines (decode_steps_per_sync > 1) warm their
-    # decode executable BEFORE reporting ready: the first-call compile of
-    # a fused k-step window can outlast the hang watchdog — especially
-    # with every replica compiling at once — and a replica must never
-    # look wedged for unavoidable one-time work. Boot time is covered by
-    # the supervisor's boot grace, not the heartbeat. Default engines
-    # keep the lazy first-call compile (pre-window boot behavior) —
-    # EXCEPT replica groups, which pre-compile EVERY admissible prefill
-    # bucket: a post-ready first-touch compile stalls the whole group's
-    # collectives with every heartbeat silent, long enough to read as a
-    # hang, and boot (covered by the group-scaled boot grace) is the
-    # only place one-time work belongs. Both ranks run this identical
-    # warmup, so the compile-time collectives line up by construction.
-    if (getattr(eng, "_in_graph", False) or group_size > 1) \
-            and role != "prefill":
+    # Replica groups pre-compile EVERY admissible prefill bucket, and the
+    # decode step, BEFORE reporting ready: a post-ready first-touch
+    # compile stalls the whole group's collectives with every heartbeat
+    # silent, long enough to read as a hang, and boot (covered by the
+    # group-scaled boot grace, not the heartbeat) is the only place
+    # one-time work belongs. Both ranks run this identical warmup, so the
+    # compile-time collectives line up by construction. Single-process
+    # replicas keep the lazy first-call compile.
+    if group_size > 1 and role != "prefill":
         cap = min(eng.max_model_len,
                   (eng.cache.num_blocks - 1) * eng.block_size)
-        lens = [4]
-        if group_size > 1:
-            lens, prev = [], 0
-            for b in eng.prefill_buckets:
-                ln = min(b - 1, cap - 1)
-                if ln > prev:
-                    lens.append(ln)
-                prev = b
+        lens, prev = [], 0
+        for b in eng.prefill_buckets:
+            ln = min(b - 1, cap - 1)
+            if ln > prev:
+                lens.append(ln)
+            prev = b
         for k, ln in enumerate(lens):
             wid = eng.add_request(
                 np.zeros(ln, dtype=np.int64),

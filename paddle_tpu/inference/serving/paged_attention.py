@@ -306,14 +306,13 @@ class DecodeAttnState(_AttnState):
     """One layer's state inside the decode graph: every row of the batch
     writes ONE token at ``positions`` and attends over ``positions + 1``
     tokens. ``table`` is the kind's block table ([B, P] pages, or [B, R]
-    ring slots for a window kind). ``active`` (optional [B] bool) parks the
-    frozen rows' writes on the null block (fused decode windows)."""
+    ring slots for a window kind)."""
 
     def __init__(self, spec, block_size, positions, table, k_pool, v_pool,
-                 k_scale=None, v_scale=None, active=None, counters=None):
+                 k_scale=None, v_scale=None, counters=None):
         super().__init__(spec, block_size, k_pool, v_pool, k_scale, v_scale,
                          counters)
-        self.positions, self.table, self.active = positions, table, active
+        self.positions, self.table = positions, table
 
     def rope(self, x, cos_t, sin_t):
         """Rotate ``x [B, 1, H, D]`` at each row's own position; ``cos_t``/
@@ -340,11 +339,6 @@ class DecodeAttnState(_AttnState):
             page = page % tables.shape[1]
         blk = tables[jnp.arange(bsz), page]
         off = positions % bs
-        if self.active is not None:
-            # EOS-freeze: park frozen rows' writes on the null
-            # block (reserved, never allocated to a request)
-            blk = jnp.where(self.active, blk, 0)
-            off = jnp.where(self.active, off, 0)
         if self.quantized:
             qk, sk = quantize_kv_rows(ka)   # [B,1,Hkv,D]
             qv, sv = quantize_kv_rows(va)

@@ -678,14 +678,10 @@ class Scheduler:
             if victim is req:
                 return None
 
-    def ensure_decode_room(self, extra=0, extra_for=None):
+    def ensure_decode_room(self, extra=0):
         """Grow every running request that is about to write past its last
         block; ``extra`` reserves additional lookahead positions (the
-        speculative verify window writes ``k+1`` tokens at once).
-        ``extra_for`` — a ``Request -> int`` callable — overrides ``extra``
-        per request: fused decode windows (ISSUE 18) reserve
-        ``min(k, tokens_remaining) - 1`` positions so a request one token
-        from its budget cap never grows a block it will not write. On
+        speculative verify window writes ``k+1`` tokens at once). On
         exhaustion, evict the most-recently-admitted running request (free
         its blocks, re-queue at the FRONT) and retry — token-granularity
         eviction. Divergent-write targets that are shared get a private
@@ -701,14 +697,12 @@ class Scheduler:
                 # lookahead and write nothing here
                 self._room(req, None, req.num_tokens - 1, evicted)
                 continue
-            lookahead = int(extra_for(req) if extra_for is not None
-                            else extra)
             # the decode step writes ONE token at position len(tokens)-1
-            # (plus ``lookahead`` speculative positions), so capacity
-            # len(tokens)+lookahead is exactly enough — demanding more
+            # (plus ``extra`` speculative positions), so capacity
+            # len(tokens)+extra is exactly enough — demanding more
             # would evict needlessly when the pool is full at a boundary
             self._room(req, req.num_cached,
-                       req.num_tokens - 1 + lookahead, evicted)
+                       req.num_tokens - 1 + extra, evicted)
         return evicted
 
     def reserve_ahead(self, rows):

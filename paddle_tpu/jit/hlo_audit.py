@@ -106,9 +106,21 @@ def _strip_tail(line):
     return re.split(r",\s*(?:metadata|backend_config|sharding)=", line)[0]
 
 
+def _operand_names(operand_txt):
+    """The ``%name`` operands inside an instruction's own parentheses
+    (``operand_txt`` starts right after the opening one)."""
+    depth, end = 1, len(operand_txt)
+    for i, ch in enumerate(operand_txt):
+        depth += (ch == "(") - (ch == ")")
+        if depth == 0:
+            end = i
+            break
+    return re.findall(r"%([\w.\-]+)", operand_txt[:end])
+
+
 class _Instr:
     __slots__ = ("name", "opcode", "result_txt", "results", "operands",
-                 "line")
+                 "operand_names", "line", "is_root")
 
     def __init__(self, name, opcode, result_txt, line, operand_txt):
         self.name = name
@@ -118,7 +130,22 @@ class _Instr:
         # operand_txt starts right after the opcode's opening paren, so
         # operands[0] is the first REAL operand (never the result token)
         self.operands = _shape_tokens(_strip_tail(operand_txt))
+        self.operand_names = _operand_names(operand_txt)
         self.line = line
+        self.is_root = line.startswith("ROOT ")
+
+
+def _resolve_operands(instrs):
+    """jax 0.9 prints operands by name alone (``dot(%a.1, %b.1)``): an
+    instruction that carries no operand shape inline takes each operand's
+    from the instruction of that name in its own computation (the first
+    element of a tuple), so that a ``dot``'s contracted size, a
+    ``convolution``'s kernel and every operand read are known again."""
+    shapes = {ins.name: ins.results for ins in instrs}
+    for ins in instrs:
+        if not ins.operands:
+            ins.operands = [shapes[n][0] for n in ins.operand_names
+                            if shapes.get(n)]
 
 
 def _parse_computations(hlo_text):
@@ -135,6 +162,7 @@ def _parse_computations(hlo_text):
         if cur is None:
             continue
         if line == "}":
+            _resolve_operands(cur)
             cur = None
             continue
         mi = _INSTR_RE.match(line)
@@ -250,6 +278,23 @@ def _trip_count(ins, comps):
     return best
 
 
+def _updates_in_place(ins, comps):
+    """Whether a fusion's root updates one of the fused body's parameters
+    in place: a ``scatter`` or ``dynamic-update-slice`` whose operand 0 is
+    a parameter, so that the fusion's result is that operand's buffer and
+    only the updated region moves. XLA:CPU wraps a bare scatter so
+    (``wrapped_scatter``, a fusion of one instruction)."""
+    for cname in _CALLS_RE.findall(ins.line):
+        instrs = comps.get(cname, (False, []))[1]
+        params = {i.name for i in instrs if i.opcode == "parameter"}
+        for i2 in instrs:
+            if i2.is_root:
+                return (i2.opcode in ("scatter", "dynamic-update-slice")
+                        and bool(i2.operand_names)
+                        and i2.operand_names[0] in params)
+    return False
+
+
 def _fusion_cost(ins, comps):
     """A fusion's traffic: result write + each external operand read at
     the granularity the fused body touches it (an operand consumed only
@@ -287,12 +332,19 @@ def _fusion_cost(ins, comps):
                     # param updated in place: update-region traffic
                     region += 2 * (_nbytes(i2.operands[1])
                                    if len(i2.operands) > 1 else full)
+                elif (i2.opcode == "scatter"
+                      and i2.operand_names[:1] == [pname]):
+                    # scattered into in place: the rows the updates name
+                    region += 2 * (_nbytes(i2.operands[2])
+                                   if len(i2.operands) > 2 else full)
                 else:
                     sliced_only = False
                     break
             touched[pidx] = (min(full, region) if sliced_only and region
                              else full)
-    res = sum(_nbytes(t) for t in ins.results)
+    # a result that is an operand's own buffer is written with its region
+    res = (0 if _updates_in_place(ins, comps)
+           else sum(_nbytes(t) for t in ins.results))
     if touched:
         nb = float(res + sum(touched.values()))
     else:
@@ -341,6 +393,8 @@ def _dense_shapes(ins, comps, seen=frozenset()):
         return out
     if ins.opcode in _FREE - {"broadcast"} or ins.opcode in (
             "dynamic-slice", "dynamic-update-slice", "gather", "slice"):
+        return []
+    if ins.opcode == "fusion" and _updates_in_place(ins, comps):
         return []
     return list(ins.results)
 

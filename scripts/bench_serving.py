@@ -45,24 +45,15 @@ ISSUE 20 adds the integrity-sentinel overhead A/B:
            replica, so latency-tier TTFT p99 must stay within ~1.1x
            and outputs bit-exact vs the in-process greedy reference
 
-ISSUE 18 adds the device-resident decode A/B:
-
-  --workload decode_sync      decode-bound mix through three arms over
-           the same weights: per-step host sampling ([B, V] f32 logits
-           fetched per token) vs in-graph greedy sampling ([B] int32 per
-           step) vs fused k-step decode windows (one [B, k] fetch per k
-           tokens) — bit-exact greedy asserted, host syncs and fetch
-           bytes per token reported from ``LLMEngine.metrics()``
-
 The harness (``default_sizing`` / ``request_stream`` / ``run_naive`` /
 ``run_engine`` / ``run_shared_prefix_ab`` / ``run_chunked_ab`` /
-``run_spec_ab`` / ``run_decode_sync_ab``) is also imported by bench.py's
+``run_spec_ab``) is also imported by bench.py's
 ``serving`` workload and tests/test_serving.py's acceptance tests so the
 bench line, the probe and the test can never drift apart.
 
 Usage:
   python scripts/bench_serving.py [--workload poisson|shared-prefix|
-      chunked|spec|decode_sync] [--requests 16] [--rate 40]
+      chunked|spec] [--requests 16] [--rate 40]
       [--max-batch 4] [--seed 0] [--tiny]
 """
 
@@ -197,11 +188,8 @@ def run_engine(model, stream, engine=None, **engine_kwargs):
     eng.reset_metrics()
     eng.reset_block_high_water()
     try:
-        # in-graph engines decode through the fused window executable;
-        # host-sampling engines through the per-step decode graph — the
-        # zero-compiles-in-window acceptance tracks whichever one serves
-        jit_name = (eng._window_name if getattr(eng, "_in_graph", False)
-                    else eng._decode_name)
+        # the zero-compiles-in-window acceptance tracks the decode graph
+        jit_name = eng._decode_name
         row = cache_stats().get(jit_name) or {}
         compiles0 = row.get("compiles", 0)
         lat, rids = [], []
@@ -439,103 +427,6 @@ def run_shared_prefix_ab(tiny=True, seed=0, repeat=1):
     return out
 
 
-def decode_sync_sizing(tiny):
-    """(cfg, stream kwargs, engine kwargs, k) for the device-resident
-    decode A/B: a decode-bound mix — short prompts, long tails, every
-    arrival effectively immediate — so steady-state decode rounds
-    dominate and the host-sync structure is what the arms vary."""
-    from paddle_tpu.models import llama_small, llama_tiny
-
-    if tiny:  # CI / CPU smoke
-        cfg = llama_tiny()
-        stream = dict(n=12, rate=500.0, min_prompt=4, max_prompt=10,
-                      min_new=48, max_new=80)
-        engine = dict(num_blocks=160, block_size=8, max_batch_size=8,
-                      max_prefills_per_step=2)
-    else:
-        cfg = llama_small()
-        stream = dict(n=32, rate=300.0, min_prompt=8, max_prompt=32,
-                      min_new=64, max_new=128)
-        engine = dict(num_blocks=512, block_size=16, max_batch_size=8,
-                      max_prefills_per_step=2)
-    return cfg, stream, engine, 8
-
-
-def run_decode_sync_ab(tiny=True, seed=0, repeat=1, k=None):
-    """Device-resident decode A/B (ISSUE 18): ONE seeded decode-bound
-    stream through three engine arms over the same weights —
-
-      host_sampling  per-step host path: every decode step fetches the
-                     full [B, V] f32 logits and argmaxes on the host
-      in_graph       in-graph greedy sampling: the decode graph returns
-                     [B] int32 tokens, same one-step cadence
-      window         fused k-step decode windows: one [B, k] int32 fetch
-                     per k decode iterations (decode_steps_per_sync=k)
-
-    Greedy outputs must be bit-exact across arms (asserted by callers via
-    ``bit_exact``); the win is decode-bound tokens/s, explained by the
-    engine-owned ``serving_host_syncs_total`` /
-    ``serving_decode_fetch_bytes_total`` telemetry. ``repeat`` replays
-    the window N times per arm and reports each arm's best-throughput
-    run (min-of-N against transient host load)."""
-    import paddle_tpu as paddle
-    from paddle_tpu.models import LlamaForCausalLM
-
-    cfg, stream_kwargs, engine_kwargs, k_default = decode_sync_sizing(tiny)
-    k = int(k) if k is not None else k_default
-    paddle.seed(seed)
-    np.random.seed(seed)
-    model = LlamaForCausalLM(cfg)
-    model.eval()
-    stream = request_stream(cfg, seed=seed, **stream_kwargs)
-    warm = request_stream(cfg, seed=seed + 1, **stream_kwargs)
-    arms = (("host_sampling", dict(capture_logits=True)),
-            ("in_graph", dict(in_graph_sampling=True)),
-            ("window", dict(decode_steps_per_sync=k)))
-    engines = {}
-    runs = {name: [] for name, _ in arms}
-    try:
-        for name, extra in arms:
-            engines[name] = _warm_engine(model, warm, **engine_kwargs,
-                                         **extra)
-        for _ in range(max(int(repeat), 1)):
-            for name, _ in arms:
-                runs[name].append(
-                    run_engine(model, stream, engine=engines[name]))
-    finally:
-        for eng in engines.values():
-            eng.close()
-    res = {name: max(rs, key=lambda r: r["tokens_per_sec"])
-           for name, rs in runs.items()}
-    bit_exact = all(
-        _bit_exact(runs["host_sampling"][0]["outputs"], r["outputs"])
-        for rs in runs.values() for r in rs)
-    gen_tokens = res["host_sampling"]["gen_tokens"]
-
-    def _per_token(r):
-        return dict(r, host_syncs_per_token=round(
-            r["host_syncs"] / max(gen_tokens, 1), 3),
-            fetch_bytes_per_token=round(
-                r["decode_fetch_bytes"] / max(gen_tokens, 1), 1))
-
-    out = dict(
-        {name: {kk: v for kk, v in _per_token(res[name]).items()
-                if kk != "outputs"} for name in res},
-        speedup=round(res["window"]["tokens_per_sec"]
-                      / res["host_sampling"]["tokens_per_sec"], 3),
-        in_graph_speedup=round(res["in_graph"]["tokens_per_sec"]
-                               / res["host_sampling"]["tokens_per_sec"],
-                               3),
-        sync_reduction=round(res["host_sampling"]["host_syncs"]
-                             / max(res["window"]["host_syncs"], 1), 2),
-        window_k=k,
-        repeats=max(int(repeat), 1),
-        bit_exact=bool(bit_exact),
-        num_requests=len(stream),
-    )
-    return out
-
-
 def chunked_sizing(tiny):
     from paddle_tpu.models import llama_small, llama_tiny
 
@@ -649,16 +540,9 @@ def run_spec_ab(tiny=True, seed=0, spec_tokens=3, draft="self"):
     be bit-exact — speculation changes WHEN tokens are produced, never
     WHICH. ``draft='self'`` uses the target model as its own draft
     (accept ratio 1.0 — the machinery's upper bound; a production draft
-    is a distilled smaller llama, which only changes the ratio).
-
-    ISSUE 16 adds a third arm: the SAME speculative engine with the
-    fused ragged catch-up disabled (``fuse_draft_catchup=False`` — the
-    pre-16 per-token dispatch loop). Its outputs and acceptance counts
-    must be bit-identical to the fused arm (``fused_bit_exact``);
-    ``catchup_fused_speedup`` is fused/unfused tokens/s. With
-    ``draft='self'`` every proposal is accepted and the catch-up window
-    stays at one token, so the speedup only shows with a real
-    (divergent) draft — ``draft='tiny'``."""
+    is a distilled smaller llama, which only changes the ratio; with
+    ``draft='tiny'`` a one-layer draft diverges, so the fused ragged
+    catch-up of ISSUE 16 runs)."""
     import paddle_tpu as paddle
     from paddle_tpu.models import LlamaForCausalLM
 
@@ -679,36 +563,21 @@ def run_spec_ab(tiny=True, seed=0, spec_tokens=3, draft="self"):
     stream = request_stream(cfg, seed=seed, **stream_kwargs)
     warm = request_stream(cfg, seed=seed + 1, **stream_kwargs)
     res = {}
-    for arm, dm, fused in (("plain", None, True),
-                           ("spec", draft_model, True),
-                           ("spec_unfused", draft_model, False)):
+    for arm, dm in (("plain", None), ("spec", draft_model)):
         kw = dict(engine_kwargs)
         if dm is not None:
-            kw.update(draft_model=dm, spec_tokens=spec_tokens,
-                      fuse_draft_catchup=fused)
+            kw.update(draft_model=dm, spec_tokens=spec_tokens)
         eng = _warm_engine(model, warm, **kw)
         try:
             res[arm] = run_engine(model, stream, engine=eng)
         finally:
             eng.close()
     bit_exact = _bit_exact(res["plain"]["outputs"], res["spec"]["outputs"])
-    # the fused catch-up must change WHEN draft rows are written, never
-    # WHAT: identical outputs AND identical acceptance behaviour
-    fused_bit_exact = (
-        _bit_exact(res["spec"]["outputs"], res["spec_unfused"]["outputs"])
-        and res["spec"]["spec_accept_ratio"]
-        == res["spec_unfused"]["spec_accept_ratio"])
     return dict(
         plain={k: v for k, v in res["plain"].items() if k != "outputs"},
         spec={k: v for k, v in res["spec"].items() if k != "outputs"},
-        spec_unfused={k: v for k, v in res["spec_unfused"].items()
-                      if k != "outputs"},
         speedup=round(res["spec"]["tokens_per_sec"]
                       / res["plain"]["tokens_per_sec"], 3),
-        catchup_fused_speedup=round(
-            res["spec"]["tokens_per_sec"]
-            / max(res["spec_unfused"]["tokens_per_sec"], 1e-9), 3),
-        fused_bit_exact=bool(fused_bit_exact),
         spec_accept_ratio=res["spec"]["spec_accept_ratio"],
         spec_tokens=spec_tokens,
         draft=draft,
@@ -1687,7 +1556,7 @@ def main():
     ap.add_argument("--workload", default="poisson",
                     choices=["poisson", "shared-prefix", "chunked", "spec",
                              "fleet", "quantized", "disagg", "tiering",
-                             "qos", "decode_sync", "tpfleet", "audit"])
+                             "qos", "tpfleet", "audit"])
     ap.add_argument("--requests", type=int, default=None)
     ap.add_argument("--rate", type=float, default=None)
     ap.add_argument("--max-batch", type=int, default=None)
@@ -1730,9 +1599,6 @@ def main():
         print(json.dumps(res, indent=2))
         if not res["bit_exact"]:
             sys.exit("FAIL: speculative arm diverges from plain greedy")
-        if not res["fused_bit_exact"]:
-            sys.exit("FAIL: fused draft catch-up diverges from the "
-                     "sequential catch-up loop")
         return
     if args.workload == "tiering":
         res = run_tiering_ab(tiny=tiny, seed=args.seed)
@@ -1772,16 +1638,6 @@ def main():
         if not res["bit_exact"]:
             sys.exit("FAIL: disaggregated fleet outputs diverge from the "
                      "in-process engine greedy reference")
-        return
-    if args.workload == "decode_sync":
-        res = run_decode_sync_ab(tiny=tiny, seed=args.seed, repeat=2)
-        print(json.dumps(res, indent=2))
-        if not res["bit_exact"]:
-            sys.exit("FAIL: in-graph/window arms diverge from per-step "
-                     "host-sampling greedy")
-        if res["window"]["decode_compiles_in_window"]:
-            sys.exit("FAIL: window graph recompiled inside the timed "
-                     "window")
         return
     if args.workload == "qos":
         res = run_qos_ab(tiny=tiny, seed=args.seed)

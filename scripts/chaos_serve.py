@@ -136,26 +136,11 @@ ENGINE_KW = dict(num_blocks=64, block_size=8, max_batch_size=4,
                  max_prefills_per_step=2)
 
 
-def _window_k():
-    return int(ENGINE_KW.get("decode_steps_per_sync", 1))
-
-
-def _hang_after_steps():
-    """Busy-tick count before the armed replica wedges. Calibrated in
-    TOKENS (12) for one-token engine steps; a fused decode window emits
-    k tokens per step, so the trigger scales down to keep the wedge
-    landing mid-burst instead of after the work is done."""
-    return max(3, 12 // _window_k())
-
-
-def _hang_timeout_s():
-    """Watchdog staleness bound. Calibrated (3s) for one-token engine
-    steps; a k-step fused window multiplies the legitimate worst-case
-    gap between heartbeats — both the serve-loop beat cadence and the
-    one-time window compile — so the bound scales with k. On a one-core
-    runner an unscaled bound cascades: one hang verdict respawns a
-    replica whose re-warmup starves the others past the bound in turn."""
-    return 3.0 * _window_k()
+#: busy ticks before the armed replica wedges: mid-burst, not after the
+#: work is done
+HANG_AFTER_STEPS = 12
+#: the watchdog's staleness bound
+HANG_TIMEOUT_S = 3.0
 
 
 def check(cond, msg):
@@ -322,8 +307,8 @@ def drill_kill(out, model, n, hang_too=True):
     if arm_hang:
         env = {"CHAOS_SERVE_SITE": "serve.replica_hang",
                "CHAOS_SERVE_REPLICA": str(n - 1),
-               "CHAOS_SERVE_AFTER_STEPS": str(_hang_after_steps())}
-    fleet = _fleet(out, n, hang_timeout_s=_hang_timeout_s(), env_extra=env)
+               "CHAOS_SERVE_AFTER_STEPS": str(HANG_AFTER_STEPS)}
+    fleet = _fleet(out, n, hang_timeout_s=HANG_TIMEOUT_S, env_extra=env)
     try:
         victim = {}
 
@@ -395,8 +380,8 @@ def drill_hang(out, model, n):
     baseline = baseline_outputs(model, stream)
     env = {"CHAOS_SERVE_SITE": "serve.replica_hang",
            "CHAOS_SERVE_REPLICA": str(n - 1),
-           "CHAOS_SERVE_AFTER_STEPS": str(_hang_after_steps())}
-    fleet = _fleet(out, n, hang_timeout_s=_hang_timeout_s(), env_extra=env)
+           "CHAOS_SERVE_AFTER_STEPS": str(HANG_AFTER_STEPS)}
+    fleet = _fleet(out, n, hang_timeout_s=HANG_TIMEOUT_S, env_extra=env)
     try:
         gids, shed, wall = run_burst(fleet, stream)
         wait_all_ready(fleet)
@@ -536,7 +521,7 @@ def drill_quant(out, model, n):
         1, model=model_q)
     stream = request_stream(_cfg(model_q))
     baseline = baseline_outputs(model_q, stream, engine_kw=engine_kw)
-    fleet = _fleet(out, n, engine_kw=engine_kw, hang_timeout_s=_hang_timeout_s())
+    fleet = _fleet(out, n, engine_kw=engine_kw, hang_timeout_s=HANG_TIMEOUT_S)
     try:
         victim = {}
 
@@ -598,7 +583,7 @@ def drill_disagg(out, model, n):
                {"site": "serve.replica_hang", "replica": total - 1,
                 "after": 12},
            ])}
-    fleet = _fleet(out, total, roles=roles, hang_timeout_s=_hang_timeout_s(),
+    fleet = _fleet(out, total, roles=roles, hang_timeout_s=HANG_TIMEOUT_S,
                    env_extra=env)
     try:
         gids, shed, wall = run_burst(fleet, stream)
@@ -1044,11 +1029,11 @@ def drill_tpgroup(out, model, n):
     baseline = baseline_outputs(model, stream)
     env = {"CHAOS_SERVE_SITES": json.dumps([
         {"site": "serve.group_member_crash", "replica": 0, "rank": 1,
-         "after": _hang_after_steps()},
+         "after": HANG_AFTER_STEPS},
         {"site": "serve.group_member_hang", "replica": 1, "rank": 1,
-         "after": _hang_after_steps()},
+         "after": HANG_AFTER_STEPS},
     ])}
-    fleet = _fleet(out, n, hang_timeout_s=_hang_timeout_s(),
+    fleet = _fleet(out, n, hang_timeout_s=HANG_TIMEOUT_S,
                    env_extra=env, group_size=2,
                    plan={"axes": {"tp": 2}, "strategies": ["tp"]})
     try:
@@ -1305,16 +1290,8 @@ def main(argv=None):
                              "disagg", "warmstore", "qos", "tpgroup",
                              "sdc", "all"])
     ap.add_argument("--fleet", type=int, default=3)
-    ap.add_argument("--decode-window", type=int, default=1,
-                    help="decode_steps_per_sync for every engine (baseline "
-                    "AND fleet replicas): >1 proves redispatch replay is "
-                    "window-agnostic (ISSUE 18)")
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
-    if args.decode_window > 1:
-        # threaded through the ONE shared kwargs dict so the single-engine
-        # baseline and the replicas stay the same engine configuration
-        ENGINE_KW["decode_steps_per_sync"] = args.decode_window
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     out_root = args.out or tempfile.mkdtemp(prefix="chaos_serve.")
     print(f"[chaos] serving fleet drill, scratch: {out_root}, "

@@ -5,7 +5,7 @@ tokens to k+1 on the device.
 The synchronous engine these cases compare with is the SAME class with the
 one decision overridden (``Synchronous._dispatch_ahead``): what is left
 then is the path the engine takes by itself beside a sampled row, under
-pool pressure, on a draft model or a fused window. Every case plays one
+pool pressure or on a draft model. Every case plays one
 script of submissions and events, call by call, on both, and wants the
 same tokens a request, the same reasons, the same rows of logits."""
 
@@ -287,6 +287,48 @@ def _rings_cancel(net):
     return MIMO, reqs, {7: lambda eng, rids, out: eng.cancel(rids[0])}
 
 
+def _mimo_mixed(net):
+    """``_mixed`` on the second model: rows finish by length and requests
+    join beside a step in flight while the rings of the others turn."""
+    ps = prompts_of((5, 21, 38, 12, 9, 17), seed=14)
+    reqs = [(p, dict(max_new_tokens=n), 0)
+            for p, n in zip(ps, (14, 1, 6, 11, 9, 2))]
+    return dict(MIMO, max_prefills_per_step=1), reqs, None
+
+
+def _mimo_eos(net):
+    """``_eos`` on the second model: the discarded row had turned its ring
+    and sent a page back before its request was found gone."""
+    ps = prompts_of((14, 21, 9), seed=15)
+    plain = play(Synchronous, net, MIMO,
+                 [(p, dict(max_new_tokens=16), 0) for p in ps])
+    reqs = [(p, dict(max_new_tokens=16, eos_token_id=plain.tokens[k][5 + k]), 0)
+            for k, p in enumerate(ps)]
+    return MIMO, reqs, None
+
+
+def _mimo_deadline(net):
+    ps = prompts_of((14, 21, 9), seed=16)
+    reqs = [(p, dict(max_new_tokens=16), 0) for p in ps]
+
+    def expire(eng, rids, out):
+        eng.request(rids[0]).deadline = time.time() - 1.0
+
+    return MIMO, reqs, {7: expire}
+
+
+def _mimo_chunked_join(net):
+    """``_chunked_join`` on the second model: a chunk turns the joining
+    request's ring in prefill between a step in flight and the next."""
+    ps = prompts_of((5, 7, 38, 25), seed=17)
+    reqs = [(ps[0], dict(max_new_tokens=20), 0),
+            (ps[1], dict(max_new_tokens=18), 0),
+            (ps[2], dict(max_new_tokens=6), 3),
+            (ps[3], dict(max_new_tokens=5), 4)]
+    return dict(MIMO, max_prefill_tokens_per_step=8,
+                max_prefills_per_step=1), reqs, None
+
+
 CASES = {"mixed-finish-lengths": (llama, _mixed),
          "all-at-once": (llama, _at_once),
          "eos-in-flight": (llama, _eos),
@@ -298,13 +340,18 @@ CASES = {"mixed-finish-lengths": (llama, _mixed),
          "int8-pools": (llama, _int8),
          "tier-revival": (llama, _tier),
          "rings-turn": (mimo, _rings),
-         "rings-turn-cancel": (mimo, _rings_cancel)}
+         "rings-turn-cancel": (mimo, _rings_cancel),
+         "mimo-mixed-finish-lengths": (mimo, _mimo_mixed),
+         "mimo-eos-in-flight": (mimo, _mimo_eos),
+         "mimo-deadline-in-flight": (mimo, _mimo_deadline),
+         "mimo-chunked-prefill-joins": (mimo, _mimo_chunked_join)}
 
 
 #: the cases in which no request joins beside a step in flight (all are
 #: admitted by the first call): there the CALLS are the same, token for token
 ALIGNED = {"all-at-once", "eos-in-flight", "cancel-in-flight",
-           "deadline-in-flight", "int8-pools", "rings-turn-cancel"}
+           "deadline-in-flight", "int8-pools", "rings-turn-cancel",
+           "mimo-eos-in-flight", "mimo-deadline-in-flight"}
 
 
 @pytest.fixture(scope="module")
@@ -406,6 +453,27 @@ def test_what_each_case_is_there_for(played):
     assert a.reasons[0] == "cancelled" and a.metrics["decode_rows_discarded"] == 1
     assert a.metrics["window_blocks_in_use"] == 0
 
+    # the second model's own cases: what the first model's show, with rings
+    # that turned on both engines alike and every page back at the end
+    for case in CASES:
+        if case.startswith("mimo-"):
+            a, s = played(case)
+            assert a.metrics["window_blocks_released"] == \
+                s.metrics["window_blocks_released"] > 0
+            assert a.metrics["global_blocks_in_use"] == \
+                a.metrics["window_blocks_in_use"] == 0
+    a, s = played("mimo-mixed-finish-lengths")
+    assert a.metrics["decode_steps_sync_by_reason"] == {"idle": 1}
+    assert a.metrics["decode_rows_discarded"] == 0
+    a, s = played("mimo-eos-in-flight")
+    assert set(a.reasons.values()) == {"eos"}
+    assert 1 <= a.metrics["decode_rows_discarded"] <= 3
+    assert a.cached == s.cached
+    a, s = played("mimo-deadline-in-flight")
+    assert a.reasons[0] == "timeout" and a.metrics["decode_rows_discarded"] == 1
+    a, s = played("mimo-chunked-prefill-joins")
+    assert a.metrics["prefill_chunks"] == s.metrics["prefill_chunks"] > 6
+
 
 def test_the_second_models_tokens_are_the_float32_references():
     """Against code that shares nothing with the engine: each token the
@@ -444,11 +512,10 @@ def test_greedy_rows_beside_a_sampled_row_take_the_synchronous_path():
     assert alone.metrics["decode_steps_sync_by_reason"] == {"idle": 1}
 
 
-@pytest.mark.parametrize("path", ["speculative", "window", "prefill-only"])
+@pytest.mark.parametrize("path", ["speculative", "prefill-only"])
 def test_the_other_decode_paths_are_not_touched(path):
     net = llama()
     kw = {"speculative": dict(draft_model=net, spec_tokens=2),
-          "window": dict(decode_steps_per_sync=4),
           "prefill-only": dict(prefill_only=True)}[path]
     ps = prompts_of((5, 11), seed=13)
     with LLMEngine(net, **LLAMA, **kw) as eng:

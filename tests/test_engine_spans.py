@@ -30,7 +30,6 @@ PER_STEP = [DECODE_PHASES[:2] + DECODE_PHASES, DECODE_PHASES,
             DECODE_PHASES[:1] + DECODE_PHASES[2:]]
 PATHS = {
     "per-step": {},
-    "window": {"decode_steps_per_sync": 4},
     "speculative": {"spec_tokens": 3},   # draft_model: the model itself
 }
 
@@ -110,11 +109,9 @@ def test_every_step_is_cut_into_the_same_phases(model, tracer, path):
         if path == "speculative":
             assert decode == ["engine.decode.prepare", "engine.decode.draft",
                               *DECODE_PHASES]
-        elif path == "per-step":
+        else:
             assert decode in PER_STEP
             shapes.append(PER_STEP.index(decode))
-        else:
-            assert decode == DECODE_PHASES
         prefills += names.count("engine.prefill")
         assert set(names) <= {"engine.admit", "engine.prefill",
                               "engine.bookkeeping", "engine.decode.draft",

@@ -443,9 +443,8 @@ def test_export_refuses_a_cache_that_is_not_uniform():
 
 
 @pytest.mark.parametrize("kwargs", [
-    dict(decode_steps_per_sync=4), dict(in_graph_sampling=True),
     dict(draft_model="a-llama"), dict(plan="a-plan")],
-    ids=["decode-window", "in-graph-sampling", "draft-verify", "plan"])
+    ids=["draft-verify", "plan"])
 def test_the_llama_only_paths_refuse_another_model_by_name(kwargs):
     net = build()
     if "draft_model" in kwargs:

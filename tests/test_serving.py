@@ -582,6 +582,24 @@ class TestEngine:
                     == compiles)
 
 
+def test_the_readme_tables_every_engine_option():
+    """README "`LLMEngine` options" against the constructor's signature:
+    the same keywords in the same order, each with its default."""
+    import inspect
+    import re
+
+    with open(os.path.join(REPO, "README.md")) as f:
+        section = f.read().split("### `LLMEngine` options", 1)[1]
+    section = section.split("\n### ", 1)[0]
+    table = [(m.group(1), m.group(2)) for m in re.finditer(
+        r"^\| `(\w+)` \| `([^`]*)` \| \S.*\|$", section, re.M)]
+    options = [(name, repr(p.default)) for name, p in inspect.signature(
+        LLMEngine.__init__).parameters.items()
+        if p.kind is inspect.Parameter.KEYWORD_ONLY]
+    assert table == options
+    assert len(options) == 20
+
+
 # ---------------------------------------------------------------------------
 # create_predictor wiring
 # ---------------------------------------------------------------------------
@@ -1684,25 +1702,6 @@ class TestChaosServeDrill:
             [_sys.executable, os.path.join(REPO, "scripts",
                                            "chaos_serve.py"),
              "--drill", "shed", "--fleet", "2", "--out", str(tmp_path)],
-            env=_chaos_env(), cwd=REPO, capture_output=True, text=True,
-            timeout=560)
-        assert r.returncode == 0, (r.stdout[-3000:], r.stderr[-2000:])
-        assert "SERVE DRILL PASSED" in r.stdout
-
-    def test_drill_kill_windowed(self, tmp_path):
-        """ISSUE 18: the kill storm with fused decode windows (k=4) on
-        every engine — baseline AND replicas — proves redispatch replay
-        is window-agnostic: the router replays prompt + already-emitted
-        tokens on a survivor and the windowed engine reproduces the
-        bit-identical continuation."""
-        import subprocess
-        import sys as _sys
-
-        r = subprocess.run(
-            [_sys.executable, os.path.join(REPO, "scripts",
-                                           "chaos_serve.py"),
-             "--drill", "kill", "--fleet", "3", "--decode-window", "4",
-             "--out", str(tmp_path)],
             env=_chaos_env(), cwd=REPO, capture_output=True, text=True,
             timeout=560)
         assert r.returncode == 0, (r.stdout[-3000:], r.stderr[-2000:])
