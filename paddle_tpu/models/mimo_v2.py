@@ -58,7 +58,7 @@ __all__ = ["MiMoV2Config", "MiMoV2ForCausalLM", "moe_dropless",
 
 #: device-side counters an expert layer adds to, per call
 SERVE_COUNTERS = ("moe_pairs_routed_here", "moe_experts_hit",
-                  "moe_layer_steps")
+                  "moe_layer_steps", "moe_weight_passes")
 
 
 @dataclasses.dataclass
@@ -125,15 +125,24 @@ def _store_width(k_dim):
 
 
 def moe_dropless(x, router_w, bias, experts, held_slot, *, top_k,
-                 norm_topk=True, scaling=None, tm=None):
+                 norm_topk=True, scaling=None, tm=None, with_passes=False):
     """The expert block on arrays. ``x`` [T, D]; ``router_w`` [D, E] and
     ``bias`` [E] float32; ``experts``: one ``(gate [D, F], up [D, F], down
     [F, D])`` a held expert; ``held_slot`` int32 [E]: an expert's place in
     ``experts``, ``len(experts)`` if it is not held here. Returns ``(y
     [T, D], routed (token, held expert) pairs, held experts with a
-    token)``."""
+    token)``, and with ``with_passes`` a fourth: how many times an expert's
+    three matrices were streamed.
+
+    Between "rows sorted by expert" and "weighted rows added into the
+    output" runs the grouped kernel (``ops/pallas/grouped_ffn.py``) on the
+    chip, and under ``PT_PALLAS_INTERPRET=1`` at widths it takes; the tile
+    loop is the CPU's path. ``tm`` is the tests' hook: the rows a tile or a
+    pass holds."""
     import jax
     import jax.numpy as jnp
+
+    from ..ops.pallas.grouped_ffn import use_pallas_grouped_ffn
 
     t, d = x.shape
     n_held = len(experts)
@@ -148,12 +157,29 @@ def moe_dropless(x, router_w, bias, experts, held_slot, *, top_k,
         w = w * scaling
     slot = jnp.asarray(held_slot)[sel].reshape(-1)            # [T * k]
     order = jnp.argsort(slot, stable=True)
-    s_tok = (jnp.arange(t * top_k, dtype=jnp.int32) // top_k)[order]
-    s_w = w.reshape(-1)[order]
     sizes = jnp.bincount(slot, length=n_held + 1)[:n_held].astype(jnp.int32)
     starts = jnp.cumsum(sizes) - sizes
+    rows = (_grouped_rows if use_pallas_grouped_ffn(d, experts[0][0].shape[1])
+            else _tile_loop)
+    out, passes = rows(x, experts, slot, w, order, sizes, starts, tm)
+    res = (out.astype(x.dtype), jnp.sum(sizes),
+           jnp.sum((sizes > 0).astype(jnp.int32)))
+    return res + (passes,) if with_passes else res
+
+
+def _tile_loop(x, experts, slot, w, order, sizes, starts, tm):
+    """The sorted rows cut into tiles of ``tm`` that each belong to one
+    expert, a ``while_loop`` over the tiles that exist: the CPU's path.
+    Returns (the weighted sum a token [T, D] float32, tiles run)."""
+    import jax
+    import jax.numpy as jnp
+
+    t, d = x.shape
+    n_pairs = order.shape[0]
+    s_tok = (order // (n_pairs // t)).astype(jnp.int32)
+    s_w = w.reshape(-1)[order]
     if tm is None:
-        tm = 128 if t * top_k >= 128 else 8
+        tm = 128 if n_pairs >= 128 else 8
     tiles = (sizes + tm - 1) // tm
     tile_end = jnp.cumsum(tiles)
 
@@ -172,7 +198,7 @@ def moe_dropless(x, router_w, bias, experts, held_slot, *, top_k,
         e = jnp.searchsorted(tile_end, i, side="right").astype(jnp.int32)
         r = (i - (tile_end[e] - tiles[e])) * tm + jnp.arange(tm, dtype=jnp.int32)
         valid = r < sizes[e]
-        rows = jnp.clip(starts[e] + r, 0, t * top_k - 1)
+        rows = jnp.clip(starts[e] + r, 0, n_pairs - 1)
         tok = jnp.where(valid, s_tok[rows], 0)
         wt = jnp.where(valid, s_w[rows], 0.0)
         y = jax.lax.switch(e, branches, x[tok])               # [tm, D]
@@ -181,8 +207,42 @@ def moe_dropless(x, router_w, bias, experts, held_slot, *, top_k,
     _, out = jax.lax.while_loop(
         lambda c: c[0] < tile_end[-1], body,
         (jnp.int32(0), jnp.zeros((t, d), jnp.float32)))
-    return (out.astype(x.dtype), jnp.sum(sizes),
-            jnp.sum((sizes > 0).astype(jnp.int32)))
+    return out, tile_end[-1]
+
+
+def _grouped_rows(x, experts, slot, w, order, sizes, starts, tm):
+    """The sorted pairs through ``grouped_swiglu``: one call, which fetches
+    the rows of the pairs routed here, streams each hit expert's weights
+    once for every ``rows`` of its pairs, and puts each pair's result in the
+    pair's own row; then one weighted sum of a token's rows, in the order of
+    its choice. The rows a pass holds follow from ``T`` (``rows_for``).
+    Returns (the sum [T, D] float32, passes made)."""
+    import jax.numpy as jnp
+
+    from ..ops.pallas.grouped_ffn import grouped_swiglu, rows_for
+
+    t, d = x.shape
+    n_held = len(experts)
+    n_pairs = slot.shape[0]
+    top_k = n_pairs // t
+    rows = tm or rows_for(t)
+    # the items: an expert's passes follow one another
+    passes = (sizes + rows - 1) // rows
+    item_end = jnp.cumsum(passes)
+    i = jnp.arange(n_held + n_pairs // rows, dtype=jnp.int32)
+    e = jnp.minimum(jnp.searchsorted(item_end, i, side="right"), n_held - 1)
+    done = (i - (item_end[e] - passes[e])) * rows    # of e's pairs, before i
+    live = jnp.where(i < item_end[-1], jnp.clip(sizes[e] - done, 0, rows), 0)
+    y = grouped_swiglu(x, order, e, starts[e] + done, live, item_end[-1],
+                       experts, rows=rows, top_k=top_k)
+    # a pair of no held expert has no row of y: whatever lies there is
+    # selected away, not multiplied. y comes in lane blocks, [T x k, D /
+    # 128, 128]: summed as it lies (one pass over it), only the [T, D] sum
+    # is laid out anew
+    y = y.reshape(t, top_k, d // 128, 128)
+    held = (slot < n_held).reshape(t, top_k, 1, 1)
+    out = jnp.sum(jnp.where(held, y * w[:, :, None, None], 0.0), axis=1)
+    return out.reshape(t, d), item_end[-1]
 
 
 class MiMoV2Router(Layer):
@@ -219,7 +279,7 @@ class MiMoV2MoE(Layer):
 
     def forward_arrays(self, x, tm=None):
         """``x`` [T, D] array -> (this chip's part of the block's output,
-        routed pairs, experts hit)."""
+        routed pairs, experts hit, weight passes)."""
         c = self.config
         return moe_dropless(
             x, self.router.weight._data,
@@ -228,11 +288,11 @@ class MiMoV2MoE(Layer):
               e.down_proj.weight._data) for e in self.experts],
             self._held_slot,
             top_k=c.num_experts_per_tok, norm_topk=c.norm_topk_prob,
-            scaling=c.routed_scaling_factor, tm=tm)
+            scaling=c.routed_scaling_factor, tm=tm, with_passes=True)
 
     def forward(self, x):
         shape = x.shape
-        y, _, _ = self.forward_arrays(x._data.reshape(-1, shape[-1]))
+        y = self.forward_arrays(x._data.reshape(-1, shape[-1]))[0]
         return Tensor._wrap(y.reshape(shape))
 
 
@@ -401,11 +461,12 @@ class MiMoV2ForCausalLM(Layer):
         if not layer.is_moe:
             return x + layer.mlp(h)
         shape = h.shape
-        y, pairs, hit = layer.mlp.forward_arrays(
+        y, pairs, hit, passes = layer.mlp.forward_arrays(
             h._data.reshape(-1, shape[-1]))
         state.count("moe_pairs_routed_here", pairs)
         state.count("moe_experts_hit", hit)
         state.count("moe_layer_steps", 1)
+        state.count("moe_weight_passes", passes)
         return x + Tensor._wrap(y.reshape(shape))
 
     def serve_norm(self, x):

@@ -7,9 +7,11 @@ warm-up, a full batch), and keeps every ``LLMEngine.metrics()`` the runner
 takes: the last two are the measured window's ends. Prints one JSON object:
 the window's tokens/s and decode-only step p50 on the host's clock, the
 engage share ``ahead / (ahead + synchronous)`` of the window's decode steps
-with the synchronous ones by reason and the rows discarded, the window's
-seconds by kind of call, and with
+with the synchronous ones by reason and the rows discarded, for an expert
+model the experts hit over the weight passes made (decode steps and
+prefill chunks apart), the window's seconds by kind of call, and with
 ``--trace 1`` the device's busy time in ``decode_pure`` a traced decode step
+(and the grouped expert kernel's part of it), the largest device operations
 and the device's idle share. Run on the chip:
 
     python3 scripts/decode_ahead_microbench.py \
@@ -40,6 +42,15 @@ def window_counts(snaps):
                              if v - r0.get(k, 0)}
     steps = out["decode_steps_ahead"] + out["decode_steps_sync"]
     out["engage_share"] = out["decode_steps_ahead"] / steps if steps else None
+    # an expert model's grouped kernel (ISSUE 30): of the times an expert's
+    # weights were streamed, the share that was that expert's only read
+    # that layer-step
+    for kind in ("decode", "prefill"):
+        hit, passes = (m1.get(f"moe_{k}_{kind}", 0) - m0.get(f"moe_{k}_{kind}", 0)
+                       for k in ("experts_hit", "weight_passes"))
+        if passes:
+            out[f"moe_{kind}"] = {"experts_hit": hit, "weight_passes": passes,
+                                  "single_read_share": hit / passes}
     return out
 
 
@@ -102,8 +113,17 @@ def main(argv=None):
             "decode_step_ms_p50": stats.percentile(
                 [(s[1] - s[0]) * 1e3 for s in run["traced_steps"]
                  if s[4] and not s[3]], 50),
+            "grouped_ffn_device_ms_a_step": 1e3 * trace_reduce.op_seconds(
+                tr["events"], "moe_grouped_swiglu", "decode_pure") / steps,
             "idle_share": 100.0 * (1.0 - tr["busy_s"] / tr["window_s"]),
             "idle_gaps": tr["breakdown"]["idle_gaps"],
+            "device_ops": trace_reduce.top_ops(tr["events"], 16),
+            # seconds of the traced window by program
+            "device_s_by_program": {
+                prog: trace_reduce.busy_seconds(
+                    trace_reduce.select(tr["events"], None, prog))
+                for prog in ("decode_pure", "chunk_pure")},
+            "window_s": tr["window_s"],
         }
         # the benchmark's own per-layer readings of this run
         out["per_layer"] = {k: v["value"] for k, v in bench.result_line(

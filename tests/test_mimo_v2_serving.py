@@ -241,20 +241,24 @@ def _share(net, x, held, layer=1, bias=None, **kw):
         [experts[e] for e in held], slot, top_k=c.num_experts_per_tok, **kw)
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
+@pytest.mark.parametrize("path,width", [("loop", 64), ("kernel", 128)])
+def test_the_shares_add_up_to_the_uncut_layer(path, width, monkeypatch):
     """model-configs section 4: the parts that the four shares of eight
-    experts give add up to what the uncut reference gives for the layer."""
-    net = build()
-    x = jnp.asarray(np.random.default_rng(1).normal(size=(37, 64)), jnp.float32)
+    experts give add up to what the uncut reference gives for the layer,
+    through the tile loop and through the grouped kernel (interpret mode, at
+    a width it takes)."""
+    monkeypatch.setenv("PT_PALLAS_INTERPRET", "1" if path == "kernel" else "0")
+    net = build(hidden_size=width, moe_intermediate_size=2 * width)
+    x = jnp.asarray(np.random.default_rng(1).normal(size=(37, width)),
+                    jnp.float32)
     w = {k[len("model.layers.1."):]: v for k, v in weights_of(net).items()
          if k.startswith("model.layers.1.")}
-    w = dict(w, **{"post_attention_layernorm.weight": jnp.ones(64)})
+    w = dict(w, **{"post_attention_layernorm.weight": jnp.ones(width)})
     full, _ = ref._expert_ffn(
-        x[None] / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-5)[None] * 0
-        + x[None], w, eps=0.0, top_k=4, norm_topk=True, scaling=None,
+        x[None], w, eps=0.0, top_k=4, norm_topk=True, scaling=None,
         held=tuple(range(32)))
     # the reference norms its input: hand the shares the same normed rows
-    normed = np.asarray(ref._rms(x, jnp.ones(64), 0.0))
+    normed = np.asarray(ref._rms(x, jnp.ones(width), 0.0))
     parts, pairs = 0.0, 0
     for s in range(4):
         y, n_pairs, _ = _share(net, jnp.asarray(normed), range(8 * s, 8 * s + 8))
