@@ -217,17 +217,25 @@ def _counter_names(model):
     return tuple(getattr(model, "serve_counters", ()))
 
 
+#: a device-side counter is two int32 limbs, ``low + _LIMB * carried``: a
+#: count of millions a step (cached rows a decode step walks, over the
+#: layers) passes 2^31 within a thousand steps, and ``metrics()`` is what
+#: fetches and resets it. A step's own count stays under ``_LIMB``.
+_LIMB = 1 << 30
+
+
 def _add_counts(counters, counts, names, decode):
-    """The step's counter array plus what the layers counted: the decode
-    graph's counts in the first ``len(names)`` places, the prefill chunk's
-    in the second."""
+    """The step's counter array ``[2, 2 * len(names)]`` (low limbs, carried
+    limbs) plus what the layers counted: the decode graph's counts in the
+    first ``len(names)`` places, the prefill chunk's in the second."""
     import jax.numpy as jnp
 
     if not names:
         return counters
     got = jnp.stack([jnp.asarray(counts.get(n, 0), jnp.int32) for n in names])
     zero = jnp.zeros_like(got)
-    return counters + jnp.concatenate([got, zero] if decode else [zero, got])
+    low = counters[0] + jnp.concatenate([got, zero] if decode else [zero, got])
+    return jnp.stack([low % _LIMB, counters[1] + low // _LIMB])
 
 
 @contextlib.contextmanager
@@ -833,8 +841,8 @@ class LLMEngine:
         if self.cache.window is None and not self._counter_names:
             return ()
         if self._counters_dev is None:
-            self._counters_dev = self._g(
-                np.zeros(max(2 * len(self._counter_names), 1), np.int32))
+            self._counters_dev = self._g(np.zeros(
+                (2, max(2 * len(self._counter_names), 1)), np.int32))
         return (window_operand, self._counters_dev)
 
     def _ensure_open(self):
@@ -2626,10 +2634,10 @@ class LLMEngine:
         cache, bs = self.cache, self.block_size
         window = cache.window
         if self._counters_dev is not None and self._counter_names:
-            got = self._fetch(self._counters_dev)
+            low, carried = self._fetch(self._counters_dev)
             self._counters_dev = None
-            for name, v in zip(self._counter_totals, got):
-                self._counter_totals[name] += int(v)
+            for name, lo, hi in zip(self._counter_totals, low, carried):
+                self._counter_totals[name] += int(lo) + _LIMB * int(hi)
         g_steps, w_steps = self._page_steps
         g_bytes = cache.published_bytes_per_token("global")
         w_bytes = cache.published_bytes_per_token("window")

@@ -562,8 +562,14 @@ class KVLayerSpec:
     is stored at (``k_dim`` unless padded up to the lanes); ``prefill`` is
     how a prefill chunk reads the layer's keys: ``"paged"`` page by page
     through the multi-query kernel (the verify step's too), ``"linear"``
-    with the request's pages laid out in a row for the chunk kernel."""
-    kind: str = "global"            # "global" | "window"
+    with the request's pages laid out in a row for the chunk kernel.
+
+    A ``"latent"`` layer (compressed keys and values) caches ONE row of
+    ``k_dim`` a token, shared by every query head, whose first ``v_dim``
+    values are also what the softmax weighs: one pool a layer and no V pool.
+    Its pages come from the global allocator and table, so to the scheduler
+    they are global pages."""
+    kind: str = "global"            # "global" | "window" | "latent"
     num_kv_heads: int = 1
     k_dim: int = 128
     v_dim: int = 128
@@ -572,8 +578,15 @@ class KVLayerSpec:
     prefill: str = "paged"
 
     def __post_init__(self):
-        if self.kind not in ("global", "window"):
+        if self.kind not in ("global", "window", "latent"):
             raise ValueError(f"unknown KV layer kind {self.kind!r}")
+        if self.kind == "latent" and (
+                self.num_kv_heads != 1 or self.prefill != "linear"
+                or not 0 < self.v_dim <= self.k_dim):
+            raise ValueError(
+                "a latent layer caches one row a token (num_kv_heads 1), "
+                "its first v_dim values the values, read in a row by a "
+                "chunk (prefill='linear')")
         if (self.kind == "window") != (self.window is not None):
             raise ValueError("a window kind, and only it, states a window")
         if self.kind == "window" and self.prefill != "linear":
@@ -594,7 +607,10 @@ class KVLayerSpec:
         return (n, block_size * self.num_kv_heads, width)
 
     def bytes_per_token(self, itemsize=2):
-        """K and V of one token in this layer, at the published widths."""
+        """K and V of one token in this layer, at the published widths (a
+        latent row holds both)."""
+        if self.kind == "latent":
+            return self.k_dim * itemsize
         return self.num_kv_heads * (self.k_dim + self.v_dim) * itemsize
 
 
@@ -771,7 +787,10 @@ class PagedKVCache:
                              pool_dtype)
 
         self.k = [pool(sp, sp.k_store) for sp in self.layout]
-        self.v = [pool(sp, sp.v_dim) for sp in self.layout]
+        # a latent layer has no V pool: an empty array keeps its place in
+        # the lists the step functions thread
+        self.v = [jnp.zeros((0,), pool_dtype) if sp.kind == "latent"
+                  else pool(sp, sp.v_dim) for sp in self.layout]
         if self.quantized:
             self.k_scale = [jnp.zeros(kp.shape[:-1], jnp.float32)
                             for kp in self.k]
@@ -795,10 +814,11 @@ class PagedKVCache:
                 f"this cache has {kinds}")
 
     def published_bytes_per_token(self, kind, itemsize=2):
-        """K and V bytes of one token over all layers of ``kind``, at the
-        published widths."""
+        """K and V bytes of one token over all layers whose pages are of
+        ``kind`` (``"window"``: the rings; ``"global"``: the global table's,
+        latent rows among them), at the published widths."""
         return sum(sp.bytes_per_token(itemsize) for sp in self.layout
-                   if sp.kind == kind)
+                   if (sp.kind == "window") == (kind == "window"))
 
     def bytes_saved_vs_unquantized(self, config):
         """Total pool bytes an int8 cache saves versus the SAME pool in

@@ -21,9 +21,9 @@ import jax.numpy as jnp
 
 from ...nn.functional.flash_attention import _sdpa_ref
 
-__all__ = ["paged_decode_attention", "paged_multiquery_attention",
-           "chunk_attention", "kv_pool_specs", "ChunkAttnState",
-           "DecodeAttnState"]
+__all__ = ["paged_decode_attention", "paged_decode_attention_latent",
+           "paged_multiquery_attention", "chunk_attention", "kv_pool_specs",
+           "ChunkAttnState", "DecodeAttnState"]
 
 
 def kv_pool_specs(plan, num_heads, num_kv_heads):
@@ -181,6 +181,32 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, context_lens,
                          window=window, ring=ring, sink=sink)
 
 
+def paged_decode_attention_latent(q, pool, block_tables, context_lens, scale,
+                                  v_dim, name="paged_decode_attention_latent"):
+    """One decode token a request against latent pages (ISSUE 31): q
+    ``[B, H, D]``, every head's ``[q_lat | q_rope]`` at the stored width;
+    pool ``[N, block, D]``, one row a token that all heads share. Scores
+    over the whole row, values its first ``v_dim``; returns ``[B, H,
+    v_dim]``. Pallas on the TPU, the gather below here."""
+    from ...ops.pallas.paged_attention import (
+        paged_decode_attention_latent_pallas, use_pallas_paged)
+
+    if use_pallas_paged(q.shape[-1], pool.shape[1]):
+        return paged_decode_attention_latent_pallas(
+            q, pool, block_tables, context_lens, float(scale), v_dim,
+            name=name)
+    b, p = block_tables.shape
+    rows = pool[block_tables].reshape(b, p * pool.shape[1], -1)
+    rows = rows.astype(jnp.float32)
+    s = jnp.einsum("bhd,bsd->bhs", q.astype(jnp.float32), rows) * scale
+    live = jnp.arange(rows.shape[1])[None, None, :] \
+        < context_lens[:, None, None]
+    prob = _softmax_with_sink(s[:, :, None], live[:, :, None], None)[:, :, 0]
+    # a page's unwritten slots may hold anything: 0 x NaN is NaN
+    vals = jnp.where(live[:, 0, :, None], rows[..., :v_dim], 0.0)
+    return jnp.einsum("bhs,bsc->bhc", prob, vals).astype(q.dtype)
+
+
 def _lax_multiquery_fallback(q, k_pool, v_pool, block_tables, context_lens,
                              q_start, scale, k_scale=None, v_scale=None):
     """q [B, T, H, D] -> [B, T, H, D]: gather + per-row causal mask."""
@@ -270,6 +296,11 @@ def chunk_attention(q, k, v, q_start, k_start, upto, scale, *, window=None,
 # of q over the request's state. What a "state" is (pages of one pool, a
 # ring of pages, int8 codes) is the engine's and the cache's business, not
 # the model's. ``state.count`` adds to the step's device-side counters.
+#
+# A layer with compressed keys and values (ISSUE 31) hands over one row a
+# token and the matrix that expands it, ``state.attend_latent``: the state
+# writes the row and attends in its phase's way (a decode step absorbed, a
+# chunk expanded), which the model does not choose either.
 
 
 def _pad_last(x, width):
@@ -300,6 +331,10 @@ class _AttnState:
     def _kernel_name(self, base):
         return base if self.spec.prefill == "paged" \
             else f"{base}_{self.spec.kind}"
+
+    def _latent_row(self, row):
+        """A token's latent row at the pool's width and dtype."""
+        return _pad_last(row, self.spec.k_store).astype(self.k_pool.dtype)
 
 
 class DecodeAttnState(_AttnState):
@@ -374,6 +409,35 @@ class DecodeAttnState(_AttnState):
             ring=spec.kind == "window", sink=sink,
             num_kv_heads=spec.num_kv_heads,
             name=self._kernel_name("paged_decode_attention"))
+
+
+    def attend_latent(self, q_nope, q_rope, row, w_kvb, scale):
+        """A latent layer's step (ISSUE 31). ``q_nope [B, 1, H, dn]`` and
+        ``q_rope [B, 1, H, dr]`` (rotated), ``row [B, 1, k_dim]``: the
+        token's ``[c | k_r]`` (normed, rotated), which is all the layer
+        caches; ``w_kvb [v_dim, H * (dn + dv)]`` expands a row's ``c`` to
+        every head's ``[k_nope | v]``. Writes the row, then attends
+        ABSORBED: the queries go through ``W_UK`` into the rows' own space,
+        the kernel takes scores and values from the rows as they lie, and
+        the result comes back through ``W_UV``. Returns ``[B, 1, H, dv]``."""
+        spec, bs = self.spec, self.block_size
+        positions, tables = self.positions, self.table
+        h, dn = q_nope.shape[2], q_nope.shape[3]
+        w = w_kvb.reshape(spec.v_dim, h, -1)
+        blk = tables[jnp.arange(tables.shape[0]), positions // bs]
+        # the rows of empty slots all land on the null block's first
+        self.k_pool = self.k_pool.at[blk, positions % bs].set(
+            self._latent_row(row[:, 0]))
+        q_lat = jnp.einsum("bhd,chd->bhc", q_nope[:, 0], w[:, :, :dn])
+        qa = _pad_last(jnp.concatenate(
+            [q_lat, q_rope[:, 0].astype(q_lat.dtype)], -1), spec.k_store)
+        out = paged_decode_attention_latent(
+            qa, self.k_pool, tables, positions + 1, scale, spec.v_dim,
+            name=self._kernel_name("paged_decode_attention"))
+        # a slot with no request reads the null page: not a live row
+        self.count("mla_latent_tokens_read", jnp.sum(
+            jnp.where(tables[:, 0] != 0, positions + 1, 0)))
+        return jnp.einsum("bhc,chd->bhd", out, w[:, :, dn:])[:, None]
 
 
 class ChunkAttnState(_AttnState):
@@ -473,3 +537,47 @@ class ChunkAttnState(_AttnState):
             qa[0], in_a_row(kp[self.tables_row]),
             in_a_row(vp[self.tables_row]), start, 0, upto, scale, sink=sink,
             block_size=bs, name=self._kernel_name("chunk_attention"))[None]
+
+    def attend_latent(self, q_nope, q_rope, row, w_kvb, scale):
+        """A latent layer's chunk (``DecodeAttnState.attend_latent`` has the
+        operands, ``[1, C, ...]`` here). Writes the chunk's rows into its
+        pages, then attends EXPANDED: the request's cached rows, in a row,
+        go through ``w_kvb`` to every head's ``[k_nope | k_r]`` and ``v``,
+        and the chunk kernel runs over them as over any heads. Rows are
+        expanded a block of ``C`` at a time up to the chunk's end, so the
+        work follows the context and not the table's length."""
+        import jax
+
+        spec, bs = self.spec, self.block_size
+        start, upto = self.start, self.upto
+        c_len, h, dn = q_nope.shape[1], q_nope.shape[2], q_nope.shape[3]
+        r, dr = spec.v_dim, q_rope.shape[-1]
+        dv = w_kvb.shape[1] // h - dn
+        pages = c_len // bs
+        blks = jax.lax.dynamic_slice(self.tables_row, (start // bs,), (pages,))
+        self.k_pool = kp = self.k_pool.at[blks].set(
+            self._latent_row(row[0]).reshape(pages, bs, -1))
+        n_rows = self.tables_row.shape[0] * bs
+        step = min(c_len, n_rows)
+        width = -(-(dn + dr) // 128) * 128 if dn + dr >= 128 else dn + dr
+        n_blocks = (upto + step - 1) // step
+
+        def expand(i, kv):
+            k, v = kv
+            at = jnp.minimum(i * step, n_rows - step)     # the last may lap
+            rows = kp[jax.lax.dynamic_slice(
+                self.tables_row, (at // bs,), (step // bs,))].reshape(step, -1)
+            e = jnp.dot(rows[:, :r], w_kvb).reshape(step, h, dn + dv)
+            k_r = jnp.broadcast_to(rows[:, None, r:r + dr], (step, h, dr))
+            k_blk = _pad_last(jnp.concatenate([e[..., :dn], k_r], -1), width)
+            return (jax.lax.dynamic_update_slice(k, k_blk, (at, 0, 0)),
+                    jax.lax.dynamic_update_slice(v, e[..., dn:], (at, 0, 0)))
+
+        k, v = jax.lax.fori_loop(0, n_blocks, expand, (
+            jnp.zeros((n_rows, h, width), kp.dtype),
+            jnp.zeros((n_rows, h, dv), kp.dtype)))
+        self.count("mla_context_tokens_expanded", n_blocks * step)
+        q = _pad_last(jnp.concatenate([q_nope[0], q_rope[0]], -1), width)
+        return chunk_attention(
+            q.astype(kp.dtype), k, v, start, 0, upto, scale, block_size=bs,
+            name="chunk_attention_global")[None]

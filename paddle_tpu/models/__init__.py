@@ -15,3 +15,6 @@ from .llama import (  # noqa: F401
 from .mimo_v2 import (  # noqa: F401
     MiMoV2Config, MiMoV2ForCausalLM, mimo_v2_tiny,
 )
+from .joyai_flash import (  # noqa: F401
+    JoyAIFlashConfig, JoyAIFlashForCausalLM, joyai_flash_tiny,
+)

@@ -37,6 +37,16 @@ the MXU, so a tail chunk's V rows past the context (never copied, or a
 page's unwritten slots) are zeroed before the dot. GQA, one kv head a
 shard, one query head a kv head are all shapes of this one kernel.
 
+**Latent pages (ISSUE 31).** A layer with compressed keys and values caches
+ONE row a token, ``[c | k_r]``, shared by every query head, whose first
+``v_dim`` values are the values too: pool ``[N, block, D]``, no V pool. Its
+decode (``paged_decode_attention_latent_pallas``) is the kernel above with
+``latent=v_dim``: one copy a page, ``groups = H`` (one "kv head", nothing
+masked away), scores of the absorbed queries ``[q_lat | q_rope]`` over the
+whole row and the second dot over the first ``v_dim`` lanes of the SAME
+buffer, so a row is read from HBM once. 64 pages a chunk at 640-wide bf16
+rows.
+
 **Quantized pools (ISSUE 14, dequant-in-kernel):** with
 ``kv_dtype="int8"`` the pools hold int8 codes and two sidecar scale
 pools ``[N, block, Hkv]`` f32 ride along. The kernels take two extra
@@ -71,6 +81,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .flash_attention import NEG_INF, _interpret
 
 __all__ = ["paged_decode_attention_pallas",
+           "paged_decode_attention_latent_pallas",
            "paged_multiquery_attention_pallas", "chunk_attention_pallas",
            "use_pallas_paged"]
 
@@ -99,6 +110,10 @@ def use_pallas_paged(head_dim, block_size):
 _DECODE_VMEM_BUDGET = 4 * 1024 * 1024
 
 
+#: page copies of a full chunk the latent kernel writes out a loop step
+_LATENT_UNROLL = 16
+
+
 def _decode_chunk(block_size, hkv, h, d, itemsize, p, dv=None):
     """``(C, bytes)``: the pages of a decode chunk — the largest power of
     two whose VMEM plan fits ``_DECODE_VMEM_BUDGET``, and no more than a
@@ -120,7 +135,7 @@ def _decode_chunk(block_size, hkv, h, d, itemsize, p, dv=None):
 
 
 def _kernel(tables_ref, lens_ref, *refs, block_size, chunk, groups, scale,
-            window=None, ring=False, sink=False):
+            window=None, ring=False, sink=False, latent=None):
     """Decode over fp pools: one request a grid step, a loop over its live
     chunks of ``chunk`` pages inside (see the module docstring). K rows are
     ``q``'s width and V rows the output's; the two may differ.
@@ -131,14 +146,26 @@ def _kernel(tables_ref, lens_ref, *refs, block_size, chunk, groups, scale,
     page ``p`` sits in slot ``p % P``, so a row of ``ceil(window / block) +
     1`` slots serves any context. ``sink`` adds one operand ``[H, 1]`` f32,
     a per-head logit that joins the softmax's denominator and carries no
-    value: the fold simply starts from ``m = sink, l = 1``."""
-    if sink:
+    value: the fold simply starts from ``m = sink, l = 1``.
+
+    ``latent`` (a width) says the pool holds ONE row a token that every
+    query head shares, whose first ``latent`` values are the values too
+    (ISSUE 31): there is no V pool and no V buffer, a page is copied once,
+    and the second dot reads the first ``latent`` lanes of the SAME block the
+    scores were taken over."""
+    if latent:
+        q_ref, k_hbm, o_ref, k_buf, sems, slot_ref = refs
+        v_hbm = v_buf = None
+    elif sink:
         q_ref, sink_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref = refs
     else:
         q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref = refs
+    # the pools copied a page, and the buffer the values are read from
+    pools = ((k_hbm, k_buf), (v_hbm, v_buf))[:1 if latent else 2]
+    val_buf = k_buf if latent else v_buf
     b = pl.program_id(0)
     h = q_ref.shape[1]
-    d = v_buf.shape[-1]
+    d = latent or v_buf.shape[-1]
     hkv = h // groups
     rows = block_size * hkv                 # pool rows a page
     cols = chunk * rows
@@ -164,13 +191,36 @@ def _kernel(tables_ref, lens_ref, *refs, block_size, chunk, groups, scale,
         def page(j, carry):
             at = base + first + j
             idx = tables_ref[r * p_max + (at % p_max if ring else at)]
-            for i, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+            for i, (hbm, buf) in enumerate(pools):
                 act(pltpu.make_async_copy(
                     hbm.at[idx], buf.at[slot, pl.ds(j * rows, rows)],
                     sems.at[i, slot]))
             return carry
 
-        jax.lax.fori_loop(0, jnp.clip(n_pages(r) - first, 0, chunk), page, 0)
+        live = jnp.clip(n_pages(r) - first, 0, chunk)
+        if not latent:
+            jax.lax.fori_loop(0, live, page, 0)
+            return
+        # one pool of narrow pages: twice the copies a byte of the K / V
+        # kernels, and what bounds the walk is the rate they are issued
+        # at, loop overhead and all (alone on the chip, PR 31: the copies
+        # without the fold 1,009 us a call, the fold without the copies
+        # 497). A full chunk, which all but a request's last are, is
+        # issued in groups of _LATENT_UNROLL written out: 763 us
+        group = min(_LATENT_UNROLL, chunk)
+
+        @pl.when(live == chunk)
+        def _full():
+            def pages(g, carry):
+                for i in range(group):
+                    page(g * group + i, carry)
+                return carry
+
+            jax.lax.fori_loop(0, chunk // group, pages, 0)
+
+        @pl.when(live != chunk)
+        def _part():
+            jax.lax.fori_loop(0, live, page, 0)
 
     @pl.when(b == 0)
     def _first():
@@ -212,9 +262,9 @@ def _kernel(tables_ref, lens_ref, *refs, block_size, chunk, groups, scale,
             # rows past the context were not copied, or are a page's
             # unwritten slots: 0 x NaN is NaN in the PV dot
             live = jax.lax.broadcasted_iota(
-                jnp.int32, (cols, d), 0) < seen * hkv
-            v_buf[slot] = jnp.where(live, v_buf[slot],
-                                    jnp.zeros((), v_buf.dtype))
+                jnp.int32, (cols, val_buf.shape[-1]), 0) < seen * hkv
+            val_buf[slot] = jnp.where(live, val_buf[slot],
+                                      jnp.zeros((), val_buf.dtype))
 
         s = jax.lax.dot_general(
             q, k_buf[slot].astype(cdt), (((1,), (1,)), ((), ())),
@@ -228,7 +278,8 @@ def _kernel(tables_ref, lens_ref, *refs, block_size, chunk, groups, scale,
         corr = jnp.exp(m_prev - m_new)
         l_new = l_prev * corr + jnp.sum(pexp, axis=1, keepdims=True)
         acc = acc * corr + jax.lax.dot_general(
-            pexp.astype(cdt), v_buf[slot].astype(cdt),
+            pexp.astype(cdt),
+            (val_buf[slot, :, :d] if latent else v_buf[slot]).astype(cdt),
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         return m_new, l_new, acc
 
@@ -388,6 +439,44 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
         name=name,
     )(tables_flat, lens, *operands, k_pool.reshape(n, rows, d),
       v_pool.reshape(n, rows, dv))
+
+
+def paged_decode_attention_latent_pallas(q, pool, block_tables, context_lens,
+                                         scale, v_dim, *,
+                                         name="paged_decode_attention_latent"):
+    """Decode over latent pages (ISSUE 31). q ``[B, H, D]``: every head's
+    ``[q_lat | q_rope]`` at the stored width; pool ``[N, block, D]``: one
+    row a token, shared by all heads; block_tables ``[B, P]`` and
+    context_lens ``[B]`` int32. Scores over all ``D``, values the rows' first
+    ``v_dim``. Returns ``[B, H, v_dim]``. ``_kernel``'s copy pipeline with
+    one pool: a row is read from HBM once."""
+    b, h, d = q.shape
+    n, block_size, _ = pool.shape
+    chunk, _ = _decode_chunk(block_size, 1, h, d, pool.dtype.itemsize,
+                             block_tables.shape[1], 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, h, d), lambda i, T, L: (i, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, h, v_dim), lambda i, T, L: (i, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, chunk * block_size, d), pool.dtype),
+            pltpu.SemaphoreType.DMA((1, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel, block_size=block_size, chunk=chunk,
+                          groups=h, scale=float(scale), latent=int(v_dim)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, v_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(),
+        name=name,
+    )(block_tables.reshape(-1).astype(jnp.int32),
+      context_lens.astype(jnp.int32), q, pool)
 
 
 #: multi-query grid tile: at most ``_MQ_ROWS`` query rows and at most

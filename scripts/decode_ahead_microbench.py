@@ -9,13 +9,15 @@ the window's tokens/s and decode-only step p50 on the host's clock, the
 engage share ``ahead / (ahead + synchronous)`` of the window's decode steps
 with the synchronous ones by reason and the rows discarded, for an expert
 model the experts hit over the weight passes made (decode steps and
-prefill chunks apart), the window's seconds by kind of call, and with
-``--trace 1`` the device's busy time in ``decode_pure`` a traced decode step
-(and the grouped expert kernel's part of it), the largest device operations
-and the device's idle share. Run on the chip:
+prefill chunks apart), for a latent cache the cached rows a decode step
+walked and a chunk expanded (a layer), the window's seconds by kind of call,
+and with ``--trace 1`` the device's busy time in ``decode_pure`` a traced
+decode step (and the grouped expert kernel's and the latent decode kernel's
+parts of it), the largest device operations and the device's idle share. Run
+on the chip, any serving cell:
 
     python3 scripts/decode_ahead_microbench.py \
-        --workload mimo-v2-flash-serve.mixed-len-decode --seed 7 --trace 1
+        --workload joyai-llm-flash-serve.long-ctx-decode --seed 7 --trace 1
 """
 import argparse
 import importlib
@@ -32,9 +34,10 @@ KEYS = ("decode_steps_ahead", "decode_steps_sync", "decode_rows_discarded",
         "host_syncs", "tokens_out")
 
 
-def window_counts(snaps):
+def window_counts(snaps, layers):
     """The counters' change between the last two snapshots, and the
-    synchronous steps of that stretch by reason."""
+    synchronous steps of that stretch by reason; ``layers`` is the model's
+    depth."""
     m0, m1 = snaps[-2], snaps[-1]
     out = {k: m1[k] - m0[k] for k in KEYS}
     r0, r1 = (m["decode_steps_sync_by_reason"] for m in (m0, m1))
@@ -45,12 +48,21 @@ def window_counts(snaps):
     # an expert model's grouped kernel (ISSUE 30): of the times an expert's
     # weights were streamed, the share that was that expert's only read
     # that layer-step
+    d = lambda k: m1.get(k, 0) - m0.get(k, 0)  # noqa: E731
     for kind in ("decode", "prefill"):
-        hit, passes = (m1.get(f"moe_{k}_{kind}", 0) - m0.get(f"moe_{k}_{kind}", 0)
+        hit, passes = (d(f"moe_{k}_{kind}")
                        for k in ("experts_hit", "weight_passes"))
         if passes:
             out[f"moe_{kind}"] = {"experts_hit": hit, "weight_passes": passes,
                                   "single_read_share": hit / passes}
+    # a latent cache (ISSUE 31): the cached rows a decode step's kernel
+    # walked and a prefill chunk expanded to heads, in one layer
+    if d("mla_latent_tokens_read_decode"):
+        out["mla"] = {
+            "rows_read_a_decode_step": d("mla_latent_tokens_read_decode")
+            / layers / max(out["host_syncs"], 1),
+            "rows_expanded_a_chunk": d("mla_context_tokens_expanded_prefill")
+            / layers / max(d("prefill_chunks"), 1)}
     return out
 
 
@@ -95,7 +107,7 @@ def main(argv=None):
            "serve_tokens_per_s": run["values"]["serve_tokens_per_s"],
            "decode_step_ms_p50": stats.percentile(
                run["series"]["decode_step_ms"], 50),
-           "window": window_counts(snaps),
+           "window": window_counts(snaps, config["num_hidden_layers"]),
            # where the window's seconds went: calls that prefilled
            # nothing, and calls that ended a prefill (chunk + decode)
            "account": {k: {"calls": len(v), "seconds": sum(v) / 1e3,
@@ -115,6 +127,11 @@ def main(argv=None):
                  if s[4] and not s[3]], 50),
             "grouped_ffn_device_ms_a_step": 1e3 * trace_reduce.op_seconds(
                 tr["events"], "moe_grouped_swiglu", "decode_pure") / steps,
+            "latent_decode_device_ms_a_step": 1e3 * trace_reduce.op_seconds(
+                tr["events"], "paged_decode_attention_latent",
+                "decode_pure") / steps,
+            "chunk_attention_device_s": trace_reduce.op_seconds(
+                tr["events"], "chunk_attention_global", "chunk_pure"),
             "idle_share": 100.0 * (1.0 - tr["busy_s"] / tr["window_s"]),
             "idle_gaps": tr["breakdown"]["idle_gaps"],
             "device_ops": trace_reduce.top_ops(tr["events"], 16),

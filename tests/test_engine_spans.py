@@ -423,6 +423,17 @@ def _paged_decode_window():
                 jnp.zeros((2, 3), jnp.int32), jnp.ones((2,), jnp.int32))
 
 
+def _paged_decode_latent():
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    def fn(q, pool, t, l):
+        return pa.paged_decode_attention_latent_pallas(q, pool, t, l, 0.3, 32)
+
+    return fn, (jnp.zeros((2, 4, 48), jnp.float32),
+                jnp.zeros((8, 4, 48), jnp.float32),
+                jnp.zeros((2, 3), jnp.int32), jnp.ones((2,), jnp.int32))
+
+
 def _grouped_swiglu():
     from paddle_tpu.ops.pallas import grouped_ffn as gf
 
@@ -442,6 +453,7 @@ KERNELS = [
     ("chunk_attention_global", _chunk_attention),
     ("paged_decode_attention", _paged_decode),
     ("paged_decode_attention", lambda: _paged_decode(int8=True)),
+    ("paged_decode_attention_latent", _paged_decode_latent),
     ("paged_prefill_attention", _paged_prefill),
     ("flash_attention_fwd", lambda: _flash(False)),
     ("flash_attention_bwd_dq", lambda: _flash(True)),
