@@ -1,5 +1,5 @@
 """Paged-attention kernels — Pallas TPU (ISSUE 7 tentpole, part b; the decode
-kernel rewritten by ISSUE 26).
+kernel rewritten by ISSUE 26, its page copies' issue path by ISSUE 32).
 
 Attention over a block-paged KV cache (PAPERS.md: "Ragged Paged Attention:
 A High-Performance and Flexible LLM Inference Kernel for TPU"). The block
@@ -22,6 +22,24 @@ starts the next chunk's copies — the first chunk of the NEXT request, at a
 request's last chunk — before it waits for and folds this one, so the copy
 pipeline does not drain between requests. ``C`` follows from the operands'
 shapes (``_decode_chunk``): 16 pages, 256 tokens, at 8 kv heads x 128 bf16.
+
+**What a page's copy costs to issue (ISSUE 31, 32).** Pages are small (8 to
+32 KB) and the core that folds a chunk also issues the next one's copies,
+so beside the bytes there is a cost a PAGE: a start, a wait, and whatever
+loop they sit in. Every page kind takes the same path, because the kinds
+differ in counts (pools, pages a chunk) and not in what they want. A full
+chunk, which all but a request's last are, has its ``C`` starts a pool
+written out; a last chunk's run in a loop a page. Waits are by bytes: the
+chip's DMA semaphore counts what was transferred and a wait takes off the
+size of ITS descriptor, whatever copies signalled (asked of the chip by
+``scripts/dma_wait_probe.py``, PR 32: right data, no hang, no early return,
+as jax's two interpreters have it), so a chunk is waited for by the binary
+digits of its live pages: one descriptor of ``C`` pages a pool when it is
+full, at most ``log2 C`` smaller ones when not. The two slots have
+semaphores of their own, so the prefetch into the other slot is never
+counted in. A wait for more bytes than were started never returns:
+``tests/test_paged_decode_dma_books.py`` runs every kind in the TPU
+interpreter with byte-counted semaphores under a time limit.
 
 A page arrives as ``block * Hkv`` rows of D, token-major, exactly as the
 pool holds it (the wrapper's ``[N, block * Hkv, D]`` view is a bitcast), so
@@ -110,10 +128,6 @@ def use_pallas_paged(head_dim, block_size):
 _DECODE_VMEM_BUDGET = 4 * 1024 * 1024
 
 
-#: page copies of a full chunk the latent kernel writes out a loop step
-_LATENT_UNROLL = 16
-
-
 def _decode_chunk(block_size, hkv, h, d, itemsize, p, dv=None):
     """``(C, bytes)``: the pages of a decode chunk — the largest power of
     two whose VMEM plan fits ``_DECODE_VMEM_BUDGET``, and no more than a
@@ -183,49 +197,58 @@ def _kernel(tables_ref, lens_ref, *refs, block_size, chunk, groups, scale,
             top = jnp.minimum(top, p_max)
         return top - first_page(r)
 
-    def copies(r, c, slot, act):
-        """Start, or wait for, the live page copies of request r's chunk c."""
-        first = c * chunk
-        base = first_page(r)
+    def live_pages(r, c):
+        """Pages of request r's chunk c that hold a live token."""
+        return jnp.clip(n_pages(r) - c * chunk, 0, chunk)
 
-        def page(j, carry):
-            at = base + first + j
+    def start(r, c, slot):
+        """Start one copy a live page a pool of request r's chunk c, all on
+        the slot's semaphores. A full chunk, which all but a request's last
+        are, is written out: a loop a page costs a third more a start (the
+        latent kind alone on the chip, PR 31: 41 ns a page against 31)."""
+        at0 = first_page(r) + c * chunk
+
+        def page(j, carry=None):
+            at = at0 + j
             idx = tables_ref[r * p_max + (at % p_max if ring else at)]
             for i, (hbm, buf) in enumerate(pools):
-                act(pltpu.make_async_copy(
+                pltpu.make_async_copy(
                     hbm.at[idx], buf.at[slot, pl.ds(j * rows, rows)],
-                    sems.at[i, slot]))
+                    sems.at[i, slot]).start()
             return carry
 
-        live = jnp.clip(n_pages(r) - first, 0, chunk)
-        if not latent:
-            jax.lax.fori_loop(0, live, page, 0)
-            return
-        # one pool of narrow pages: twice the copies a byte of the K / V
-        # kernels, and what bounds the walk is the rate they are issued
-        # at, loop overhead and all (alone on the chip, PR 31: the copies
-        # without the fold 1,009 us a call, the fold without the copies
-        # 497). A full chunk, which all but a request's last are, is
-        # issued in groups of _LATENT_UNROLL written out: 763 us
-        group = min(_LATENT_UNROLL, chunk)
+        live = live_pages(r, c)
 
         @pl.when(live == chunk)
         def _full():
-            def pages(g, carry):
-                for i in range(group):
-                    page(g * group + i, carry)
-                return carry
-
-            jax.lax.fori_loop(0, chunk // group, pages, 0)
+            for j in range(chunk):
+                page(j)
 
         @pl.when(live != chunk)
         def _part():
             jax.lax.fori_loop(0, live, page, 0)
 
+    def wait(r, c, slot):
+        """Wait for what ``start(r, c, slot)`` started. A DMA semaphore
+        counts bytes and a wait takes off those of ITS descriptor (the chip
+        as jax's interpreters: ``scripts/dma_wait_probe.py``, PR 32), so a
+        chunk's copies are waited for by the binary digits of their number:
+        one descriptor of ``chunk`` pages a pool when it is full, at most
+        ``log2(chunk)`` smaller ones when it is a request's last."""
+        live = live_pages(r, c)
+        for k in range(chunk.bit_length()):
+            n = 1 << k
+
+            @pl.when(live & n != 0)
+            def _digit():
+                for i, (_, buf) in enumerate(pools):
+                    part = buf.at[slot, pl.ds(0, n * rows)]
+                    pltpu.make_async_copy(part, part, sems.at[i, slot]).wait()
+
     @pl.when(b == 0)
     def _first():
         slot_ref[0] = 0
-        copies(0, 0, 0, lambda cp: cp.start())
+        start(0, 0, 0)
 
     ctx = lens_ref[b]
     base = first_page(b)
@@ -250,10 +273,9 @@ def _kernel(tables_ref, lens_ref, *refs, block_size, chunk, groups, scale,
 
         @pl.when(nr < pl.num_programs(0))
         def _prefetch():
-            copies(nr, jnp.where(last, 0, c + 1), 1 - slot,
-                   lambda cp: cp.start())
+            start(nr, jnp.where(last, 0, c + 1), 1 - slot)
 
-        copies(b, c, slot, lambda cp: cp.wait())
+        wait(b, c, slot)
         # live tokens from this chunk's first on
         seen = ctx - (base + c * chunk) * block_size
 
@@ -407,38 +429,11 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
     chunk, _ = _decode_chunk(block_size, hkv, h, d, k_pool.dtype.itemsize,
                              block_tables.shape[1], dv)
     rows = block_size * hkv
-    q_spec = pl.BlockSpec((1, h, d), lambda i, T, L: (i, 0, 0))
-    o_spec = pl.BlockSpec((1, h, dv), lambda i, T, L: (i, 0, 0))
-    hbm = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs, operands = [q_spec], [q]
-    if sink is not None:
-        in_specs.append(pl.BlockSpec((h, 1), lambda i, T, L: (0, 0)))
-        operands.append(sink.astype(jnp.float32).reshape(h, 1))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b,),
-        in_specs=in_specs + [hbm, hbm],
-        out_specs=o_spec,
-        scratch_shapes=[
-            pltpu.VMEM((2, chunk * rows, d), k_pool.dtype),
-            pltpu.VMEM((2, chunk * rows, dv), v_pool.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SMEM((1,), jnp.int32),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel, block_size=block_size, chunk=chunk,
-                          groups=h // hkv, scale=float(scale),
-                          window=window, ring=ring, sink=sink is not None),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
-        # the copy pipeline runs from one request into the next
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=_interpret(),
-        name=name,
-    )(tables_flat, lens, *operands, k_pool.reshape(n, rows, d),
-      v_pool.reshape(n, rows, dv))
+    return _decode_call(
+        tables_flat, lens, q, sink,
+        (k_pool.reshape(n, rows, d), v_pool.reshape(n, rows, dv)),
+        block_size=block_size, chunk=chunk, scale=float(scale), window=window,
+        ring=ring, latent=None, interpret=_interpret(), name=name)
 
 
 def paged_decode_attention_latent_pallas(q, pool, block_tables, context_lens,
@@ -451,32 +446,63 @@ def paged_decode_attention_latent_pallas(q, pool, block_tables, context_lens,
     ``v_dim``. Returns ``[B, H, v_dim]``. ``_kernel``'s copy pipeline with
     one pool: a row is read from HBM once."""
     b, h, d = q.shape
-    n, block_size, _ = pool.shape
+    block_size = pool.shape[1]
     chunk, _ = _decode_chunk(block_size, 1, h, d, pool.dtype.itemsize,
                              block_tables.shape[1], 0)
+    return _decode_call(
+        block_tables.reshape(-1).astype(jnp.int32),
+        context_lens.astype(jnp.int32), q, None, (pool,),
+        block_size=block_size, chunk=chunk, scale=float(scale), window=None,
+        ring=False, latent=int(v_dim), interpret=_interpret(), name=name)
+
+
+# a jit of its own, as ``grouped_ffn._call``: a model's layers of one kind
+# are the same shapes, so the kernel is traced and lowered once a program
+# and not once a layer. A full chunk's written-out copies are text: sixteen
+# layers of them took a decode program's tracing and lowering from 1.0 to
+# 3.0 s and decode-sat's ``setup_s`` from 51 to 67 s (PR 32). ``chunk`` and
+# ``interpret`` are read by the callers: what a trace is kept under
+@functools.partial(jax.jit, static_argnames=(
+    "block_size", "chunk", "scale", "window", "ring", "latent", "interpret",
+    "name"))
+def _decode_call(tables_flat, lens, q, sink, pools, *, block_size, chunk,
+                 scale, window, ring, latent, interpret,
+                 name="paged_decode_attention"):
+    """``_kernel`` over ``pools``: K and V as rows ``[N, block * Hkv, D]``,
+    or the one pool of a latent layer (``latent`` its value width)."""
+    b, h, d = q.shape
+    dv = latent or pools[1].shape[-1]
+    rows = pools[0].shape[1]
+    in_specs = [pl.BlockSpec((1, h, d), lambda i, T, L: (i, 0, 0))]
+    operands = [q]
+    if sink is not None:
+        in_specs.append(pl.BlockSpec((h, 1), lambda i, T, L: (0, 0)))
+        operands.append(sink.astype(jnp.float32).reshape(h, 1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
-        in_specs=[pl.BlockSpec((1, h, d), lambda i, T, L: (i, 0, 0)),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((1, h, v_dim), lambda i, T, L: (i, 0, 0)),
+        in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+        out_specs=pl.BlockSpec((1, h, dv), lambda i, T, L: (i, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, chunk * block_size, d), pool.dtype),
-            pltpu.SemaphoreType.DMA((1, 2)),
+            *(pltpu.VMEM((2, chunk * rows, pool.shape[-1]), pool.dtype)
+              for pool in pools),
+            pltpu.SemaphoreType.DMA((len(pools), 2)),
             pltpu.SMEM((1,), jnp.int32),
         ],
     )
     return pl.pallas_call(
         functools.partial(_kernel, block_size=block_size, chunk=chunk,
-                          groups=h, scale=float(scale), latent=int(v_dim)),
+                          groups=h // (rows // block_size), scale=scale,
+                          window=window, ring=ring, sink=sink is not None,
+                          latent=latent),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, v_dim), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
+        # the copy pipeline runs from one request into the next
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=_interpret(),
+        interpret=interpret,
         name=name,
-    )(block_tables.reshape(-1).astype(jnp.int32),
-      context_lens.astype(jnp.int32), q, pool)
+    )(tables_flat, lens, *operands, *pools)
 
 
 #: multi-query grid tile: at most ``_MQ_ROWS`` query rows and at most

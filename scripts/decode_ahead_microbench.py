@@ -13,8 +13,11 @@ prefill chunks apart), for a latent cache the cached rows a decode step
 walked and a chunk expanded (a layer), the window's seconds by kind of call,
 and with ``--trace 1`` the device's busy time in ``decode_pure`` a traced
 decode step (and the grouped expert kernel's and the latent decode kernel's
-parts of it), the largest device operations and the device's idle share. Run
-on the chip, any serving cell:
+parts of it), the largest device operations, the device's idle share, and for
+each kind of decode kernel the share of the chunks it walked in the traced
+steps whose every page was live (ISSUE 32: those are started written out and
+waited for with one descriptor a pool; from the loop's own context lengths).
+Run on the chip, any serving cell:
 
     python3 scripts/decode_ahead_microbench.py \
         --workload joyai-llm-flash-serve.long-ctx-decode --seed 7 --trace 1
@@ -66,6 +69,31 @@ def window_counts(snaps, layers):
     return out
 
 
+def full_chunk_shares(cache, heads, max_model_len, lens_by_step):
+    """``full_chunk_share`` of ``scripts/paged_decode_microbench.py`` over
+    the decode rows' context lengths of every step of ``lens_by_step``, for
+    each distinct layer spec of ``cache`` (a ``PagedKVCache``), with the
+    chunk the decode kernel plans for it."""
+    from paged_decode_microbench import full_chunk_share
+    from paddle_tpu.ops.pallas.paged_attention import _decode_chunk
+
+    block = cache.block_size
+    lens = [n for step in lens_by_step for n in step]
+    out = {}
+    for spec in dict.fromkeys(cache.layout):
+        pages = cache.window.ring if spec.kind == "window" \
+            else -(-max_model_len // block)
+        hkv, dv = (1, 0) if spec.kind == "latent" \
+            else (spec.num_kv_heads, spec.v_dim)
+        chunk = _decode_chunk(block, hkv, heads, spec.k_store, 2, pages,
+                              dv)[0]
+        out[spec.kind] = {
+            "chunk": chunk, "layers": cache.layout.count(spec),
+            "full_chunk_share": full_chunk_share(lens, block, chunk,
+                                                 spec.window)}
+    return out
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
@@ -95,6 +123,22 @@ def main(argv=None):
         return m
 
     LLMEngine.metrics = kept
+    # the context lengths of every step's decode rows, from the loop's own
+    # books (``serve.Loop.step`` keeps only their sum), keyed by the step's
+    # start
+    from benchmarks.runners import serve
+
+    lens_of, engines, plain_step = {}, [], serve.Loop.step
+
+    def step(self):
+        before = [(lv, len(lv.token_ts)) for lv in self.live.values()]
+        plain_step(self)
+        engines[:] = [self.eng]
+        lens_of[self.steps[-1][0]] = [
+            lv.item.prompt_len + j for lv, j in before
+            if j and len(lv.token_ts) > j]
+
+    serve.Loop.step = step
     runner = importlib.import_module("benchmarks.runners." + config["kind"])
     run = runner.run(config, traffic, seed=args.seed, seconds=args.seconds,
                      trace=bool(args.trace),
@@ -141,6 +185,10 @@ def main(argv=None):
                     trace_reduce.select(tr["events"], None, prog))
                 for prog in ("decode_pure", "chunk_pure")},
             "window_s": tr["window_s"],
+            "decode_chunks": full_chunk_shares(
+                engines[0].cache, config["num_attention_heads"],
+                config["engine"]["max_model_len"],
+                [lens_of[s[0]] for s in run["traced_steps"]]),
         }
         # the benchmark's own per-layer readings of this run
         out["per_layer"] = {k: v["value"] for k, v in bench.result_line(

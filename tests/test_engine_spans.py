@@ -449,6 +449,7 @@ def _grouped_swiglu():
 
 #: one entry a ``pl.pallas_call`` site; a site that takes its name from its
 #: wrapper is listed once more under each name the serving path gives it
+#: (the fp decode site, ``_decode_call``, serves K / V and latent pages)
 KERNELS = [
     ("chunk_attention_global", _chunk_attention),
     ("paged_decode_attention", _paged_decode),
@@ -500,7 +501,8 @@ def test_every_pallas_call_has_a_name():
             assert re.search(r'\bname="[a-z_]+"', body) or (
                 re.search(r"\bname=name\b", body)
                 and re.search(r'\bname="[a-z_]+"\):', head)), (f, body[:80])
-    assert calls == len(KERNELS)
+    # ``_decode_call`` is listed under both its wrappers' names
+    assert calls == len(KERNELS) - 1
 
 
 @pytest.mark.parametrize("name,make", [

@@ -173,14 +173,28 @@ def _latent_case(seed=5, b=5, h=4, rank=32, rope=8, bs=4, p_max=12, n=64):
     return rng, lens, pool, tables, rows, store
 
 
-@pytest.mark.parametrize("interpret", ["0", "1"], ids=["lax", "pallas"])
-def test_latent_decode_kernel_over_ragged_lengths(interpret, monkeypatch):
+@pytest.mark.parametrize("interpret,chunk_pages",
+                         [("0", None), ("1", None), ("1", 4)],
+                         ids=["lax", "pallas", "pallas-chunks-of-4"])
+def test_latent_decode_kernel_over_ragged_lengths(interpret, chunk_pages,
+                                                  monkeypatch):
     """Every head of a request against the SAME cached rows: scores over the
     whole row, values its first ``v_dim``; lengths of one token, under a
-    page, across pages and across chunks of the copy pipeline."""
+    page, across pages and across chunks of the copy pipeline. One chunk
+    holds any of these requests; with the kernel's plan cut to 4 pages a
+    chunk they take 1, 2 and 3 pages of one, a full chunk and 2, two full
+    chunks and 2: copies started written out and in the last chunk's loop,
+    waits of 4 pages and of the binary digits of fewer."""
     monkeypatch.setenv("PT_PALLAS_INTERPRET", interpret)
     rng, lens, pool, tables, rows, store = _latent_case()
     h, rank = 4, 32
+    if chunk_pages:
+        from paddle_tpu.ops.pallas import paged_attention as pa
+
+        plan = (4, 1, h, store, 4)
+        monkeypatch.setattr(pa, "_DECODE_VMEM_BUDGET",
+                            pa._decode_chunk(*plan, chunk_pages, 0)[1])
+        assert pa._decode_chunk(*plan, tables.shape[1], 0)[0] == chunk_pages
     q = rng.normal(size=(len(lens), h, store)).astype(np.float32)
     q[..., 40:] = 0.0
     got = np.asarray(spa.paged_decode_attention_latent(
@@ -198,9 +212,9 @@ def test_latent_decode_kernel_over_ragged_lengths(interpret, monkeypatch):
 def test_the_pallas_kernel_and_the_lax_fallback_agree_on_a_long_table(
         chunk_pages, monkeypatch):
     """256 pages a request at the widths of the CPU tests. With the kernel's
-    VMEM plan cut to 32 pages a chunk: full chunks (whose copies are issued
-    in written-out groups of 16) and a ragged last one, a request that ends
-    on a chunk's edge, one of a single page, one of none."""
+    VMEM plan cut to 32 pages a chunk: full chunks (whose copies are started
+    written out and waited for with one descriptor) and a ragged last one, a
+    request that ends on a chunk's edge, one of a single page, one of none."""
     from paddle_tpu.ops.pallas import paged_attention as pa
 
     rng = np.random.default_rng(11)

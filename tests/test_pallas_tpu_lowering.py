@@ -257,32 +257,39 @@ def test_decode_chunk_fits_its_vmem_budget():
 
 @pytest.mark.parametrize("groups", [1, 4])
 @pytest.mark.parametrize("pool", ["float32-2pages", "float32",
-                                  "bfloat16-2pages", "int8"])
+                                  "bfloat16-2pages", "int8",
+                                  "float32-8pages"])
 def test_decode_interpret_matches_lax_fallback(groups, pool, monkeypatch):
     """One batch with a context at every edge of a page and of a chunk
     (1, block - 1, block, C*block - 1, C*block, C*block + 1, the cap),
     unused table slots on the null page, and NaN in every pool slot that
-    holds no live token: a dead column has p = 0, and 0 x NaN is NaN."""
+    holds no live token: a dead column has p = 0, and 0 x NaN is NaN. With 8
+    pages a chunk under a table of 20: full chunks, whose 8 copies a pool
+    are started written out and waited for with one descriptor, and last
+    chunks of 1, 3 and 4 pages, started in a loop and waited for by the
+    binary digits of their number."""
     import numpy as np
 
     from paddle_tpu.inference.serving.kv_cache import quantize_kv_rows
     from paddle_tpu.inference.serving.paged_attention import _lax_fallback
 
     monkeypatch.setenv("PT_PALLAS_INTERPRET", "1")
-    hkv, d, blk, p = 2, 16, 4, 10
+    hkv, d, blk = 2, 16, 4
     h = hkv * groups
     dtype = jnp.bfloat16 if pool.startswith("bfloat16") else jnp.float32
-    if pool.endswith("2pages"):
-        # the budget that fits a 2-page chunk and not a 4-page one
+    pages = {"2pages": 2, "8pages": 8}.get(pool.split("-")[-1], 16)
+    p = 20 if pages == 8 else 10
+    if pages < 16:
+        # the budget that fits a chunk of that many pages and not twice it
         for budget in range(256, 1 << 20, 64):
             monkeypatch.setattr(pa, "_DECODE_VMEM_BUDGET", budget)
-            if pa._decode_chunk(blk, hkv, h, d, 4, p)[0] == 2:
+            if pa._decode_chunk(blk, hkv, h, d, 4, p)[0] == pages:
                 break
-    chunk = pa._decode_chunk(blk, hkv, h, d, 4, p)[0]
-    assert chunk == (2 if pool.endswith("2pages") else 16)
-    c = 2 * blk   # the chunk edge of the 2-page cases; a page edge otherwise
-    lens = np.array([1, blk - 1, blk, c - 1, c, c + 1, 2 * c + 2, p * blk],
-                    np.int32)
+    assert pa._decode_chunk(blk, hkv, h, d, 4, p)[0] == pages
+    # the chunk edge of the cases cut to a chunk; a page edge otherwise
+    c = (pages if pages < 16 else 2) * blk
+    lens = np.array([1, blk - 1, blk, c - 1, c, c + 1, 2 * c + 2 * blk + 2,
+                     p * blk], np.int32)
     b, n = len(lens), len(lens) * p + 1
     rng = np.random.default_rng(groups)
     order = rng.permutation(np.arange(1, n))
