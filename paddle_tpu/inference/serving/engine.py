@@ -217,6 +217,19 @@ def _counter_names(model):
     return tuple(getattr(model, "serve_counters", ()))
 
 
+def _keep_names(model):
+    """The rows a model's ``serve_layer`` hands the host beside the logits
+    (``state.keep``), by name."""
+    return tuple(getattr(model, "serve_keeps", ()))
+
+
+def _stack_kept(kept, names):
+    """What the layers kept, a name an array ``[layers, tokens, ...]``."""
+    import jax.numpy as jnp
+
+    return {n: jnp.stack(kept[n]) for n in names}
+
+
 #: a device-side counter is two int32 limbs, ``low + _LIMB * carried``: a
 #: count of millions a step (cached rows a decode step walks, over the
 #: layers) passes 2^31 within a thousand steps, and ``metrics()`` is what
@@ -282,7 +295,11 @@ class _StepPhases:
     commit, latency observations, finishes), ``engine.bookkeeping`` (store
     autosave, gauges); the speculative path adds ``engine.decode.draft``
     (the draft model's catch-up and proposals, with their own fetches).
-    All lie inside the step's ``engine.step`` and carry its ``args``.
+    All lie inside the step's ``engine.step`` and carry its ``args``. A
+    state kind adds no name: a decode step's slots are made in
+    ``engine.decode.prepare``, a chunk's slot in ``engine.prefill``, and a
+    step that ``_drain`` commits between two calls records its fetch and
+    emit under an ``engine.step`` of their own.
 
     On the per-step path a steady call's prepare and dispatch are the
     NEXT step's and its fetch and emit this step's (ISSUE 28); the first
@@ -313,13 +330,16 @@ class _DecodeInFlight:
     request, position written, the request's admit_seq then)]``; ``logits``
     and ``greedy`` are its result arrays, still on the device; ``sampled``
     says that a row's token is the host sampler's to choose; ``how`` is
-    how it was dispatched: ``"ahead"``, or the reason it was not."""
+    how it was dispatched: ``"ahead"``, or the reason it was not; ``kept``
+    is what the model's layers kept for the host (``state.keep``), None
+    for a model that keeps nothing."""
 
     rows: list
     logits: object
     greedy: object
     sampled: bool
     how: str
+    kept: object = None
 
     def standing(self, slots):
         """The rows whose request still sits where the step was made for
@@ -693,7 +713,9 @@ class LLMEngine:
             self._draft_decode_jit = None
             self._verify_jit = None
         #: read at every step, so a caller may switch it off once it has
-        #: the rows it wanted: greedy steps then fetch tokens only
+        #: the rows it wanted: greedy steps then fetch tokens only. While it
+        #: is on, what the model's layers keep for the host (``state.keep``,
+        #: ``model.serve_keeps``) is fetched too and put on ``Request.kept``
         self.capture_logits = bool(capture_logits)
         # hoisted from _emit (ISSUE 18 satellite): one import at
         # construction instead of one per emitted token
@@ -712,12 +734,15 @@ class LLMEngine:
         # small int32 array carried through the chunk and decode graphs,
         # fetched (and folded into these host totals) only by metrics()
         self._counter_names = _counter_names(model)
+        self._keep_names = _keep_names(model)
         self._counters_dev = None
         self._counter_totals = dict.fromkeys(
             [n + tail for tail in ("_decode", "_prefill")
              for n in self._counter_names], 0)
         # page-steps of either kind, for the live-bytes-vs-one-table ratio
         self._page_steps = [0, 0]
+        # slots holding a request, summed a step likewise (a state kind)
+        self._state_slot_steps = 0
         self._requests: dict[int, Request] = {}
         self._closed = False
         # fused ragged draft catch-up (ISSUE 16 perf satellite): one
@@ -761,6 +786,13 @@ class LLMEngine:
         self._ahead = None
         self._sync_reason = None
         self._no_prev = None
+        # a state kind (ISSUE 33): the slots operand of the last decode
+        # step and the live rows it was made for; the outputs of a step
+        # ``_drain`` committed outside a call of ``step``, which the next
+        # call hands out first
+        self._slots_dev = None
+        self._slots_live = None
+        self._committed = []
 
     # ------------------------------------------------------------------
     # persistent prefix store (ISSUE 16)
@@ -834,16 +866,20 @@ class LLMEngine:
         return (f"{what} is built for LlamaForCausalLM only; this engine "
                 f"serves {type(self.model).__name__}")
 
-    def _graph_extras(self, window_operand):
-        """The operands a cache with a window kind, or a model with
-        counters, adds behind a graph's pools (none for Llama, whose
-        graphs keep their operands)."""
-        if self.cache.window is None and not self._counter_names:
+    def _graph_extras(self, window_row=None, slots=None):
+        """The operands a cache with a window or a state kind, or a model
+        with counters, adds behind a graph's pools (none for Llama, whose
+        graphs keep their operands): a chunk's window row, a state kind's
+        slots (a chunk's request's, a decode step's a row), the counters.
+        The operand of a kind the cache lacks is None, which is no operand
+        of the program."""
+        if self.cache.window is None and self.cache.state_slots is None \
+                and not self._counter_names:
             return ()
         if self._counters_dev is None:
             self._counters_dev = self._g(np.zeros(
                 (2, max(2 * len(self._counter_names), 1)), np.int32))
-        return (window_operand, self._counters_dev)
+        return (window_row, slots, self._counters_dev)
 
     def _ensure_open(self):
         if self._closed:
@@ -1236,7 +1272,8 @@ class LLMEngine:
             return False
         if self._ingest is not None and self._ingest.pending:
             return True
-        return self.scheduler.has_work()
+        # outputs of a step committed between two calls wait for the next
+        return bool(self._committed) or self.scheduler.has_work()
 
     # ------------------------------------------------------------------
     # compiled graphs
@@ -1271,15 +1308,17 @@ class LLMEngine:
 
         The layer body is the model's (``serve_layer``); what a layer
         writes and attends over is its ``ChunkAttnState``. A cache with a
-        window kind, or a model with counters, adds two operands behind
-        the pools — the request's window row and the counter array — and
-        one result, the counters."""
+        window or a state kind, or a model with counters, adds
+        ``_graph_extras``' operands behind the pools — the request's window
+        row, its slot (int32 ``[1]``; the state arrays come and go in the
+        pools' places), the counter array — and one result, the counters."""
         from ...core.tensor import Tensor
 
         block_size = self.block_size
         _arr = self._arr
         layout = model.kv_layout()
         counter_names = _counter_names(model)
+        keep_names = _keep_names(model)
         n_tail = self.cache.window.n_tail if self.cache.window else 0
 
         def chunk_pure(param_arrays, ids, start, true_upto, tables_row,
@@ -1291,8 +1330,9 @@ class LLMEngine:
 
             quantized, ks_in, vs_in = _scales_in(k_pools, v_pools,
                                                  k_scales, v_scales)
-            window_row, counters = extra if extra else (None, None)
+            window_row, slot, counters = extra if extra else (None,) * 3
             counts = {} if extra else None
+            kept = {} if keep_names else None
             with _params_swapped(params, param_arrays):
                 start = jnp.asarray(start, jnp.int32)
                 upto = jnp.asarray(true_upto, jnp.int32)
@@ -1303,7 +1343,8 @@ class LLMEngine:
                     st = ChunkAttnState(
                         spec, block_size, start, upto, tables_row,
                         kp, vp, ksc, vsc, window_row=window_row,
-                        n_tail=n_tail, counters=counts)
+                        n_tail=n_tail, counters=counts, slot=slot,
+                        kept=kept)
                     x = model.serve_layer(i, x, st)
                     new_k.append(st.k_pool)
                     new_v.append(st.v_pool)
@@ -1319,6 +1360,8 @@ class LLMEngine:
             out = (_arr(logits)[:, 0], new_k, new_v, new_ks, new_vs)
             if extra:
                 out += (_add_counts(counters, counts, counter_names, False),)
+            if keep_names:
+                out += (_stack_kept(kept, keep_names),)
             return out
 
         return chunk_pure
@@ -1331,13 +1374,14 @@ class LLMEngine:
         between modes. Assumes the caller is inside ``_params_swapped``.
         The layer body is the model's (``serve_layer``) over a
         ``DecodeAttnState`` a layer. ``wtables`` is the window kind's ring
-        table, ``counts`` the dict the layers' counters land in."""
+        table, ``counts`` the dict the layers' counters land in, ``slots`` a
+        state kind's slot a row."""
         block_size = self.block_size
         _arr = self._arr
         layout = model.kv_layout()
 
         def core(ids, positions, tables, k_pools, v_pools, ks_in, vs_in,
-                 wtables=None, counts=None):
+                 wtables=None, counts=None, slots=None, kept=None):
             from .paged_attention import DecodeAttnState
 
             quantized = ks_in[0] is not None if ks_in else False
@@ -1348,7 +1392,8 @@ class LLMEngine:
                 st = DecodeAttnState(
                     spec, block_size, positions,
                     wtables if spec.kind == "window" else tables,
-                    kp, vp, ksc, vsc, counters=counts)
+                    kp, vp, ksc, vsc, counters=counts, slots=slots,
+                    kept=kept)
                 x = model.serve_layer(i, x, st)
                 new_k.append(st.k_pool)
                 new_v.append(st.v_pool)
@@ -1371,11 +1416,13 @@ class LLMEngine:
         host sampler's choice, bit for bit), so that a step whose requests
         all decode greedily fetches ``[B]`` int32 and not ``[B, V]``
         float32. Quantized caches quantize the written row and
-        store its per-head scale beside the codes (ISSUE 14). The two
-        extra operands and the extra result are ``_make_chunk_fn``'s, but
-        the first of them is empty here: with a window kind ``tables`` is
+        store its per-head scale beside the codes (ISSUE 14). The extra
+        operands and the extra result are ``_make_chunk_fn``'s, but the
+        window row is empty here: with a window kind ``tables`` is
         ``_decode_tables``' ``[B, P + R]``, the rings behind the global
-        table, so that a step puts one table and not two.
+        table, so that a step puts one table and not two. With a state kind
+        the slots are int32 ``[B]`` (``_state_slots``): where each row's
+        state lies, the null slot for a dead row.
 
         With ``feed_back`` (the engine's own decode, ISSUE 28) ``ids`` is
         ``[B, 2]`` and one operand comes before the others behind the
@@ -1388,6 +1435,7 @@ class LLMEngine:
 
         core = self._make_decode_core(model)
         counter_names = _counter_names(model)
+        keep_names = _keep_names(model)
         windowed, P = self.cache.window is not None, self.max_pages
 
         def decode_pure(param_arrays, ids, positions, tables,
@@ -1399,19 +1447,23 @@ class LLMEngine:
                 ids = jnp.where(ids[:, 1:] != 0, prev[:, None], ids[:, :1])
             _, ks_in, vs_in = _scales_in(k_pools, v_pools, k_scales,
                                          v_scales)
-            _, counters = extra if extra else (None, None)
+            _, slots, counters = extra if extra else (None,) * 3
             counts = {} if extra else None
+            kept = {} if keep_names else None
             wtables = None
             if windowed:        # ``_decode_tables``: global | rings
                 tables, wtables = tables[:, :P], tables[:, P:]
             with _params_swapped(params, param_arrays):
                 logits, new_k, new_v, new_ks, new_vs = core(
                     ids, positions, tables, k_pools, v_pools,
-                    ks_in, vs_in, wtables=wtables, counts=counts)
+                    ks_in, vs_in, wtables=wtables, counts=counts,
+                    slots=slots, kept=kept)
             out = (logits, greedy_tokens_in_graph(logits),
                    new_k, new_v, new_ks, new_vs)
             if extra:
                 out += (_add_counts(counters, counts, counter_names, True),)
+            if keep_names:
+                out += (_stack_kept(kept, keep_names),)
             return out
 
         return decode_pure
@@ -1762,20 +1814,33 @@ class LLMEngine:
             # of the staged ids, already replicated on the global mesh)
             start_a, upto_a = self._g(start_a), self._g(upto_a)
         cache = self.cache
-        window_row = None
+        window_row = slot = None
         if cache.window is not None:
             # the ring turns here: the pages behind the chunk's newest go
             # back to the allocator before the chunk is dispatched
             window_row = self._g(cache.window.chunk_row(
                 req.rid, start, start + take, C // self.block_size))
+        if cache.state_slots is not None:
+            # a request's state lies in its decode slot from its first
+            # chunk on; a chunk at ``start == 0`` does not read what the
+            # slot held (``ChunkAttnState.scan``)
+            slot = self._g(np.asarray(
+                [self.scheduler.slots.index(req)], np.int32))
         (logits, cache.k, cache.v, cache.k_scale, cache.v_scale,
-         *counters) = self._prefill_jit(
+         *tail) = self._prefill_jit(
                 [p._data for p in self._params], ids_chunk,
                 start_a, upto_a, tables_dev,
                 cache.k, cache.v, cache.k_scale, cache.v_scale,
-                *self._graph_extras(window_row))
-        if counters:
-            self._counters_dev = counters[0]
+                *self._graph_extras(window_row, slot))
+        kept = tail.pop() if self._keep_names else None
+        if tail:
+            self._counters_dev = tail[0]
+        if kept is not None and self.capture_logits:
+            if start == 0:
+                req.kept = {}         # recomputed from its first token
+            for name, rows in kept.items():
+                req.kept.setdefault(name, []).append(
+                    self._fetch(rows)[:, :take])
         if self.draft_model is not None:
             # mirror every target chunk into the draft pools: the draft
             # proposes continuations over the same block tables, so its
@@ -1832,9 +1897,13 @@ class LLMEngine:
                        else None)
         with _obs_trace.span("engine.step", cat="engine", args=phases.args):
             try:
-                return self._step(phases)
+                outputs = self._step(phases)
             finally:
                 phases.end()
+        if self._committed:
+            # a step ``_drain`` committed between two calls (a state kind)
+            outputs, self._committed = self._committed + outputs, []
+        return outputs
 
     def _step(self, phases):
         sched = self.scheduler
@@ -1934,16 +2003,47 @@ class LLMEngine:
 
     # -- decode dispatch-ahead (ISSUE 28) -------------------------------
     def _drain(self, reason="drain"):
-        """Forget the decode step in flight, if there is one: its tokens
-        are dropped and no ``num_cached`` moves, so the next call makes the
-        same step again with nothing in flight. What the forgotten step
-        wrote, each row at its request's own next position, that step
-        writes again, the same. For a reload of the weights (the step ran
-        on the old ones), ``close``, and a call that finds nothing left to
-        run. Page import and export, tier revival and a store save do NOT
+        """Be rid of the decode step in flight, if there is one, so that the
+        next call dispatches with nothing in flight.
+
+        Without a state kind the step is FORGOTTEN: its tokens are dropped
+        and no ``num_cached`` moves, so the next call makes the same step
+        again. What the forgotten step wrote, each row at its request's own
+        next position, that step writes again, the same. For a reload of
+        the weights (the step ran on the old ones), ``close``, and a call
+        that finds nothing left to run.
+
+        With a state kind (ISSUE 33) a forgotten step would be applied
+        twice: ``h <- a h + b`` is no write at a position. The step is
+        COMMITTED instead: its rows that still stand are fetched and emitted
+        as a call of ``step`` would have, here, and the outputs are kept for
+        the next call to hand out first (``_committed``). A reload of the
+        weights therefore keeps the token the old weights made, as it keeps
+        the pages and the states they made; a step whose requests are all
+        gone has no row that stands and is dropped as ever.
+
+        Page import and export, tier revival and a store save do NOT
         drain: the pools are arrays handed from program to program, so
         their gathers and scatters are enqueued behind the step in flight,
         and its writes lie past what they cover (``DESIGN_DECISIONS.md``)."""
+        if self.cache.state_slots is not None and self._ahead is not None:
+            cur = self._take_ahead()
+            if cur is not None:
+                # between two calls of ``step`` (inside one, ``_drain`` is
+                # reached only with no request left, so no row stands): the
+                # fetch and the emit are a step's, under a span of its own
+                cur.how = "commit"
+                phases = self._phases
+                phases.args = ({"engine": self._name}
+                               if _obs_trace.enabled() else None)
+                with _obs_trace.span("engine.step", cat="engine",
+                                     args=phases.args):
+                    try:
+                        self._emit_decode(cur, self._committed)
+                    finally:
+                        phases.end()
+                self._sync_reason = reason
+            return
         a, self._ahead = self._ahead, None
         if a is not None:
             _M_ROWS_DISCARDED.inc(len(a.rows), instance=self._name)
@@ -1979,15 +2079,34 @@ class LLMEngine:
                 self._no_prev = self._g(z)
         return self._no_prev
 
-    def _decode_args(self, ids, positions, tables, prev=None):
+    def _decode_args(self, ids, positions, tables, prev=None, slots=None):
         """The decode executable's operands, in its order: ``ids [B, 2]``,
         ``positions [B]``, the tables, the pools, the step before's greedy
-        tokens (zeros where there is none), then ``_graph_extras``."""
+        tokens (zeros where there is none), then ``_graph_extras`` (with a
+        state kind: ``slots``)."""
         c = self.cache
         return ([p._data for p in self._params], ids, positions, tables,
                 c.k, c.v, c.k_scale, c.v_scale,
                 self._prev_operand() if prev is None else prev,
-                *self._graph_extras(None))
+                *self._graph_extras(slots=slots))
+
+    def _state_slots(self, rows):
+        """With a state kind: where each row of the batch reads and writes
+        its state in a decode step for ``rows``, int32 ``[B]``. A live row's
+        slot is its own; every other row (an empty slot, a slot mid-prefill,
+        a row the step leaves out) is pointed at the null slot, the last, as
+        ``_tables`` points its pages at the null block: the decode graph
+        updates a state for EVERY row, and a step between two chunks of a
+        request's prefill must not advance that request's state. Kept on
+        the device while the live rows stay the same."""
+        if self.cache.state_slots is None:
+            return None
+        live = tuple(row[0] for row in rows)
+        if live != self._slots_live:
+            slots = np.full(self.max_batch_size, self.max_batch_size, np.int32)
+            slots[list(live)] = live
+            self._slots_dev, self._slots_live = self._g(slots), live
+        return self._slots_dev
 
     def decode_abstract_args(self):
         """``_decode_args`` as ``ShapeDtypeStruct``s, for lowering the decode
@@ -2003,7 +2122,8 @@ class LLMEngine:
         args = self._decode_args(
             self._g(np.zeros((B, 2), np.int32)),
             self._g(np.zeros(B, np.int32)),
-            self._g(np.zeros((B, width), np.int32)))
+            self._g(np.zeros((B, width), np.int32)),
+            slots=self._state_slots(()))
         return jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct(
                 x.shape, x.dtype,
@@ -2028,13 +2148,15 @@ class LLMEngine:
             positions[i] = pos
         c = self.cache
         args = self._decode_args(self._g(ids), self._g(positions),
-                                 self._decode_tables(rows), prev)
+                                 self._decode_tables(rows), prev,
+                                 self._state_slots(rows))
         self._phases.begin("engine.decode.dispatch")
         (logits, greedy, c.k, c.v, c.k_scale, c.v_scale,
-         *counters) = self._decode_jit(*args)
-        if counters:
-            self._counters_dev = counters[0]
-        return _DecodeInFlight(rows, logits, greedy, sampled, how)
+         *tail) = self._decode_jit(*args)
+        kept = tail.pop() if self._keep_names else None
+        if tail:
+            self._counters_dev = tail[0]
+        return _DecodeInFlight(rows, logits, greedy, sampled, how, kept)
 
     def _dispatch_ahead(self, cur):
         """Enqueue the decode step AFTER ``cur`` while ``cur`` is still the
@@ -2083,14 +2205,17 @@ class LLMEngine:
         """Fetch ``cur``'s result and commit a token a row. A step whose
         rows all decode greedily fetches its ``[B]`` int32 tokens, and with
         ``capture_logits`` on (read here, so it may be flipped between
-        calls) the ``[B, V]`` rows beside them for ``last_logits``: the
-        token is the graph's argmax either way. A step with a sampled row
-        fetches the rows and the host chooses every token from them."""
+        calls) the ``[B, V]`` rows beside them for ``last_logits`` and what
+        the layers kept (``Request.kept``): the token is the graph's argmax
+        either way. A step with a sampled row fetches the rows and the host
+        chooses every token from them."""
         phases = self._phases
         phases.begin("engine.decode.fetch")
         greedy = None if cur.sampled else self._fetch(cur.greedy)
         logits = (self._fetch(cur.logits)
                   if cur.sampled or self.capture_logits else None)
+        kept = ({n: self._fetch(a) for n, a in cur.kept.items()}
+                if cur.kept is not None and self.capture_logits else {})
         phases.begin("engine.decode.emit")
         inst = self._name
         _M_HOST_SYNCS.inc(instance=inst)
@@ -2103,6 +2228,8 @@ class LLMEngine:
             _M_DECODE_SYNC.inc(instance=inst, reason=cur.how)
         for i, req, _, _ in cur.rows:
             req.num_cached += 1
+            for name, rows in kept.items():
+                req.kept.setdefault(name, []).append(rows[:, i:i + 1])
             if greedy is None:
                 outputs.extend(self._emit(req, logits[i]))
                 continue
@@ -2177,6 +2304,8 @@ class LLMEngine:
         self._page_steps[0] += usable - self.cache.allocator.num_free
         if self.cache.window is not None:
             self._page_steps[1] += self.cache.window.blocks_in_use
+        if self.cache.state_slots is not None:
+            self._state_slot_steps += len(self.scheduler.running)
         _G_KV_UTIL.set(1.0 - self.cache.allocator.num_free / usable,
                        instance=self._name)
         _G_OCCUPANCY.set(len(self.scheduler.running) / self.max_batch_size,
@@ -2460,7 +2589,8 @@ class LLMEngine:
         back to ``latest_valid_step()``), a checkpoint step directory, or
         a state-dict file path. Returns the restored step (or None)."""
         # a step in flight ran on the weights that are about to go: drop
-        # it, and the next call makes it again on the new ones
+        # it, and the next call makes it again on the new ones (with a
+        # state kind it is committed: a state cannot take a step twice)
         self._drain()
         try:
             step = self._reload_weights_impl(source)
@@ -2630,7 +2760,10 @@ class LLMEngine:
         steps so far, and what ONE table paging every layer alike would
         hold for the same requests. The model's device-side counters are
         fetched here and nowhere else; each comes whole and split by the
-        graph that counted it (``_decode``, ``_prefill``)."""
+        graph that counted it (``_decode``, ``_prefill``). ``state_bytes``:
+        what the requests in the slots hold in the state layers now, at the
+        published widths; ``state_byte_steps`` the same summed over the
+        steps, as the pages' are."""
         cache, bs = self.cache, self.block_size
         window = cache.window
         if self._counters_dev is not None and self._counter_names:
@@ -2641,7 +2774,15 @@ class LLMEngine:
         g_steps, w_steps = self._page_steps
         g_bytes = cache.published_bytes_per_token("global")
         w_bytes = cache.published_bytes_per_token("window")
+        # a state is held a request, not a token: beside the pages' bytes,
+        # never among them (0 without a state kind)
+        in_slots = (len(self.scheduler.running)
+                    if cache.state_slots is not None else 0)
+        s_bytes = cache.state_bytes_per_request()
         return {
+            "state_slots_in_use": in_slots,
+            "state_bytes": in_slots * s_bytes,
+            "state_byte_steps": self._state_slot_steps * s_bytes,
             "global_blocks_in_use":
                 cache.num_blocks - 1 - cache.allocator.num_free,
             "window_blocks_in_use": window.blocks_in_use if window else 0,

@@ -99,6 +99,26 @@ the request advances, in prefill chunks and in decode. Everything that
 assumes ONE layout (prefix sharing, the host tier, page export/import,
 int8 pools, checksums, copy-on-write) refuses a cache that is not uniform,
 at construction and by name, rather than corrupt.
+
+ISSUE 31 adds *latent* layers: one row a token that all heads share, one
+pool a layer and no V pool, paged through the global table.
+
+ISSUE 33 adds **state that is not pages, and layers that keep nothing**. A
+*state* layer (a state-space mixer) holds, a REQUEST and not a token, the
+last few rows that enter its causal convolution and one recurrent state of
+fixed size, in float32 whatever the model's dtype. The cache holds two
+arrays a state layer over ``max_batch_size + 1`` slots, in the places a
+paged layer's K and V pools take in the lists the step functions thread and
+donate. A request's slot IS its decode slot: nothing is allocated, nothing
+can run out, so the kind never preempts; the last slot is the **null
+slot**, which every dead row of a decode step reads and writes, as a dead
+row's K/V lands in the null block. A *none* layer (a feed-forward block
+that is a layer of its own) keeps nothing: two empty arrays hold its place.
+Neither is counted a token: ``bytes_per_token``, ``kv_live_byte_steps`` and
+the scheduler's room are of the layers that page; ``state_bytes`` reports
+the state beside them. So the five kinds are ``global`` and ``latent``
+(pages of the global table), ``window`` (a ring of pages), ``state`` (a
+slot) and ``none``.
 """
 
 from __future__ import annotations
@@ -558,27 +578,42 @@ class PrefixCache:
 
 @dataclasses.dataclass(frozen=True)
 class KVLayerSpec:
-    """What one layer keeps in the cache. ``k_store`` is the width a K row
-    is stored at (``k_dim`` unless padded up to the lanes); ``prefill`` is
-    how a prefill chunk reads the layer's keys: ``"paged"`` page by page
-    through the multi-query kernel (the verify step's too), ``"linear"``
-    with the request's pages laid out in a row for the chunk kernel.
+    """What one layer keeps in the cache, by ``kind``: ``"global"`` pages
+    of K and V over the whole context, ``"window"`` a ring of such pages,
+    ``"latent"`` pages of one row a token, ``"state"`` a slot of fixed size
+    a request, ``"none"`` nothing.
+
+    ``k_store`` is the width a K row is stored at (``k_dim`` unless padded
+    up to the lanes); ``prefill`` is how a prefill chunk reads the layer's
+    keys: ``"paged"`` page by page through the multi-query kernel (the
+    verify step's too), ``"linear"`` with the request's pages laid out in a
+    row for the chunk kernel.
 
     A ``"latent"`` layer (compressed keys and values) caches ONE row of
     ``k_dim`` a token, shared by every query head, whose first ``v_dim``
     values are also what the softmax weighs: one pool a layer and no V pool.
     Its pages come from the global allocator and table, so to the scheduler
-    they are global pages."""
-    kind: str = "global"            # "global" | "window" | "latent"
+    they are global pages.
+
+    A ``"state"`` layer (ISSUE 33) keeps, a request, ``conv_rows`` rows of
+    ``k_dim`` (what enters its causal convolution next, in the model's
+    dtype) and a recurrent state of ``num_kv_heads`` heads x ``v_dim`` x
+    ``state_dim`` in float32: :meth:`state_shapes` says how the cache holds
+    them; ``scan_block`` is the tokens a block of a prefill chunk's scan
+    takes. A ``"none"`` layer states no width."""
+    kind: str = "global"            # global | window | latent | state | none
     num_kv_heads: int = 1
     k_dim: int = 128
     v_dim: int = 128
     k_store: int = 0                # 0: as published
     window: int | None = None
     prefill: str = "paged"
+    conv_rows: int = 0              # a state kind's convolution tail
+    state_dim: int = 0              # a state kind's N
+    scan_block: int = 128           # a state kind's block of a chunk's scan
 
     def __post_init__(self):
-        if self.kind not in ("global", "window", "latent"):
+        if self.kind not in ("global", "window", "latent", "state", "none"):
             raise ValueError(f"unknown KV layer kind {self.kind!r}")
         if self.kind == "latent" and (
                 self.num_kv_heads != 1 or self.prefill != "linear"
@@ -587,12 +622,22 @@ class KVLayerSpec:
                 "a latent layer caches one row a token (num_kv_heads 1), "
                 "its first v_dim values the values, read in a row by a "
                 "chunk (prefill='linear')")
+        if (self.kind == "state") != (self.state_dim > 0) or (
+                self.kind == "state" and self.conv_rows < 1):
+            raise ValueError(
+                "a state kind, and only it, states the rows of its "
+                "convolution tail and the width of its recurrent state")
         if (self.kind == "window") != (self.window is not None):
             raise ValueError("a window kind, and only it, states a window")
         if self.kind == "window" and self.prefill != "linear":
             raise ValueError("a window layer's chunk reads keys in a row")
         if not self.k_store:
             object.__setattr__(self, "k_store", self.k_dim)
+
+    @property
+    def paged(self):
+        """Whether the layer keeps rows a token, in pages."""
+        return self.kind in ("global", "window", "latent")
 
     def pool_shape(self, n, block_size, width):
         """The shape a pool of ``n`` pages is held in. A ``"paged"`` layer
@@ -606,9 +651,42 @@ class KVLayerSpec:
             return (n, block_size, self.num_kv_heads, width)
         return (n, block_size * self.num_kv_heads, width)
 
+    @property
+    def heads_a_lane_row(self):
+        """Heads of a state kind that lie side by side in the lanes: a head
+        ``v_dim`` wide fills ``v_dim`` of 128 lanes, so ``128 // v_dim`` of
+        them share a row where the head count allows it."""
+        pack = 128 // self.v_dim if 0 < self.v_dim < 128 else 1
+        return pack if pack > 0 and self.num_kv_heads % pack == 0 else 1
+
+    def state_shapes(self, slots):
+        """``(convolution tail, recurrent state)`` of ``slots`` slots. The
+        tail's rows lie in a row, ``[slots, conv_rows * k_dim]``, oldest
+        first (three rows of a ``[slots, 3, D]`` array would be padded to a
+        tile of sixteen). The state lies TRANSPOSED, ``[slots, H / pack, N,
+        pack * P]``: the state dim over the sublanes and ``pack`` heads
+        (``heads_a_lane_row``) side by side in the lanes, so that a decode
+        step's ``y = h C`` sums over sublanes and ``x`` lies as it comes."""
+        pack = self.heads_a_lane_row
+        return ((slots, self.conv_rows * self.k_dim),
+                (slots, self.num_kv_heads // pack, self.state_dim,
+                 pack * self.v_dim))
+
+    def state_bytes(self, itemsize=2):
+        """What ONE request holds in this layer whatever its length: the
+        convolution tail in the model's dtype and the state in float32 (0
+        for a kind that pages or keeps nothing)."""
+        if self.kind != "state":
+            return 0
+        return (self.conv_rows * self.k_dim * itemsize
+                + self.num_kv_heads * self.v_dim * self.state_dim * 4)
+
     def bytes_per_token(self, itemsize=2):
         """K and V of one token in this layer, at the published widths (a
-        latent row holds both)."""
+        latent row holds both; a state or a none kind holds nothing a
+        token)."""
+        if not self.paged:
+            return 0
         if self.kind == "latent":
             return self.k_dim * itemsize
         return self.num_kv_heads * (self.k_dim + self.v_dim) * itemsize
@@ -730,6 +808,12 @@ class PagedKVCache:
     threaded through compiled steps exactly like the payload pools;
     ``kv_dtype=None`` keeps ``k_scale``/``v_scale`` as empty lists so
     the fp path's pytrees carry zero extra leaves.
+
+    A layer that does not page (ISSUE 33) keeps its place in both lists:
+    a ``"state"`` layer's convolution tails stand in ``k`` and its recurrent
+    states (float32) in ``v``, each over ``state_slots = max_batch_size + 1``
+    slots, the last the null slot; a ``"none"`` layer has an empty array in
+    each. The step functions thread and donate them as they do the pools.
     """
 
     # ISSUE 20: when armed (``LLMEngine(kv_page_checksums=True)`` sets
@@ -779,6 +863,14 @@ class PagedKVCache:
             self.window = WindowPages(BlockAllocator(self.window_num_blocks),
                                       window, self.block_size)
         pool_dtype = jnp.int8 if self.quantized else dtype
+        #: slots of a state kind: one a decode slot and the null slot, which
+        #: is the last (a dead row of a decode step reads and writes it)
+        self.state_slots = None
+        if any(sp.kind == "state" for sp in self.layout):
+            if not max_batch_size:
+                raise ValueError("a state kind needs max_batch_size: it "
+                                 "holds a slot for every decode slot")
+            self.state_slots = int(max_batch_size) + 1
 
         def pool(sp, width):
             n = self.window_num_blocks if sp.kind == "window" \
@@ -786,11 +878,20 @@ class PagedKVCache:
             return jnp.zeros(sp.pool_shape(n, self.block_size, width),
                              pool_dtype)
 
-        self.k = [pool(sp, sp.k_store) for sp in self.layout]
-        # a latent layer has no V pool: an empty array keeps its place in
-        # the lists the step functions thread
-        self.v = [jnp.zeros((0,), pool_dtype) if sp.kind == "latent"
-                  else pool(sp, sp.v_dim) for sp in self.layout]
+        def held(sp, which):
+            """What stands in the K list (0) or the V list (1) for ``sp``:
+            a pool of pages, a state kind's convolution tails (model's
+            dtype) or its recurrent states (float32), or an empty array that
+            keeps the place (a latent layer's V, a none layer's both)."""
+            if sp.kind == "state":
+                return jnp.zeros(sp.state_shapes(self.state_slots)[which],
+                                 jnp.float32 if which else dtype)
+            if sp.kind == "none" or (which and sp.kind == "latent"):
+                return jnp.zeros((0,), pool_dtype)
+            return pool(sp, sp.v_dim if which else sp.k_store)
+
+        self.k = [held(sp, 0) for sp in self.layout]
+        self.v = [held(sp, 1) for sp in self.layout]
         if self.quantized:
             self.k_scale = [jnp.zeros(kp.shape[:-1], jnp.float32)
                             for kp in self.k]
@@ -816,9 +917,15 @@ class PagedKVCache:
     def published_bytes_per_token(self, kind, itemsize=2):
         """K and V bytes of one token over all layers whose pages are of
         ``kind`` (``"window"``: the rings; ``"global"``: the global table's,
-        latent rows among them), at the published widths."""
+        latent rows among them), at the published widths. A state is no
+        bytes a token: ``state_bytes_per_request`` has it."""
         return sum(sp.bytes_per_token(itemsize) for sp in self.layout
                    if (sp.kind == "window") == (kind == "window"))
+
+    def state_bytes_per_request(self, itemsize=2):
+        """What one request holds in the state layers, whatever its length,
+        at the published widths (0 without a state kind)."""
+        return sum(sp.state_bytes(itemsize) for sp in self.layout)
 
     def bytes_saved_vs_unquantized(self, config):
         """Total pool bytes an int8 cache saves versus the SAME pool in

@@ -301,6 +301,16 @@ def chunk_attention(q, k, v, q_start, k_start, upto, scale, *, window=None,
 # token and the matrix that expands it, ``state.attend_latent``: the state
 # writes the row and attends in its phase's way (a decode step absorbed, a
 # chunk expanded), which the model does not choose either.
+#
+# A state-space layer (ISSUE 33) does not attend: it hands over what enters
+# its causal convolution and its step sizes, ``state.scan``, and gets back
+# the convolved channels and the recurrence's output. The state holds the
+# convolution's tail and the recurrent state in the request's SLOT (a decode
+# step: every row its own slot, a dead row the null slot, updated in place
+# by the kernel; a chunk: from zeros if it is the request's first, from what
+# the chunk before left otherwise, its padding changing nothing). A layer
+# that keeps nothing (kind ``"none"``) is handed a state too, for
+# ``state.count``; it calls nothing else of it.
 
 
 def _pad_last(x, width):
@@ -310,13 +320,36 @@ def _pad_last(x, width):
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, extra)])
 
 
+def _conv_and_split(spec, shifted, conv_w, conv_b):
+    """The causal depthwise convolution and what it feeds. ``shifted``: the
+    ``K`` arrays ``[..., D]`` whose row ``t`` is the row ``K - 1 - j`` before
+    token ``t``'s own (oldest first, the last the tokens themselves);
+    ``conv_w [D, K]``, ``conv_b [D]``. Returns ``silu(sum_j w[:, j] *
+    shifted[j] + b)`` cut into ``(x [..., H, P], B [..., G, N], C [..., G,
+    N])``, in the rows' dtype."""
+    import jax
+
+    f32 = jnp.float32
+    out = conv_b.astype(f32)
+    for j, rows in enumerate(shifted):
+        out = out + rows.astype(f32) * conv_w[:, j].astype(f32)
+    out = jax.nn.silu(out).astype(shifted[-1].dtype)
+    heads, p, n = spec.num_kv_heads, spec.v_dim, spec.state_dim
+    groups = (spec.k_dim - heads * p) // (2 * n)
+    lead = out.shape[:-1]
+    x = out[..., :heads * p].reshape(*lead, heads, p)
+    b = out[..., heads * p:heads * p + groups * n].reshape(*lead, groups, n)
+    c = out[..., heads * p + groups * n:].reshape(*lead, groups, n)
+    return x, b, c
+
+
 class _AttnState:
     def __init__(self, spec, block_size, k_pool, v_pool, k_scale, v_scale,
-                 counters):
+                 counters, kept=None):
         self.spec, self.block_size = spec, block_size
         self.k_pool, self.v_pool = k_pool, v_pool
         self.k_scale, self.v_scale = k_scale, v_scale
-        self._counters = counters
+        self._counters, self._kept = counters, kept
 
     @property
     def quantized(self):
@@ -328,6 +361,16 @@ class _AttnState:
         if self._counters is not None:
             self._counters[name] = self._counters.get(name, 0) + value
 
+    def keep(self, name, rows):
+        """Hand the host ``rows`` (``[tokens, ...]``: a row a token of the
+        step, in its order) under ``name``, beside the logits: what the
+        layers keep comes out of the graph stacked, a layer after another,
+        and an engine that captures (``LLMEngine.capture_logits``) puts each
+        request's rows on it (``Request.kept``). Fetched by nothing
+        otherwise."""
+        if self._kept is not None:
+            self._kept.setdefault(name, []).append(rows)
+
     def _kernel_name(self, base):
         return base if self.spec.prefill == "paged" \
             else f"{base}_{self.spec.kind}"
@@ -335,6 +378,12 @@ class _AttnState:
     def _latent_row(self, row):
         """A token's latent row at the pool's width and dtype."""
         return _pad_last(row, self.spec.k_store).astype(self.k_pool.dtype)
+
+    def _require(self, *kinds):
+        if self.spec.kind not in kinds:
+            raise ValueError(
+                f"a {self.spec.kind!r} layer has no such state: this entry "
+                f"is for {' / '.join(kinds)} layers")
 
 
 class DecodeAttnState(_AttnState):
@@ -344,10 +393,13 @@ class DecodeAttnState(_AttnState):
     ring slots for a window kind)."""
 
     def __init__(self, spec, block_size, positions, table, k_pool, v_pool,
-                 k_scale=None, v_scale=None, counters=None):
+                 k_scale=None, v_scale=None, counters=None, slots=None,
+                 kept=None):
         super().__init__(spec, block_size, k_pool, v_pool, k_scale, v_scale,
-                         counters)
+                         counters, kept)
         self.positions, self.table = positions, table
+        #: a state kind's slot a row ``[B]``: the null slot for a dead row
+        self.slots = slots
 
     def rope(self, x, cos_t, sin_t):
         """Rotate ``x [B, 1, H, D]`` at each row's own position; ``cos_t``/
@@ -358,11 +410,40 @@ class DecodeAttnState(_AttnState):
         sn = sin_t[self.positions][:, None, None, :]
         return rope_rotate(x, c, sn)
 
+    def scan(self, xbc, dt, a, conv_w, conv_b):
+        """A state-space layer's step (ISSUE 33), one token a row. ``xbc
+        [B, 1, D]``: what enters the convolution; ``dt [B, 1, H]`` float32,
+        the step after its softplus; ``a [H]`` (negative); the convolution's
+        ``conv_w [D, K]`` and ``conv_b [D]``. Each
+        row's slot gives the ``K - 1`` rows before this one and takes the
+        newest ``K - 1`` back;
+        the recurrent state is read and written where it lies by
+        ``mamba2_decode_update``. Every row of the batch does so: a dead
+        row's slot is the null slot. Returns ``(x [B, 1, H, P], y [B, 1, H,
+        P] float32)``: the convolved channels and ``h_t C_t``."""
+        from ...ops.pallas.mamba2 import mamba2_decode_update
+
+        self._require("state")
+        spec, slots = self.spec, self.slots
+        tail = self.k_pool[slots]                     # [B, (K - 1) * D]
+        window = jnp.concatenate([tail, xbc[:, 0].astype(tail.dtype)], -1)
+        self.k_pool = self.k_pool.at[slots].set(window[:, spec.k_dim:])
+        d = spec.k_dim
+        x, b, c = _conv_and_split(
+            spec, [window[:, j * d:(j + 1) * d]
+                   for j in range(spec.conv_rows + 1)], conv_w, conv_b)
+        y, self.v_pool = mamba2_decode_update(self.v_pool, slots, x, dt[:, 0],
+                                              a, b, c)
+        self.count("ssm_state_rows_updated",
+                   jnp.sum(slots != self.v_pool.shape[0] - 1))
+        return x[:, None], y[:, None]
+
     def attend(self, q, k, v, scale, sink=None):
         import jax
 
         from .kv_cache import quantize_kv_rows
 
+        self._require("global", "window")
         spec, bs = self.spec, self.block_size
         positions, tables = self.positions, self.table
         kp, vp, ksc, vsc = self.k_pool, self.v_pool, self.k_scale, self.v_scale
@@ -453,11 +534,50 @@ class ChunkAttnState(_AttnState):
 
     def __init__(self, spec, block_size, start, upto, tables_row, k_pool,
                  v_pool, k_scale=None, v_scale=None, window_row=None,
-                 n_tail=0, counters=None):
+                 n_tail=0, counters=None, slot=None, kept=None):
         super().__init__(spec, block_size, k_pool, v_pool, k_scale, v_scale,
-                         counters)
+                         counters, kept)
         self.start, self.upto, self.tables_row = start, upto, tables_row
         self.window_row, self.n_tail = window_row, n_tail
+        #: a state kind: the request's slot, int32 ``[1]``
+        self.slot = slot
+
+    def scan(self, xbc, dt, a, conv_w, conv_b):
+        """A state-space layer's chunk (``DecodeAttnState.scan`` has the
+        operands, ``[1, C, ...]`` here). The request's FIRST chunk (``start
+        == 0``) starts from zeros whatever its slot holds: the slot may be
+        recycled, and a row dispatched ahead for the request that left it
+        may have written there since (programs run in order, and this one
+        does not read it). A later chunk starts from what the chunk before
+        wrote. Positions at or past ``upto`` change nothing: their step is
+        0, and the convolution's tail that goes back is the last ``K - 1``
+        REAL rows. The recurrence runs chunked, ``spec.scan_block`` tokens a
+        block (``ssd_chunk_scan``)."""
+        import jax
+
+        from ...ops.pallas.mamba2 import from_stored, ssd_chunk_scan, to_stored
+
+        self._require("state")
+        spec, slot = self.spec, self.slot[0]
+        pack = spec.heads_a_lane_row
+        fresh = self.start == 0
+        real = self.upto - self.start                 # tokens of the chunk
+        tail = jnp.where(fresh, 0, self.k_pool[slot]).reshape(-1, spec.k_dim)
+        rows = jnp.concatenate([tail.astype(xbc.dtype), xbc[0]])
+        keep = tail.shape[0]
+        self.k_pool = self.k_pool.at[slot].set(jax.lax.dynamic_slice_in_dim(
+            rows, real, keep).reshape(-1).astype(self.k_pool.dtype))
+        c_len = xbc.shape[1]
+        x, b, c = _conv_and_split(
+            spec, [rows[j:j + c_len] for j in range(keep + 1)], conv_w,
+            conv_b)
+        dt = jnp.where(jnp.arange(c_len)[:, None] < real, dt[0], 0.0)
+        h0 = from_stored(jnp.where(fresh, 0.0, self.v_pool[slot]), pack)
+        y, h = ssd_chunk_scan(x, dt, a, b, c, h0, spec.scan_block)
+        self.v_pool = self.v_pool.at[slot].set(
+            to_stored(h, pack).astype(self.v_pool.dtype))
+        self.count("ssm_tokens_scanned", real)
+        return x[None], y[None]
 
     def rope(self, x, cos_t, sin_t):
         """Rotate ``x [1, C, H, D]``, whose rows sit at ``start + i``."""
@@ -470,6 +590,7 @@ class ChunkAttnState(_AttnState):
 
         from .kv_cache import quantize_kv_rows
 
+        self._require("global", "window")
         spec, bs = self.spec, self.block_size
         kp, vp, ksc, vsc = self.k_pool, self.v_pool, self.k_scale, self.v_scale
         start, upto = self.start, self.upto

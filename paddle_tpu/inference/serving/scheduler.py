@@ -273,6 +273,11 @@ class Request:
         # built with ``capture_logits=True`` (ISSUE 18: the copy is a [V]
         # f32 D2H per emission, so it is opt-in); None otherwise
         self.last_logits = None
+        # what the model's layers kept for the host (``state.keep``) beside
+        # those rows, a name a list of ``[layers, tokens, ...]`` arrays in
+        # the order the engine computed this request's positions (a chunk,
+        # ..., a token a decode step); filled under ``capture_logits`` only
+        self.kept = {}
         self._rng = (np.random.RandomState(self.sampling.seed)
                      if self.sampling.do_sample else None)
 

@@ -18,3 +18,6 @@ from .mimo_v2 import (  # noqa: F401
 from .joyai_flash import (  # noqa: F401
     JoyAIFlashConfig, JoyAIFlashForCausalLM, joyai_flash_tiny,
 )
+from .nemotron_h import (  # noqa: F401
+    NemotronHConfig, NemotronHForCausalLM, nemotron_h_tiny,
+)

@@ -125,14 +125,17 @@ def _store_width(k_dim):
 
 
 def moe_dropless(x, router_w, bias, experts, held_slot, *, top_k,
-                 norm_topk=True, scaling=None, tm=None, with_passes=False):
+                 norm_topk=True, scaling=None, tm=None, with_passes=False,
+                 with_choice=False):
     """The expert block on arrays. ``x`` [T, D]; ``router_w`` [D, E] and
     ``bias`` [E] float32; ``experts``: one ``(gate [D, F], up [D, F], down
-    [F, D])`` a held expert; ``held_slot`` int32 [E]: an expert's place in
+    [F, D])`` a held expert, or one ``(up, down)`` for experts with no gate,
+    whose activation is ``relu(.)^2``; ``held_slot`` int32 [E]: an expert's place in
     ``experts``, ``len(experts)`` if it is not held here. Returns ``(y
     [T, D], routed (token, held expert) pairs, held experts with a
     token)``, and with ``with_passes`` a fourth: how many times an expert's
-    three matrices were streamed.
+    three matrices were streamed; with ``with_choice`` last of all the
+    experts each token chose, global ids int32 ``[T, top_k]``.
 
     Between "rows sorted by expert" and "weighted rows added into the
     output" runs the grouped kernel (``ops/pallas/grouped_ffn.py``) on the
@@ -164,7 +167,9 @@ def moe_dropless(x, router_w, bias, experts, held_slot, *, top_k,
     out, passes = rows(x, experts, slot, w, order, sizes, starts, tm)
     res = (out.astype(x.dtype), jnp.sum(sizes),
            jnp.sum((sizes > 0).astype(jnp.int32)))
-    return res + (passes,) if with_passes else res
+    if with_passes:
+        res += (passes,)
+    return res + (sel.astype(jnp.int32),) if with_choice else res
 
 
 def _tile_loop(x, experts, slot, w, order, sizes, starts, tm):
@@ -187,6 +192,10 @@ def _tile_loop(x, experts, slot, w, order, sizes, starts, tm):
         # a branch of its own an expert: its weights are read where they
         # lie. (One stacked array indexed by the tile's expert made XLA
         # copy the 16 MiB slice before each of the three dots.)
+        if len(weights) == 2:                 # no gate: relu(.)^2
+            up_w, down_w = weights
+            return lambda xs: jnp.dot(
+                jnp.square(jax.nn.relu(jnp.dot(xs, up_w))), down_w)
         gate_w, up_w, down_w = weights
         return lambda xs: jnp.dot(
             jax.nn.silu(jnp.dot(xs, gate_w)) * jnp.dot(xs, up_w), down_w)

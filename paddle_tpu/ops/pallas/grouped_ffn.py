@@ -1,9 +1,21 @@
-"""Grouped SwiGLU feed-forward — Pallas TPU (ISSUE 30).
+"""Grouped expert feed-forward — Pallas TPU (ISSUE 30; the ungated form
+ISSUE 33).
 
 The feed-forward of a layer's held experts as ONE call: the (token, expert)
 pairs sorted by expert go in, ``(silu(x g_e) * (x u_e)) d_e`` of each pair's
 token by its expert comes out, and every hit expert's three matrices are
 streamed through VMEM once a pass, block by block, where they lie.
+
+**The gate is optional.** An expert given as ``(up, down)`` has no gate and
+the activation is then ``relu(.)^2``: ``relu(x u_e)^2 d_e``. It is the same
+kernel body with the gate's buffers, copies and product absent, named
+``moe_grouped_relu2`` in a trace (``moe_grouped_swiglu`` with a gate). Below,
+"gate and up" reads "up" for such experts.
+
+**A width that is no multiple of 128 is stored wider.** The blocks are
+multiples of 128 rows, so an expert 1,856 wide is held 1,920 wide with zeros
+in ``up``'s last columns and ``down``'s last rows (``relu(0)^2 = 0`` and
+``silu(0) * 0 = 0``: exact); a roofline counts the published width.
 
 **Weights are read where they lie.** The ``3 x experts`` matrices are the
 model's own parameters, one array each, left in HBM (``pl.ANY``): nothing is
@@ -74,8 +86,10 @@ def use_pallas_grouped_ffn(d, f):
     if not ok:
         # PR 21: no hidden fallback on the chip
         raise ValueError(
-            "the grouped feed-forward kernel takes hidden and intermediate "
-            f"widths that are multiples of 128; got {d} and {f}")
+            "the grouped feed-forward kernel (gated or not) takes hidden "
+            "and intermediate widths that are multiples of 128, an expert's "
+            "matrices stored wider with zeros where its published width is "
+            f"none; got {d} and {f}")
     return True
 
 
@@ -95,11 +109,16 @@ def _block_rows(extent, width, itemsize, block_bytes):
 
 
 def _kernel(order_ref, e_ref, start_ref, rows_ref, n_ref, x_hbm, *refs,
-            n_held, top_k, kb, fb):
-    w = refs[:3 * n_held]                     # gate, up, down an expert
-    y_hbm = refs[3 * n_held]
-    (xbuf, xcast, gbuf, ubuf, dbuf, g_acc, u_acc, h_buf, o_acc, obuf,
-     sems) = refs[3 * n_held + 1:]
+            n_held, top_k, kb, fb, gated=True):
+    m = 3 if gated else 2
+    w = refs[:m * n_held]                     # (gate,) up, down an expert
+    y_hbm = refs[m * n_held]
+    scratch = refs[m * n_held + 1:]
+    if gated:
+        (xbuf, xcast, gbuf, ubuf, dbuf, g_acc, u_acc, h_buf, o_acc, obuf,
+         sems) = scratch
+    else:
+        xbuf, xcast, ubuf, dbuf, u_acc, h_buf, o_acc, obuf, sems = scratch
     d, f = w[0].shape
     n_k, n_f = d // kb, f // fb
     i = pl.program_id(0)
@@ -107,13 +126,13 @@ def _kernel(order_ref, e_ref, start_ref, rows_ref, n_ref, x_hbm, *refs,
 
     def gate_up(k, j, slot):
         at = pl.ds(j * kb, kb)
-        return [pltpu.make_async_copy(w[3 * k].at[at], gbuf.at[slot],
-                                      sems.at[0, slot]),
-                pltpu.make_async_copy(w[3 * k + 1].at[at], ubuf.at[slot],
-                                      sems.at[1, slot])]
+        gate = [pltpu.make_async_copy(w[3 * k].at[at], gbuf.at[slot],
+                                      sems.at[0, slot])] if gated else []
+        return gate + [pltpu.make_async_copy(
+            w[m * k + m - 2].at[at], ubuf.at[slot], sems.at[1, slot])]
 
     def down(k, j, slot):
-        return [pltpu.make_async_copy(w[3 * k + 2].at[pl.ds(j * fb, fb)],
+        return [pltpu.make_async_copy(w[m * k + m - 1].at[pl.ds(j * fb, fb)],
                                       dbuf.at[slot], sems.at[2, slot])]
 
     def start(e, b):
@@ -189,12 +208,14 @@ def _kernel(order_ref, e_ref, start_ref, rows_ref, n_ref, x_hbm, *refs,
                         at = c * 128 % kb
                         xcast[c * 128 // kb, :, at:at + 128] = \
                             xbuf[i % 2, :, c, :].astype(xcast.dtype)
-                    g_acc[...] = jnp.zeros_like(g_acc)
+                    if gated:
+                        g_acc[...] = jnp.zeros_like(g_acc)
                     u_acc[...] = jnp.zeros_like(u_acc)
 
                 wait(gate_up, b)
-                g_acc[...] += jnp.dot(xcast[b], gbuf[b % 2],
-                                      preferred_element_type=jnp.float32)
+                if gated:
+                    g_acc[...] += jnp.dot(xcast[b], gbuf[b % 2],
+                                          preferred_element_type=jnp.float32)
                 u_acc[...] += jnp.dot(xcast[b], ubuf[b % 2],
                                       preferred_element_type=jnp.float32)
 
@@ -205,10 +226,14 @@ def _kernel(order_ref, e_ref, start_ref, rows_ref, n_ref, x_hbm, *refs,
                 @pl.when(j == 0)
                 def _act():
                     for c in range(n_f):
-                        g = g_acc[:, c * fb:(c + 1) * fb]
-                        h_buf[c] = (g * jax.nn.sigmoid(g)
-                                    * u_acc[:, c * fb:(c + 1) * fb]
-                                    ).astype(h_buf.dtype)
+                        if gated:
+                            g = g_acc[:, c * fb:(c + 1) * fb]
+                            h_buf[c] = (g * jax.nn.sigmoid(g)
+                                        * u_acc[:, c * fb:(c + 1) * fb]
+                                        ).astype(h_buf.dtype)
+                        else:
+                            u = jnp.maximum(u_acc[:, c * fb:(c + 1) * fb], 0.0)
+                            h_buf[c] = (u * u).astype(h_buf.dtype)
                     o_acc[...] = jnp.zeros_like(o_acc)
 
                 wait(down, j)
@@ -234,17 +259,23 @@ def _kernel(order_ref, e_ref, start_ref, rows_ref, n_ref, x_hbm, *refs,
 
 def grouped_swiglu(x, order, item_expert, item_start, item_rows, n_items,
                    experts, *, rows, top_k, block_bytes=_BLOCK_BYTES,
-                   name="moe_grouped_swiglu"):
+                   name=None):
     """``x`` [T, D]; ``order`` int32 [T x k]: the pairs (pair ``p``
     is token ``p // top_k``) sorted by expert; ``item_expert`` /
     ``item_start`` / ``item_rows`` int32 [I]: an item's expert (its place in
     ``experts``), its first pair's place in ``order`` and how many pairs it
     holds (at most ``rows``); ``n_items`` int32: the items that exist (at
     most I); ``experts``: ``(gate [D, F], up [D, F], down [F, D])`` an
-    expert, of one dtype, which the dots run in. Returns float32
-    ``[T x k, D / 128, 128]``: row ``p`` the feed-forward of pair ``p``'s
-    token by the expert of the item that holds it; a pair in no item has
-    its row left as it was."""
+    expert, or ``(up [D, F], down [F, D])`` for experts with no gate (the
+    activation is then ``relu(.)^2``), of one dtype, which the dots run in.
+    Returns float32 ``[T x k, D / 128, 128]``: row ``p`` the feed-forward
+    of pair ``p``'s token by the expert of the item that holds it; a pair
+    in no item has its row left as it was. ``name`` is the kernel's name in
+    a trace: ``moe_grouped_swiglu``, or ``moe_grouped_relu2`` without a
+    gate."""
+    if name is None:
+        name = ("moe_grouped_swiglu" if len(experts[0]) == 3
+                else "moe_grouped_relu2")
     return _call(x, order, item_expert, item_start, item_rows, n_items,
                  experts, rows=rows, top_k=top_k, block_bytes=block_bytes,
                  interpret=_interpret(), name=name)
@@ -258,6 +289,7 @@ def grouped_swiglu(x, order, item_expert, item_start, item_rows, n_items,
 def _call(x, order, item_expert, item_start, item_rows, n_items, experts, *,
           rows, top_k, block_bytes, interpret, name="moe_grouped_swiglu"):
     t, d = x.shape
+    gated = len(experts[0]) == 3
     f = experts[0][0].shape[1]
     dtype = experts[0][0].dtype
     if d % 128 or f % 128 or rows % ROW_ALIGN:
@@ -265,28 +297,32 @@ def _call(x, order, item_expert, item_start, item_rows, n_items, experts, *,
             f"grouped_swiglu takes widths that are multiples of 128 and row "
             f"tiles that are multiples of {ROW_ALIGN}; got D={d}, F={f}, "
             f"rows={rows}")
+    shapes = ((d, f),) * (len(experts[0]) - 1) + ((f, d),)
     for mats in experts:
-        if tuple(m.shape for m in mats) != ((d, f), (d, f), (f, d)) \
+        if len(mats) not in (2, 3) \
+                or tuple(m.shape for m in mats) != shapes \
                 or any(m.dtype != dtype for m in mats):
             raise ValueError("every expert is (gate [D, F], up [D, F], down "
-                             "[F, D]) of one dtype")
+                             "[F, D]), or (up, down) alike, of one dtype")
     kb = _block_rows(d, f, dtype.itemsize, block_bytes)
     fb = _block_rows(f, d, dtype.itemsize, block_bytes)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
+    up_block = pltpu.VMEM((2, kb, f), dtype)
+    up_acc = pltpu.VMEM((rows, f), jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(item_expert.shape[0],),
-        in_specs=[hbm] * (1 + 3 * len(experts)),
+        in_specs=[hbm] * (1 + len(experts[0]) * len(experts)),
         out_specs=hbm,
         scratch_shapes=[
             pltpu.VMEM((2, rows, d // 128, 128), jnp.float32),  # rows fetched
             pltpu.VMEM((d // kb, rows, kb), dtype),       # rounded, by block
-            pltpu.VMEM((2, kb, f), dtype),                # gate blocks
-            pltpu.VMEM((2, kb, f), dtype),                # up blocks
+            *([up_block] if gated else []),               # gate blocks
+            up_block,                                     # up blocks
             pltpu.VMEM((2, fb, d), dtype),                # down blocks
-            pltpu.VMEM((rows, f), jnp.float32),           # x g
-            pltpu.VMEM((rows, f), jnp.float32),           # x u
-            pltpu.VMEM((f // fb, rows, fb), dtype),       # silu(x g) * (x u)
+            *([up_acc] if gated else []),                 # x g
+            up_acc,                                       # x u
+            pltpu.VMEM((f // fb, rows, fb), dtype),       # the activation
             pltpu.VMEM((rows, d), jnp.float32),           # the down dot
             pltpu.VMEM((rows, d // 128, 128), jnp.float32),     # rows put back
             pltpu.SemaphoreType.DMA((5, 2)),
@@ -295,9 +331,11 @@ def _call(x, order, item_expert, item_start, item_rows, n_items, experts, *,
     ints = [jnp.asarray(a, jnp.int32) for a in
             (order, item_expert, item_start, item_rows,
              jnp.reshape(n_items, (1,)))]
+    kernel = dict(n_held=len(experts), top_k=top_k, kb=kb, fb=fb)
+    if not gated:
+        kernel["gated"] = False
     return pl.pallas_call(
-        functools.partial(_kernel, n_held=len(experts), top_k=top_k, kb=kb,
-                          fb=fb),
+        functools.partial(_kernel, **kernel),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((order.shape[0], d // 128, 128),
                                        jnp.float32),

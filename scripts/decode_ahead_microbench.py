@@ -10,10 +10,13 @@ engage share ``ahead / (ahead + synchronous)`` of the window's decode steps
 with the synchronous ones by reason and the rows discarded, for an expert
 model the experts hit over the weight passes made (decode steps and
 prefill chunks apart), for a latent cache the cached rows a decode step
-walked and a chunk expanded (a layer), the window's seconds by kind of call,
+walked and a chunk expanded (a layer), for a state kind (ISSUE 33) the
+states a decode step updated and the tokens a chunk scanned (a layer), the
+window's seconds by kind of call,
 and with ``--trace 1`` the device's busy time in ``decode_pure`` a traced
-decode step (and the grouped expert kernel's and the latent decode kernel's
-parts of it), the largest device operations, the device's idle share, and for
+decode step (and the grouped expert kernel's, the latent decode kernel's and
+the state update's parts of it), the largest device operations and
+``decode_pure``'s time by kind of operation, the device's idle share, and for
 each kind of decode kernel the share of the chunks it walked in the traced
 steps whose every page was live (ISSUE 32: those are started written out and
 waited for with one descriptor a pool; from the loop's own context lengths).
@@ -66,7 +69,36 @@ def window_counts(snaps, layers):
             / layers / max(out["host_syncs"], 1),
             "rows_expanded_a_chunk": d("mla_context_tokens_expanded_prefill")
             / layers / max(d("prefill_chunks"), 1)}
+    # a state kind (ISSUE 33): the states a decode step's kernel read and
+    # wrote and the tokens a prefill chunk scanned, in one state layer
+    if d("ssm_state_rows_updated_decode"):
+        state_layers = max(sum(
+            1 for sp in snaps[-1].get("_layout", ()) if sp == "state"), 1)
+        out["ssm"] = {
+            "states_updated_a_decode_step": d("ssm_state_rows_updated_decode")
+            / state_layers / max(out["host_syncs"], 1),
+            "tokens_scanned_a_chunk": d("ssm_tokens_scanned_prefill")
+            / state_layers / max(d("prefill_chunks"), 1),
+            "state_bytes_now": m1.get("state_bytes"),
+            "state_slots_in_use": m1.get("state_slots_in_use")}
     return out
+
+
+def by_kind(events, n):
+    """``[(kind, seconds, calls)]``: device time by kind of operation, a
+    Mosaic kernel by its name and an XLA operation by its opcode and result
+    (``fusion bf16[192,2688]``), the ``n`` largest."""
+    import re
+
+    kinds = {}
+    for name, _, dur, _ in events:
+        head, _, rest = name.partition(" ")
+        kind = re.sub(r"\.\d+$", "", head) if " custom-call" in " " + rest \
+            else rest
+        sec, calls = kinds.get(kind, (0.0, 0))
+        kinds[kind] = (sec + dur, calls + 1)
+    return sorted(((k, s, c) for k, (s, c) in kinds.items()),
+                  key=lambda r: -r[1])[:n]
 
 
 def full_chunk_shares(cache, heads, max_model_len, lens_by_step):
@@ -80,7 +112,7 @@ def full_chunk_shares(cache, heads, max_model_len, lens_by_step):
     block = cache.block_size
     lens = [n for step in lens_by_step for n in step]
     out = {}
-    for spec in dict.fromkeys(cache.layout):
+    for spec in dict.fromkeys(sp for sp in cache.layout if sp.paged):
         pages = cache.window.ring if spec.kind == "window" \
             else -(-max_model_len // block)
         hkv, dv = (1, 0) if spec.kind == "latent" \
@@ -119,7 +151,7 @@ def main(argv=None):
 
     def kept(self):
         m = plain(self)
-        snaps.append(m)
+        snaps.append(dict(m, _layout=[sp.kind for sp in self.cache.layout]))
         return m
 
     LLMEngine.metrics = kept
@@ -170,10 +202,16 @@ def main(argv=None):
                 [(s[1] - s[0]) * 1e3 for s in run["traced_steps"]
                  if s[4] and not s[3]], 50),
             "grouped_ffn_device_ms_a_step": 1e3 * trace_reduce.op_seconds(
-                tr["events"], "moe_grouped_swiglu", "decode_pure") / steps,
+                tr["events"], "moe_grouped_(swiglu|relu2)", "decode_pure")
+            / steps,
             "latent_decode_device_ms_a_step": 1e3 * trace_reduce.op_seconds(
                 tr["events"], "paged_decode_attention_latent",
                 "decode_pure") / steps,
+            "ssm_decode_device_ms_a_step": 1e3 * trace_reduce.op_seconds(
+                tr["events"], "mamba2_decode_update", "decode_pure") / steps,
+            "decode_pure_by_kind": by_kind(decode, 40),
+            "chunk_pure_by_kind": by_kind(trace_reduce.select(
+                tr["events"], None, "chunk_pure"), 24),
             "chunk_attention_device_s": trace_reduce.op_seconds(
                 tr["events"], "chunk_attention_global", "chunk_pure"),
             "idle_share": 100.0 * (1.0 - tr["busy_s"] / tr["window_s"]),

@@ -191,6 +191,35 @@ def test_the_names_the_benchmark_reads_are_the_engines(model, tracer):
     assert ps.span_ms(parsed[0], ps.PREPARE) > 0
 
 
+def test_a_state_kind_adds_no_span_name(tracer):
+    """ISSUE 33: the slots operand is made inside ``engine.decode.prepare``
+    and a chunk's slot inside ``engine.prefill``; admission grows no phase. A
+    step committed by ``_drain`` between two calls is a fetch and an emit
+    under an ``engine.step`` of their own, without a step number."""
+    from paddle_tpu.models import NemotronHForCausalLM, nemotron_h_tiny
+
+    paddle.seed(7)
+    net = NemotronHForCausalLM(nemotron_h_tiny())
+    net.eval()
+    with LLMEngine(net, num_blocks=64, block_size=4, max_batch_size=4,
+                   max_prefill_tokens_per_step=8) as eng:
+        _submit(eng, lengths=(5, 19), new=6)
+        for _ in range(5):
+            eng.step()
+        assert eng._ahead is not None
+        eng._drain()
+        while eng.has_work():
+            eng.step()
+        assert eng.metrics()["decode_steps_sync_by_reason"]["commit"] == 1
+    steps = _steps(tracer.events())
+    names = {e["name"] for _, inside in steps for e in inside}
+    assert names == {"engine.admit", "engine.prefill", "engine.bookkeeping",
+                     *DECODE_PHASES}
+    committed = [[e["name"] for e in inside] for step, inside in steps
+                 if "step" not in step["args"]]
+    assert committed == [["engine.decode.fetch", "engine.decode.emit"]]
+
+
 def test_a_tick_without_work_is_no_step(model, tracer):
     with _engine(model) as eng:
         assert eng.step() == []
@@ -434,22 +463,34 @@ def _paged_decode_latent():
                 jnp.zeros((2, 3), jnp.int32), jnp.ones((2,), jnp.int32))
 
 
-def _grouped_swiglu():
+def _grouped_swiglu(gated=True):
     from paddle_tpu.ops.pallas import grouped_ffn as gf
 
     def fn(x, order, w):
         items = jnp.zeros((2,), jnp.int32)
         return gf.grouped_swiglu(
             x, order, items, items, jnp.full((2,), 4, jnp.int32), jnp.int32(1),
-            [(w, w, w.T)], rows=16, top_k=2)
+            [(w, w, w.T) if gated else (w, w.T)], rows=16, top_k=2)
 
     return fn, (jnp.zeros((4, 128), jnp.float32), jnp.arange(8, dtype=jnp.int32),
                 jnp.zeros((128, 128), jnp.float32))
 
 
+def _mamba2_decode():
+    from paddle_tpu.ops.pallas import mamba2
+
+    def fn(state, x, dt, b):
+        return mamba2.mamba2_decode_update(
+            state, jnp.asarray([1, 2], jnp.int32), x, dt, -jnp.ones((4,)), b, b)
+
+    return fn, (jnp.zeros((3, 4, 16, 8), jnp.float32), jnp.zeros((2, 4, 8)),
+                jnp.zeros((2, 4)), jnp.zeros((2, 2, 16)))
+
+
 #: one entry a ``pl.pallas_call`` site; a site that takes its name from its
 #: wrapper is listed once more under each name the serving path gives it
-#: (the fp decode site, ``_decode_call``, serves K / V and latent pages)
+#: (the fp decode site, ``_decode_call``, serves K / V and latent pages; the
+#: grouped expert site serves experts with a gate and without)
 KERNELS = [
     ("chunk_attention_global", _chunk_attention),
     ("paged_decode_attention", _paged_decode),
@@ -463,6 +504,8 @@ KERNELS = [
     ("layer_norm_fwd", _layer_norm),
     ("moe_ffn", _moe_ffn),
     ("moe_grouped_swiglu", _grouped_swiglu),
+    ("moe_grouped_relu2", lambda: _grouped_swiglu(gated=False)),
+    ("mamba2_decode_update", _mamba2_decode),
 ]
 
 
@@ -498,11 +541,12 @@ def test_every_pallas_call_has_a_name():
             # literal (the serving dispatch names a kernel by the kind of
             # layer it serves, ISSUE 27)
             head = src[:m.start()].rsplit("\ndef ", 1)[-1]
-            assert re.search(r'\bname="[a-z_]+"', body) or (
+            assert re.search(r'\bname="[a-z_0-9]+"', body) or (
                 re.search(r"\bname=name\b", body)
-                and re.search(r'\bname="[a-z_]+"\):', head)), (f, body[:80])
-    # ``_decode_call`` is listed under both its wrappers' names
-    assert calls == len(KERNELS) - 1
+                and re.search(r'\bname="[a-z_0-9]+"\):', head)), (f, body[:80])
+    # ``_decode_call`` and the grouped expert call are listed under both
+    # their names
+    assert calls == len(KERNELS) - 2
 
 
 @pytest.mark.parametrize("name,make", [

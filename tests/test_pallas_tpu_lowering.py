@@ -124,12 +124,53 @@ def _grouped_ffn_cases():
     return cases
 
 
-CASES = _flash_cases() + _paged_cases() + _grouped_ffn_cases()
+def _nemotron_cases():
+    """ISSUE 33's two kernels at the published widths and the benchmark's
+    batch: the state update over 192 rows of 193 slots (64 heads of 64 over a
+    state of 128, two heads a lane row), and the grouped expert kernel
+    WITHOUT a gate at hidden 2,688, an expert 1,856 wide stored 1,920, a
+    decode step's 192 tokens and a 2,048-token chunk."""
+    from paddle_tpu.ops.pallas import mamba2
+
+    sds = jax.ShapeDtypeStruct
+    b, heads, p, n, groups = 192, 64, 64, 128, 8
+    def update(*operands):      # the kernel itself, whatever the backend
+        return mamba2._call(*operands, interpret=False)
+
+    cases = [("ssm-decode-update", update, (
+        sds((b + 1, heads // 2, n, 2 * p), jnp.float32), sds((b,), jnp.int32),
+        sds((b, heads, p), jnp.bfloat16), sds((b, heads), jnp.float32),
+        sds((heads,), jnp.float32), sds((b, groups, n), jnp.bfloat16),
+        sds((b, groups, n), jnp.bfloat16)))]
+    d, f, held, top_k = 2688, 1920, 16, 6
+
+    def ffn(rows):
+        def run(x, order, item_expert, item_start, item_rows, n_items, *w):
+            return gf.grouped_swiglu(
+                x, order, item_expert, item_start, item_rows, n_items,
+                [tuple(w[2 * e:2 * e + 2]) for e in range(held)], rows=rows,
+                top_k=top_k)
+        return run
+
+    for t in (192, 2048):
+        rows = gf.rows_for(t)
+        items = sds((held + t * top_k // rows,), jnp.int32)
+        cases.append((f"grouped-relu2-{t}", ffn(rows), (
+            sds((t, d), jnp.bfloat16), sds((t * top_k,), jnp.int32),
+            items, items, items, sds((), jnp.int32)) + tuple(
+                sds(shape, jnp.bfloat16) for _ in range(held)
+                for shape in ((d, f), (f, d)))))
+    return cases
+
+
+CASES = _flash_cases() + _paged_cases() + _grouped_ffn_cases() \
+    + _nemotron_cases()
 #: stage 2 keeps tier-1 short: the backward cases (a grad compiles the
 #: forward kernel too), decode, the top rung that VMEM decides, and the
 #: grouped expert kernel (48 operands left in HBM, 48 MiB of VMEM asked for)
 COMPILED_CASES = [c for c in CASES if c[0].startswith(
-    ("flash-bwd", "decode", "mq2048", "grouped-ffn"))]
+    ("flash-bwd", "decode", "mq2048", "grouped-ffn", "ssm-decode",
+     "grouped-relu2"))]
 
 
 @pytest.mark.parametrize("name,fn,args", CASES, ids=[c[0] for c in CASES])
@@ -229,6 +270,8 @@ def test_compiles_for_v5e_without_a_chip():
     for case, want in (("decode", ["paged_decode_attention"]),
                        ("mq2048", ["paged_prefill_attention"]),
                        ("grouped-ffn", ["moe_grouped_swiglu"]),
+                       ("grouped-relu2", ["moe_grouped_relu2"]),
+                       ("ssm-decode", ["mamba2_decode_update"]),
                        ("flash-bwd", ["flash_attention_fwd",
                                       "flash_attention_bwd_dq",
                                       "flash_attention_bwd_dkv"])):
