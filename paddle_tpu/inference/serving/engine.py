@@ -108,6 +108,13 @@ _M_TOKENS = _obs_metrics.counter(
 _M_PREFILLS = _obs_metrics.counter(
     "serving_prefills_total", "prefill completions (incl. eviction "
     "re-prefills)")
+_M_PREFILL_BEHIND = _obs_metrics.counter(
+    "serving_prefill_ends_behind_decode_total",
+    "prefill completions whose last chunk's logits were fetched with a "
+    "decode step enqueued behind the chunk: the call found a step in "
+    "flight and dispatched the next one ahead before it fetched (the "
+    "others fetched at once, with nothing in flight, or with a step in "
+    "flight behind which nothing could be dispatched)")
 _M_PREFILL_CHUNKS = _obs_metrics.counter(
     "serving_prefill_chunks_total",
     "block-aligned prefill chunk executions (chunked prefill splits one "
@@ -180,7 +187,8 @@ _M_ROWS_DISCARDED = _obs_metrics.counter(
 # histogram would leak warm-phase samples into bench percentiles)
 _SERVING_METRICS = (_M_ADMITTED, _M_EVICTIONS, _M_FINISHED, _M_QUEUED_EXH,
                     _M_PREFIX_REUSED, _M_COW, _M_PREFILLS,
-                    _M_PREFILL_CHUNKS, _M_SPEC_PROPOSED, _M_SPEC_ACCEPTED,
+                    _M_PREFILL_BEHIND, _M_PREFILL_CHUNKS,
+                    _M_SPEC_PROPOSED, _M_SPEC_ACCEPTED,
                     _M_TOKENS, _M_DEADLINE, _M_KV_SAVED, _H_TTFT, _H_ITL,
                     _H_QUEUE_WAIT,
                     _G_SPEC_RATIO, _G_KV_UTIL, _G_OCCUPANCY,
@@ -286,8 +294,11 @@ class _StepPhases:
     path the step takes. Same names on every path:
 
     ``engine.admit`` (ingest drain, deadline scan, admission, tier
-    revivals), ``engine.prefill`` (one chunk: dispatch, and on the last
-    chunk the fetch and the first token), ``engine.decode.prepare`` (decode
+    revivals), ``engine.prefill`` (one chunk's dispatch and, with nothing
+    in flight, a last chunk's fetch and first token; beside a decode step
+    in flight that fetch and token come in an ``engine.prefill`` of their
+    own, once a call, behind the call's decode dispatch: ISSUE 34),
+    ``engine.decode.prepare`` (decode
     room, copy-on-write, the ready list, the step's inputs and their
     puts), ``engine.decode.dispatch`` (the call of the decode or
     verify executable until it returns), ``engine.decode.fetch`` (the wait
@@ -305,7 +316,10 @@ class _StepPhases:
     NEXT step's and its fetch and emit this step's (ISSUE 28); the first
     call after a break holds prepare and dispatch twice, this step's and
     the next one's, and a call that dispatches nothing ahead holds an
-    empty prepare."""
+    empty prepare. A call that ends a prefill beside a step in flight
+    holds ``engine.prefill`` twice: its chunks before the prepare, the
+    fetch of their logits and the first tokens between the dispatch and
+    this step's fetch."""
 
     __slots__ = ("args", "_open")
 
@@ -1776,11 +1790,16 @@ class LLMEngine:
                 self.draft_cache.copy_block(src, dst)
         self.scheduler.pending_cow.clear()
 
-    def _run_chunk(self, req, start, take, outputs):
+    def _run_chunk(self, req, start, take, outputs, owed):
         """One block-aligned prefill chunk: materialize ``take`` tokens of
-        ``req`` starting at ``start`` in the pool(s); on the final chunk,
-        sample the first output token from the chunk's last-position
-        logits."""
+        ``req`` starting at ``start`` in the pool(s). The final chunk's
+        last-position logits are the request's first token. With no
+        decode step of this call's in flight (``owed`` is None) they are
+        fetched and the token emitted here, at once; beside one, the
+        request and the logits, still on the device, go on ``owed`` and
+        ``_step`` fetches them behind its decode dispatch
+        (``_first_tokens``, ISSUE 34). Until then the request stays
+        ``prefilling``, so nothing counts it among the rows to decode."""
         self._phases.begin("engine.prefill")
         staged = getattr(req, "_staged", None)
         if staged is None or staged[2] != req.prefill_upto:
@@ -1864,27 +1883,51 @@ class LLMEngine:
             self.prefix_cache.register(req.tokens, req.blocks,
                                        req.num_cached, tenant=req.tenant)
         if req.num_cached >= req.prefill_upto:
-            req.prefilling = False
             self.stats_extra["prefills"] += 1
             _M_PREFILLS.inc(instance=self._name)
-            # the _emit below fetches logits (the existing sync point);
-            # the prefill span closes right after it
-            outputs.extend(self._emit(req, self._fetch(logits)[0]))
-            req.t_decode_start = time.perf_counter_ns()
-            if _obs_trace.enabled():
-                _obs_trace.add_complete(
-                    "request.prefill",
-                    getattr(req, "_t_admit", req.t_queue_start),
-                    req.t_decode_start, cat="request", tid=req.rid,
-                    args={"rid": req.rid, "engine": self._name,
-                          "bucket": bucket, "true_len": req.prefill_upto})
+            if owed is None:
+                self._first_token(req, logits, bucket, outputs)
+            else:
+                owed.append((req, logits, bucket))
+
+    def _first_token(self, req, logits, bucket, outputs):
+        """The host's end of a prefill: fetch the last chunk's logits (the
+        sync point), emit the first token from them, and the request is
+        ready to decode; its prefill span closes here."""
+        req.prefilling = False
+        outputs.extend(self._emit(req, self._fetch(logits)[0]))
+        req.t_decode_start = time.perf_counter_ns()
+        if _obs_trace.enabled():
+            _obs_trace.add_complete(
+                "request.prefill",
+                getattr(req, "_t_admit", req.t_queue_start),
+                req.t_decode_start, cat="request", tid=req.rid,
+                args={"rid": req.rid, "engine": self._name,
+                      "bucket": bucket, "true_len": req.prefill_upto})
+
+    def _first_tokens(self, owed, outputs, behind):
+        """Empty ``owed``, the last chunks this call ran beside a decode
+        step in flight: a second ``engine.prefill`` in the call, after its
+        decode dispatch. ``behind`` says that the dispatch enqueued a step
+        behind the chunks, so that the device has work while the host
+        waits for these logits, emits and prepares the step after."""
+        self._phases.begin("engine.prefill")
+        if behind:
+            _M_PREFILL_BEHIND.inc(len(owed), instance=self._name)
+        for req, logits, bucket in owed:
+            self._first_token(req, logits, bucket, outputs)
+        owed.clear()
 
     def step(self):
         """One engine tick: drain ingest, admit, advance chunked prefills
         under the token budget, one decode (or speculative verify) for all
         decode-ready slots. Returns the ``StepOutput`` tokens produced:
         call k returns step k's tokens, also where step k was enqueued by
-        call k-1 and step k+1 is enqueued by this one (``_dispatch_ahead``).
+        call k-1 and step k+1 is enqueued by this one (``_dispatch_ahead``),
+        and before them the first token of every request whose last chunk
+        ran in this call, also where its logits were fetched behind step
+        k+1's dispatch (``_first_tokens``): such a request decodes first in
+        step k+2.
 
         The call is one ``engine.step`` span cut into the phases of
         ``_StepPhases``; each carries the instance's name and, once the
@@ -1954,10 +1997,16 @@ class LLMEngine:
         self._drain_revives()
 
         # -- chunked prefill (budgeted; interleaves with decode below) ---
+        # this call's decode step, where the last call dispatched it ahead
+        # and a row of it still stands (no chunk changes that). Beside it a
+        # last chunk's first token is owed until the decode dispatch below
+        # (ISSUE 34); with none it is fetched where the chunk is enqueued
+        cur = self._take_ahead()
+        owed = None if cur is None else []
         for req, start, take in sched.prefill_work(
                 self.max_prefill_tokens_per_step,
                 align=self.prefill_buckets[0]):
-            self._run_chunk(req, start, take, outputs)
+            self._run_chunk(req, start, take, outputs, owed)
 
         if self.prefill_only:
             # disaggregated prefill worker: decode-ready requests wait
@@ -1967,14 +2016,17 @@ class LLMEngine:
             return outputs
 
         # -- decode ------------------------------------------------------
-        # this call's decode step is the one the last call dispatched ahead,
-        # where it did and a row of it still stands; else it is made now,
-        # as ever. Either way the NEXT one is enqueued behind it before its
-        # tokens are fetched, where ``_dispatch_ahead`` finds it can be, so
-        # that fetch, emit and the next call's admission run beside the
-        # device and not between two of its steps (ISSUE 28)
+        # this call's decode step is the one the last call dispatched ahead
+        # (``cur``, taken above); else it is made now, as ever. Either way
+        # the NEXT one is enqueued behind it before its tokens are fetched,
+        # where ``_dispatch_ahead`` finds it can be, so that fetch, emit and
+        # the next call's admission run beside the device and not between
+        # two of its steps (ISSUE 28). The first
+        # tokens this call's last chunks owe are fetched behind that
+        # dispatch too (ISSUE 34): the device's queue is then [this step]
+        # [the chunks] [the next step] while the host waits for a chunk's
+        # logits, and the new requests join the step after the next
         phases.begin("engine.decode.prepare")
-        cur = self._take_ahead()
         if cur is None:
             sched.ensure_decode_room(extra=self._spec_k)
             self._drain_cow()
@@ -1992,6 +2044,14 @@ class LLMEngine:
                 phases.begin("engine.decode.prepare")
         if cur is not None:
             self._ahead = self._dispatch_ahead(cur)
+            if owed:
+                self._first_tokens(owed, outputs, self._ahead is not None)
+                if self._ahead is None:
+                    # nothing could go ahead of that fetch (no row of
+                    # ``cur`` goes on, or no room): the order it had
+                    # before, the new rows joining the next step now
+                    phases.begin("engine.decode.prepare")
+                    self._ahead = self._dispatch_ahead(cur)
             self._emit_decode(cur, outputs)
             if self._ahead is not None and not any(sched.slots):
                 # every request ended on this step (EOS: not seen ahead)
@@ -2164,13 +2224,18 @@ class LLMEngine:
         with the reason kept for the counter. It runs the rows of ``cur``
         that ``cur``'s token does not finish by length, each a position on
         and fed by ``cur``'s greedy tokens without their visiting the
-        host, and the rows that became ready since (a prefill's last chunk
-        ended in this call: their first token is on the host). Not
-        dispatched: beside a row the host samples for; where room for it
-        takes an eviction or a copy (``Scheduler.reserve_ahead``: the next
-        call's ``ensure_decode_room`` does those, with nothing in flight);
-        on a mesh that spans processes, whose ranks are held in step call
-        by call. Stops and EOS cannot be seen ahead: such a row is run,
+        host, and the rows that became ready since: their first token is
+        on the host, fetched by the call before this one behind its own
+        dispatch (``_first_tokens``) or, where that call had nothing in
+        flight, by this one at its last chunk. A request whose last chunk
+        ran in this call beside ``cur`` is still ``prefilling`` here and
+        has no row: its pages and its state are the chunk's until the
+        step after this one. Not dispatched: beside a row the host samples
+        for; where room for it takes an eviction or a copy
+        (``Scheduler.reserve_ahead``: the next call's
+        ``ensure_decode_room`` does those, with nothing in flight); on a
+        mesh that spans processes, whose ranks are held in step call by
+        call. Stops and EOS cannot be seen ahead: such a row is run,
         and discarded when the next call finds its request gone."""
         if self._mp:
             self._sync_reason = "path"
@@ -2198,6 +2263,7 @@ class LLMEngine:
         if not self.scheduler.reserve_ahead([(r[1], r[2]) for r in rows]):
             self._sync_reason = "evict"
             return None
+        self._sync_reason = None    # of a first try in this call (``_step``)
         return self._dispatch_decode(rows, fed, False, "ahead",
                                      prev=cur.greedy)
 
@@ -2688,6 +2754,10 @@ class LLMEngine:
             "queued_on_exhaustion": int(
                 _M_QUEUED_EXH.value(instance=inst)),
             "prefills": int(_M_PREFILLS.value(instance=inst)),
+            # of those, the ones whose first token was fetched with the
+            # next decode step already enqueued behind the chunk (ISSUE 34)
+            "prefill_ends_behind_decode": int(
+                _M_PREFILL_BEHIND.value(instance=inst)),
             "prefill_chunks": int(_M_PREFILL_CHUNKS.value(instance=inst)),
             "prefix_blocks_reused": int(
                 _M_PREFIX_REUSED.value(instance=inst)),

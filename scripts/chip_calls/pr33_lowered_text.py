@@ -2,6 +2,8 @@
 lowered for a v5e without the chip, for the three serving configurations the
 benchmark had (``mistral7b-serve``, ``mimo-v2-flash-serve``,
 ``joyai-llm-flash-serve``) as their runners build them: one sha256 a graph.
+Since PR 34 the fourth too (``nemotron3-nano-serve``, whose graphs take a
+state kind's slots), where the checkout has its runner.
 
     JAX_PLATFORMS=cpu python scripts/chip_calls/pr33_lowered_text.py \
         --repo <checkout> --out <dir>
@@ -89,6 +91,10 @@ def main():
                                 serve_mimo_v2.build_model),
         "joyai-llm-flash-serve": (serve_joyai_flash.model_sizes,
                                   serve_joyai_flash.build_model)}
+    if os.path.exists("benchmarks/runners/serve_nemotron_h.py"):
+        from benchmarks.runners import serve_nemotron_h
+        builders["nemotron3-nano-serve"] = (serve_nemotron_h.model_sizes,
+                                            serve_nemotron_h.build_model)
     hashes = {}
     for name, (sizes, build) in builders.items():
         with open(f"benchmarks/configs/{name}.json") as f:
@@ -108,21 +114,24 @@ def main():
             w = c.window
             weights = on_chip([p._data for p in eng._params])
             pools = on_chip([c.k, c.v])
-            # (window row, counters) at the parent; (window row, slots,
-            # counters) at the change: what lies between them is None here
+            # (window row, counters) before PR 33, (window row, slots,
+            # counters) since: the slots are None without a state kind
             extras = eng._graph_extras(None)
-            counters = ([None] * (len(extras) - 2) + [on_chip(extras[-1])]
-                        if extras else [])
+            state = getattr(c, "state_slots", None) is not None
+
+            def behind_the_row(n_slots):
+                return ([i32(n_slots) if state else None]
+                        * (len(extras) - 2) + [on_chip(extras[-1])])
             width = eng.max_pages + (w.ring if w is not None else 0)
             graphs = {
                 "decode_pure": eng._decode_jit._jit.trace(
                     weights, i32(B, 2), i32(B), i32(B, width), *pools, [], [],
-                    i32(B), *([None] + counters if extras else [])),
+                    i32(B), *([None] + behind_the_row(B) if extras else [])),
                 "chunk_pure@2048": eng._prefill_jit._jit.trace(
                     weights, i32(1, 2048), i32(), i32(), i32(eng.max_pages),
                     *pools, [], [], *([
                         i32(w.n_tail + min(w.ring, 2048 // eng.block_size) + 1)
-                        if w is not None else None] + counters
+                        if w is not None else None] + behind_the_row(1)
                         if extras else []))}
             for graph, traced in graphs.items():
                 text = traced.lower().as_text()

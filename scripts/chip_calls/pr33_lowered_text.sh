@@ -1,6 +1,6 @@
-# PR 33, no chip: the decode step and the 2048-token prefill chunk of the three serving configurations the benchmark had,
-# lowered for a v5e at the parent and at the change (scripts/chip_calls/pr33_lowered_text.py says how; both sides imported
-# through ONE path). All six texts must be byte-identical: the expert kernel's optional gate, the graphs' slot operand and
+# PR 33, no chip: the decode step and the 2048-token prefill chunk of the three serving configurations the benchmark had
+# (since PR 34 of the fourth too, where both checkouts have its runner: pr34_lowered_text.sh), lowered for a v5e at the
+# parent and at the change (scripts/chip_calls/pr33_lowered_text.py says how; both sides imported through ONE path). All texts must be byte-identical: the expert kernel's optional gate, the graphs' slot operand and
 # the cache's new kinds must leave the programs of models without them as they were.
 # Nothing is read or written outside the checkout: the parent is HEAD, unpacked by `git archive` into .archive_check/parent
 # (or the directory given), and the texts go under chiprun_out/pr33_lowered (both are in .gitignore).

@@ -12,11 +12,16 @@ model the experts hit over the weight passes made (decode steps and
 prefill chunks apart), for a latent cache the cached rows a decode step
 walked and a chunk expanded (a layer), for a state kind (ISSUE 33) the
 states a decode step updated and the tokens a chunk scanned (a layer), the
-window's seconds by kind of call,
+window's prefill completions and of them the share whose logits were
+fetched behind the call's decode dispatch (ISSUE 34:
+``prefill_ends_behind_decode / prefills``), the window's seconds by kind of
+call,
 and with ``--trace 1`` the device's busy time in ``decode_pure`` a traced
 decode step (and the grouped expert kernel's, the latent decode kernel's and
 the state update's parts of it), the largest device operations and
-``decode_pure``'s time by kind of operation, the device's idle share, and for
+``decode_pure``'s time by kind of operation, the device's idle share and its
+idle seconds by what the host was doing meanwhile (``idle_gaps``: the host
+span or call over each gap, as the ledger's ``breakdown`` lists them), and for
 each kind of decode kernel the share of the chunks it walked in the traced
 steps whose every page was live (ISSUE 32: those are started written out and
 waited for with one descriptor a pool; from the loop's own context lengths).
@@ -51,10 +56,18 @@ def window_counts(snaps, layers):
                              if v - r0.get(k, 0)}
     steps = out["decode_steps_ahead"] + out["decode_steps_sync"]
     out["engage_share"] = out["decode_steps_ahead"] / steps if steps else None
+    d = lambda k: m1.get(k, 0) - m0.get(k, 0)  # noqa: E731
+    # prefills that ended in the window, and of them those whose logits
+    # were fetched with the next decode step enqueued behind the chunk
+    # (ISSUE 34; an engine from before it counts none)
+    ends = d("prefills")
+    out["prefill_ends"] = {
+        "prefills": ends, "behind_decode": d("prefill_ends_behind_decode"),
+        "engage_share": d("prefill_ends_behind_decode") / ends if ends
+        else None}
     # an expert model's grouped kernel (ISSUE 30): of the times an expert's
     # weights were streamed, the share that was that expert's only read
     # that layer-step
-    d = lambda k: m1.get(k, 0) - m0.get(k, 0)  # noqa: E731
     for kind in ("decode", "prefill"):
         hit, passes = (d(f"moe_{k}_{kind}")
                        for k in ("experts_hit", "weight_passes"))
@@ -215,6 +228,7 @@ def main(argv=None):
             "chunk_attention_device_s": trace_reduce.op_seconds(
                 tr["events"], "chunk_attention_global", "chunk_pure"),
             "idle_share": 100.0 * (1.0 - tr["busy_s"] / tr["window_s"]),
+            "idle_s": tr["window_s"] - tr["busy_s"],
             "idle_gaps": tr["breakdown"]["idle_gaps"],
             "device_ops": trace_reduce.top_ops(tr["events"], 16),
             # seconds of the traced window by program

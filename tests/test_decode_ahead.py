@@ -7,7 +7,12 @@ one decision overridden (``Synchronous._dispatch_ahead``): what is left
 then is the path the engine takes by itself beside a sampled row, under
 pool pressure or on a draft model. Every case plays one
 script of submissions and events, call by call, on both, and wants the
-same tokens a request, the same reasons, the same rows of logits."""
+same tokens a request, the same reasons, the same rows of logits.
+
+A prefill that ends beside a step in flight (ISSUE 34) has its logits
+fetched behind the call's decode dispatch: the cases named in ``ENDS`` play
+the ways a last chunk can meet a step, and the counter
+``prefill_ends_behind_decode`` says in which the order engaged."""
 
 import itertools
 import os
@@ -163,6 +168,11 @@ def same_requests(a, s):
     adds_up(s.metrics)
     assert s.metrics["decode_steps_ahead"] == 0
     assert s.metrics["decode_rows_discarded"] == 0
+    # a prefill's end is fetched behind a dispatch only where one was made
+    assert s.metrics["prefill_ends_behind_decode"] == 0
+    assert a.metrics["prefills"] == s.metrics["prefills"] or \
+        a.metrics["evictions"]
+    assert a.metrics["prefill_ends_behind_decode"] <= a.metrics["prefills"]
     assert a.kept["free"] == s.kept["free"]            # nothing leaked
 
 
@@ -329,6 +339,120 @@ def _mimo_chunked_join(net):
                 max_prefills_per_step=1), reqs, None
 
 
+# -- a prefill's end beside a step in flight (ISSUE 34) ----------------------
+
+def _arrival(net):
+    """One request arrives into a decoding batch: its one chunk is enqueued
+    behind the step in flight, the next step is dispatched for the two rows
+    that decode, and only then are the chunk's logits fetched."""
+    ps = prompts_of((5, 11, 9), seed=23)
+    reqs = [(ps[0], dict(max_new_tokens=12), 0),
+            (ps[1], dict(max_new_tokens=10), 0),
+            (ps[2], dict(max_new_tokens=6), 3)]
+    return LLAMA, reqs, None
+
+
+def _two_ends(net):
+    """Two short prompts whose last chunks share a call: both enqueued,
+    one dispatch, then both fetches."""
+    ps = prompts_of((5, 11, 9, 7), seed=24)
+    reqs = [(ps[0], dict(max_new_tokens=12), 0),
+            (ps[1], dict(max_new_tokens=10), 0),
+            (ps[2], dict(max_new_tokens=6), 3),
+            (ps[3], dict(max_new_tokens=5), 3)]
+    return LLAMA, reqs, None
+
+
+def _long_prompt(net):
+    """A long prompt eight tokens a call: its middle chunks fetch nothing,
+    decode steps run between them, and the last one ends in a call of its
+    own beside a step in flight."""
+    ps = prompts_of((5, 3, 30), seed=25)
+    reqs = [(ps[0], dict(max_new_tokens=16), 0),
+            (ps[1], dict(max_new_tokens=14), 0),
+            (ps[2], dict(max_new_tokens=5), 2)]
+    return dict(LLAMA, max_prefill_tokens_per_step=8), reqs, None
+
+
+def _one_token(net):
+    """The arriving request wants one token: it ends at the deferred fetch,
+    after the next step was dispatched without it, and never decodes."""
+    ps = prompts_of((5, 11, 9), seed=26)
+    reqs = [(ps[0], dict(max_new_tokens=12), 0),
+            (ps[1], dict(max_new_tokens=10), 0),
+            (ps[2], dict(max_new_tokens=1), 3)]
+    return LLAMA, reqs, None
+
+
+def _sampled_arrival(net):
+    """A sampled request arrives beside greedy rows: while it prefills
+    nobody sees a sampled row, so the next step goes ahead of its fetch;
+    once it is ready the batch takes the synchronous path."""
+    ps = prompts_of((5, 11, 9), seed=27)
+    reqs = [(ps[0], dict(max_new_tokens=12), 0),
+            (ps[1], dict(max_new_tokens=10), 0),
+            (ps[2], dict(max_new_tokens=6, do_sample=True, temperature=1.2,
+                         seed=5), 3)]
+    return LLAMA, reqs, None
+
+
+def _beside_sampled(net):
+    """A greedy request arrives beside a sampled row: nothing is in flight
+    there, so its first token is fetched at once, as ever."""
+    ps = prompts_of((5, 11, 9), seed=28)
+    reqs = [(ps[0], dict(max_new_tokens=6, do_sample=True, temperature=1.2,
+                         seed=5), 0),
+            (ps[1], dict(max_new_tokens=16), 0),
+            (ps[2], dict(max_new_tokens=6), 3)]
+    return LLAMA, reqs, None
+
+
+def _idle_arrival(net):
+    """An arrival when nothing is in flight: the engine had run dry."""
+    ps = prompts_of((5, 11), seed=29)
+    reqs = [(ps[0], dict(max_new_tokens=3), 0),
+            (ps[1], dict(max_new_tokens=4), 8)]
+    return LLAMA, reqs, None
+
+
+def _last_row_ends(net):
+    """The one decoding row ends by length on the step in flight as another
+    request arrives: nothing can go ahead of the fetch, so the first token
+    is fetched right after the refusal and the dispatch tried again with the
+    new row, the order of ISSUE 28: it decodes a call later, not two."""
+    ps = prompts_of((5, 9), seed=32)
+    reqs = [(ps[0], dict(max_new_tokens=5), 0),
+            (ps[1], dict(max_new_tokens=6), 3)]
+    return LLAMA, reqs, None
+
+
+def _every_row_leaves(net):
+    """Both decoding requests are cancelled with their rows in flight, and a
+    third arrives before the next call: its chunk meets a step in flight of
+    which no row stands, and the call goes on as with none."""
+    ps = prompts_of((5, 11, 9), seed=33)
+    reqs = [(ps[0], dict(max_new_tokens=12), 0),
+            (ps[1], dict(max_new_tokens=10), 0),
+            (ps[2], dict(max_new_tokens=6), 4)]
+
+    def cancel(eng, rids, out):
+        out.kept["was_in_flight"] = eng._ahead is not None
+        assert eng.cancel(rids[0]) and eng.cancel(rids[1])
+
+    return LLAMA, reqs, {4: cancel}
+
+
+def _mimo_arrival(net):
+    """``_arrival`` on the second model: the step dispatched before the
+    first token leaves the new request's ring and pages as the chunk wrote
+    them (its row reads the null block), while the others' rings turn."""
+    ps = prompts_of((5, 21, 38), seed=30)
+    reqs = [(ps[0], dict(max_new_tokens=14), 0),
+            (ps[1], dict(max_new_tokens=12), 0),
+            (ps[2], dict(max_new_tokens=7), 3)]
+    return MIMO, reqs, None
+
+
 CASES = {"mixed-finish-lengths": (llama, _mixed),
          "all-at-once": (llama, _at_once),
          "eos-in-flight": (llama, _eos),
@@ -344,7 +468,31 @@ CASES = {"mixed-finish-lengths": (llama, _mixed),
          "mimo-mixed-finish-lengths": (mimo, _mimo_mixed),
          "mimo-eos-in-flight": (mimo, _mimo_eos),
          "mimo-deadline-in-flight": (mimo, _mimo_deadline),
-         "mimo-chunked-prefill-joins": (mimo, _mimo_chunked_join)}
+         "mimo-chunked-prefill-joins": (mimo, _mimo_chunked_join),
+         "arrival-beside-a-step": (llama, _arrival),
+         "two-ends-in-one-call": (llama, _two_ends),
+         "long-prompt-ends-alone": (llama, _long_prompt),
+         "one-token-arrival": (llama, _one_token),
+         "sampled-arrival": (llama, _sampled_arrival),
+         "arrival-beside-a-sampled-row": (llama, _beside_sampled),
+         "arrival-into-an-idle-engine": (llama, _idle_arrival),
+         "arrival-as-the-last-row-ends": (llama, _last_row_ends),
+         "arrival-as-every-row-leaves": (llama, _every_row_leaves),
+         "mimo-arrival-beside-a-step": (mimo, _mimo_arrival)}
+
+#: the cases in which a prefill ends in a call of its own, handed in while
+#: others decode or have just stopped (ISSUE 34): for each request that
+#: arrives, how many calls after its first token's its first decode step
+#: comes out. Two where the next decode step was dispatched before the
+#: chunk's logits were fetched, which the counter counts; one where that
+#: dispatch was made right after the fetch; none with nothing in flight.
+ENDS = {"arrival-beside-a-step": {2: 2}, "two-ends-in-one-call": {2: 2, 3: 2},
+        "long-prompt-ends-alone": {2: 2}, "one-token-arrival": {2: 2},
+        "sampled-arrival": {2: 2}, "arrival-beside-a-sampled-row": {2: 0},
+        "arrival-into-an-idle-engine": {1: 0},
+        "arrival-as-the-last-row-ends": {1: 1},
+        "arrival-as-every-row-leaves": {2: 0},
+        "mimo-arrival-beside-a-step": {2: 2}}
 
 
 #: the cases in which no request joins beside a step in flight (all are
@@ -394,8 +542,10 @@ def test_a_call_returns_its_own_steps_tokens(played, case):
         for k, calls in seen.items():
             gaps = np.diff(calls)
             # the first token is the prefill's; its call may hold the first
-            # decode too (0), or the decode joins one call on (1): from
-            # then on one a call, but for a preemption's wait
+            # decode too (0), or the decode joins one call on (1), or two
+            # where the prefill ended beside a step in flight and the next
+            # was dispatched before its fetch (ISSUE 34): from then on one
+            # a call, but for a preemption's wait
             late = {"eviction-pressure", "tier-revival"}
             assert (gaps[1:] == 1).all() or case in late, (case, k, calls)
             assert len(gaps) == 0 or gaps[0] in (0, 1, 2) or case in late
@@ -475,6 +625,92 @@ def test_what_each_case_is_there_for(played):
     assert a.metrics["prefill_chunks"] == s.metrics["prefill_chunks"] > 6
 
 
+@pytest.mark.parametrize("case", list(ENDS))
+def test_a_prefills_end_beside_a_step_in_flight(played, case):
+    """ISSUE 34. The first token comes out of the call that ran the last
+    chunk, before that call's own step's tokens, on both engines in the
+    same call; where the next step was dispatched ahead of the fetch the
+    request decodes first two calls on, and the counter says so."""
+    a, s = played(case)
+    assert a.metrics["prefill_ends_behind_decode"] == \
+        list(ENDS[case].values()).count(2)
+    calls_of = {}
+    for name, run in (("ahead", a), ("sync", s)):
+        seen = calls_of[name] = {}
+        for call, outs in enumerate(run.calls):
+            order = [k for k, *_ in outs]
+            new = [k for k in dict.fromkeys(order) if k not in seen]
+            # first tokens stand before the tokens of the call's step
+            assert order[:len(new)] == new, (case, name, call)
+            for k in order:
+                seen.setdefault(k, []).append(call)
+    first = {name: {k: c[0] for k, c in seen.items()}
+             for name, seen in calls_of.items()}
+    assert first["ahead"] == first["sync"]
+    for k, calls in calls_of["ahead"].items():
+        if len(calls) > 1:
+            # whoever the first call admits decodes in it
+            assert calls[1] - calls[0] == ENDS[case].get(k, 0), (k, calls)
+
+
+def test_what_each_prefills_end_is_there_for(played):
+    a, s = played("arrival-beside-a-step")
+    m = a.metrics
+    assert m["prefills"] == 3
+    assert m["decode_steps_sync_by_reason"] == {"idle": 1}
+    assert m["decode_rows_discarded"] == 0
+
+    a, s = played("two-ends-in-one-call")
+    assert a.metrics["prefills"] == 4
+    assert sorted(k for k, *_ in a.calls[3][:2]) == [2, 3]
+
+    a, s = played("long-prompt-ends-alone")
+    assert a.metrics["prefill_chunks"] == s.metrics["prefill_chunks"] == 2 + 4
+    # the two that decode got a token in every call the long prompt's
+    # chunks ran in: a decode step between two chunks, and beside the last
+    for call in range(2, 6):
+        assert {k for k, *_ in a.calls[call]} == ({0, 1, 2} if call == 5
+                                                 else {0, 1})
+
+    a, s = played("one-token-arrival")
+    assert a.tokens[2] == s.tokens[2] and len(a.tokens[2]) == 1
+    assert a.reasons[2] == "length" and a.metrics["decode_rows_discarded"] == 0
+
+    a, s = played("sampled-arrival")
+    by = a.metrics["decode_steps_sync_by_reason"]
+    assert by["sampled"] >= 4 and by["idle"] == 1
+    assert len(a.tokens[2]) == 6
+
+    a, s = played("arrival-beside-a-sampled-row")
+    by = a.metrics["decode_steps_sync_by_reason"]
+    assert by["sampled"] >= 4 and a.metrics["prefills"] == 3
+
+    a, s = played("arrival-into-an-idle-engine")
+    assert a.metrics["decode_steps_sync_by_reason"] == {"idle": 2}
+    assert a.calls == s.calls
+
+    a, s = played("arrival-as-the-last-row-ends")
+    assert a.metrics["decode_steps_sync_by_reason"] == {"idle": 1}
+    assert a.metrics["decode_steps_ahead"] == a.metrics["host_syncs"] - 1
+
+    a, s = played("arrival-as-every-row-leaves")
+    assert a.kept["was_in_flight"] and not s.kept["was_in_flight"]
+    assert a.metrics["decode_rows_discarded"] == 2
+    assert a.metrics["decode_steps_sync_by_reason"] == {"idle": 2}
+    assert {a.reasons[0], a.reasons[1]} == {"cancelled"}
+
+    a, s = played("mimo-arrival-beside-a-step")
+    assert a.metrics["window_blocks_released"] == \
+        s.metrics["window_blocks_released"] > 0
+    assert a.metrics["global_blocks_in_use"] == \
+        a.metrics["window_blocks_in_use"] == 0
+
+    # where room for the next step takes an eviction nothing goes ahead of
+    # the fetch: not every prefill of that case engaged
+    a, s = played("eviction-pressure")
+    assert a.metrics["prefill_ends_behind_decode"] < a.metrics["prefills"]
+
+
 def test_the_second_models_tokens_are_the_float32_references():
     """Against code that shares nothing with the engine: each token the
     engine chose with steps in flight is the argmax of the reference's row
@@ -525,6 +761,8 @@ def test_the_other_decode_paths_are_not_touched(path):
             assert eng._ahead is None
         m = eng.metrics()
         assert m["decode_steps_ahead"] == 0
+        # nothing is ever in flight here: every first token fetched at once
+        assert (m["prefills"], m["prefill_ends_behind_decode"]) == (2, 0)
         if path == "prefill-only":
             assert m["decode_steps_sync"] == 0
             assert all(len(eng.request(r).output_tokens) == 1 for r in rids)
@@ -572,6 +810,40 @@ def test_last_logits_are_the_synchronous_engines_bit_for_bit(build, capture):
     if capture == "off":
         assert a.metrics["decode_fetch_bytes"] == \
             a.metrics["host_syncs"] * engine["max_batch_size"] * 4
+
+
+def rows_by_token(run):
+    """``{(request, how many tokens it had): last_logits then}``: the row
+    kept after a call belongs to the last token the call returned."""
+    have, out = {}, {}
+    for call, outs in enumerate(run.calls):
+        for k, *_ in outs:
+            have[k] = have.get(k, 0) + 1
+        for k in {o[0] for o in outs}:
+            out[(k, have[k])] = run.logits[(call, k)]
+    return out
+
+
+@pytest.mark.parametrize("build", [llama, mimo], ids=["llama", "mimo"])
+def test_a_first_tokens_row_fetched_behind_the_dispatch_is_bit_for_bit(build):
+    """ISSUE 34 under ``capture_logits``: the row an arriving request's
+    first token was chosen from is fetched after the next decode step was
+    dispatched, and it and every decode row after it are the synchronous
+    engine's to the last bit, token for token (the calls differ: that
+    request decodes a call later)."""
+    net = build()
+    engine, reqs, _ = (_mimo_arrival if build is mimo else _arrival)(net)
+    a, s = both(net, dict(engine, capture_logits=True), reqs)
+    same_requests(a, s)
+    assert a.metrics["prefill_ends_behind_decode"] == 1
+    ra, rs = rows_by_token(a), rows_by_token(s)
+    # a call that returns a first token and a decode token keeps the second's
+    # row only: with nothing in flight both engines, the synchronous one always
+    assert set(rs) == set(ra) - {(2, 1)} and (2, 1) in ra
+    for key, row in rs.items():
+        assert np.array_equal(ra[key], row), key
+    for (k, n), row in ra.items():
+        assert int(row.argmax()) == a.tokens[k][n - 1]
 
 
 # --------------------------------------------------------------------------
@@ -741,6 +1013,41 @@ def test_nothing_compiles_after_the_warm_up(build):
         assert m["decode_steps_ahead"] > 12
 
 
+@pytest.mark.parametrize("build", [llama, mimo], ids=["llama", "mimo"])
+def test_nothing_compiles_where_prefills_end_beside_steps_in_flight(build):
+    """ISSUE 34 moved a fetch, not a shape: requests handed in call by call,
+    so that their prefills end beside steps in flight (one, two in a call,
+    a chunked one), build nothing the second time round."""
+    net = build()
+    engine = dict(MIMO if build is mimo else LLAMA, max_prefill_tokens_per_step=16)
+    ps = prompts_of((5, 13, 9, 7, 30), seed=31)
+    with LLMEngine(net, **engine) as eng:
+        def burst():
+            rids = []
+            for call in itertools.count():
+                for p, n, at in zip(ps, (14, 12, 6, 5, 4), (0, 0, 2, 2, 3)):
+                    if at == call:
+                        rids.append(eng.add_request(
+                            p, SamplingParams(max_new_tokens=n)))
+                if call > 3 and not eng.has_work():
+                    break
+                eng.step()
+            for r in rids:
+                eng.release(r)
+
+        burst()
+        before = COMPILES[0]
+        m0 = eng.metrics()
+        burst()
+        assert COMPILES[0] == before
+        m = eng.metrics()
+        # the second prompt's last chunk, the two handed in before call 2
+        # and the long one's last chunk, each time round
+        assert m["prefill_ends_behind_decode"] == \
+            2 * m0["prefill_ends_behind_decode"] == 8
+        assert m["decode_steps_sync_by_reason"] == {"idle": 2}
+
+
 @pytest.mark.parametrize("kind", ["llama", "llama-int8", "llama-tp2", "mimo"])
 def test_the_decode_executable_lowers_from_the_engines_own_operands(kind):
     """``chip_smoke.py`` reads the decode executable's compiled text; the
@@ -858,9 +1165,20 @@ def test_the_counters_are_registry_series_and_reset_with_the_others():
         assert metrics.REGISTRY.get("serving_decode_rows_discarded_total").value(
             instance=inst) == 0
         assert "serving_decode_steps_sync_total" in metrics.to_prometheus_text()
+        # a third prompt beside the step the two left in flight (ISSUE 34)
+        rids = [eng.add_request(p, SamplingParams(max_new_tokens=9)) for p in ps]
+        eng.step()
+        eng.step()
+        eng.add_request(ps[0], SamplingParams(max_new_tokens=2))
+        eng.step()
+        behind = metrics.REGISTRY.get("serving_prefill_ends_behind_decode_total")
+        assert behind.value(instance=inst) == 1 == \
+            eng.metrics()["prefill_ends_behind_decode"]
+        assert "last chunk" in behind.help
         eng.reset_metrics()
         m = eng.metrics()
         assert m["decode_steps_ahead"] == m["decode_steps_sync"] == 0
+        assert m["prefill_ends_behind_decode"] == m["prefills"] == 0
         assert m["decode_steps_sync_by_reason"] == {}
 
 

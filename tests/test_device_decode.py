@@ -111,7 +111,11 @@ class TestInGraphSampling:
             other = eng.add_request(q, SamplingParams(
                 max_new_tokens=3, do_sample=True, temperature=1.1, seed=5))
             # the step in flight was made before the sampled request came
-            # (ISSUE 28): this call prefills it beside that greedy step
+            # (ISSUE 28): this call prefills it beside that greedy step, and
+            # dispatches the next greedy step before it fetches the sampled
+            # request's first token (ISSUE 34)
+            assert stretch(1) == B * 4
+            assert len(eng.request(other).output_tokens) == 1
             assert stretch(1) == B * 4
             assert len(eng.request(other).output_tokens) == 1
             while not eng.request(other).finished:

@@ -120,7 +120,10 @@ def test_every_step_is_cut_into_the_same_phases(model, tracer, path):
         # two dispatches in the first call, one in every call after it
         # until the last, which only fetches what the one before it made
         assert shapes == [0] + [1] * (n_steps - 2) + [2]
-    assert prefills == 3   # one chunk a prompt, in the steps that admit them
+    # one chunk a prompt, in the steps that admit them; the two prompts
+    # admitted beside a step in flight open the phase a second time, behind
+    # the call's decode dispatch, for the fetch and the first token (ISSUE 34)
+    assert prefills == (5 if path == "per-step" else 3)
     assert "engine.prefill" in [e["name"] for e in steps[0][1]]
     assert "engine.prefill" not in [e["name"] for e in steps[-1][1]]
     # request spans keep the request id as their shared identifier
@@ -151,12 +154,16 @@ def test_trace_report_tables_the_phases_of_an_export(model, tracer, tmp_path):
     doc = json.load(open(path))
     agg = report.aggregate_spans(doc["traceEvents"])
     # every step was dispatched once and fetched once; the first call
-    # prepared two (ISSUE 28)
+    # prepared two (ISSUE 28), and so did the second: its step's one row
+    # ended there, so nothing could go ahead of its prefill's fetch, and the
+    # dispatch was tried again behind it, as before ISSUE 34
     for name in ("engine.step", "engine.admit", "engine.bookkeeping",
                  *DECODE_PHASES[1:]):
         assert agg[name]["count"] == n_steps, name
-    assert agg["engine.decode.prepare"]["count"] == n_steps + 1
-    assert agg["engine.prefill"]["count"] == 3
+    assert agg["engine.decode.prepare"]["count"] == n_steps + 2
+    # a chunk a prompt, and a fetch apart for the two that ended beside a
+    # step in flight
+    assert agg["engine.prefill"]["count"] == 3 + 2
     assert "engine.decode.fetch" in report.build_report(trace_doc=doc)
 
 
@@ -189,6 +196,49 @@ def test_the_names_the_benchmark_reads_are_the_engines(model, tracer):
                           for e in tracer.events()])
     assert len(ps.decode_only(parsed)) == 1
     assert ps.span_ms(parsed[0], ps.PREPARE) > 0
+
+
+def test_a_prefill_that_ends_beside_a_step_in_flight_opens_its_phase_twice(
+        model, tracer):
+    """ISSUE 34: the chunk is enqueued under ``engine.prefill`` before the
+    prepare, the next decode step is dispatched, and only then are the
+    chunk's logits fetched and the first token emitted, under a second
+    ``engine.prefill``, so that the wait is seen under a phase of the
+    engine. No new name; by ``benchmarks/harness/program_spans.py``'s rule
+    such a call is no decode-only step. With nothing in flight (the first
+    call) the fetch stays inside the chunk's own phase."""
+    from benchmarks.harness import program_spans as ps
+
+    with _engine(model, ingest_async=False) as eng:
+        first, = _submit(eng, lengths=(5,), new=8)
+        outs = eng.step()
+        assert [o.rid for o in outs] == [first, first]    # nothing in flight
+        eng.step()
+        tracer.clear()
+        second, = _submit(eng, lengths=(11,), new=4)
+        outs = eng.step()
+        assert [o.rid for o in outs] == [second, first]   # first tokens first
+        m = eng.metrics()
+        assert (m["prefills"], m["prefill_ends_behind_decode"]) == (2, 1)
+        eng.step()
+    (step, inside), (after, inside_after) = _steps(tracer.events())
+    assert [e["name"] for e in inside] == [
+        "engine.admit", "engine.prefill", "engine.decode.prepare",
+        "engine.decode.dispatch", "engine.prefill", "engine.decode.fetch",
+        "engine.decode.emit", "engine.bookkeeping"]
+    assert all(e["args"] == step["args"] for e in inside)
+    assert [e["name"] for e in inside_after] == [
+        "engine.admit", *DECODE_PHASES, "engine.bookkeeping"]
+    # the request's own span closes with its first token, in the second phase
+    fetch = [e for e in inside if e["name"] == "engine.prefill"][1]
+    prefill, = [e for e in tracer.events() if e["name"] == "request.prefill"]
+    assert prefill["tid"] == second
+    assert fetch["ts"] <= prefill["ts"] + prefill["dur"] <= \
+        fetch["ts"] + fetch["dur"] + 1e-3
+    parsed = ps.steps_of([(e["name"], e["ts"] * 1e-6, e["dur"] * 1e-6)
+                          for e in tracer.events()])
+    assert len(parsed) == 2 and len(parsed[0][ps.PREFILL]) == 2
+    assert ps.decode_only(parsed) == [parsed[1]]
 
 
 def test_a_state_kind_adds_no_span_name(tracer):

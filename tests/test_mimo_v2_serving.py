@@ -328,6 +328,60 @@ def test_a_requests_logits_are_the_same_alone_and_in_a_full_batch():
     assert np.array_equal(full.argmax(-1), alone.argmax(-1))
 
 
+def test_a_step_dispatched_before_the_first_token_leaves_the_new_ring_alone():
+    """ISSUE 34 on the window kind: a request arrives beside one that
+    decodes, its chunk (prompt past the window: the ring has turned in
+    prefill) is enqueued behind the step in flight and the NEXT step is
+    dispatched before the chunk's logits are fetched. To that step the new
+    request is mid-prefill: its row of the table, ring and all, is the null
+    block. It then reads as it does alone, and as the reference does."""
+    net = build()
+    short, new = prompts_of((6, 14), seed=12)
+    engine = dict(ENGINE, ingest_async=False)
+
+    def rows_of_last(eng, rid):
+        rows = [eng.request(rid).last_logits.copy()]
+        while not eng.request(rid).finished:
+            rows += [eng.request(rid).last_logits.copy() for o in eng.step()
+                     if o.rid == rid]
+        return rows
+
+    with LLMEngine(net, capture_logits=True, **engine) as fresh:
+        rid = fresh.add_request(new, SamplingParams(max_new_tokens=5))
+        fresh.step()
+        want = rows_of_last(fresh, rid)     # alone, the first call decodes too
+        want_toks = list(fresh.request(rid).output_tokens)
+    with LLMEngine(net, capture_logits=True, **engine) as eng:
+        a = eng.add_request(short, SamplingParams(max_new_tokens=40))
+        while len(eng.request(a).output_tokens) < 3:
+            eng.step()
+        assert eng._ahead is not None
+        b = eng.add_request(new, SamplingParams(max_new_tokens=5))
+        outs = eng.step()
+        assert [o.rid for o in outs] == [b, a]
+        m = eng.metrics()
+        assert (m["prefills"], m["prefill_ends_behind_decode"]) == (2, 1)
+        # the step in flight now was dispatched before b's first token: one
+        # live row, every other row of its table at the null block
+        assert [row[1].rid for row in eng._ahead.rows] == [a]
+        slot = eng.scheduler.slots.index(eng.request(b))
+        table = np.asarray(eng._tables_dev)
+        assert not table[slot].any() and table.any()
+        held = eng.cache.window.held(b)
+        rows = rows_of_last(eng, b)
+        assert eng.cache.window.held(b) == 0 < held
+        got = list(eng.request(b).output_tokens)
+        eng.cancel(a)
+    assert got == want_toks
+    assert len(rows) == 5 == len(want) + 1
+    np.testing.assert_allclose(np.stack(rows[1:]), np.stack(want), atol=1e-5)
+    full = np.asarray(ref.logits(
+        weights_of(net), np.concatenate([new, got])[None].astype(np.int32),
+        model_of(net), experts_held=net.config.experts_held))[0]
+    for j, row in enumerate(rows):
+        assert ref.row_error(row, full[len(new) - 1 + j]) < 2e-5, j
+
+
 def test_a_greedy_step_fetches_its_tokens_and_they_are_the_rows_argmax():
     """The default engine fetches ``[B]`` tokens a decode step, the decode
     graph's own argmax; with ``capture_logits`` it fetches the rows beside

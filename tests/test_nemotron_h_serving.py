@@ -536,6 +536,66 @@ def test_a_decode_step_between_two_chunks_leaves_the_prefilling_request_alone():
     assert [int(np.argmax(r)) for r in want_a] == alone
 
 
+def _state_and_tail(eng, slot):
+    """What the state blocks hold in a slot: ``[(tail, state)]`` a block,
+    read behind whatever is in flight."""
+    return [(np.asarray(k[slot], np.float32), np.asarray(v[slot]))
+            for sp, k, v in zip(eng.cache.layout, eng.cache.k, eng.cache.v)
+            if sp.kind == "state"]
+
+
+def test_a_step_dispatched_before_the_first_token_leaves_the_new_state_alone():
+    """ISSUE 34: a request arrives beside one that decodes. Its chunk is
+    enqueued behind the step in flight, and the NEXT step is dispatched, for
+    the row that decodes, before the chunk's logits are fetched: the new
+    request is still mid-prefill to that step, its row at the null slot and
+    the null block. Its state and its convolution's tail are then as the
+    chunk wrote them, and it reads on as it does alone."""
+    net = build()
+    short, new = prompts_of((6, 13), seed=11)
+    engine = dict(ENGINE, ingest_async=False)
+    with LLMEngine(net, capture_logits=True, **engine) as fresh:
+        want = generate(fresh, new, 5)
+        # what the chunk alone leaves: a request that wants one token ends
+        # there, and no decode step follows in its slot
+        generate(fresh, new, 1)
+        chunk_wrote = _state_and_tail(fresh, 0)
+    with LLMEngine(net, capture_logits=True, **engine) as eng:
+        a = eng.add_request(short, SamplingParams(max_new_tokens=40))
+        while len(eng.request(a).output_tokens) < 3:
+            eng.step()
+        assert eng._ahead is not None
+        b = eng.add_request(new, SamplingParams(max_new_tokens=5))
+        outs = eng.step()
+        assert [o.rid for o in outs] == [b, a]
+        m = eng.metrics()
+        assert (m["prefills"], m["prefill_ends_behind_decode"]) == (2, 1)
+        # the step in flight now was dispatched before b's first token
+        ahead = eng._ahead
+        assert [row[1].rid for row in ahead.rows] == [a]
+        assert np.asarray(eng._slots_dev).tolist().count(4) == 3
+        slot = eng.scheduler.slots.index(eng.request(b))
+        for (tail, state), (t0, s0) in zip(_state_and_tail(eng, slot),
+                                           chunk_wrote):
+            assert np.abs(s0).max() > 1e-3
+            np.testing.assert_allclose(state, s0, rtol=2e-5, atol=2e-6)
+            np.testing.assert_allclose(tail, t0, rtol=2e-5, atol=2e-6)
+        rows = [eng.request(b).last_logits.copy()]
+        while not eng.request(b).finished:
+            rows += [eng.request(b).last_logits.copy() for o in eng.step()
+                     if o.rid == b]
+        got = list(eng.request(b).output_tokens)
+        alone = list(eng.request(a).output_tokens)
+        eng.cancel(a)
+    assert got == want[0]
+    for x, y in zip(rows[1:], want[1]):
+        assert ref.row_error(x, y) < 2e-5
+    for row, wanted in zip(rows, reference_rows(net, new, got)):
+        assert ref.row_error(row, wanted) < 2e-5
+    # the request that decoded beside the chunk is the reference's too
+    assert [int(np.argmax(r)) for r in reference_rows(net, short, alone)] == alone
+
+
 @pytest.mark.parametrize("how", ["drain", "reload"])
 def test_a_drained_step_is_never_applied_twice(how, tmp_path):
     """``_drain`` with a step in flight, then on: the tokens of an undrained

@@ -1134,7 +1134,10 @@ class TestPrefixSharingEngine:
         refs = [model.generate(paddle.to_tensor(p[None]),
                                max_new_tokens=10).numpy()[0]
                 for p in prompts]
-        with LLMEngine(model, num_blocks=14, block_size=4, max_batch_size=3,
+        # a pool one block tighter since ISSUE 34: a request whose prefill
+        # ends beside a step in flight decodes a call later, and with 14
+        # blocks the first one had left before the third grew
+        with LLMEngine(model, num_blocks=13, block_size=4, max_batch_size=3,
                        enable_prefix_cache=True) as eng:
             outs = eng.generate(prompts, SamplingParams(max_new_tokens=10))
             em = eng.metrics()
