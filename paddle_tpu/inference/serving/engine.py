@@ -119,6 +119,16 @@ _M_PREFILL_CHUNKS = _obs_metrics.counter(
     "serving_prefill_chunks_total",
     "block-aligned prefill chunk executions (chunked prefill splits one "
     "prompt across several of these)")
+_M_PREFILL_TOKENS = _obs_metrics.counter(
+    "serving_prefill_tokens_total",
+    "prompt tokens the prefill chunks materialized (re-prefills after an "
+    "eviction included)")
+_M_PREFILL_PADDED = _obs_metrics.counter(
+    "serving_prefill_padded_tokens_total",
+    "tokens the prefill chunk graphs computed: each chunk runs at a rung "
+    "of prefill_buckets at least as long as its tokens, so this over "
+    "serving_prefill_tokens_total, less 1, is the share of chunk work "
+    "spent on padding")
 _M_SPEC_PROPOSED = _obs_metrics.counter(
     "serving_spec_proposed_total",
     "draft tokens proposed by the speculative decoder")
@@ -188,6 +198,7 @@ _M_ROWS_DISCARDED = _obs_metrics.counter(
 _SERVING_METRICS = (_M_ADMITTED, _M_EVICTIONS, _M_FINISHED, _M_QUEUED_EXH,
                     _M_PREFIX_REUSED, _M_COW, _M_PREFILLS,
                     _M_PREFILL_BEHIND, _M_PREFILL_CHUNKS,
+                    _M_PREFILL_TOKENS, _M_PREFILL_PADDED,
                     _M_SPEC_PROPOSED, _M_SPEC_ACCEPTED,
                     _M_TOKENS, _M_DEADLINE, _M_KV_SAVED, _H_TTFT, _H_ITL,
                     _H_QUEUE_WAIT,
@@ -294,11 +305,16 @@ class _StepPhases:
     path the step takes. Same names on every path:
 
     ``engine.admit`` (ingest drain, deadline scan, admission, tier
-    revivals), ``engine.prefill`` (one chunk's dispatch and, with nothing
-    in flight, a last chunk's fetch and first token; beside a decode step
-    in flight that fetch and token come in an ``engine.prefill`` of their
-    own, once a call, behind the call's decode dispatch: ISSUE 34),
-    ``engine.decode.prepare`` (decode
+    revivals), ``engine.prefill`` (one chunk: staging, the choice of its
+    rung, the dispatch, the books; the call of the chunk executable alone
+    is a child span, ``engine.prefill.chunk``, that says what the chunk
+    did: ``rid``, ``start``, ``tokens`` real ones in a graph of ``padded``,
+    ``last``), ``engine.prefill.first_token`` (the fetch of a last chunk's
+    logits and the first token: at once behind its chunk with nothing in
+    flight, ``requests`` 1; beside a decode step in flight once a call,
+    behind the call's decode dispatch, for all the call's last chunks,
+    ``behind`` 1 if a step was enqueued behind them: ISSUE 34; one name,
+    one meaning: ISSUE 35), ``engine.decode.prepare`` (decode
     room, copy-on-write, the ready list, the step's inputs and their
     puts), ``engine.decode.dispatch`` (the call of the decode or
     verify executable until it returns), ``engine.decode.fetch`` (the wait
@@ -306,20 +322,21 @@ class _StepPhases:
     commit, latency observations, finishes), ``engine.bookkeeping`` (store
     autosave, gauges); the speculative path adds ``engine.decode.draft``
     (the draft model's catch-up and proposals, with their own fetches).
-    All lie inside the step's ``engine.step`` and carry its ``args``. A
-    state kind adds no name: a decode step's slots are made in
-    ``engine.decode.prepare``, a chunk's slot in ``engine.prefill``, and a
-    step that ``_drain`` commits between two calls records its fetch and
-    emit under an ``engine.step`` of their own.
+    All lie inside the step's ``engine.step`` and carry its ``args``,
+    built under ``trace.live()`` (in a profile the scalars among them are
+    the event's statistics). A state kind adds no name: a decode step's
+    slots are made in ``engine.decode.prepare``, a chunk's slot in
+    ``engine.prefill``, and a step that ``_drain`` commits between two
+    calls records its fetch and emit under an ``engine.step`` of their own.
 
     On the per-step path a steady call's prepare and dispatch are the
     NEXT step's and its fetch and emit this step's (ISSUE 28); the first
     call after a break holds prepare and dispatch twice, this step's and
     the next one's, and a call that dispatches nothing ahead holds an
-    empty prepare. A call that ends a prefill beside a step in flight
-    holds ``engine.prefill`` twice: its chunks before the prepare, the
-    fetch of their logits and the first tokens between the dispatch and
-    this step's fetch."""
+    empty prepare. A call that ends a prefill still holds
+    ``engine.prefill`` (its chunk), whichever way its first token comes,
+    so a reader that tells a decode-only call by the absence of that name
+    (``benchmarks/harness/program_spans.py``) reads as before."""
 
     __slots__ = ("args", "_open")
 
@@ -327,9 +344,14 @@ class _StepPhases:
         self.args = None
         self._open = None
 
-    def begin(self, name):
+    def begin(self, name, args=None):
+        """``args``: what this phase says beside the step's own."""
         self.end()
-        self._open = _obs_trace.span(name, cat="engine", args=self.args)
+        if args is None:
+            args = self.args
+        elif self.args is not None:
+            args = {**self.args, **args}
+        self._open = _obs_trace.span(name, cat="engine", args=args)
 
     def end(self):
         if self._open is not None:
@@ -1795,7 +1817,8 @@ class LLMEngine:
         ``req`` starting at ``start`` in the pool(s). The final chunk's
         last-position logits are the request's first token. With no
         decode step of this call's in flight (``owed`` is None) they are
-        fetched and the token emitted here, at once; beside one, the
+        fetched and the token emitted here, at once, under a phase of
+        their own (``engine.prefill.first_token``); beside one, the
         request and the logits, still on the device, go on ``owed`` and
         ``_step`` fetches them behind its decode dispatch
         (``_first_tokens``, ISSUE 34). Until then the request stays
@@ -1845,12 +1868,20 @@ class LLMEngine:
             # slot held (``ChunkAttnState.scan``)
             slot = self._g(np.asarray(
                 [self.scheduler.slots.index(req)], np.int32))
-        (logits, cache.k, cache.v, cache.k_scale, cache.v_scale,
-         *tail) = self._prefill_jit(
-                [p._data for p in self._params], ids_chunk,
-                start_a, upto_a, tables_dev,
-                cache.k, cache.v, cache.k_scale, cache.v_scale,
-                *self._graph_extras(window_row, slot))
+        last = start + take >= req.prefill_upto
+        # what this chunk does, said where it is known (ISSUE 35): ``take``
+        # real tokens from ``start`` in a graph of ``C``
+        said = ({"rid": req.rid, "start": start, "tokens": take,
+                 "padded": C, "last": int(last)}
+                if _obs_trace.live() else None)
+        with _obs_trace.span("engine.prefill.chunk", cat="engine",
+                             args=said):
+            (logits, cache.k, cache.v, cache.k_scale, cache.v_scale,
+             *tail) = self._prefill_jit(
+                    [p._data for p in self._params], ids_chunk,
+                    start_a, upto_a, tables_dev,
+                    cache.k, cache.v, cache.k_scale, cache.v_scale,
+                    *self._graph_extras(window_row, slot))
         kept = tail.pop() if self._keep_names else None
         if tail:
             self._counters_dev = tail[0]
@@ -1873,6 +1904,8 @@ class LLMEngine:
             req.draft_cached = start + take
         req.num_cached = start + take
         _M_PREFILL_CHUNKS.inc(instance=self._name)
+        _M_PREFILL_TOKENS.inc(take, instance=self._name)
+        _M_PREFILL_PADDED.inc(C, instance=self._name)
         # QoS accounting (ISSUE 17): prefill work charges the tenant's
         # quota/vtime as it is SERVED, chunk by chunk
         self.scheduler.note_served(req, take)
@@ -1882,10 +1915,14 @@ class LLMEngine:
             # eviction) can share them
             self.prefix_cache.register(req.tokens, req.blocks,
                                        req.num_cached, tenant=req.tenant)
-        if req.num_cached >= req.prefill_upto:
+        if last:
             self.stats_extra["prefills"] += 1
             _M_PREFILLS.inc(instance=self._name)
             if owed is None:
+                self._phases.begin(
+                    "engine.prefill.first_token",
+                    {"requests": 1, "behind": 0}
+                    if _obs_trace.live() else None)
                 self._first_token(req, logits, bucket, outputs)
             else:
                 owed.append((req, logits, bucket))
@@ -1907,11 +1944,15 @@ class LLMEngine:
 
     def _first_tokens(self, owed, outputs, behind):
         """Empty ``owed``, the last chunks this call ran beside a decode
-        step in flight: a second ``engine.prefill`` in the call, after its
-        decode dispatch. ``behind`` says that the dispatch enqueued a step
-        behind the chunks, so that the device has work while the host
-        waits for these logits, emits and prepares the step after."""
-        self._phases.begin("engine.prefill")
+        step in flight: one ``engine.prefill.first_token`` in the call,
+        after its decode dispatch. ``behind`` says that the dispatch
+        enqueued a step behind the chunks, so that the device has work
+        while the host waits for these logits, emits and prepares the step
+        after."""
+        self._phases.begin(
+            "engine.prefill.first_token",
+            {"requests": len(owed), "behind": int(behind)}
+            if _obs_trace.live() else None)
         if behind:
             _M_PREFILL_BEHIND.inc(len(owed), instance=self._name)
         for req, logits, bucket in owed:
@@ -1936,7 +1977,7 @@ class LLMEngine:
         if self._decode_jit is None:
             self._build_jits()
         phases = self._phases
-        phases.args = ({"engine": self._name} if _obs_trace.enabled()
+        phases.args = ({"engine": self._name} if _obs_trace.live()
                        else None)
         with _obs_trace.span("engine.step", cat="engine", args=phases.args):
             try:
@@ -2095,7 +2136,7 @@ class LLMEngine:
                 cur.how = "commit"
                 phases = self._phases
                 phases.args = ({"engine": self._name}
-                               if _obs_trace.enabled() else None)
+                               if _obs_trace.live() else None)
                 with _obs_trace.span("engine.step", cat="engine",
                                      args=phases.args):
                     try:
@@ -2759,6 +2800,11 @@ class LLMEngine:
             "prefill_ends_behind_decode": int(
                 _M_PREFILL_BEHIND.value(instance=inst)),
             "prefill_chunks": int(_M_PREFILL_CHUNKS.value(instance=inst)),
+            # what the chunks were asked for and what their graphs ran:
+            # padded / tokens - 1 is the work spent on padding (ISSUE 35)
+            "prefill_tokens": int(_M_PREFILL_TOKENS.value(instance=inst)),
+            "prefill_padded_tokens": int(
+                _M_PREFILL_PADDED.value(instance=inst)),
             "prefix_blocks_reused": int(
                 _M_PREFIX_REUSED.value(instance=inst)),
             "cow_copies": int(_M_COW.value(instance=inst)),

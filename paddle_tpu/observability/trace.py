@@ -16,20 +16,26 @@ wraps an async dispatch mid-flight, and costs one ``perf_counter_ns``
 pair plus a dict append when enabled.
 
 ``span()`` is the one way the program opens a span, and it is live in two
-cases. With the tracer enabled the event goes to the in-memory buffer that
-``export`` writes out. While a ``jax.profiler`` session runs
-(``TraceAnnotation.is_enabled()``) the span also enters a
-``jax.profiler.TraceAnnotation`` of exactly its name: a ``perf_counter_ns``
-stamp cannot be laid over the profile afterwards (the ``.xplane.pb`` counts
-from the session's start), so a span shares the device line's clock only by
-being an event *in* the profile, nested in whatever span the caller holds.
-With both off (the default) ``span()`` returns a shared no-op context
-manager and ``add_complete`` returns before taking the lock: one attribute
-read and one ``is_enabled()`` call, nothing allocated. A caller that passes
-``args`` builds them under ``enabled()`` so that this stays true of the
-call site too. ``args`` go to the buffer only; the profile's event carries
-the name alone. ``jax`` is imported by the first ``span()`` call, not by
-importing this module.
+cases (``live()`` says whether either holds). With the tracer enabled the
+event goes to the in-memory buffer that ``export`` writes out. While a
+``jax.profiler`` session runs (``TraceAnnotation.is_enabled()``) the span
+also enters a ``jax.profiler.TraceAnnotation`` of exactly its name: a
+``perf_counter_ns`` stamp cannot be laid over the profile afterwards (the
+``.xplane.pb`` counts from the session's start), so a span shares the
+device line's clock only by being an event *in* the profile, nested in
+whatever span the caller holds. The span's ``args`` ride with it: the
+buffer's event keeps them all, the profile's event carries those that are
+``int``, ``float``, ``bool`` or ``str`` as its statistics (what a
+``TraceAnnotation`` can encode, ``_statistics``; ``None`` and anything else
+stay in the buffer only), under the same name, so a reader that finds
+spans by name reads as before and one that wants the counts reads
+``event.stats``. With both off (the default) ``span()`` returns a shared
+no-op context manager and ``add_complete`` returns before taking the lock:
+one attribute read and one ``is_enabled()`` call, nothing allocated. A
+caller that passes ``args`` to ``span()`` builds them under ``live()``, and
+one that passes them to ``add_complete`` (the buffer's alone) under
+``enabled()``, so that this stays true of the call site too. ``jax`` is
+imported by the first ``span()`` call, not by importing this module.
 
 Timestamps are ``time.perf_counter_ns`` (monotonic), emitted in the
 chrome-trace microsecond unit. Complete events use ``ph="X"``; per-request
@@ -46,7 +52,7 @@ import threading
 import time
 
 __all__ = ["Tracer", "TRACER", "span", "instant", "add_complete", "enable",
-           "disable", "enabled", "clear", "events", "drain", "export"]
+           "disable", "enabled", "live", "clear", "events", "drain", "export"]
 
 
 class _NoopSpan:
@@ -79,9 +85,27 @@ def _profiling():
     return _annotation.is_enabled()
 
 
+#: the profile writes an event as ``name#key=value,key=value#``
+_SEPARATORS = str.maketrans("#,", "__")
+
+
+def _statistics(args):
+    """The ``args`` a profile's event can carry: ``int``, ``float``, ``bool``
+    (an ``int``) and ``str``, a string with ``_`` where it holds one of the
+    encoding's own separators (an engine is named ``llm_engine#1``)."""
+    out = {}
+    for key, value in (args or {}).items():
+        if isinstance(value, str):
+            out[key] = value.translate(_SEPARATORS)
+        elif isinstance(value, (int, float)):
+            out[key] = value
+    return out
+
+
 class _Span:
-    """A live span: in the profile when ``profiled``, and in the tracer's
-    buffer if the tracer is enabled when it ends."""
+    """A live span: in the profile when ``profiled``, its scalar ``args``
+    as the event's statistics, and in the tracer's buffer if the tracer is
+    enabled when it ends."""
 
     __slots__ = ("_tracer", "name", "cat", "tid", "args", "_start", "_ann")
 
@@ -93,7 +117,7 @@ class _Span:
         self.args = args
         self._ann = None
         if profiled:
-            self._ann = _annotation(name)
+            self._ann = _annotation(name, **_statistics(args))
             self._ann.__enter__()
         self._start = time.perf_counter_ns()
 
@@ -269,6 +293,13 @@ def disable():
 
 def enabled():
     return TRACER.enabled
+
+
+def live():
+    """True if a ``span()`` opened now would be recorded anywhere: the
+    tracer is enabled or a ``jax.profiler`` session runs. What a call site
+    builds its span's ``args`` under."""
+    return TRACER.enabled or _profiling()
 
 
 def clear():

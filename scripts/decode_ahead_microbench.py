@@ -14,9 +14,11 @@ walked and a chunk expanded (a layer), for a state kind (ISSUE 33) the
 states a decode step updated and the tokens a chunk scanned (a layer), the
 window's prefill completions and of them the share whose logits were
 fetched behind the call's decode dispatch (ISSUE 34:
-``prefill_ends_behind_decode / prefills``), the window's seconds by kind of
-call,
-and with ``--trace 1`` the device's busy time in ``decode_pure`` a traced
+``prefill_ends_behind_decode / prefills``) and, from the same deltas, what
+the chunk graphs computed over what they were asked for (ISSUE 35:
+``prefill_padded_tokens / prefill_tokens``, and the padded share of the
+first), the window's seconds by kind of call,
+and with ``--trace 1`` the traced stretch's median call, the device's busy time in ``decode_pure`` a traced
 decode step (and the grouped expert kernel's, the latent decode kernel's and
 the state update's parts of it), the largest device operations and
 ``decode_pure``'s time by kind of operation, the device's idle share and its
@@ -65,6 +67,13 @@ def window_counts(snaps, layers):
         "prefills": ends, "behind_decode": d("prefill_ends_behind_decode"),
         "engage_share": d("prefill_ends_behind_decode") / ends if ends
         else None}
+    # what the window's chunk graphs computed over the real tokens they
+    # were asked for (ISSUE 35; an engine from before it counts neither)
+    real, padded = d("prefill_tokens"), d("prefill_padded_tokens")
+    out["prefill_padding"] = {
+        "tokens": real, "padded_tokens": padded,
+        "padded_over_tokens": padded / real if real else None,
+        "padded_share": 100.0 * (padded - real) / padded if padded else None}
     # an expert model's grouped kernel (ISSUE 30): of the times an expert's
     # weights were streamed, the share that was that expert's only read
     # that layer-step
@@ -208,6 +217,9 @@ def main(argv=None):
         decode = trace_reduce.select(tr["events"], None, "decode_pure")
         steps = sum(1 for s in run["traced_steps"] if s[4])
         out["traced"] = {
+            "calls": len(run["traced_steps"]),
+            "call_ms_p50": stats.percentile(
+                [(s[1] - s[0]) * 1e3 for s in run["traced_steps"]], 50),
             "decode_steps": steps,
             "decode_pure_device_ms_a_step":
                 1e3 * trace_reduce.busy_seconds(decode) / steps,

@@ -1,9 +1,10 @@
 """The engine's step phases as spans (ISSUE 25): the same names on every
 decode path (a steady call of the per-step path holds the next step's
-prepare and dispatch and this step's fetch and emit: ISSUE 28), nothing
-recorded and nothing built with tracing off, the spans
-in a real ``jax.profiler`` session on the CPU, the Pallas kernels' names in
-the lowered text, and ``add_request(arrival_t=)`` as the due time."""
+prepare and dispatch and this step's fetch and emit: ISSUE 28), what a
+prefill chunk says of itself and the first token's own phase (ISSUE 35),
+nothing recorded and nothing built with tracing off, the spans and their
+args in a real ``jax.profiler`` session on the CPU, the Pallas kernels'
+names in the lowered text, and ``add_request(arrival_t=)`` as the due time."""
 
 import glob
 import re
@@ -28,6 +29,10 @@ DECODE_PHASES = ["engine.decode.prepare", "engine.decode.dispatch",
 #: dispatch at all, this one being in flight and the last
 PER_STEP = [DECODE_PHASES[:2] + DECODE_PHASES, DECODE_PHASES,
             DECODE_PHASES[:1] + DECODE_PHASES[2:]]
+#: a phase that holds a chunk, and the child span around the chunk
+#: executable's call (ISSUE 35): no phase, it lies INSIDE ``engine.prefill``
+PREFILL, CHUNK = "engine.prefill", "engine.prefill.chunk"
+FIRST_TOKEN = "engine.prefill.first_token"
 PATHS = {
     "per-step": {},
     "speculative": {"spec_tokens": 3},   # draft_model: the model itself
@@ -60,6 +65,19 @@ def _engine(model, **kw):
                      **kw)
 
 
+def _chunks(events):
+    """The chunk spans by time, each checked to lie inside a phase
+    ``engine.prefill``."""
+    phases = [e for e in events if e["name"] == PREFILL]
+    out = sorted((e for e in events if e["name"] == CHUNK),
+                 key=lambda e: e["ts"])
+    for c in out:
+        assert c["cat"] == "engine" and sum(
+            p["ts"] <= c["ts"] and c["ts"] + c["dur"] <= p["ts"] + p["dur"] + 1e-3
+            for p in phases) == 1
+    return out
+
+
 def _submit(eng, lengths=(5, 11, 17), new=6, **kw):
     rng = np.random.RandomState(0)
     vocab = eng.model.config.vocab_size
@@ -69,9 +87,9 @@ def _submit(eng, lengths=(5, 11, 17), new=6, **kw):
 
 
 def _steps(events):
-    """[(engine.step event, [the engine.* events inside it])], by time."""
-    evs = sorted((e for e in events if e["name"].startswith("engine.")),
-                 key=lambda e: e["ts"])
+    """[(engine.step event, [the phases inside it])], by time."""
+    evs = sorted((e for e in events if e["name"].startswith("engine.")
+                  and e["name"] != CHUNK), key=lambda e: e["ts"])
     steps = [(e, []) for e in evs if e["name"] == "engine.step"]
     for e in evs:
         if e["name"] == "engine.step":
@@ -95,12 +113,15 @@ def test_every_step_is_cut_into_the_same_phases(model, tracer, path):
             n_steps += 1
     steps = _steps(tracer.events())
     assert len(steps) == n_steps > 3
-    prefills, shapes = 0, []
+    prefills, first, shapes = 0, [], []
     for k, (step, inside) in enumerate(steps, start=1):
         names = [e["name"] for e in inside]
         assert step["args"] == {"engine": name, "step": k}
-        assert all(e["args"] == step["args"] and e["cat"] == "engine"
-                   for e in inside)
+        # a phase carries the step's args; the first token's adds its own
+        assert all(e["cat"] == "engine" and step["args"] == {
+            k: v for k, v in e["args"].items()
+            if e["name"] != FIRST_TOKEN or k not in ("requests", "behind")}
+            for e in inside)
         # the phases follow each other: none starts before the last ended
         for a, b in zip(inside, inside[1:]):
             assert a["ts"] + a["dur"] <= b["ts"] + 1e-3, (a["name"], b["name"])
@@ -112,20 +133,28 @@ def test_every_step_is_cut_into_the_same_phases(model, tracer, path):
         else:
             assert decode in PER_STEP
             shapes.append(PER_STEP.index(decode))
-        prefills += names.count("engine.prefill")
-        assert set(names) <= {"engine.admit", "engine.prefill",
+        prefills += names.count(PREFILL)
+        first += [(e["args"]["requests"], e["args"]["behind"])
+                  for e in inside if e["name"] == FIRST_TOKEN]
+        if FIRST_TOKEN in names:
+            assert PREFILL in names      # such a call holds its chunk too
+        assert set(names) <= {"engine.admit", PREFILL, FIRST_TOKEN,
                               "engine.bookkeeping", "engine.decode.draft",
                               *DECODE_PHASES}
     if path == "per-step":
         # two dispatches in the first call, one in every call after it
         # until the last, which only fetches what the one before it made
         assert shapes == [0] + [1] * (n_steps - 2) + [2]
-    # one chunk a prompt, in the steps that admit them; the two prompts
-    # admitted beside a step in flight open the phase a second time, behind
-    # the call's decode dispatch, for the fetch and the first token (ISSUE 34)
-    assert prefills == (5 if path == "per-step" else 3)
-    assert "engine.prefill" in [e["name"] for e in steps[0][1]]
-    assert "engine.prefill" not in [e["name"] for e in steps[-1][1]]
+    # one chunk a prompt, in the steps that admit them, and a first token a
+    # prompt: at once behind its chunk with nothing in flight (the first
+    # call, and every call of the speculative path); the two prompts admitted
+    # beside a step in flight get theirs behind the call's decode dispatch,
+    # which enqueued a step behind their chunks (ISSUE 34)
+    assert prefills == 3 == len(_chunks(tracer.events()))
+    assert first == ([(1, 0), (1, 1), (1, 1)] if path == "per-step"
+                     else [(1, 0)] * 3)
+    assert PREFILL in [e["name"] for e in steps[0][1]]
+    assert PREFILL not in [e["name"] for e in steps[-1][1]]
     # request spans keep the request id as their shared identifier
     queued = [e for e in tracer.events() if e["name"] == "request.queued"]
     assert sorted(e["args"]["rid"] for e in queued) == sorted(
@@ -161,9 +190,10 @@ def test_trace_report_tables_the_phases_of_an_export(model, tracer, tmp_path):
                  *DECODE_PHASES[1:]):
         assert agg[name]["count"] == n_steps, name
     assert agg["engine.decode.prepare"]["count"] == n_steps + 2
-    # a chunk a prompt, and a fetch apart for the two that ended beside a
-    # step in flight
-    assert agg["engine.prefill"]["count"] == 3 + 2
+    # a chunk a prompt, the executable's call a child span of each, and a
+    # first token a prompt under its own name
+    assert agg[PREFILL]["count"] == agg[CHUNK]["count"] == 3
+    assert agg[FIRST_TOKEN]["count"] == 3
     assert "engine.decode.fetch" in report.build_report(trace_doc=doc)
 
 
@@ -198,21 +228,27 @@ def test_the_names_the_benchmark_reads_are_the_engines(model, tracer):
     assert ps.span_ms(parsed[0], ps.PREPARE) > 0
 
 
-def test_a_prefill_that_ends_beside_a_step_in_flight_opens_its_phase_twice(
-        model, tracer):
+def test_a_first_token_has_a_phase_of_its_own_on_both_ways(model, tracer):
     """ISSUE 34: the chunk is enqueued under ``engine.prefill`` before the
     prepare, the next decode step is dispatched, and only then are the
-    chunk's logits fetched and the first token emitted, under a second
-    ``engine.prefill``, so that the wait is seen under a phase of the
-    engine. No new name; by ``benchmarks/harness/program_spans.py``'s rule
-    such a call is no decode-only step. With nothing in flight (the first
-    call) the fetch stays inside the chunk's own phase."""
+    chunk's logits fetched and the first token emitted. ISSUE 35: that
+    wait has a name of its own, ``engine.prefill.first_token`` (one name,
+    one meaning), also where it follows its chunk at once because nothing
+    is in flight (the first call). Either call still holds
+    ``engine.prefill``, so by ``benchmarks/harness/program_spans.py``'s
+    rule it is no decode-only step."""
     from benchmarks.harness import program_spans as ps
 
     with _engine(model, ingest_async=False) as eng:
         first, = _submit(eng, lengths=(5,), new=8)
         outs = eng.step()
         assert [o.rid for o in outs] == [first, first]    # nothing in flight
+        (_, alone), = _steps(tracer.events())
+        assert [e["name"] for e in alone] == [
+            "engine.admit", PREFILL, FIRST_TOKEN, *DECODE_PHASES[:2],
+            *DECODE_PHASES, "engine.bookkeeping"]
+        assert (alone[2]["args"]["requests"], alone[2]["args"]["behind"]) \
+            == (1, 0)
         eng.step()
         tracer.clear()
         second, = _submit(eng, lengths=(11,), new=4)
@@ -223,22 +259,61 @@ def test_a_prefill_that_ends_beside_a_step_in_flight_opens_its_phase_twice(
         eng.step()
     (step, inside), (after, inside_after) = _steps(tracer.events())
     assert [e["name"] for e in inside] == [
-        "engine.admit", "engine.prefill", "engine.decode.prepare",
-        "engine.decode.dispatch", "engine.prefill", "engine.decode.fetch",
+        "engine.admit", PREFILL, "engine.decode.prepare",
+        "engine.decode.dispatch", FIRST_TOKEN, "engine.decode.fetch",
         "engine.decode.emit", "engine.bookkeeping"]
-    assert all(e["args"] == step["args"] for e in inside)
     assert [e["name"] for e in inside_after] == [
         "engine.admit", *DECODE_PHASES, "engine.bookkeeping"]
-    # the request's own span closes with its first token, in the second phase
-    fetch = [e for e in inside if e["name"] == "engine.prefill"][1]
+    # the request's own span closes with its first token, in that phase,
+    # which says that a step was enqueued behind the chunk it waits for
+    fetch, = [e for e in inside if e["name"] == FIRST_TOKEN]
+    assert fetch["args"] == {**step["args"], "requests": 1, "behind": 1}
+    chunk, = _chunks(tracer.events())
+    assert (chunk["args"]["rid"], chunk["args"]["last"]) == (second, 1)
     prefill, = [e for e in tracer.events() if e["name"] == "request.prefill"]
     assert prefill["tid"] == second
     assert fetch["ts"] <= prefill["ts"] + prefill["dur"] <= \
         fetch["ts"] + fetch["dur"] + 1e-3
     parsed = ps.steps_of([(e["name"], e["ts"] * 1e-6, e["dur"] * 1e-6)
                           for e in tracer.events()])
-    assert len(parsed) == 2 and len(parsed[0][ps.PREFILL]) == 2
+    assert len(parsed) == 2 and len(parsed[0][ps.PREFILL]) == 1
     assert ps.decode_only(parsed) == [parsed[1]]
+
+
+def test_a_chunk_says_what_it_did(model, tracer):
+    """ISSUE 35: ``engine.prefill.chunk`` lies around the chunk
+    executable's call and carries ``tokens`` real tokens from ``start`` in
+    a graph of ``padded``, a rung; the chunks of a request tile its prompt;
+    ``metrics()`` sums the same two numbers whether anything traces or not."""
+    lengths = (5, 29, 70)
+    with LLMEngine(model, num_blocks=64, block_size=8, max_batch_size=4,
+                   max_prefill_tokens_per_step=32) as eng:
+        rungs = eng.prefill_buckets
+        rids = _submit(eng, lengths=lengths, new=3)
+        while eng.has_work():
+            eng.step()
+        m = eng.metrics()
+        text = metrics.to_prometheus_text()
+        assert "serving_prefill_tokens_total" in text
+        assert "serving_prefill_padded_tokens_total" in text
+    chunks = [c["args"] for c in _chunks(tracer.events())]
+    assert all(set(c) == {"rid", "start", "tokens", "padded", "last"}
+               for c in chunks)
+    for rid, n in zip(rids, lengths):
+        mine = [c for c in chunks if c["rid"] == rid]
+        at = 0
+        for c in mine:              # in order, each from where the last ended
+            assert c["start"] == at and 0 < c["tokens"] <= c["padded"]
+            assert c["padded"] in rungs and c["padded"] <= 32
+            at += c["tokens"]
+        assert at == n and [c["last"] for c in mine] == \
+            [0] * (len(mine) - 1) + [1]
+    assert len([c for c in chunks if c["rid"] == rids[2]]) >= 3
+    assert m["prefill_chunks"] == len(chunks)
+    assert m["prefill_tokens"] == sum(c["tokens"] for c in chunks) \
+        == sum(lengths)
+    assert m["prefill_padded_tokens"] == sum(c["padded"] for c in chunks) \
+        > m["prefill_tokens"]
 
 
 def test_a_state_kind_adds_no_span_name(tracer):
@@ -263,8 +338,8 @@ def test_a_state_kind_adds_no_span_name(tracer):
         assert eng.metrics()["decode_steps_sync_by_reason"]["commit"] == 1
     steps = _steps(tracer.events())
     names = {e["name"] for _, inside in steps for e in inside}
-    assert names == {"engine.admit", "engine.prefill", "engine.bookkeeping",
-                     *DECODE_PHASES}
+    assert names == {"engine.admit", PREFILL, FIRST_TOKEN,
+                     "engine.bookkeeping", *DECODE_PHASES}
     committed = [[e["name"] for e in inside] for step, inside in steps
                  if "step" not in step["args"]]
     assert committed == [["engine.decode.fetch", "engine.decode.emit"]]
@@ -281,12 +356,23 @@ def test_a_tick_without_work_is_no_step(model, tracer):
 def test_with_tracing_off_nothing_is_recorded_or_built(model, monkeypatch):
     """The disabled path: ``span()`` hands back the one shared no-op,
     ``engine.step()`` appends no event, no ``add_complete`` call is even
-    made (so no ``args`` dict is built for one), and ``trace.py`` itself
-    allocates nothing."""
+    made (so no ``args`` dict is built for one), no ``span()`` call is
+    handed an ``args`` dict (they are built under ``live()``), and
+    ``trace.py`` itself allocates nothing. The two prefill counters count
+    all the same."""
     TRACER.disable()
     TRACER.clear()
     assert not jax.profiler.TraceAnnotation.is_enabled()
+    assert not trace.live()
     assert trace.span("x") is trace.span("y", cat="engine") is trace._NOOP
+    opened, real_span = [], trace.span
+
+    def span(name, cat="host", tid=None, args=None):
+        assert args is None, f"{name}: args built with tracing off"
+        opened.append(name)
+        return real_span(name, cat=cat, tid=tid, args=args)
+
+    monkeypatch.setattr(trace, "span", span)
 
     def refuse(*a, **kw):
         raise AssertionError("add_complete called with the tracer off")
@@ -332,8 +418,11 @@ def test_with_tracing_off_nothing_is_recorded_or_built(model, monkeypatch):
             if not grown:
                 break
             grown &= grown_in_trace_py(steps)
+        m = eng.metrics()
     assert grown == set()
     assert TRACER.events() == []
+    assert {"engine.step", PREFILL, CHUNK, FIRST_TOKEN} <= set(opened)
+    assert m["prefill_padded_tokens"] >= m["prefill_tokens"] > 0
 
 
 def test_spans_land_in_a_profile_inside_the_callers_span(model, tmp_path):
@@ -379,6 +468,54 @@ def test_spans_land_in_a_profile_inside_the_callers_span(model, tmp_path):
     step = [(a, b) for n, a, b in line if n == "engine.step"]
     emit = [(a, b) for n, a, b in line if n == "engine.decode.emit"]
     assert all(sa <= a and b <= sb for (a, b), (sa, sb) in zip(emit, step))
+
+
+def test_a_spans_args_ride_in_the_profile_as_statistics(model, tmp_path):
+    """ISSUE 35: under a ``jax.profiler`` session, with the tracer off, the
+    scalars among a span's args are the statistics of an event still named
+    as the span is, so the benchmark reads a chunk's counts on the device
+    line's clock; what is no scalar stays out."""
+    TRACER.disable()
+    TRACER.clear()
+    with _engine(model, ingest_async=False) as eng:
+        _submit(eng, lengths=(5,), new=8)
+        for _ in range(3):
+            eng.step()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            assert trace.live() and not trace.enabled()
+            with trace.span("x", args={"n": 3, "f": 0.5, "b": True,
+                                       "s": "t#1,u", "none": None, "l": [1]}):
+                pass
+            rid, = _submit(eng, lengths=(11,), new=4)
+            for _ in range(2):
+                with jax.profiler.TraceAnnotation("outer"):
+                    eng.step()
+        finally:
+            jax.profiler.stop_trace()
+    assert not trace.live() and TRACER.events() == []
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    found = {}
+    for plane in data.planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in ("x", CHUNK, FIRST_TOKEN, "engine.step"):
+                    found.setdefault(e.name, []).append(dict(e.stats))
+    # (the profile's own separators in a string are written "_")
+    assert found["x"] == [{"n": 3, "f": 0.5, "b": 1, "s": "t_1_u"}]
+    assert found[CHUNK] == [{"rid": rid, "start": 0, "tokens": 11,
+                             "padded": 16, "last": 1}]
+    (first,), steps = found[FIRST_TOKEN], found["engine.step"]
+    assert (first["requests"], first["behind"]) == (1, 1)
+    assert first["engine"] == steps[0]["engine"] == eng._name.replace("#", "_")
+    # the step's number is known once the tick is seen to have work: the
+    # phases from there on carry it, ``engine.step`` itself was entered before
+    assert first["step"] == 4 and "step" not in steps[0]
 
 
 def test_arrival_t_is_the_due_time(model, tracer):
