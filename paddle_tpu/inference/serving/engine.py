@@ -119,6 +119,13 @@ _M_PREFILL_CHUNKS = _obs_metrics.counter(
     "serving_prefill_chunks_total",
     "block-aligned prefill chunk executions (chunked prefill splits one "
     "prompt across several of these)")
+_M_PREFILL_IN_A_ROW = _obs_metrics.counter(
+    "serving_prefill_chunks_in_a_row_total",
+    "of serving_prefill_chunks_total, the chunks whose program read the "
+    "request's keys laid out in a row, through the chunk kernel on the TPU "
+    "(every layer of it: chunk_reads_in_a_row); 0 on an engine whose "
+    "Llama-form pools hold int8 codes, which the page-by-page multi-query "
+    "kernel reads")
 _M_PREFILL_TOKENS = _obs_metrics.counter(
     "serving_prefill_tokens_total",
     "prompt tokens the prefill chunks materialized (re-prefills after an "
@@ -198,6 +205,7 @@ _M_ROWS_DISCARDED = _obs_metrics.counter(
 _SERVING_METRICS = (_M_ADMITTED, _M_EVICTIONS, _M_FINISHED, _M_QUEUED_EXH,
                     _M_PREFIX_REUSED, _M_COW, _M_PREFILLS,
                     _M_PREFILL_BEHIND, _M_PREFILL_CHUNKS,
+                    _M_PREFILL_IN_A_ROW,
                     _M_PREFILL_TOKENS, _M_PREFILL_PADDED,
                     _M_SPEC_PROPOSED, _M_SPEC_ACCEPTED,
                     _M_TOKENS, _M_DEADLINE, _M_KV_SAVED, _H_TTFT, _H_ITL,
@@ -625,6 +633,10 @@ class LLMEngine:
         # (tier revive, page import, prefix-store entries) verify and
         # degrade to re-prefill on mismatch
         self.cache.page_checksums = bool(kv_page_checksums)
+        from .paged_attention import chunk_reads_in_a_row
+        self._chunks_in_a_row = all(
+            chunk_reads_in_a_row(sp, self.cache.quantized)
+            for sp in self.cache.layout)
         self._place_cache(self.cache, self.config)
         self._kv_bytes_saved = self.cache.bytes_saved_vs_unquantized(
             self.config)
@@ -1336,9 +1348,10 @@ class LLMEngine:
         position true_upto-1, pools, scale pools)``. ``start`` is the
         block-aligned absolute offset of the chunk (0 for a whole-prompt
         prefill; the shared-prefix boundary or the previous chunk's end
-        otherwise); queries attend causally over pool pages
-        [0, true_upto) via paged multi-query attention, so one graph per
-        chunk-length bucket serves every offset. Quantized caches
+        otherwise); queries attend causally over the request's pool pages
+        [0, true_upto), laid out in a row for the chunk kernel (page by
+        page through the multi-query kernel over int8 codes), so one graph
+        per chunk-length bucket serves every offset. Quantized caches
         (non-empty scale lists) quantize each page's rows on write and
         store the per-row scales beside the codes (ISSUE 14).
 
@@ -1904,6 +1917,8 @@ class LLMEngine:
             req.draft_cached = start + take
         req.num_cached = start + take
         _M_PREFILL_CHUNKS.inc(instance=self._name)
+        if self._chunks_in_a_row:
+            _M_PREFILL_IN_A_ROW.inc(instance=self._name)
         _M_PREFILL_TOKENS.inc(take, instance=self._name)
         _M_PREFILL_PADDED.inc(C, instance=self._name)
         # QoS accounting (ISSUE 17): prefill work charges the tenant's
@@ -2800,6 +2815,11 @@ class LLMEngine:
             "prefill_ends_behind_decode": int(
                 _M_PREFILL_BEHIND.value(instance=inst)),
             "prefill_chunks": int(_M_PREFILL_CHUNKS.value(instance=inst)),
+            # of those, the ones that read the request's keys in a row
+            # through the chunk kernel (ISSUE 36): all, or with int8
+            # Llama-form pools none
+            "prefill_chunks_in_a_row": int(
+                _M_PREFILL_IN_A_ROW.value(instance=inst)),
             # what the chunks were asked for and what their graphs ran:
             # padded / tokens - 1 is the work spent on padding (ISSUE 35)
             "prefill_tokens": int(_M_PREFILL_TOKENS.value(instance=inst)),

@@ -584,10 +584,15 @@ class KVLayerSpec:
     a request, ``"none"`` nothing.
 
     ``k_store`` is the width a K row is stored at (``k_dim`` unless padded
-    up to the lanes); ``prefill`` is how a prefill chunk reads the layer's
-    keys: ``"paged"`` page by page through the multi-query kernel (the
-    verify step's too), ``"linear"`` with the request's pages laid out in a
-    row for the chunk kernel.
+    up to the lanes); ``prefill`` is the form the layer's pools are held in
+    (:meth:`pool_shape`) and so who may read them: ``"paged"`` pools
+    ``[N, block, Hkv, D]`` (Llama's: a plan shards them over the kv heads,
+    int8 codes keep scales ``[N, block, Hkv]``, and the page-by-page
+    multi-query kernel reads them for the verify step and for a chunk over
+    int8 codes), ``"linear"`` pools ``[N, block * Hkv, D]``. A prefill
+    chunk over unquantized pools reads the request's pages laid out in a
+    row through the chunk kernel in EITHER form (ISSUE 36:
+    ``paged_chunk_attention`` gathers a 4-D pool's pages).
 
     A ``"latent"`` layer (compressed keys and values) caches ONE row of
     ``k_dim`` a token, shared by every query head, whose first ``v_dim``

@@ -15,14 +15,17 @@ gathered [B, P*block, Hkv, D] view; the kernel never does).
 
 from __future__ import annotations
 
+import functools
 import math
 
+import jax
 import jax.numpy as jnp
 
 from ...nn.functional.flash_attention import _sdpa_ref
 
 __all__ = ["paged_decode_attention", "paged_decode_attention_latent",
-           "paged_multiquery_attention", "chunk_attention", "kv_pool_specs",
+           "paged_multiquery_attention", "paged_chunk_attention",
+           "chunk_reads_in_a_row", "chunk_attention", "kv_pool_specs",
            "ChunkAttnState", "DecodeAttnState"]
 
 
@@ -259,6 +262,65 @@ def paged_multiquery_attention(q, k_pool, v_pool, block_tables, context_lens,
     return _lax_multiquery_fallback(q, k_pool, v_pool, block_tables,
                                     context_lens, q_start, float(scale),
                                     k_scale=k_scale, v_scale=v_scale)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _pages_in_a_row(q, k_pool, v_pool, tables_row, q_start, upto, *, scale,
+                    interpret):
+    """The chunk kernel over one request's pages of 4-D pools, gathered in a
+    row. A ``jax.jit`` of its own: a program's layers are the same shapes,
+    so the gather and the kernel are traced and lowered once a program and
+    not once a layer (``ops/pallas``'s ``_decode_call`` says what that
+    cost). ``interpret`` only keys the trace: the kernel's wrapper reads the
+    environment itself."""
+    from ...ops.pallas.paged_attention import chunk_attention_pallas
+
+    def row(pool):
+        # [P, block, Hkv, D] as tokens [P * block, Hkv, D]: no transpose
+        return pool[tables_row].reshape((-1,) + pool.shape[2:])
+
+    return chunk_attention_pallas(q[0], row(k_pool), row(v_pool), q_start, 0,
+                                  upto, scale, name="chunk_attention")[None]
+
+
+def chunk_reads_in_a_row(spec, quantized):
+    """Whether a prefill chunk reads this layer's keys laid out in a row
+    (the chunk kernel on the TPU, a gather off it) and not page by page:
+    every layer but a Llama-form one (``prefill == "paged"``, 4-D pools)
+    whose pools hold int8 codes. ``LLMEngine`` counts its chunks by it
+    (``metrics()["prefill_chunks_in_a_row"]``)."""
+    return spec.prefill != "paged" or not quantized
+
+
+def paged_chunk_attention(q, k_pool, v_pool, tables_row, q_start, upto, scale,
+                          k_scale=None, v_scale=None):
+    """ONE request's prefill chunk over its pages of ``[N, block, Hkv, D]``
+    pools (ISSUE 36): q ``[1, T, H, D]`` at positions ``q_start + t``,
+    ``tables_row [P]`` the request's pages, ``upto`` its visible tokens.
+    Returns ``[1, T, H, D]``; rows past ``upto`` are undefined.
+
+    Over unquantized pools on the TPU the request's pages are gathered in a
+    row and the chunk kernel (``chunk_attention_pallas``: bf16 products, a
+    key tile of 256; ``chunk_attention`` in a trace, as ``_kernel_name``
+    has it for this form) reads them, per shard under a plan, each shard
+    gathering its own kv heads. Int8 pools keep the page-by-page
+    multi-query kernel (the chunk kernel takes no scales), and off the TPU
+    the gather fallback stays what the bit-exact suites compare with."""
+    from ...ops.pallas.paged_attention import _interpret, use_pallas_paged
+
+    if k_scale is not None or not use_pallas_paged(q.shape[-1],
+                                                   k_pool.shape[1]):
+        return paged_multiquery_attention(
+            q, k_pool, v_pool, tables_row[None], upto[None], q_start[None],
+            scale=scale, k_scale=k_scale, v_scale=v_scale)
+
+    def kernel(q, k_pool, v_pool, tables_row, q_start, upto):
+        return _pages_in_a_row(
+            q, k_pool, v_pool, tables_row, q_start, upto,
+            scale=float(scale), interpret=_interpret())
+
+    return _per_shard_paged(kernel, q, k_pool, False, 3)(
+        q, k_pool, v_pool, tables_row, q_start, upto)
 
 
 def chunk_attention(q, k, v, q_start, k_start, upto, scale, *, window=None,
@@ -649,9 +711,8 @@ class ChunkAttnState(_AttnState):
             vp = vp.at[blks].set(as_pages(va).astype(vp.dtype))
         self.k_pool, self.v_pool, self.k_scale, self.v_scale = kp, vp, ksc, vsc
         if spec.prefill == "paged":
-            return paged_multiquery_attention(
-                qa, kp, vp, self.tables_row[None], upto[None], start[None],
-                scale=scale, k_scale=ksc, v_scale=vsc)
+            return paged_chunk_attention(
+                qa, kp, vp, self.tables_row, start, upto, scale, ksc, vsc)
         # one request's pages in a row: a few tens of MB at the longest
         # context, against the chunk's own matmuls
         return chunk_attention(

@@ -81,9 +81,11 @@ keeps the per-page kernel** (``_int8_page_kernel``: grid ``(B, P)``, one
 pool block a step through the ``BlockSpec`` pipeline, a VPU fold), chosen
 by the scale operands being there.
 
-The multi-query kernel (prefill chunks, speculative verify) has real
-``M = T`` rows, one pool block a grid step, and batched MXU dots; its
-query rows are tiled over a grid axis so VMEM holds one tile
+The multi-query kernel (speculative verify, and a prefill chunk over int8
+codes; since ISSUE 36 a chunk over unquantized pools reads the request's
+pages in a row through ``chunk_attention_pallas`` whatever the pool's
+form) has real ``M = T`` rows, one pool block a grid step, and batched MXU
+dots; its query rows are tiled over a grid axis so VMEM holds one tile
 (``_MQ_ROWS``), not the whole chunk.
 """
 

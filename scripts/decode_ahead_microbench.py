@@ -17,7 +17,9 @@ fetched behind the call's decode dispatch (ISSUE 34:
 ``prefill_ends_behind_decode / prefills``) and, from the same deltas, what
 the chunk graphs computed over what they were asked for (ISSUE 35:
 ``prefill_padded_tokens / prefill_tokens``, and the padded share of the
-first), the window's seconds by kind of call,
+first) and the share of the chunks that read the request's keys in a row
+through the chunk kernel (ISSUE 36: ``prefill_chunks_in_a_row /
+prefill_chunks``), the window's seconds by kind of call,
 and with ``--trace 1`` the traced stretch's median call, the device's busy time in ``decode_pure`` a traced
 decode step (and the grouped expert kernel's, the latent decode kernel's and
 the state update's parts of it), the largest device operations and
@@ -74,6 +76,13 @@ def window_counts(snaps, layers):
         "tokens": real, "padded_tokens": padded,
         "padded_over_tokens": padded / real if real else None,
         "padded_share": 100.0 * (padded - real) / padded if padded else None}
+    # of the window's chunks, those whose program read the request's keys
+    # in a row through the chunk kernel (ISSUE 36; an engine from before it
+    # counts none)
+    chunks, in_a_row = d("prefill_chunks"), d("prefill_chunks_in_a_row")
+    out["prefill_in_a_row"] = {
+        "chunks": chunks, "in_a_row": in_a_row,
+        "share": in_a_row / chunks if chunks else None}
     # an expert model's grouped kernel (ISSUE 30): of the times an expert's
     # weights were streamed, the share that was that expert's only read
     # that layer-step
@@ -202,6 +211,7 @@ def main(argv=None):
     out = {"workload": args.workload, "seed": args.seed,
            "correct": bool(run["correct"]),
            "compiles_in_window": run["compiles_in_window"],
+           "setup_s": run["setup_s"],
            "serve_tokens_per_s": run["values"]["serve_tokens_per_s"],
            "decode_step_ms_p50": stats.percentile(
                run["series"]["decode_step_ms"], 50),
@@ -237,8 +247,11 @@ def main(argv=None):
             "decode_pure_by_kind": by_kind(decode, 40),
             "chunk_pure_by_kind": by_kind(trace_reduce.select(
                 tr["events"], None, "chunk_pure"), 24),
+            # a chunk's attention kernels, whatever implements them (the
+            # benchmark's own pattern: ``prefill_spans.ATTENTION``)
             "chunk_attention_device_s": trace_reduce.op_seconds(
-                tr["events"], "chunk_attention_global", "chunk_pure"),
+                tr["events"], "(paged_prefill_attention|chunk_attention)",
+                "chunk_pure"),
             "idle_share": 100.0 * (1.0 - tr["busy_s"] / tr["window_s"]),
             "idle_s": tr["window_s"] - tr["busy_s"],
             "idle_gaps": tr["breakdown"]["idle_gaps"],

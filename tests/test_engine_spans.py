@@ -696,6 +696,13 @@ KERNELS = [
 ]
 
 
+def _carries(text, name):
+    """Whether a lowered text (``debug_info=True``) holds a Pallas call
+    named ``name`` (a call that is a jit of its own starts its name stack
+    with the name)."""
+    return bool(re.search(r'loc\("(?:[^"]*[/(])?' + name + r'[/)]', text))
+
+
 @pytest.mark.parametrize("name,make", KERNELS, ids=[k[0] for k in KERNELS])
 def test_the_lowered_text_carries_the_kernels_name(name, make, monkeypatch):
     """The name a ``pl.pallas_call`` is given enters the name stack of
@@ -703,9 +710,7 @@ def test_the_lowered_text_carries_the_kernels_name(name, make, monkeypatch):
     HLO instruction's own name, ``%paged_decode_attention.16``)."""
     monkeypatch.setenv("PT_PALLAS_INTERPRET", "1")
     fn, args = make()
-    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
-    # (a call that is a jit of its own starts its name stack with the name)
-    assert re.search(r'loc\("(?:[^"]*[/(])?' + name + r'[/)]', text), name
+    assert _carries(jax.jit(fn).lower(*args).as_text(debug_info=True), name)
 
 
 def test_every_pallas_call_has_a_name():
@@ -744,3 +749,63 @@ def test_a_layer_kind_names_its_kernels(name, make, monkeypatch):
     """Window and global layers run the same two call sites under names of
     their own, so that a trace tells them apart."""
     test_the_lowered_text_carries_the_kernels_name(name, make, monkeypatch)
+
+
+# --- which kernel a Llama engine's chunk program carries (ISSUE 36) -------------
+
+def _program_kernels(text):
+    return {name for name in ("chunk_attention", "paged_prefill_attention")
+            if _carries(text, name)}
+
+
+def _chunk_text(eng, rung):
+    c = eng.cache
+    if eng._prefill_jit is None:
+        eng._build_jits()
+    return eng._prefill_jit.lower(
+        [p._data for p in eng._params], jnp.zeros((1, rung), jnp.int32),
+        np.int32(0), np.int32(rung), jnp.zeros(eng.max_pages, jnp.int32),
+        c.k, c.v, c.k_scale, c.v_scale).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("kv_dtype,kernel,share", [
+    (None, "chunk_attention", 1), ("int8", "paged_prefill_attention", 0)],
+    ids=["bf16-in-a-row", "int8-page-by-page"])
+def test_a_llama_chunk_program_reads_by_its_pools_dtype(
+        model, monkeypatch, kv_dtype, kernel, share):
+    """A Llama engine's chunk program carries the chunk kernel over
+    unquantized pools (the request's pages in a row) and the page-by-page
+    multi-query kernel over int8 codes, never both; ``metrics()`` counts the
+    chunks of the first kind."""
+    monkeypatch.setenv("PT_PALLAS_INTERPRET", "1")
+    with _engine(model, kv_dtype=kv_dtype,
+                 max_prefill_tokens_per_step=16) as eng:
+        assert _program_kernels(
+            _chunk_text(eng, eng.prefill_buckets[0])) == {kernel}
+        _submit(eng, lengths=(5, 29), new=2)
+        while eng.has_work():
+            eng.step()
+        m = eng.metrics()
+        # (a counter nothing has added to has no series)
+        assert not share or "serving_prefill_chunks_in_a_row_total" in \
+            metrics.to_prometheus_text()
+    assert m["prefill_chunks"] >= 3
+    assert m["prefill_chunks_in_a_row"] == share * m["prefill_chunks"]
+
+
+def test_the_verify_program_keeps_the_multi_query_kernel(model, monkeypatch):
+    """``[B, k+1]`` rows of many requests are no chunk of one request: the
+    speculative verify step reads page by page as before, beside a chunk
+    program (the target's and the draft's) that reads in a row."""
+    monkeypatch.setenv("PT_PALLAS_INTERPRET", "1")
+    with _engine(model, spec_tokens=3) as eng:
+        c, B, K = eng.cache, eng.max_batch_size, 3
+        eng._build_jits()
+        text = eng._verify_jit.lower(
+            [p._data for p in eng._params], jnp.zeros((B, K + 1), jnp.int32),
+            jnp.zeros(B, jnp.int32), jnp.zeros((B, eng.max_pages), jnp.int32),
+            jnp.zeros((B, K), jnp.int32), c.k, c.v, c.k_scale,
+            c.v_scale).as_text(debug_info=True)
+        assert _program_kernels(text) == {"paged_prefill_attention"}
+        assert _program_kernels(
+            _chunk_text(eng, eng.prefill_buckets[0])) == {"chunk_attention"}

@@ -79,6 +79,26 @@ def _paged_cases():
     return cases
 
 
+def _chunk_row_cases():
+    """ISSUE 36: a Llama-form layer's prefill chunk, the request's pages
+    gathered in a row for the chunk kernel, at the benchmark's serving
+    geometry (Mistral-7B: a 2,048-token chunk, 256 pages of 16 a request out
+    of 6,145, 32 heads over 8 kv heads of 128), beside ``mq2048``: the
+    multi-query kernel stays int8 pools' and the verify step's."""
+    from paddle_tpu.inference.serving import paged_attention as spa
+
+    sds = jax.ShapeDtypeStruct
+
+    def chunk(q, k, v, t, start, upto):
+        return spa._pages_in_a_row(q, k, v, t, start, upto, scale=0.088,
+                                   interpret=False)
+
+    pool = sds((6145, BLK, 8, D), jnp.bfloat16)
+    return [("chunk-row2048-mistral", chunk, (
+        sds((1, 2048, 32, D), jnp.bfloat16), pool, pool,
+        sds((256,), jnp.int32), sds((), jnp.int32), sds((), jnp.int32)))]
+
+
 def _flash_cases():
     sds = jax.ShapeDtypeStruct
     cases = []
@@ -164,13 +184,13 @@ def _nemotron_cases():
 
 
 CASES = _flash_cases() + _paged_cases() + _grouped_ffn_cases() \
-    + _nemotron_cases()
+    + _nemotron_cases() + _chunk_row_cases()
 #: stage 2 keeps tier-1 short: the backward cases (a grad compiles the
 #: forward kernel too), decode, the top rung that VMEM decides, and the
 #: grouped expert kernel (48 operands left in HBM, 48 MiB of VMEM asked for)
 COMPILED_CASES = [c for c in CASES if c[0].startswith(
     ("flash-bwd", "decode", "mq2048", "grouped-ffn", "ssm-decode",
-     "grouped-relu2"))]
+     "grouped-relu2", "chunk-row"))]
 
 
 @pytest.mark.parametrize("name,fn,args", CASES, ids=[c[0] for c in CASES])
@@ -238,6 +258,23 @@ _COMPILE = textwrap.dedent("""
         print("REFUSED bare mosaic under gspmd", flush=True)
     compile_step_with_plan(attn, plan).trace(q, q, q).lower().compile()
     print("COMPILED per-shard under plan", flush=True)
+
+    # ISSUE 36: a Llama-form chunk under a plan gathers the request's pages
+    # in a row INSIDE the manual region, each shard its own kv heads (the
+    # dispatcher asks the backend, which is the CPU here: told it is a TPU)
+    from paddle_tpu.inference.serving import paged_attention as spa
+    T.pa.use_pallas_paged = lambda *a: True
+    name, _, args = T._chunk_row_cases()[0]
+    heads = NamedSharding(mesh, P(None, None, "tp", None))
+    rep = NamedSharding(mesh, P())
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                 sharding=heads if a.ndim == 4 else rep)
+            for a in args]
+    text = compile_step_with_plan(
+        lambda *a: spa.paged_chunk_attention(*a, 0.088), plan).trace(
+            *args).lower().compile().as_text()
+    assert "chunk_attention" in text and "all-gather" not in text
+    print("COMPILED chunk in a row per-shard under plan", flush=True)
 """)
 
 
@@ -260,7 +297,8 @@ def test_compiles_for_v5e_without_a_chip():
     done = {ln.split(" ", 1)[1] for ln in r.stdout.splitlines()
             if ln.startswith("COMPILED ")}
     assert done == {c[0] for c in COMPILED_CASES} | {
-        "rope", "per-shard under plan"}
+        "rope", "per-shard under plan",
+        "chunk in a row per-shard under plan"}
     assert "REFUSED bare mosaic under gspmd" in r.stdout
     # a pallas_call's ``name`` becomes its HLO instruction's own name (under
     # jax.grad wrapped: ``transpose_jvp_flash_attention_bwd_dq__``), which is
@@ -269,6 +307,7 @@ def test_compiles_for_v5e_without_a_chip():
                r.stdout.splitlines() if ln.startswith("KERNELS ")}
     for case, want in (("decode", ["paged_decode_attention"]),
                        ("mq2048", ["paged_prefill_attention"]),
+                       ("chunk-row", ["chunk_attention"]),
                        ("grouped-ffn", ["moe_grouped_swiglu"]),
                        ("grouped-relu2", ["moe_grouped_relu2"]),
                        ("ssm-decode", ["mamba2_decode_update"]),
