@@ -370,8 +370,12 @@ def chunk_attention(q, k, v, q_start, k_start, upto, scale, *, window=None,
 # convolution's tail and the recurrent state in the request's SLOT (a decode
 # step: every row its own slot, a dead row the null slot, updated in place
 # by the kernel; a chunk: from zeros if it is the request's first, from what
-# the chunk before left otherwise, its padding changing nothing). A layer
-# that keeps nothing (kind ``"none"``) is handed a state too, for
+# the chunk before left otherwise, its padding changing nothing). A
+# gated-delta layer (ISSUE 37) is the same kind with another recurrence,
+# ``state.delta``: it hands over what enters its convolution (q, k and v),
+# its decays and its ``beta``, and gets back ``S_t^T q_t``; the slot, its
+# tail, the first chunk's zeros and the null slot are ``scan``'s, as code. A
+# layer that keeps nothing (kind ``"none"``) is handed a state too, for
 # ``state.count``; it calls nothing else of it.
 
 
@@ -382,20 +386,26 @@ def _pad_last(x, width):
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, extra)])
 
 
-def _conv_and_split(spec, shifted, conv_w, conv_b):
-    """The causal depthwise convolution and what it feeds. ``shifted``: the
+def _conv_silu(shifted, conv_w, conv_b=None):
+    """The causal depthwise convolution and its ``silu``. ``shifted``: the
     ``K`` arrays ``[..., D]`` whose row ``t`` is the row ``K - 1 - j`` before
     token ``t``'s own (oldest first, the last the tokens themselves);
-    ``conv_w [D, K]``, ``conv_b [D]``. Returns ``silu(sum_j w[:, j] *
-    shifted[j] + b)`` cut into ``(x [..., H, P], B [..., G, N], C [..., G,
-    N])``, in the rows' dtype."""
+    ``conv_w [D, K]``, ``conv_b [D]`` or None. Returns ``silu(sum_j w[:, j] *
+    shifted[j] + b)`` in the rows' dtype."""
     import jax
 
     f32 = jnp.float32
-    out = conv_b.astype(f32)
+    out = None if conv_b is None else conv_b.astype(f32)
     for j, rows in enumerate(shifted):
-        out = out + rows.astype(f32) * conv_w[:, j].astype(f32)
-    out = jax.nn.silu(out).astype(shifted[-1].dtype)
+        term = rows.astype(f32) * conv_w[:, j].astype(f32)
+        out = term if out is None else out + term
+    return jax.nn.silu(out).astype(shifted[-1].dtype)
+
+
+def _conv_and_split(spec, shifted, conv_w, conv_b):
+    """``_conv_silu`` cut into what the selective scan reads: ``(x [..., H,
+    P], B [..., G, N], C [..., G, N])``."""
+    out = _conv_silu(shifted, conv_w, conv_b)
     heads, p, n = spec.num_kv_heads, spec.v_dim, spec.state_dim
     groups = (spec.k_dim - heads * p) // (2 * n)
     lead = out.shape[:-1]
@@ -403,6 +413,24 @@ def _conv_and_split(spec, shifted, conv_w, conv_b):
     b = out[..., heads * p:heads * p + groups * n].reshape(*lead, groups, n)
     c = out[..., heads * p + groups * n:].reshape(*lead, groups, n)
     return x, b, c
+
+
+def _conv_and_split_qkv(spec, shifted, conv_w):
+    """``_conv_silu`` (no bias) cut into what the gated delta rule reads:
+    ``(q [..., Hk, N], k [..., Hk, N], v [..., Hv, P])``, ``Hk`` key heads by
+    what the ``Hv`` value heads leave of ``k_dim``."""
+    out = _conv_silu(shifted, conv_w)
+    heads, p, n = spec.num_kv_heads, spec.v_dim, spec.state_dim
+    key_heads = (spec.k_dim - heads * p) // (2 * n)
+    if spec.heads_a_lane_row != 1 or key_heads < 1 or heads % key_heads:
+        raise ValueError(
+            "a delta layer's state kind holds a value head a lane row, its "
+            "value heads a multiple of its key heads; got "
+            f"{heads} value heads of {p}, {key_heads} key heads of {n}")
+    lead = out.shape[:-1]
+    q = out[..., :key_heads * n].reshape(*lead, key_heads, n)
+    k = out[..., key_heads * n:2 * key_heads * n].reshape(*lead, key_heads, n)
+    return q, k, out[..., 2 * key_heads * n:].reshape(*lead, heads, p)
 
 
 class _AttnState:
@@ -472,33 +500,60 @@ class DecodeAttnState(_AttnState):
         sn = sin_t[self.positions][:, None, None, :]
         return rope_rotate(x, c, sn)
 
+    def _shifted(self, rows):
+        """What a state layer's convolution reads at this step: each row's
+        slot gives the ``K - 1`` rows before this one (``rows [B, 1, D]``)
+        and takes the newest ``K - 1`` back. Every row of the batch does so:
+        a dead row's slot is the null slot. Returns the ``K`` arrays ``[B,
+        D]``, oldest first."""
+        spec, slots = self.spec, self.slots
+        tail = self.k_pool[slots]                     # [B, (K - 1) * D]
+        window = jnp.concatenate([tail, rows[:, 0].astype(tail.dtype)], -1)
+        self.k_pool = self.k_pool.at[slots].set(window[:, spec.k_dim:])
+        d = spec.k_dim
+        return [window[:, j * d:(j + 1) * d]
+                for j in range(spec.conv_rows + 1)]
+
+    def _live_rows(self):
+        """Rows of the batch whose slot is not the null slot."""
+        return jnp.sum(self.slots != self.v_pool.shape[0] - 1)
+
     def scan(self, xbc, dt, a, conv_w, conv_b):
         """A state-space layer's step (ISSUE 33), one token a row. ``xbc
         [B, 1, D]``: what enters the convolution; ``dt [B, 1, H]`` float32,
         the step after its softplus; ``a [H]`` (negative); the convolution's
-        ``conv_w [D, K]`` and ``conv_b [D]``. Each
-        row's slot gives the ``K - 1`` rows before this one and takes the
-        newest ``K - 1`` back;
+        ``conv_w [D, K]`` and ``conv_b [D]``. The convolution reads and
+        renews the slot's tail (``_shifted``);
         the recurrent state is read and written where it lies by
-        ``mamba2_decode_update``. Every row of the batch does so: a dead
-        row's slot is the null slot. Returns ``(x [B, 1, H, P], y [B, 1, H,
+        ``mamba2_decode_update``. Returns ``(x [B, 1, H, P], y [B, 1, H,
         P] float32)``: the convolved channels and ``h_t C_t``."""
         from ...ops.pallas.mamba2 import mamba2_decode_update
 
         self._require("state")
-        spec, slots = self.spec, self.slots
-        tail = self.k_pool[slots]                     # [B, (K - 1) * D]
-        window = jnp.concatenate([tail, xbc[:, 0].astype(tail.dtype)], -1)
-        self.k_pool = self.k_pool.at[slots].set(window[:, spec.k_dim:])
-        d = spec.k_dim
-        x, b, c = _conv_and_split(
-            spec, [window[:, j * d:(j + 1) * d]
-                   for j in range(spec.conv_rows + 1)], conv_w, conv_b)
-        y, self.v_pool = mamba2_decode_update(self.v_pool, slots, x, dt[:, 0],
-                                              a, b, c)
-        self.count("ssm_state_rows_updated",
-                   jnp.sum(slots != self.v_pool.shape[0] - 1))
+        x, b, c = _conv_and_split(self.spec, self._shifted(xbc), conv_w,
+                                  conv_b)
+        y, self.v_pool = mamba2_decode_update(self.v_pool, self.slots, x,
+                                              dt[:, 0], a, b, c)
+        self.count("ssm_state_rows_updated", self._live_rows())
         return x[:, None], y[:, None]
+
+    def delta(self, qkv, g, beta, conv_w):
+        """A gated-delta layer's step (ISSUE 37), one token a row: the other
+        recurrence of the state kind. ``qkv [B, 1, D]``: what enters the
+        convolution (no bias), ``[q | k | v]``; ``g [B, 1, H]`` float32, the
+        log of a value head's decay; ``beta [B, 1, H]``; ``conv_w [D, K]``.
+        The tail as ``scan``'s; the state ``S [H, N, P]`` is read (``S^T
+        k``), corrected and written where it lies by
+        ``gated_delta_decode_update``, which norms q and k. Returns ``o [B,
+        1, H, P]`` float32: ``S_t^T q_t`` from the new state."""
+        from ...ops.pallas.gated_delta import gated_delta_decode_update
+
+        self._require("state")
+        q, k, v = _conv_and_split_qkv(self.spec, self._shifted(qkv), conv_w)
+        o, self.v_pool = gated_delta_decode_update(
+            self.v_pool, self.slots, q, k, v, g[:, 0], beta[:, 0])
+        self.count("delta_state_rows_updated", self._live_rows())
+        return o[:, None]
 
     def attend(self, q, k, v, scale, sink=None):
         import jax
@@ -604,35 +659,43 @@ class ChunkAttnState(_AttnState):
         #: a state kind: the request's slot, int32 ``[1]``
         self.slot = slot
 
-    def scan(self, xbc, dt, a, conv_w, conv_b):
-        """A state-space layer's chunk (``DecodeAttnState.scan`` has the
-        operands, ``[1, C, ...]`` here). The request's FIRST chunk (``start
-        == 0``) starts from zeros whatever its slot holds: the slot may be
-        recycled, and a row dispatched ahead for the request that left it
-        may have written there since (programs run in order, and this one
-        does not read it). A later chunk starts from what the chunk before
-        wrote. Positions at or past ``upto`` change nothing: their step is
-        0, and the convolution's tail that goes back is the last ``K - 1``
-        REAL rows. The recurrence runs chunked, ``spec.scan_block`` tokens a
-        block (``ssd_chunk_scan``)."""
+    def _shifted(self, rows, slot):
+        """What a state layer's convolution reads over this chunk (``rows
+        [1, C, D]``), and the tail at ``slot`` renewed. The request's FIRST chunk
+        (``start == 0``) starts from zeros whatever its slot holds: the slot
+        may be recycled, and a row dispatched ahead for the request that
+        left it may have written there since (programs run in order, and
+        this one does not read it). A later chunk starts from what the chunk
+        before wrote. The tail that goes back is the last ``K - 1`` REAL
+        rows. Returns ``(the K arrays [C, D], oldest first; whether the
+        chunk is the first; its real tokens)``."""
         import jax
 
+        spec = self.spec
+        fresh = self.start == 0
+        real = self.upto - self.start                 # tokens of the chunk
+        tail = jnp.where(fresh, 0, self.k_pool[slot]).reshape(-1, spec.k_dim)
+        rows = jnp.concatenate([tail.astype(rows.dtype), rows[0]])
+        keep = tail.shape[0]
+        self.k_pool = self.k_pool.at[slot].set(jax.lax.dynamic_slice_in_dim(
+            rows, real, keep).reshape(-1).astype(self.k_pool.dtype))
+        c_len = rows.shape[0] - keep
+        return [rows[j:j + c_len] for j in range(keep + 1)], fresh, real
+
+    def scan(self, xbc, dt, a, conv_w, conv_b):
+        """A state-space layer's chunk (``DecodeAttnState.scan`` has the
+        operands, ``[1, C, ...]`` here). The tail and the first chunk's
+        zeros are ``_shifted``'s. Positions at or past ``upto`` change
+        nothing: their step is 0. The recurrence runs chunked,
+        ``spec.scan_block`` tokens a block (``ssd_chunk_scan``)."""
         from ...ops.pallas.mamba2 import from_stored, ssd_chunk_scan, to_stored
 
         self._require("state")
         spec, slot = self.spec, self.slot[0]
         pack = spec.heads_a_lane_row
-        fresh = self.start == 0
-        real = self.upto - self.start                 # tokens of the chunk
-        tail = jnp.where(fresh, 0, self.k_pool[slot]).reshape(-1, spec.k_dim)
-        rows = jnp.concatenate([tail.astype(xbc.dtype), xbc[0]])
-        keep = tail.shape[0]
-        self.k_pool = self.k_pool.at[slot].set(jax.lax.dynamic_slice_in_dim(
-            rows, real, keep).reshape(-1).astype(self.k_pool.dtype))
+        shifted, fresh, real = self._shifted(xbc, slot)
         c_len = xbc.shape[1]
-        x, b, c = _conv_and_split(
-            spec, [rows[j:j + c_len] for j in range(keep + 1)], conv_w,
-            conv_b)
+        x, b, c = _conv_and_split(spec, shifted, conv_w, conv_b)
         dt = jnp.where(jnp.arange(c_len)[:, None] < real, dt[0], 0.0)
         h0 = from_stored(jnp.where(fresh, 0.0, self.v_pool[slot]), pack)
         y, h = ssd_chunk_scan(x, dt, a, b, c, h0, spec.scan_block)
@@ -640,6 +703,26 @@ class ChunkAttnState(_AttnState):
             to_stored(h, pack).astype(self.v_pool.dtype))
         self.count("ssm_tokens_scanned", real)
         return x[None], y[None]
+
+    def delta(self, qkv, g, beta, conv_w):
+        """A gated-delta layer's chunk (``DecodeAttnState.delta`` has the
+        operands, ``[1, C, ...]`` here). The tail and the first chunk's
+        zeros are ``_shifted``'s. Positions at or past ``upto`` change
+        nothing: their decay is 1 and their ``beta`` 0. The recurrence runs
+        in its chunked form (``gated_delta_chunk``)."""
+        from ...ops.pallas.gated_delta import gated_delta_chunk
+
+        self._require("state")
+        spec, slot = self.spec, self.slot[0]
+        shifted, fresh, real = self._shifted(qkv, slot)
+        q, k, v = _conv_and_split_qkv(spec, shifted, conv_w)
+        live = jnp.arange(qkv.shape[1])[:, None] < real
+        o, s = gated_delta_chunk(
+            q, k, v, jnp.where(live, g[0], 0.0), jnp.where(live, beta[0], 0.0),
+            jnp.where(fresh, 0.0, self.v_pool[slot]))
+        self.v_pool = self.v_pool.at[slot].set(s.astype(self.v_pool.dtype))
+        self.count("delta_tokens_scanned", real)
+        return o[None]
 
     def rope(self, x, cos_t, sin_t):
         """Rotate ``x [1, C, H, D]``, whose rows sit at ``start + i``."""

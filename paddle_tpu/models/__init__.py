@@ -21,3 +21,6 @@ from .joyai_flash import (  # noqa: F401
 from .nemotron_h import (  # noqa: F401
     NemotronHConfig, NemotronHForCausalLM, nemotron_h_tiny,
 )
+from .qwen3_next import (  # noqa: F401
+    Qwen3NextConfig, Qwen3NextForCausalLM, qwen3_next_tiny,
+)

@@ -54,6 +54,7 @@ from ..ops import manipulation as M
 from .llama import _rope_cache
 
 __all__ = ["MiMoV2Config", "MiMoV2ForCausalLM", "moe_dropless",
+           "sigmoid_scores", "softmax_scores",
            "mimo_v2_tiny"]
 
 #: device-side counters an expert layer adds to, per call
@@ -124,14 +125,39 @@ def _store_width(k_dim):
     return -(-k_dim // lane) * lane
 
 
+def sigmoid_scores(logits, bias):
+    """The score function of MiMo-V2, JoyAI-LLM-Flash and Nemotron-H:
+    ``sigmoid`` of the router's logits weighs, and the same plus a learned
+    correction (``bias`` [E] float32) chooses."""
+    import jax
+    import jax.numpy as jnp
+
+    scores = jax.nn.sigmoid(logits)
+    return scores, scores + bias.astype(jnp.float32)
+
+
+def softmax_scores(logits, bias=None):
+    """``softmax`` over ALL the router's experts weighs and chooses
+    (Qwen3-Next); there is no correction."""
+    import jax
+
+    scores = jax.nn.softmax(logits, axis=-1)
+    return scores, scores
+
+
 def moe_dropless(x, router_w, bias, experts, held_slot, *, top_k,
                  norm_topk=True, scaling=None, tm=None, with_passes=False,
-                 with_choice=False):
+                 with_choice=False, score=sigmoid_scores):
     """The expert block on arrays. ``x`` [T, D]; ``router_w`` [D, E] and
-    ``bias`` [E] float32; ``experts``: one ``(gate [D, F], up [D, F], down
+    ``bias`` [E] float32 (None where the score function takes none);
+    ``experts``: one ``(gate [D, F], up [D, F], down
     [F, D])`` a held expert, or one ``(up, down)`` for experts with no gate,
     whose activation is ``relu(.)^2``; ``held_slot`` int32 [E]: an expert's place in
-    ``experts``, ``len(experts)`` if it is not held here. Returns ``(y
+    ``experts``, ``len(experts)`` if it is not held here. ``score`` is the
+    MODEL's: ``score(logits [T, E] float32, bias) -> (what weighs, what
+    chooses)``, the ``top_k`` largest of the second are taken and weighed by
+    the first (over their sum with ``norm_topk``, times ``scaling``).
+    Returns ``(y
     [T, D], routed (token, held expert) pairs, held experts with a
     token)``, and with ``with_passes`` a fourth: how many times an expert's
     three matrices were streamed; with ``with_choice`` last of all the
@@ -149,10 +175,10 @@ def moe_dropless(x, router_w, bias, experts, held_slot, *, top_k,
 
     t, d = x.shape
     n_held = len(experts)
-    scores = jax.nn.sigmoid(jnp.dot(
+    scores, by = score(jnp.dot(
         x.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))                 # [T, E]
-    _, sel = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        precision=jax.lax.Precision.HIGHEST), bias)           # [T, E]
+    _, sel = jax.lax.top_k(by, top_k)
     w = jnp.take_along_axis(scores, sel, axis=1)              # uncorrected
     if norm_topk:
         w = w / jnp.sum(w, axis=1, keepdims=True)
@@ -297,7 +323,8 @@ class MiMoV2MoE(Layer):
               e.down_proj.weight._data) for e in self.experts],
             self._held_slot,
             top_k=c.num_experts_per_tok, norm_topk=c.norm_topk_prob,
-            scaling=c.routed_scaling_factor, tm=tm, with_passes=True)
+            scaling=c.routed_scaling_factor, tm=tm, with_passes=True,
+            score=sigmoid_scores)
 
     def forward(self, x):
         shape = x.shape

@@ -50,7 +50,7 @@ from ..nn.layer.norm import RMSNorm
 from .mimo_v2 import SERVE_COUNTERS as _MOE_COUNTERS
 from .mimo_v2 import MiMoV2ForCausalLM as _MiMoV2
 from .mimo_v2 import MiMoV2Router as _Router
-from .mimo_v2 import _store_width, moe_dropless
+from .mimo_v2 import _store_width, moe_dropless, sigmoid_scores
 
 __all__ = ["NemotronHConfig", "NemotronHForCausalLM", "nemotron_h_tiny"]
 
@@ -189,7 +189,7 @@ class NemotronHMoE(Layer):
              for e in self.experts],
             self._held_slot, top_k=c.num_experts_per_tok,
             norm_topk=c.norm_topk_prob, scaling=c.routed_scaling_factor,
-            tm=tm, with_passes=True, with_choice=True)
+            tm=tm, with_passes=True, with_choice=True, score=sigmoid_scores)
 
     def serve(self, h, state):
         shape = h.shape
@@ -201,6 +201,17 @@ class NemotronHMoE(Layer):
         state.count("moe_layer_steps", 1)
         state.count("moe_weight_passes", passes)
         return Tensor._wrap(y.reshape(shape)) + self.shared_experts(h)
+
+
+def inverse_softplus_steps(u, step_min, step_max, floor):
+    """``u`` uniform in (0, 1) -> the bias whose softplus is a step size
+    log-uniform in ``[step_min, step_max]``, floored: how the family (and the
+    gated delta layers of ``models/qwen3_next.py``) initialises ``dt_bias``."""
+    import jax.numpy as jnp
+
+    lo, hi = math.log(step_min), math.log(step_max)
+    dt = jnp.maximum(jnp.exp(u.astype(jnp.float32) * (hi - lo) + lo), floor)
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(u.dtype)
 
 
 class NemotronHMamba2(Layer):
@@ -236,13 +247,9 @@ class NemotronHMamba2(Layer):
                                weight_attr=init, bias_attr=False)
 
     def _inverse_softplus_dt(self, u):
-        import jax.numpy as jnp
-
         c = self.config
-        lo, hi = math.log(c.time_step_min), math.log(c.time_step_max)
-        dt = jnp.maximum(jnp.exp(u.astype(jnp.float32) * (hi - lo) + lo),
-                         c.time_step_floor)
-        return (dt + jnp.log(-jnp.expm1(-dt))).astype(u.dtype)
+        return inverse_softplus_steps(u, c.time_step_min, c.time_step_max,
+                                      c.time_step_floor)
 
     def kv_spec(self):
         from ..inference.serving.kv_cache import KVLayerSpec

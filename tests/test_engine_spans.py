@@ -674,6 +674,17 @@ def _mamba2_decode():
                 jnp.zeros((2, 4)), jnp.zeros((2, 2, 16)))
 
 
+def _gated_delta_decode():
+    from paddle_tpu.ops.pallas import gated_delta
+
+    def fn(state, q, v, g):
+        return gated_delta.gated_delta_decode_update(
+            state, jnp.asarray([1, 2], jnp.int32), q, q, v, g, g)
+
+    return fn, (jnp.zeros((3, 4, 16, 8), jnp.float32), jnp.zeros((2, 2, 16)),
+                jnp.zeros((2, 4, 8)), jnp.zeros((2, 4)))
+
+
 #: one entry a ``pl.pallas_call`` site; a site that takes its name from its
 #: wrapper is listed once more under each name the serving path gives it
 #: (the fp decode site, ``_decode_call``, serves K / V and latent pages; the
@@ -693,6 +704,7 @@ KERNELS = [
     ("moe_grouped_swiglu", _grouped_swiglu),
     ("moe_grouped_relu2", lambda: _grouped_swiglu(gated=False)),
     ("mamba2_decode_update", _mamba2_decode),
+    ("gated_delta_decode_update", _gated_delta_decode),
 ]
 
 

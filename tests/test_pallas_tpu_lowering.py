@@ -183,14 +183,54 @@ def _nemotron_cases():
     return cases
 
 
+def _qwen3_next_cases():
+    """ISSUE 37's kernel and the two attention kernels at V 256, at the
+    published widths and the benchmark's batch: the gated delta update over
+    96 rows of 97 slots (32 value heads of 128 over 16 key heads of 128, a
+    head a lane row), the decode kernel over pools held as rows (16 query
+    heads over 2 kv heads, K and V 256 wide, 640 pages a request of 61,441)
+    and the chunk kernel over a 10,240-token row at a 2,048-token chunk."""
+    from paddle_tpu.ops.pallas import gated_delta
+
+    sds = jax.ShapeDtypeStruct
+    b, heads, key_heads, n, p = 96, 32, 16, 128, 128
+
+    def update(*operands):      # the kernel itself, whatever the backend
+        return gated_delta._call(*operands, interpret=False)
+
+    def decode(q, k, v, t, l):
+        return pa.paged_decode_attention_pallas(
+            q, k, v, t, l, 0.0625, num_kv_heads=2,
+            name="paged_decode_attention_global")
+
+    def chunk(q, k, v):
+        return pa.chunk_attention_pallas(q, k, v, 8192, 0, 10240, 0.0625,
+                                         name="chunk_attention_global")
+
+    pool = sds((61441, BLK * 2, 256), jnp.bfloat16)
+    row = sds((10240, 2, 256), jnp.bfloat16)
+    return [
+        ("delta-decode-update", update, (
+            sds((b + 1, heads, n, p), jnp.float32), sds((b,), jnp.int32),
+            sds((b, key_heads, n), jnp.bfloat16),
+            sds((b, key_heads, n), jnp.bfloat16),
+            sds((b, heads, p), jnp.bfloat16), sds((b, heads), jnp.float32),
+            sds((b, heads), jnp.float32))),
+        ("decode-v256-qwen3next", decode, (
+            sds((b, 16, 256), jnp.bfloat16), pool, pool,
+            sds((b, 640), jnp.int32), sds((b,), jnp.int32))),
+        ("chunk-v256-qwen3next", chunk, (
+            sds((2048, 16, 256), jnp.bfloat16), row, row))]
+
+
 CASES = _flash_cases() + _paged_cases() + _grouped_ffn_cases() \
-    + _nemotron_cases() + _chunk_row_cases()
+    + _nemotron_cases() + _chunk_row_cases() + _qwen3_next_cases()
 #: stage 2 keeps tier-1 short: the backward cases (a grad compiles the
 #: forward kernel too), decode, the top rung that VMEM decides, and the
 #: grouped expert kernel (48 operands left in HBM, 48 MiB of VMEM asked for)
 COMPILED_CASES = [c for c in CASES if c[0].startswith(
     ("flash-bwd", "decode", "mq2048", "grouped-ffn", "ssm-decode",
-     "grouped-relu2", "chunk-row"))]
+     "grouped-relu2", "chunk-row", "delta-decode", "chunk-v256"))]
 
 
 @pytest.mark.parametrize("name,fn,args", CASES, ids=[c[0] for c in CASES])
@@ -311,6 +351,8 @@ def test_compiles_for_v5e_without_a_chip():
                        ("grouped-ffn", ["moe_grouped_swiglu"]),
                        ("grouped-relu2", ["moe_grouped_relu2"]),
                        ("ssm-decode", ["mamba2_decode_update"]),
+                       ("delta-decode", ["gated_delta_decode_update"]),
+                       ("chunk-v256", ["chunk_attention_global"]),
                        ("flash-bwd", ["flash_attention_fwd",
                                       "flash_attention_bwd_dq",
                                       "flash_attention_bwd_dkv"])):
@@ -331,7 +373,8 @@ def test_decode_chunk_fits_its_vmem_budget():
     for shape, want in (((16, 8, 32, 128, 2, 256), 16),    # Mistral-7B
                         ((16, 2, 8, 128, 2, 256), 64),     # a tp4 shard
                         ((16, 16, 16, 128, 2, 128), 8),    # llama_1b
-                        ((16, 8, 32, 128, 2, 4), 4)):      # a 4-page table
+                        ((16, 8, 32, 128, 2, 4), 4),       # a 4-page table
+                        ((16, 2, 16, 256, 2, 640, 256), 32)):  # Qwen3-Next
         chunk, nbytes = pa._decode_chunk(*shape)
         assert chunk == want, (shape, chunk)
         assert nbytes <= pa._DECODE_VMEM_BUDGET, (shape, nbytes)
