@@ -1675,7 +1675,17 @@ class LLMEngine:
         return verify_pure
 
     def _build_jits(self):
+        import jax
+
         from ...distributed.plan import compile_step_with_plan
+
+        # a chunk's ids out of the staged prompt with the offset an operand:
+        # one executable a (staging bucket, rung), where a slice at a static
+        # offset was one for every offset, and an offset no warm-up met
+        # compiled inside a measured window
+        self._chunk_ids = jax.jit(
+            lambda ids, start, size: jax.lax.dynamic_slice_in_dim(
+                ids, start, size, axis=1), static_argnames="size")
 
         # Under a multi-device plan every executable's pool outputs are
         # pinned to the layout the pools were committed to, so a donated
@@ -1857,7 +1867,6 @@ class LLMEngine:
         if C is None:
             C = max(b for b in self.prefill_buckets if b <= room)
             take = min(take, C)
-        ids_chunk = ids_dev[:, start:start + C]
         tables_row = np.zeros(self.max_pages, np.int32)
         nblk = min(len(req.blocks), self.max_pages)
         tables_row[:nblk] = req.blocks[:nblk]
@@ -1865,9 +1874,10 @@ class LLMEngine:
         start_a, upto_a = np.int32(start), np.int32(start + take)
         if self._mp:
             # scalars too: a host scalar beside global-mesh arrays would
-            # make jit refuse the mixed-device call (ids_chunk is a view
-            # of the staged ids, already replicated on the global mesh)
+            # make jit refuse the mixed-device call (the staged ids are
+            # already replicated on the global mesh)
             start_a, upto_a = self._g(start_a), self._g(upto_a)
+        ids_chunk = self._chunk_ids(ids_dev, start_a, size=C)
         cache = self.cache
         window_row = slot = None
         if cache.window is not None:

@@ -656,6 +656,17 @@ def paged_multiquery_attention_pallas(q, k_pool, v_pool, block_tables,
 
 #: rows of one chunk-attention tile: query rows x the heads of one kv group
 _CHUNK_TILE_ROWS = 1024
+#: lanes a chunk tile's running max and sum are held in, a row's value in
+#: every lane: as ``[rows, 1]`` each fold reduced into and broadcast out of a
+#: layout of one lane a vreg, which cost a v5e two thirds of a tile's time
+_LANES = 128
+
+
+def _lanes_to(x, width):
+    """``x`` ``[rows, _LANES]`` (one value a row) as ``[rows, width]``."""
+    if width % _LANES == 0:
+        return jnp.tile(x, (1, width // _LANES))
+    return x[:, :1]
 
 
 def _chunk_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, tq, tk, groups, scale,
@@ -663,29 +674,26 @@ def _chunk_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, tq, tk, groups, scale,
     """One ``[groups * tq]``-row query tile of one kv head against one
     ``tk``-key tile: a flash fold with the causal (and, with ``window``,
     banded) mask worked out from absolute positions. ``pos_ref`` holds
-    (position of query row 0, position of key row 0, visible tokens)."""
+    (position of query row 0, position of key row 0, visible tokens).
+    The running max ``m`` and sum ``l`` are held ``[rows, _LANES]``, a
+    row's value in every lane: the numbers ``[rows, 1]`` would hold."""
     if sink:
         sink_ref, o_ref, acc_ref, m_ref, l_ref = rest
     else:
         o_ref, acc_ref, m_ref, l_ref = rest
     i, j = pl.program_id(1), pl.program_id(2)
-    q0 = pos_ref[0] + i * tq
-    k0 = pos_ref[1] + _key_tile(pos_ref, i, j, tq, tk, window) * tk
+    q0, k0, live = _tile(pos_ref, i, j, tq, tk, window)
     upto = pos_ref[2]
 
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         if sink:
-            m_ref[...] = sink_ref[0]
+            m_ref[...] = jnp.broadcast_to(sink_ref[0], m_ref.shape)
             l_ref[...] = jnp.ones_like(l_ref)
         else:
             m_ref[...] = jnp.full_like(m_ref, NEG_INF)
             l_ref[...] = jnp.zeros_like(l_ref)
-
-    lo = q0 - window + 1 if window is not None else 0
-    live = (k0 <= jnp.minimum(q0 + tq, upto) - 1) & (k0 + tk > lo) \
-        & (j < _live_tiles(pos_ref, i, tq, tk, window))
 
     @pl.when(live)
     def _fold():
@@ -700,20 +708,69 @@ def _chunk_kernel(pos_ref, q_ref, k_ref, v_ref, *rest, tq, tk, groups, scale,
         if window is not None:
             ok = ok & (kp > qp - window)
         s = jnp.where(ok, s, NEG_INF)
-        m_prev = m_ref[...]
+        m_prev = m_ref[...]                                  # [rows, lanes]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        pexp = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+        pexp = jnp.where(ok, jnp.exp(s - _lanes_to(m_new, tk)), 0.0)
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * corr + jnp.sum(pexp, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            pexp.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * _lanes_to(corr, acc_ref.shape[1]) + \
+            jax.lax.dot_general(
+                pexp.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _out():
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).reshape(
+        l = _lanes_to(l_ref[...], acc_ref.shape[1])
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).reshape(
             o_ref.shape[1:]).astype(o_ref.dtype)
+
+
+def _chunk_tiles(t, groups, ln, window):
+    """``(tq, tk, n_k)``: query rows a tile (of each of a kv head's
+    ``groups`` query heads), keys a tile and key-tile grid steps a query
+    tile, for a chunk of ``t`` rows over ``ln`` keys."""
+    tq = min(t, max(8, _CHUNK_TILE_ROWS // groups))
+    while t % tq:
+        tq //= 2
+    tk = min(ln, 256 if window is None else tq)
+    while ln % tk:
+        tk //= 2
+    n_k = ln // tk if window is None else min(ln // tk, -(-(window + tq - 1) // tk) + 1)
+    return tq, tk, n_k
+
+
+def _tile(pos, i, j, tq, tk, window):
+    """``(q0, k0, live)`` of grid step ``(i, j)``: the positions of the
+    query tile's first row and of the key tile's first key, and whether
+    some row sees some key of the tile. Elementwise in ``i`` and ``j``, so
+    ``chunk_tile_counts`` takes the whole grid at once."""
+    q0 = pos[0] + i * tq
+    k0 = pos[1] + _key_tile(pos, i, j, tq, tk, window) * tk
+    upto = pos[2]
+    lo = q0 - window + 1 if window is not None else 0
+    live = (k0 <= jnp.minimum(q0 + tq, upto) - 1) & (k0 + tk > lo) \
+        & (j < _live_tiles(pos, i, tq, tk, window))
+    return q0, k0, live
+
+
+def chunk_tile_counts(start, tokens, rung, heads, kv_heads, ln, window=None,
+                      k_start=0):
+    """``(live, dead)`` grid steps of ``chunk_attention_pallas`` for a chunk
+    of ``tokens`` real queries at position ``start``, padded to ``rung``
+    rows, of ``heads`` query heads over ``kv_heads``, against ``ln`` keys in
+    a row from position ``k_start``: the tiles it folds and the steps that
+    fold nothing. By the kernel's own tile arithmetic (``_chunk_tiles``,
+    ``_tile``), so a count cannot drift from the grid: the dead steps are
+    what a grid over live tiles alone would drop, and this is what such a
+    grid is sized by and checked against."""
+    import numpy as np
+
+    tq, tk, n_k = _chunk_tiles(rung, heads // kv_heads, ln, window)
+    i, j = np.arange(rung // tq)[:, None], np.arange(n_k)[None, :]
+    live = _tile((start, k_start, start + tokens), i, j, tq, tk, window)[2]
+    live = kv_heads * int(np.sum(np.broadcast_to(live, (i.size, n_k))))
+    return live, kv_heads * i.size * n_k - live
 
 
 def _live_tiles(pos, i, tq, tk, window):
@@ -726,7 +783,10 @@ def _live_tiles(pos, i, tq, tk, window):
 
 
 def _first_tile(pos, i, tq, tk, window):
-    return jnp.maximum(pos[0] + i * tq - window + 1 - pos[1], 0) // tk
+    """The key tile of the first token query tile ``i``'s window reaches:
+    a tile of negative positions alone holds no token."""
+    lo = jnp.maximum(pos[0] + i * tq - window + 1, 0)
+    return jnp.maximum(lo - pos[1], 0) // tk
 
 
 def _key_tile(pos, i, j, tq, tk, window):
@@ -753,13 +813,7 @@ def chunk_attention_pallas(q, k, v, q_start, k_start, upto, scale, *,
     t, h, dk = q.shape
     ln, hkv, dv = v.shape
     groups = h // hkv
-    tq = min(t, max(8, _CHUNK_TILE_ROWS // groups))
-    while t % tq:
-        tq //= 2
-    tk = min(ln, 256 if window is None else tq)
-    while ln % tk:
-        tk //= 2
-    n_k = ln // tk if window is None else min(ln // tk, -(-(window + tq - 1) // tk) + 1)
+    tq, tk, n_k = _chunk_tiles(t, groups, ln, window)
     pos = jnp.stack([jnp.asarray(x, jnp.int32).reshape(())
                      for x in (q_start, k_start, upto)])
     # [Hkv, G, T, Dk]: a tile's rows are (head of the group, query row)
@@ -792,8 +846,8 @@ def chunk_attention_pallas(q, k, v, q_start, k_start, upto, scale, *,
             out_specs=o_spec,
             scratch_shapes=[
                 pltpu.VMEM((groups * tq, dv), jnp.float32),
-                pltpu.VMEM((groups * tq, 1), jnp.float32),
-                pltpu.VMEM((groups * tq, 1), jnp.float32),
+                pltpu.VMEM((groups * tq, _LANES), jnp.float32),
+                pltpu.VMEM((groups * tq, _LANES), jnp.float32),
             ]),
         out_shape=jax.ShapeDtypeStruct((hkv, groups, t, dv), q.dtype),
         interpret=_interpret(),

@@ -28,7 +28,11 @@ idle seconds by what the host was doing meanwhile (``idle_gaps``: the host
 span or call over each gap, as the ledger's ``breakdown`` lists them), and for
 each kind of decode kernel the share of the chunks it walked in the traced
 steps whose every page was live (ISSUE 32: those are started written out and
-waited for with one descriptor a pool; from the loop's own context lengths).
+waited for with one descriptor a pool; from the loop's own context lengths),
+and for each kind of layer a chunk attends in, the chunk kernel's live and
+dead grid steps over the traced chunks (``chunk_tiles``, from the
+``engine.prefill.chunk`` spans' ``start``, ``tokens`` and ``padded`` through
+``chunk_tile_counts``).
 Run on the chip, any serving cell:
 
     python3 scripts/decode_ahead_microbench.py \
@@ -157,6 +161,37 @@ def full_chunk_shares(cache, heads, max_model_len, lens_by_step):
     return out
 
 
+def chunk_tile_shares(eng, config, chunks):
+    """For each kind of layer a prefill chunk attends in, the chunk kernel's
+    live and dead grid steps over the traced chunks (``engine.prefill.chunk``
+    statistics: ``start``, ``tokens``, and ``padded``, the rung), by
+    ``chunk_tile_counts``. A latent layer's chunk attends over expanded
+    heads; a window layer's row is the ring's tail before the chunk and the
+    chunk."""
+    from paddle_tpu.ops.pallas.paged_attention import chunk_tile_counts
+
+    cache, bs = eng.cache, eng.cache.block_size
+    heads = config["num_attention_heads"]
+    out = {}
+    for spec in dict.fromkeys(sp for sp in cache.layout if sp.paged):
+        window = spec.window if spec.kind == "window" else None
+        hkv = heads if spec.kind == "latent" else spec.num_kv_heads
+        tail = cache.window.n_tail * bs if window else 0
+        live = dead = 0
+        for st in chunks:
+            start, tokens, rung = (int(st[k]) for k in
+                                   ("start", "tokens", "padded"))
+            got = chunk_tile_counts(
+                start, tokens, rung, config.get("swa_num_attention_heads",
+                                                heads) if window else heads,
+                hkv, tail + rung if window else eng.max_pages * bs, window,
+                start - tail if window else 0)
+            live, dead = live + got[0], dead + got[1]
+        out[spec.kind] = {"layers": cache.layout.count(spec), "live": live,
+                          "dead": dead}
+    return out
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
@@ -166,7 +201,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from benchmarks import run as bench
-    from benchmarks.harness import stats, trace_reduce
+    from benchmarks.harness import prefill_spans, stats, trace_reduce
     from benchmarks.runners import common
     from paddle_tpu.inference.serving import LLMEngine
 
@@ -267,6 +302,14 @@ def main(argv=None):
                 config["engine"]["max_model_len"],
                 [lens_of[s[0]] for s in run["traced_steps"]]),
         }
+        # the chunk kernel's grid over the traced chunks
+        spans = prefill_spans.from_record(run)
+        if spans:
+            out["traced"]["chunk_tiles"] = chunk_tile_shares(
+                engines[0], config, [
+                    st for a, _, st in prefill_spans.spans(
+                        spans[0], prefill_spans.CHUNK)
+                    if tr["t0"] <= a < tr["t1"]])
         # the benchmark's own per-layer readings of this run
         out["per_layer"] = {k: v["value"] for k, v in bench.result_line(
             manifest, args.workload, run, True)["metrics"].items()}

@@ -223,14 +223,31 @@ def _qwen3_next_cases():
             sds((2048, 16, 256), jnp.bfloat16), row, row))]
 
 
+def _joyai_chunk_cases():
+    """The chunk kernel at JoyAI-LLM-Flash's expanded heads (32 query heads
+    over 32, K 192 stored 256, V 128), a 2,048-token chunk at offset 8,192
+    of a 12,288-key row: most of its key tiles fold without a mask."""
+    sds = jax.ShapeDtypeStruct
+
+    def chunk(q, k, v):
+        return pa.chunk_attention_pallas(q, k, v, 8192, 0, 10240, 0.072,
+                                         name="chunk_attention_global")
+
+    return [("chunk-k256v128-joyai", chunk, (
+        sds((2048, 32, 256), jnp.bfloat16), sds((12288, 32, 256), jnp.bfloat16),
+        sds((12288, 32, 128), jnp.bfloat16)))]
+
+
 CASES = _flash_cases() + _paged_cases() + _grouped_ffn_cases() \
-    + _nemotron_cases() + _chunk_row_cases() + _qwen3_next_cases()
+    + _nemotron_cases() + _chunk_row_cases() + _qwen3_next_cases() \
+    + _joyai_chunk_cases()
 #: stage 2 keeps tier-1 short: the backward cases (a grad compiles the
 #: forward kernel too), decode, the top rung that VMEM decides, and the
 #: grouped expert kernel (48 operands left in HBM, 48 MiB of VMEM asked for)
 COMPILED_CASES = [c for c in CASES if c[0].startswith(
     ("flash-bwd", "decode", "mq2048", "grouped-ffn", "ssm-decode",
-     "grouped-relu2", "chunk-row", "delta-decode", "chunk-v256"))]
+     "grouped-relu2", "chunk-row", "delta-decode", "chunk-v256",
+     "chunk-k256v128"))]
 
 
 @pytest.mark.parametrize("name,fn,args", CASES, ids=[c[0] for c in CASES])
@@ -353,6 +370,7 @@ def test_compiles_for_v5e_without_a_chip():
                        ("ssm-decode", ["mamba2_decode_update"]),
                        ("delta-decode", ["gated_delta_decode_update"]),
                        ("chunk-v256", ["chunk_attention_global"]),
+                       ("chunk-k256v128", ["chunk_attention_global"]),
                        ("flash-bwd", ["flash_attention_fwd",
                                       "flash_attention_bwd_dq",
                                       "flash_attention_bwd_dkv"])):
