@@ -605,7 +605,15 @@ class KVLayerSpec:
     dtype) and a recurrent state of ``num_kv_heads`` heads x ``v_dim`` x
     ``state_dim`` in float32: :meth:`state_shapes` says how the cache holds
     them; ``scan_block`` is the tokens a block of a prefill chunk's scan
-    takes. A ``"none"`` layer states no width."""
+    takes. With ``state_dim == 0`` (a short convolution) it keeps
+    the tail alone: no recurrent state is held. A ``"none"`` layer states
+    no width.
+
+    A global ``"linear"`` layer whose heads are narrower than the lanes
+    (``heads_a_lane_row`` > 1: K and V 64 wide, an even number of kv heads)
+    holds ``pack`` kv heads side by side in a row of 128: a page is ``block
+    * Hkv / pack`` rows, the bytes of ``[block, Hkv, D]`` with no lane
+    padding, and the decode and chunk kernels read it in that form."""
     kind: str = "global"            # global | window | latent | state | none
     num_kv_heads: int = 1
     k_dim: int = 128
@@ -627,11 +635,12 @@ class KVLayerSpec:
                 "a latent layer caches one row a token (num_kv_heads 1), "
                 "its first v_dim values the values, read in a row by a "
                 "chunk (prefill='linear')")
-        if (self.kind == "state") != (self.state_dim > 0) or (
+        if (self.kind != "state" and self.state_dim > 0) or (
                 self.kind == "state" and self.conv_rows < 1):
             raise ValueError(
                 "a state kind, and only it, states the rows of its "
-                "convolution tail and the width of its recurrent state")
+                "convolution tail (at least one) and the width of its "
+                "recurrent state (0: none)")
         if (self.kind == "window") != (self.window is not None):
             raise ValueError("a window kind, and only it, states a window")
         if self.kind == "window" and self.prefill != "linear":
@@ -654,14 +663,22 @@ class KVLayerSpec:
         the whole pool (ISSUE 27, the deviceless compile's HLO)."""
         if self.prefill == "paged":
             return (n, block_size, self.num_kv_heads, width)
-        return (n, block_size * self.num_kv_heads, width)
+        pack = self.heads_a_lane_row
+        return (n, block_size * self.num_kv_heads // pack, width * pack)
 
     @property
     def heads_a_lane_row(self):
-        """Heads of a state kind that lie side by side in the lanes: a head
-        ``v_dim`` wide fills ``v_dim`` of 128 lanes, so ``128 // v_dim`` of
-        them share a row where the head count allows it."""
+        """Heads that lie side by side in the lanes: a head ``v_dim`` wide
+        fills ``v_dim`` of 128 lanes, so ``128 // v_dim`` of them share a
+        row where the head count allows it. A state kind's recurrent state
+        is held so; a global kind's pages are where K is as wide as V and
+        the pool is held in rows (``prefill == "linear"``), and only at a
+        width of 64, the one the kernels take in this form."""
         pack = 128 // self.v_dim if 0 < self.v_dim < 128 else 1
+        if self.kind != "state" and not (
+                self.kind == "global" and self.prefill == "linear"
+                and self.k_dim == self.k_store == self.v_dim == 64):
+            return 1
         return pack if pack > 0 and self.num_kv_heads % pack == 0 else 1
 
     def state_shapes(self, slots):
@@ -671,11 +688,13 @@ class KVLayerSpec:
         tile of sixteen). The state lies TRANSPOSED, ``[slots, H / pack, N,
         pack * P]``: the state dim over the sublanes and ``pack`` heads
         (``heads_a_lane_row``) side by side in the lanes, so that a decode
-        step's ``y = h C`` sums over sublanes and ``x`` lies as it comes."""
+        step's ``y = h C`` sums over sublanes and ``x`` lies as it comes. A
+        kind that holds the tail alone (``state_dim == 0``) has no state:
+        None in its place."""
         pack = self.heads_a_lane_row
         return ((slots, self.conv_rows * self.k_dim),
                 (slots, self.num_kv_heads // pack, self.state_dim,
-                 pack * self.v_dim))
+                 pack * self.v_dim) if self.state_dim else None)
 
     def state_bytes(self, itemsize=2):
         """What ONE request holds in this layer whatever its length: the
@@ -887,11 +906,14 @@ class PagedKVCache:
             """What stands in the K list (0) or the V list (1) for ``sp``:
             a pool of pages, a state kind's convolution tails (model's
             dtype) or its recurrent states (float32), or an empty array that
-            keeps the place (a latent layer's V, a none layer's both)."""
-            if sp.kind == "state":
-                return jnp.zeros(sp.state_shapes(self.state_slots)[which],
-                                 jnp.float32 if which else dtype)
-            if sp.kind == "none" or (which and sp.kind == "latent"):
+            keeps the place (a latent layer's V, a none layer's both, the
+            V of a state kind that holds a tail alone)."""
+            shape = sp.state_shapes(self.state_slots)[which] \
+                if sp.kind == "state" else None
+            if shape:
+                return jnp.zeros(shape, jnp.float32 if which else dtype)
+            # a tail-only state kind's V, too
+            if sp.kind in ("none", "state") or (which and sp.kind == "latent"):
                 return jnp.zeros((0,), pool_dtype)
             return pool(sp, sp.v_dim if which else sp.k_store)
 

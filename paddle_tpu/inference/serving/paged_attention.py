@@ -25,7 +25,9 @@ from ...nn.functional.flash_attention import _sdpa_ref
 
 __all__ = ["paged_decode_attention", "paged_decode_attention_latent",
            "paged_multiquery_attention", "paged_chunk_attention",
-           "chunk_reads_in_a_row", "chunk_attention", "kv_pool_specs",
+           "chunk_reads_in_a_row", "chunk_attention",
+           "paged_decode_attention_pairs", "chunk_attention_pairs",
+           "kv_pool_specs",
            "ChunkAttnState", "DecodeAttnState"]
 
 
@@ -346,6 +348,50 @@ def chunk_attention(q, k, v, q_start, k_start, upto, scale, *, window=None,
                              float(scale), sink)[0]
 
 
+def _unpaired(a, num_kv_heads):
+    """Rows of two 64-wide kv heads ``[..., n * Hkv / 2, 128]`` as ``[...,
+    n, Hkv, 64]``: the same bytes, read a head at a time."""
+    lead = a.shape[:-2]
+    return a.reshape(*lead, -1, num_kv_heads, a.shape[-1] // 2)
+
+
+def paged_decode_attention_pairs(q, k_pool, v_pool, block_tables,
+                                 context_lens, scale, num_kv_heads,
+                                 name="paged_decode_attention_h64"):
+    """``paged_decode_attention`` over pools of two 64-wide kv heads a lane
+    row (``KVLayerSpec.heads_a_lane_row``): q ``[B, 1, H, 64]``;
+    pools ``[N, block * Hkv / 2, 128]``. Returns ``[B, 1, H, 64]``. The
+    decode kernel in that form on the TPU, the gather here."""
+    from ...ops.pallas.paged_attention import (
+        paged_decode_attention_pairs_pallas, use_pallas_paged)
+
+    block_size = k_pool.shape[1] * 2 // num_kv_heads
+    if use_pallas_paged(k_pool.shape[-1], block_size):
+        return paged_decode_attention_pairs_pallas(
+            q[:, 0], k_pool, v_pool, block_tables, context_lens, scale,
+            num_kv_heads=num_kv_heads, name=name)[:, None]
+    return _lax_fallback(q, _unpaired(k_pool, num_kv_heads),
+                         _unpaired(v_pool, num_kv_heads), block_tables,
+                         context_lens, float(scale))
+
+
+def chunk_attention_pairs(q, k, v, q_start, k_start, upto, scale,
+                          num_kv_heads, *, block_size=8,
+                          name="chunk_attention_h64"):
+    """``chunk_attention`` over keys of two 64-wide kv heads a lane row: q
+    ``[T, H, 64]``, k and v ``[L, Hkv / 2, 128]``. Causal, no window."""
+    from ...ops.pallas.paged_attention import (chunk_attention_pairs_pallas,
+                                               use_pallas_paged)
+
+    if use_pallas_paged(k.shape[-1], block_size):
+        return chunk_attention_pairs_pallas(
+            q, k, v, q_start, k_start, upto, float(scale),
+            num_kv_heads=num_kv_heads, name=name)
+    return chunk_attention(q, k.reshape(k.shape[0], num_kv_heads, -1),
+                           v.reshape(v.shape[0], num_kv_heads, -1), q_start,
+                           k_start, upto, scale, block_size=block_size)
+
+
 # ---------------------------------------------------------------------------
 # the attention-state handles a model's layer step is given (ISSUE 27)
 # ---------------------------------------------------------------------------
@@ -376,7 +422,11 @@ def chunk_attention(q, k, v, q_start, k_start, upto, scale, *, window=None,
 # its decays and its ``beta``, and gets back ``S_t^T q_t``; the slot, its
 # tail, the first chunk's zeros and the null slot are ``scan``'s, as code. A
 # layer that keeps nothing (kind ``"none"``) is handed a state too, for
-# ``state.count``; it calls nothing else of it.
+# ``state.count``; it calls nothing else of it. A gated short convolution
+# keeps the tail alone (``state_dim == 0``): ``state.shortconv``
+# hands over what enters the convolution and gets it back convolved, no
+# bias, no activation; the slot, its tail, the first chunk's zeros and the
+# null slot are ``scan``'s again.
 
 
 def _pad_last(x, width):
@@ -386,20 +436,26 @@ def _pad_last(x, width):
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, extra)])
 
 
-def _conv_silu(shifted, conv_w, conv_b=None):
-    """The causal depthwise convolution and its ``silu``. ``shifted``: the
-    ``K`` arrays ``[..., D]`` whose row ``t`` is the row ``K - 1 - j`` before
-    token ``t``'s own (oldest first, the last the tokens themselves);
-    ``conv_w [D, K]``, ``conv_b [D]`` or None. Returns ``silu(sum_j w[:, j] *
-    shifted[j] + b)`` in the rows' dtype."""
-    import jax
-
+def _conv(shifted, conv_w, conv_b=None):
+    """The causal depthwise convolution in float32. ``shifted``: the ``K``
+    arrays ``[..., D]`` whose row ``t`` is the row ``K - 1 - j`` before token
+    ``t``'s own (oldest first, the last the tokens themselves); ``conv_w [D,
+    K]``, ``conv_b [D]`` or None. Returns ``sum_j w[:, j] * shifted[j] +
+    b``."""
     f32 = jnp.float32
     out = None if conv_b is None else conv_b.astype(f32)
     for j, rows in enumerate(shifted):
         term = rows.astype(f32) * conv_w[:, j].astype(f32)
         out = term if out is None else out + term
-    return jax.nn.silu(out).astype(shifted[-1].dtype)
+    return out
+
+
+def _conv_silu(shifted, conv_w, conv_b=None):
+    """``_conv`` and its ``silu``, in the rows' dtype."""
+    import jax
+
+    return jax.nn.silu(_conv(shifted, conv_w, conv_b)).astype(
+        shifted[-1].dtype)
 
 
 def _conv_and_split(spec, shifted, conv_w, conv_b):
@@ -515,8 +571,9 @@ class DecodeAttnState(_AttnState):
                 for j in range(spec.conv_rows + 1)]
 
     def _live_rows(self):
-        """Rows of the batch whose slot is not the null slot."""
-        return jnp.sum(self.slots != self.v_pool.shape[0] - 1)
+        """Rows of the batch whose slot is not the null slot (the last of
+        the tails, which every state kind holds)."""
+        return jnp.sum(self.slots != self.k_pool.shape[0] - 1)
 
     def scan(self, xbc, dt, a, conv_w, conv_b):
         """A state-space layer's step (ISSUE 33), one token a row. ``xbc
@@ -555,6 +612,19 @@ class DecodeAttnState(_AttnState):
         self.count("delta_state_rows_updated", self._live_rows())
         return o[:, None]
 
+    def shortconv(self, bx, conv_w):
+        """A gated short convolution's step, one token a row:
+        ``bx [B, 1, D]``, what enters the convolution (the input gate
+        already applied), ``conv_w [D, K]``. The tail as ``scan``'s; there
+        is no recurrence. Returns ``[B, 1, D]`` in ``bx``'s dtype: the
+        convolution, no bias, no activation."""
+        self._require("state")
+        out = _conv(self._shifted(bx), conv_w).astype(bx.dtype)
+        live = self._live_rows()
+        self.count("conv_tail_rows_updated", live)
+        self.count("conv_tokens_convolved", live)
+        return out[:, None]
+
     def attend(self, q, k, v, scale, sink=None):
         import jax
 
@@ -576,12 +646,19 @@ class DecodeAttnState(_AttnState):
             qk, sk = quantize_kv_rows(ka)   # [B,1,Hkv,D]
             qv, sv = quantize_kv_rows(va)
         if spec.prefill != "paged":
-            # one scatter a pool, of a token's Hkv rows a request; the
-            # rows of empty slots all land on the null block's first
-            hkv = spec.num_kv_heads
+            # one scatter a pool, of a token's Hkv rows a request (Hkv /
+            # pack with heads a lane row); the rows of empty slots all land
+            # on the null block's first
+            hkv = spec.num_kv_heads // spec.heads_a_lane_row
             rows = off[:, None] * hkv + jnp.arange(hkv)[None, :]
-            kp = kp.at[blk[:, None], rows].set(ka[:, 0].astype(kp.dtype))
-            vp = vp.at[blk[:, None], rows].set(va[:, 0].astype(vp.dtype))
+            if hkv == spec.num_kv_heads:
+                kp = kp.at[blk[:, None], rows].set(ka[:, 0].astype(kp.dtype))
+                vp = vp.at[blk[:, None], rows].set(va[:, 0].astype(vp.dtype))
+            else:
+                kp = kp.at[blk[:, None], rows].set(
+                    ka[:, 0].reshape(bsz, hkv, -1).astype(kp.dtype))
+                vp = vp.at[blk[:, None], rows].set(
+                    va[:, 0].reshape(bsz, hkv, -1).astype(vp.dtype))
         else:
             for i in range(bsz):
                 if self.quantized:
@@ -601,6 +678,13 @@ class DecodeAttnState(_AttnState):
                         vp, va[i:i + 1].astype(vp.dtype),
                         (blk[i], off[i], 0, 0))
         self.k_pool, self.v_pool, self.k_scale, self.v_scale = kp, vp, ksc, vsc
+        if spec.heads_a_lane_row > 1:
+            # a slot with no request reads the null page: not a live row
+            self.count("attn_tokens_read", jnp.sum(
+                jnp.where(tables[:, 0] != 0, positions + 1, 0)))
+            return paged_decode_attention_pairs(
+                qa, kp, vp, tables, positions + 1, scale, spec.num_kv_heads,
+                name=f"paged_decode_attention_h{spec.v_dim}")
         return paged_decode_attention(
             qa, kp, vp, tables, positions + 1, scale=scale,
             k_scale=ksc, v_scale=vsc, window=spec.window,
@@ -724,6 +808,17 @@ class ChunkAttnState(_AttnState):
         self.count("delta_tokens_scanned", real)
         return o[None]
 
+    def shortconv(self, bx, conv_w):
+        """A gated short convolution's chunk (``DecodeAttnState.shortconv``
+        has the operands, ``[1, C, D]`` here). The tail carried over the
+        chunk boundary and the first chunk's zeros are ``_shifted``'s; a
+        position at or past ``upto`` reaches no real one, which is causal."""
+        self._require("state")
+        shifted, _, real = self._shifted(bx, self.slot[0])
+        self.count("conv_tail_rows_updated", 1)
+        self.count("conv_tokens_convolved", real)
+        return _conv(shifted, conv_w).astype(bx.dtype)[None]
+
     def rope(self, x, cos_t, sin_t):
         """Rotate ``x [1, C, H, D]``, whose rows sit at ``start + i``."""
         from ...models.llama import _rope_apply_at
@@ -747,8 +842,10 @@ class ChunkAttnState(_AttnState):
             return a.reshape((pages, bs) + a.shape[2:])
 
         def in_a_row(a):
-            """Pages ``[n, block * Hkv, D]`` as tokens ``[n * block, Hkv, D]``."""
-            return a.reshape(-1, spec.num_kv_heads, a.shape[-1])
+            """Pages ``[n, block * Hkv, D]`` as tokens ``[n * block, Hkv, D]``
+            (``Hkv / pack`` rows a token with heads a lane row)."""
+            return a.reshape(-1, spec.num_kv_heads // spec.heads_a_lane_row,
+                             a.shape[-1])
 
         def as_pages(a):
             """The chunk's rows ``[1, C, Hkv, D]`` as pages of the pool's
@@ -798,6 +895,12 @@ class ChunkAttnState(_AttnState):
                 qa, kp, vp, self.tables_row, start, upto, scale, ksc, vsc)
         # one request's pages in a row: a few tens of MB at the longest
         # context, against the chunk's own matmuls
+        if spec.heads_a_lane_row > 1:
+            return chunk_attention_pairs(
+                qa[0], in_a_row(kp[self.tables_row]),
+                in_a_row(vp[self.tables_row]), start, 0, upto, scale,
+                spec.num_kv_heads, block_size=bs,
+                name=f"chunk_attention_h{spec.v_dim}")[None]
         return chunk_attention(
             qa[0], in_a_row(kp[self.tables_row]),
             in_a_row(vp[self.tables_row]), start, 0, upto, scale, sink=sink,

@@ -24,3 +24,6 @@ from .nemotron_h import (  # noqa: F401
 from .qwen3_next import (  # noqa: F401
     Qwen3NextConfig, Qwen3NextForCausalLM, qwen3_next_tiny,
 )
+from .lfm2_moe import (  # noqa: F401
+    Lfm2MoeConfig, Lfm2MoeForCausalLM, lfm2_moe_tiny,
+)

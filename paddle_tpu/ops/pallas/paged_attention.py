@@ -81,6 +81,19 @@ keeps the per-page kernel** (``_int8_page_kernel``: grid ``(B, P)``, one
 pool block a step through the ``BlockSpec`` pipeline, a VPU fold), chosen
 by the scale operands being there.
 
+**Heads of 64, two a lane row.** A pool whose rows are 64 wide
+would be padded to the 128 lanes in HBM, and every page copied twice the
+bytes it holds. A global layer with K and V 64 wide holds two kv heads side
+by side instead (``KVLayerSpec.heads_a_lane_row``): ``[N, block * Hkv / 2,
+128]``, the bytes of ``[N, block, Hkv, 64]``. The kernels above take that
+form as it is, as ``Hkv / 2`` "kv heads" of 128 and ``2 x groups`` query
+heads a kv head: a query head of an even kv head enters as ``[q, 0]`` and
+one of an odd as ``[0, q]``, so its 128-lane score is its 64-lane one, and
+``p . V`` lands its head's values in its own half of the lanes, which the
+wrapper keeps (``paged_decode_attention_pairs_pallas``,
+``chunk_attention_pairs_pallas``). A page is read from HBM once; the zero
+half spends MXU passes a 64-deep dot would leave idle anyway.
+
 The multi-query kernel (speculative verify, and a prefill chunk over int8
 codes; since ISSUE 36 a chunk over unquantized pools reads the request's
 pages in a row through ``chunk_attention_pallas`` whatever the pool's
@@ -103,7 +116,8 @@ from .flash_attention import NEG_INF, _interpret
 __all__ = ["paged_decode_attention_pallas",
            "paged_decode_attention_latent_pallas",
            "paged_multiquery_attention_pallas", "chunk_attention_pallas",
-           "use_pallas_paged"]
+           "paged_decode_attention_pairs_pallas",
+           "chunk_attention_pairs_pallas", "use_pallas_paged"]
 
 
 def use_pallas_paged(head_dim, block_size):
@@ -120,7 +134,8 @@ def use_pallas_paged(head_dim, block_size):
             f"the paged Pallas kernels take head_dim % 128 == 0 and "
             f"block_size % 8 == 0; got head_dim={head_dim}, "
             f"block_size={block_size} (pad the stored width, as "
-            "KVLayerSpec.k_store does)")
+            "KVLayerSpec.k_store does, or hold heads of 64 two a lane "
+            "row, as KVLayerSpec.heads_a_lane_row does)")
     return True
 
 
@@ -456,6 +471,46 @@ def paged_decode_attention_latent_pallas(q, pool, block_tables, context_lens,
         context_lens.astype(jnp.int32), q, None, (pool,),
         block_size=block_size, chunk=chunk, scale=float(scale), window=None,
         ring=False, latent=int(v_dim), interpret=_interpret(), name=name)
+
+
+def _pair_queries(q, hkv):
+    """``q [..., H, 64]`` -> ``[..., H, 128]``: a query head of an even kv
+    head (``h // (H / hkv)``) as ``[q, 0]``, one of an odd kv head as ``[0,
+    q]``, the half of the lane row its kv head lies in."""
+    h = q.shape[-2]
+    odd = (jnp.arange(h) // (h // hkv)) % 2 == 1
+    zero = jnp.zeros_like(q)
+    return jnp.where(odd[:, None], jnp.concatenate([zero, q], -1),
+                     jnp.concatenate([q, zero], -1))
+
+
+def _pair_outputs(out, hkv):
+    """``out [..., H, 128]`` -> ``[..., H, 64]``: each query head's half."""
+    h, d = out.shape[-2], out.shape[-1] // 2
+    odd = (jnp.arange(h) // (h // hkv)) % 2 == 1
+    return jnp.where(odd[:, None], out[..., d:], out[..., :d])
+
+
+def paged_decode_attention_pairs_pallas(q, k_pool, v_pool, block_tables,
+                                        context_lens, scale, *, num_kv_heads,
+                                        name="paged_decode_attention_h64"):
+    """Decode over pools of two 64-wide kv heads a lane row (the module
+    docstring): q ``[B, H, 64]``; pools ``[N, block * Hkv / 2, 128]``;
+    block_tables ``[B, P]``, context_lens ``[B]`` int32. Returns ``[B, H,
+    64]``. ``_kernel`` as it is, over ``Hkv / 2`` kv heads of 128."""
+    b, h, d = q.shape
+    pairs = int(num_kv_heads) // 2
+    n, rows, width = k_pool.shape
+    block_size = rows // pairs
+    chunk, _ = _decode_chunk(block_size, pairs, h, width,
+                             k_pool.dtype.itemsize, block_tables.shape[1])
+    out = _decode_call(
+        block_tables.reshape(-1).astype(jnp.int32),
+        context_lens.astype(jnp.int32), _pair_queries(q, num_kv_heads), None,
+        (k_pool, v_pool), block_size=block_size, chunk=chunk,
+        scale=float(scale), window=None, ring=False, latent=None,
+        interpret=_interpret(), name=name)
+    return _pair_outputs(out, num_kv_heads)
 
 
 # a jit of its own, as ``grouped_ffn._call``: a model's layers of one kind
@@ -854,3 +909,14 @@ def chunk_attention_pallas(q, k, v, q_start, k_start, upto, scale, *,
         name=name,
     )(pos, *operands)
     return jnp.transpose(out, (2, 0, 1, 3)).reshape(t, h, dv)
+
+
+def chunk_attention_pairs_pallas(q, k, v, q_start, k_start, upto, scale, *,
+                                 num_kv_heads, name="chunk_attention_h64"):
+    """``chunk_attention_pallas`` over keys of two 64-wide kv heads a lane
+    row (the module docstring): q ``[T, H, 64]``; k and v ``[L, Hkv / 2,
+    128]``, a page's rows laid out in a row. Returns ``[T, H, 64]``; rows
+    past ``upto`` are undefined. Causal, no window, no sink."""
+    out = chunk_attention_pallas(_pair_queries(q, num_kv_heads), k, v,
+                                 q_start, k_start, upto, scale, name=name)
+    return _pair_outputs(out, num_kv_heads)

@@ -238,16 +238,40 @@ def _joyai_chunk_cases():
         sds((12288, 32, 128), jnp.bfloat16)))]
 
 
+def _lfm2_cases():
+    """Heads of 64 held two kv heads a lane row, at
+    lfm2-8b-a1b-serve's geometry: the decode kernel over 128 requests of up
+    to 1,280 pages of 81,921 (32 query heads over 8 kv heads), and a 2,048-row
+    chunk over a request's 20,480 keys in a row."""
+    sds = jax.ShapeDtypeStruct
+    pool = sds((81921, BLK * 4, 128), jnp.bfloat16)
+
+    def decode(q, k, v, t, ln):
+        return pa.paged_decode_attention_pairs_pallas(q, k, v, t, ln, 0.125,
+                                                      num_kv_heads=8)
+
+    def chunk(q, k, v):
+        return pa.chunk_attention_pairs_pallas(q, k, v, 18432, 0, 20480, 0.125,
+                                               num_kv_heads=8)
+
+    keys = sds((20480, 4, 128), jnp.bfloat16)
+    return [("decode-h64-lfm2", decode, (
+        sds((128, 32, 64), jnp.bfloat16), pool, pool,
+        sds((128, 1280), jnp.int32), sds((128,), jnp.int32))),
+        ("chunk-h64-lfm2", chunk, (sds((2048, 32, 64), jnp.bfloat16),
+                                   keys, keys))]
+
+
 CASES = _flash_cases() + _paged_cases() + _grouped_ffn_cases() \
     + _nemotron_cases() + _chunk_row_cases() + _qwen3_next_cases() \
-    + _joyai_chunk_cases()
+    + _joyai_chunk_cases() + _lfm2_cases()
 #: stage 2 keeps tier-1 short: the backward cases (a grad compiles the
 #: forward kernel too), decode, the top rung that VMEM decides, and the
 #: grouped expert kernel (48 operands left in HBM, 48 MiB of VMEM asked for)
 COMPILED_CASES = [c for c in CASES if c[0].startswith(
     ("flash-bwd", "decode", "mq2048", "grouped-ffn", "ssm-decode",
      "grouped-relu2", "chunk-row", "delta-decode", "chunk-v256",
-     "chunk-k256v128"))]
+     "chunk-k256v128", "chunk-h64"))]
 
 
 @pytest.mark.parametrize("name,fn,args", CASES, ids=[c[0] for c in CASES])
@@ -371,6 +395,8 @@ def test_compiles_for_v5e_without_a_chip():
                        ("delta-decode", ["gated_delta_decode_update"]),
                        ("chunk-v256", ["chunk_attention_global"]),
                        ("chunk-k256v128", ["chunk_attention_global"]),
+                       ("decode-h64", ["paged_decode_attention_h64"]),
+                       ("chunk-h64", ["chunk_attention_h64"]),
                        ("flash-bwd", ["flash_attention_fwd",
                                       "flash_attention_bwd_dq",
                                       "flash_attention_bwd_dkv"])):
@@ -392,7 +418,8 @@ def test_decode_chunk_fits_its_vmem_budget():
                         ((16, 2, 8, 128, 2, 256), 64),     # a tp4 shard
                         ((16, 16, 16, 128, 2, 128), 8),    # llama_1b
                         ((16, 8, 32, 128, 2, 4), 4),       # a 4-page table
-                        ((16, 2, 16, 256, 2, 640, 256), 32)):  # Qwen3-Next
+                        ((16, 2, 16, 256, 2, 640, 256), 32),   # Qwen3-Next
+                        ((16, 4, 32, 128, 2, 1280), 32)):  # LFM2, lane pairs
         chunk, nbytes = pa._decode_chunk(*shape)
         assert chunk == want, (shape, chunk)
         assert nbytes <= pa._DECODE_VMEM_BUDGET, (shape, nbytes)
